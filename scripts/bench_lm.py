@@ -10,9 +10,8 @@ evidence for the attention stack.
     python scripts/bench_lm.py [--seq-len 2048] [--batch 8] [--depth 4]
 
 Synchronization: fetch a parameter element that is data-dependent on
-the last step's update (jax.block_until_ready on a small output can
-return before chained computation finishes on this platform — see
-bench.py).
+the last step's update (see bench.py). Exits non-zero off the TPU or
+on a chip that is not in the peak table.
 """
 
 from __future__ import annotations
@@ -29,12 +28,10 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from _chip import require_tpu  # noqa: E402
+from tpunet.utils.cache import enable_persistent_compile_cache  # noqa: E402
+
+enable_persistent_compile_cache()
 
 
 def analytic_train_flops(b: int, t: int, c: int, depth: int,
@@ -60,8 +57,10 @@ _PEAK_FLOPS = (       # bf16 peak per chip (same table as bench.py)
 
 
 def peak_flops_per_chip() -> float:
+    """Peak of this chip (``require_tpu(_PEAK_FLOPS)`` has already
+    refused a kind the table lacks)."""
     kind = jax.devices()[0].device_kind.lower()
-    return next((v for k, v in _PEAK_FLOPS if k in kind), 0.0)
+    return next(v for k, v in _PEAK_FLOPS if k in kind)
 
 
 def main() -> None:
@@ -88,6 +87,7 @@ def main() -> None:
                         "recipe: without it, backward residuals are "
                         "O(T^2) for every attention impl)")
     args = p.parse_args()
+    device = require_tpu(_PEAK_FLOPS)
 
     from tpunet.config import ModelConfig, OptimConfig
     from tpunet.models import create_model, init_variables
@@ -178,8 +178,8 @@ def main() -> None:
                    "seq_len": args.seq_len,
                    "hidden": args.hidden, "depth": args.depth,
                    "heads": args.heads, "remat": args.remat,
-                   "attention_block": args.attention_block,
-                   "platform": jax.devices()[0].platform},
+                   "attention_block": args.attention_block},
+        **device,
         "value": results,
         "unit": "tok/s",
         "analytic_flops_per_step": flops_step,

@@ -51,6 +51,18 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 
+def _answer(req, timeout: float = 600.0):
+    """The finished request's tokens. A request that ended in error
+    raises: ``Request.result`` returns the tokens whatever the finish
+    reason, so a dead engine would otherwise read as an (empty) answer
+    and a fast one."""
+    tokens = req.result(timeout=timeout)
+    if req.finish_reason == "error" or req.error:
+        raise RuntimeError(f"request {req.id} finished "
+                           f"{req.finish_reason!r}: {req.error}")
+    return tokens
+
+
 def pct(xs, q):
     if not xs:
         return None
@@ -96,7 +108,7 @@ def run_level(engine, concurrency, *, prompt_len, new_tokens,
                 req = engine.submit(prompts[i],
                                     max_new_tokens=new_tokens,
                                     trace_id=tid)
-                req.result(timeout=600)
+                _answer(req)
                 ttfts.append(req.ttft_s)
                 e2es.append(req.e2e_s)
                 if req.queue_s is not None:
@@ -228,7 +240,7 @@ def run_cold_start_child(args) -> None:
     try:
         req = engine.submit(np.zeros(args.prompt_len, np.int32),
                             max_new_tokens=1)
-        req.result(timeout=600)
+        _answer(req)
         cold_start = req.first_token_t - t0
     finally:
         engine.stop()
@@ -371,8 +383,8 @@ def run_slots_sweep(args, model, variables) -> dict:
                      sweep_slots} - {0})
     rows = []
     try:
-        engine.submit(np.zeros(args.prompt_len, np.int32),
-                      max_new_tokens=2).result(timeout=600)
+        _answer(engine.submit(np.zeros(args.prompt_len, np.int32),
+                      max_new_tokens=2))
         for c in levels:
             engine.peak_active_slots = 0
             r = run_level(engine, c, prompt_len=args.prompt_len,
@@ -444,7 +456,7 @@ def _run_prefix_variant(engine, shared, plans, *, new_tokens):
     # shared prefix, so the measurement sees steady-state hits rather
     # than the one-time cold miss.
     warm = np.concatenate([shared, np.zeros(1, np.int32)])
-    engine.submit(warm, max_new_tokens=2).result(timeout=600)
+    _answer(engine.submit(warm, max_new_tokens=2))
     base = engine.registry.snapshot()
     ttfts, shared_ttfts, e2es = [], [], []
     errors = []
@@ -454,7 +466,7 @@ def _run_prefix_variant(engine, shared, plans, *, new_tokens):
         try:
             for is_shared, p in plans[i]:
                 req = engine.submit(p, max_new_tokens=new_tokens)
-                req.result(timeout=600)
+                _answer(req)
                 ttfts.append(req.ttft_s)
                 if is_shared:
                     shared_ttfts.append(req.ttft_s)
@@ -597,7 +609,7 @@ def _run_spec_variant(engine, plans, *, new_tokens):
     warm_new = 2
     if getattr(engine, "spec_decode", False):
         warm_new = new_tokens
-    engine.submit(warm, max_new_tokens=warm_new).result(timeout=600)
+    _answer(engine.submit(warm, max_new_tokens=warm_new))
     base = engine.registry.snapshot()
     ttfts, e2es, errors = [], [], []
     done_tokens = [0] * len(plans)
@@ -606,7 +618,7 @@ def _run_spec_variant(engine, plans, *, new_tokens):
         try:
             for p in plans[i]:
                 req = engine.submit(p, max_new_tokens=new_tokens)
-                req.result(timeout=600)
+                _answer(req)
                 ttfts.append(req.ttft_s)
                 e2es.append(req.e2e_s)
                 done_tokens[i] += len(req.tokens)
@@ -1160,8 +1172,8 @@ def main() -> None:
     engine = Engine(model, variables, cfg).start()
     try:
         # warm prefill + decode programs outside the measurement
-        engine.submit(np.zeros(args.prompt_len, np.int32),
-                      max_new_tokens=2).result(timeout=600)
+        _answer(engine.submit(np.zeros(args.prompt_len, np.int32),
+                      max_new_tokens=2))
         results = [run_level(
             engine, c, prompt_len=args.prompt_len,
             new_tokens=args.new_tokens,

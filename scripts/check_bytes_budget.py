@@ -8,7 +8,7 @@ regresses more than the budget's tolerance on this device kind.
 
 Usage:
     python bench.py | python scripts/check_bytes_budget.py -
-    python scripts/check_bytes_budget.py BENCH_r05.json
+    python scripts/check_bytes_budget.py tests/fixtures/bench/BENCH_r05.json
     python bench.py --enforce-budget          # same gate, in-process
 
 Budget file semantics (docs/bytes_budget.json):

@@ -26,9 +26,12 @@ import numpy as np
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+sys.path.insert(0, os.path.join(REPO, "scripts"))
+
+from _chip import require_tpu  # noqa: E402
 from tpunet.utils.cache import enable_persistent_compile_cache  # noqa: E402
 
-enable_persistent_compile_cache(os.path.join(REPO, ".jax_cache"))
+enable_persistent_compile_cache()
 
 
 def build_step(per_chip_batch: int, image_size: int = 224):
@@ -104,21 +107,23 @@ def main() -> None:
 
     bytes_breakdown = None
     if args.from_trace:
+        # Parsing a kept trace needs no chip, and the process that
+        # parses it cannot know which device recorded it.
         trace_dir, wall, trainer = args.from_trace, None, None
+        device = {"platform": None, "device_kind": None,
+                  "device_count": None}
     else:
+        device = require_tpu()
         trainer, gx, gy = build_step(args.batch, args.image_size)
         # Byte attribution from the optimized module text (same
         # decomposition bench.py ships as bytes_per_image_breakdown);
         # AOT-compiling here warms the executable the trace reuses.
-        try:
-            from tpunet.obs import hlo_bytes
-            from tpunet.utils.prng import step_key
-            compiled = trainer.train_step.lower(
-                trainer.state, gx, gy, step_key(0, 0)).compile()
-            bytes_breakdown = hlo_bytes.per_image_breakdown(
-                compiled.as_text(), args.batch)
-        except Exception as e:
-            print(f"# byte attribution unavailable: {e}", file=sys.stderr)
+        from tpunet.obs import hlo_bytes
+        from tpunet.utils.prng import step_key
+        compiled = trainer.train_step.lower(
+            trainer.state, gx, gy, step_key(0, 0)).compile()
+        bytes_breakdown = hlo_bytes.per_image_breakdown(
+            compiled.as_text(), args.batch)
         trace_dir = tempfile.mkdtemp(prefix="tpunet-roofline-trace-")
         wall = trace_step(trainer, gx, gy, args.steps, trace_dir)
         print(f"# traced {args.steps} steps in {wall:.2f}s "
@@ -131,7 +136,8 @@ def main() -> None:
     # dir this run created, or skip closing the trainer's
     # checkpointer/threads.
     try:
-        _attrib_and_write(args, trace_dir, wall, bytes_breakdown)
+        _attrib_and_write(args, trace_dir, wall, bytes_breakdown,
+                          device)
     finally:
         if args.from_trace or args.keep_trace:
             # Never delete a trace the CALLER owns (--from-trace) or
@@ -145,8 +151,8 @@ def main() -> None:
             trainer.close()
 
 
-def _attrib_and_write(args, trace_dir: str, wall,
-                      bytes_breakdown=None) -> None:
+def _attrib_and_write(args, trace_dir: str, wall, bytes_breakdown,
+                      device: dict) -> None:
     from tpunet.obs.hlo_bytes import phase_of
 
     rows = hlo_stats(trace_dir)
@@ -199,12 +205,11 @@ def _attrib_and_write(args, trace_dir: str, wall,
 
     out = {
         "batch_per_chip": args.batch,
-        "n_chips": jax.device_count(),
+        **device,
         "steps_traced": args.steps,
         "wall_seconds": wall and round(wall, 3),
         "img_per_sec_per_chip_traced": wall and round(
             args.steps * args.batch / wall, 1),
-        "device_kind": jax.devices()[0].device_kind,
         "total_profiled_us_per_step": round(total / args.steps, 1),
         "hbm_bound_time_pct": round(100.0 * hbm_time / total, 2),
         "hbm_bound_mean_achieved_bw_gibs": round(
@@ -225,7 +230,8 @@ def _attrib_and_write(args, trace_dir: str, wall,
     with open(args.out, "w") as fp:
         json.dump(out, fp, indent=1)
     print(json.dumps({k: out[k] for k in
-                      ("img_per_sec_per_chip_traced",
+                      ("platform", "device_kind", "device_count",
+                       "img_per_sec_per_chip_traced",
                        "total_profiled_us_per_step",
                        "hbm_bound_time_pct",
                        "hbm_bound_mean_achieved_bw_gibs",

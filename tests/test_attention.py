@@ -532,22 +532,23 @@ class TestFlashAttention:
 
     @pytest.mark.slow
     def test_spmd_partitions_over_batch_and_heads(self):
-        """The custom_partitioning rule: under a (data, model) mesh with
-        batch- and head-sharded inputs the kernel runs per-shard (each
-        device's pallas_call sees 1/4 batch x 1/2 heads) and still
-        matches dense."""
+        """The mesh split (tpunet/ops/partition.py): under a (data,
+        model) mesh with batch- and head-sharded inputs the kernel runs
+        per-shard (each device's pallas_call sees 1/4 batch x 1/2
+        heads) and still matches dense."""
         from jax.sharding import NamedSharding
         from tpunet.config import MeshConfig
         from tpunet.ops.flash import flash_attention
+        from tpunet.ops.partition import traced_under
         from tpunet.parallel import make_mesh
 
         mesh = make_mesh(MeshConfig(data=4, model=2))
         q, k, v = self._qkv(b=4, t=64, h=4, d=16)
         sh = NamedSharding(mesh, P("data", None, "model", None))
         qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
-        fn = jax.jit(functools.partial(flash_attention, causal=True,
-                                       block_q=32, block_k=32,
-                                       interpret=True))
+        fn = jax.jit(traced_under(mesh, functools.partial(
+            flash_attention, causal=True, block_q=32, block_k=32,
+            interpret=True)))
         out = fn(qs, ks, vs)
         # Normalize: newer jax trims trailing Nones in PartitionSpec,
         # older jax keeps them — same sharding either way.
@@ -563,14 +564,13 @@ class TestFlashAttention:
                                    rtol=1e-5, atol=1e-5)
 
         # Gradients under the mesh: exercises the res-forward (two
-        # outputs, mixed 4-D/3-D shardings) and the 6-operand backward
-        # custom_partitioning rules.
-        gfn = jax.jit(jax.grad(
+        # outputs, mixed 4-D/3-D specs) and the 6-operand backward.
+        gfn = jax.jit(traced_under(mesh, jax.grad(
             lambda q, k, v: jnp.sum(flash_attention(
                 q, k, v, causal=True, block_q=32, block_k=32,
-                interpret=True) ** 2), argnums=(0, 1, 2)))
+                interpret=True) ** 2), argnums=(0, 1, 2))))
         gq, gk, gv = gfn(qs, ks, vs)
-        assert gq.sharding.spec == P("data", None, "model")
+        assert _trim(gq.sharding.spec) == ("data", None, "model")
         dref = jax.grad(
             lambda q, k, v: jnp.sum(dense_attention(
                 q, k, v, causal=True) ** 2), argnums=(0, 1, 2))(q, k, v)
@@ -580,12 +580,13 @@ class TestFlashAttention:
 
     @pytest.mark.slow
     def test_spmd_partitions_with_segment_ids(self):
-        """The segmented custom_partitioning trio (5/8-operand rules):
-        batch-sharded q/k/v AND segment ids run per-shard and match
-        dense, forward and gradients."""
+        """The segmented trio (5/8-operand specs): batch-sharded q/k/v
+        AND segment ids run per-shard and match dense, forward and
+        gradients."""
         from jax.sharding import NamedSharding
         from tpunet.config import MeshConfig
         from tpunet.ops.flash import flash_attention
+        from tpunet.ops.partition import traced_under
         from tpunet.parallel import make_mesh
 
         mesh = make_mesh(MeshConfig(data=4))
@@ -598,20 +599,20 @@ class TestFlashAttention:
         qs, ks, vs = (jax.device_put(x, sh4) for x in (q, k, v))
         segs = jax.device_put(seg, sh2)
 
-        fn = jax.jit(lambda q, k, v, s: flash_attention(
+        fn = jax.jit(traced_under(mesh, lambda q, k, v, s: flash_attention(
             q, k, v, causal=True, block_q=32, block_k=32,
-            interpret=True, segment_ids=(s, s)))
+            interpret=True, segment_ids=(s, s))))
         out = fn(qs, ks, vs, segs)
         ref = dense_attention(q, k, v, causal=True,
                               segment_ids=(seg, seg))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
 
-        gfn = jax.jit(jax.grad(
+        gfn = jax.jit(traced_under(mesh, jax.grad(
             lambda q, k, v, s: jnp.sum(flash_attention(
                 q, k, v, causal=True, block_q=32, block_k=32,
                 interpret=True, segment_ids=(s, s)) ** 2),
-            argnums=(0, 1, 2)))
+            argnums=(0, 1, 2))))
         gq, gk, gv = gfn(qs, ks, vs, segs)
         dref = jax.grad(
             lambda q, k, v: jnp.sum(dense_attention(

@@ -157,13 +157,17 @@ def test_restore_survives_metadata_probe_failure(tmp_path, caplog):
 
 def test_cache_dir_honors_jax_env_var(monkeypatch):
     """The shared compile-cache convention: JAX's own env var wins;
-    otherwise the per-user tempdir path."""
+    otherwise ``<checkout>/.jax_cache`` — a fixed path, never one
+    built from the temp directory or the user name."""
+    import os
+
     from tpunet.utils.cache import cache_dir
 
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
     assert cache_dir() == "/elsewhere/cache"
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-    assert "tpunet-jax-cache-" in cache_dir()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cache_dir() == os.path.join(repo, ".jax_cache")
 
 
 def test_failed_best_save_rolls_back_sidecar(tmp_path):

@@ -170,13 +170,19 @@ def _load_checked_in_budget():
         return json.load(fp)
 
 
+BENCH_FIXTURES = os.path.join(REPO, "tests", "fixtures", "bench")
+BENCH_R05 = os.path.join(BENCH_FIXTURES, "BENCH_r05.json")
+
+
 def _bench_artifacts():
-    """[(round, parsed record)] for every BENCH_r*.json in the repo
-    root, oldest first. BENCH_rN measures the tree AFTER PR N-1."""
+    """[(round, parsed record)] for every BENCH_r*.json record kept
+    under tests/fixtures/bench/ (pretty-printed, the bench.py record
+    under 'parsed' — the shape the driver stores), oldest first.
+    BENCH_rN measures the tree AFTER PR N-1."""
     import glob
     import re
     out = []
-    for path in glob.glob(os.path.join(REPO, "BENCH_r*.json")):
+    for path in glob.glob(os.path.join(BENCH_FIXTURES, "BENCH_r*.json")):
         m = re.search(r"BENCH_r(\d+)\.json$", path)
         if not m:
             continue
@@ -207,7 +213,7 @@ def test_checked_in_budget_file_is_valid():
 
 def test_budget_vs_latest_bench_artifact():
     """Budget/measurement drift fails tier-1 instead of waiting for a
-    slow bench run: every BENCH_r* artifact measuring this-or-newer
+    slow bench run: every BENCH_r* record measuring this-or-newer
     trees (round > the entry's as_of_round; BENCH_rN measures the
     tree after PR N-1) must PASS the checked-in budget, and the budget
     must not sit above the latest matching measurement (a stale or
@@ -318,8 +324,7 @@ def test_budget_cli_accepts_pretty_printed_artifact(tmp_path, capsys):
     from check_bytes_budget import main as budget_main
     b = tmp_path / "budget.json"
     b.write_text(json.dumps(_budget(139e6)))
-    rc = budget_main([os.path.join(REPO, "BENCH_r05.json"),
-                      "--budget", str(b)])
+    rc = budget_main([BENCH_R05, "--budget", str(b)])
     out = capsys.readouterr().out
     assert rc == 0 and "xla_bytes_accessed_per_image" in out
 
@@ -331,7 +336,7 @@ def test_budget_cli_flag_order_and_missing_value(tmp_path, capsys):
     from check_bytes_budget import main as budget_main
     b = tmp_path / "budget.json"
     b.write_text(json.dumps(_budget(139e6)))
-    art = os.path.join(REPO, "BENCH_r05.json")
+    art = BENCH_R05
     assert budget_main(["--budget", str(b), art]) == 0
     assert budget_main([art, "--budget", str(b)]) == 0
     assert budget_main([art, "--budget"]) == 2

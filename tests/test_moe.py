@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpunet.compat import shard_map
+from jax import shard_map
 from tpunet.config import (CheckpointConfig, DataConfig, MeshConfig,
                            ModelConfig, OptimConfig, TrainConfig)
 from tpunet.models import create_model, init_variables

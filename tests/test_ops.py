@@ -85,8 +85,7 @@ def test_gradients_match_reference():
 
 
 def test_jit_composes():
-    # NOTE: jax.vmap over the op is unsupported (custom_partitioning has
-    # no batching rule); the op is already batched over N.
+    # NOTE: the op is already batched over N; nothing vmaps over it.
     x = _rand((4, 28, 28, 8), 8)
     w = _rand((3, 3, 8), 9)
     f = jax.jit(lambda x, w: depthwise_conv3x3(x, w, 1, True))

@@ -424,6 +424,50 @@ def test_healthz_carries_run_id():
         srv.drain(timeout=10.0)
 
 
+
+def test_engine_step_error_is_an_error_answer_not_an_empty_one():
+    """A step that raises kills the engine thread. The request must end
+    with finish_reason 'error' and the cause, the HTTP frontend must
+    answer non-200, and /healthz must fail — never a 200 with an empty
+    token list (which is what a dead engine's ``result()`` returns)."""
+    srv = make_server()
+    base = f"http://127.0.0.1:{srv.port}"
+
+    def boom(*a, **kw):
+        raise RuntimeError("injected step failure")
+
+    srv.engine._dispatch_step = boom
+    try:
+        code, out = post(base, "/v1/generate",
+                         {"tokens": [1, 2, 3], "max_new_tokens": 4})
+        assert code == 500, (code, out)
+        assert out["finish_reason"] == "error"
+        assert "injected step failure" in out["error"]
+        assert out["tokens"] == []
+        assert "injected step failure" in srv.engine.error
+        # The dead engine admits nothing more: 503, not an empty 200.
+        code, out = post(base, "/v1/generate",
+                         {"tokens": [1, 2, 3], "max_new_tokens": 4})
+        assert code == 503, (code, out)
+        code, health = get(base, "/healthz")
+        assert code != 200, health
+    finally:
+        srv.drain(timeout=10.0)
+
+
+def _answer(engine, prompt, new_tokens):
+    """Greedy tokens of one request — which must not have ended in
+    error: ``result()`` returns the tokens whatever the finish reason,
+    so a dead engine reads as an empty answer."""
+    req = engine.submit(prompt, max_new_tokens=new_tokens)
+    tokens = req.result(timeout=120)
+    assert req.finish_reason in ("length", "stop"), \
+        (req.finish_reason, req.error, engine.error)
+    assert engine.error is None and not req.error
+    assert len(tokens) == new_tokens
+    return tokens
+
+
 def test_engine_aot_store_roundtrip(tmp_path):
     """AOT warm-start parity: a second engine boot deserializes every
     program ('loaded') and produces token-identical greedy output."""
@@ -438,7 +482,7 @@ def test_engine_aot_store_roundtrip(tmp_path):
 
     eng = Engine(model, variables, cfg, aot_store=store).start()
     try:
-        toks1 = eng.submit(prompt, max_new_tokens=5).result(timeout=120)
+        toks1 = _answer(eng, prompt, 5)
     finally:
         eng.stop()
     assert all(v.startswith("compiled")
@@ -447,7 +491,7 @@ def test_engine_aot_store_roundtrip(tmp_path):
 
     eng2 = Engine(model, variables, cfg, aot_store=store).start()
     try:
-        toks2 = eng2.submit(prompt, max_new_tokens=5).result(timeout=120)
+        toks2 = _answer(eng2, prompt, 5)
     finally:
         eng2.stop()
     assert eng2.aot_status == {"w1": "loaded", "w16": "loaded"}
@@ -456,7 +500,7 @@ def test_engine_aot_store_roundtrip(tmp_path):
     # jit fallback (no store) agrees too.
     eng3 = Engine(model, variables, cfg).start()
     try:
-        toks3 = eng3.submit(prompt, max_new_tokens=5).result(timeout=120)
+        toks3 = _answer(eng3, prompt, 5)
     finally:
         eng3.stop()
     assert toks3 == toks1
@@ -467,7 +511,7 @@ def test_engine_aot_store_roundtrip(tmp_path):
     store4 = build_aot_store(str(tmp_path), TINY, cfg4)
     eng4 = Engine(model, variables, cfg4, aot_store=store4).start()
     try:
-        eng4.submit(prompt, max_new_tokens=2).result(timeout=120)
+        _answer(eng4, prompt, 2)
     finally:
         eng4.stop()
     assert all(v.startswith("compiled")

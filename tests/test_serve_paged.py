@@ -522,21 +522,59 @@ def test_prefix_spill_and_warm_start_roundtrip(tmp_path, tiny_lm):
 # int8 KV parity gate
 # ---------------------------------------------------------------------------
 
+# A top-1/top-2 logit margin below this is a tie as far as int8 KV is
+# concerned: the tiny random model's logits have a std of ~0.13, and
+# absmax int8 rounds each K/V element by up to 1/254 of its row's max,
+# which moves a logit by a few 1e-4. Decided steps (the smallest other
+# margins across the six prompts are 7e-3..9e-3) must still agree.
+INT8_NEAR_TIE = 2e-3
+
+
+def _float_margin(tiny_lm, tokens):
+    """Top-1 minus top-2 of the float model's next-token logits."""
+    model, variables = tiny_lm
+    logits = model.apply(variables, np.asarray(tokens, np.int32)[None],
+                         train=False)
+    top = np.sort(np.asarray(logits[0, -1], np.float64))
+    return float(top[-1] - top[-2])
+
+
 def test_int8_kv_eval_parity_gate(tiny_lm):
     """The eval-parity gate for --kv-dtype int8: greedy decode through
     quantized pages must be token-identical to the float32 path on the
-    tiny model across a prompt spread. (Quantization error exists —
-    this gate is what keeps it below argmax-flipping size; a model
-    where it trips must not ship int8 KV.)"""
+    tiny model across a prompt spread, at every step the float model
+    actually DECIDES. (Quantization error exists — this gate is what
+    keeps it below argmax-flipping size; a model where it trips must
+    not ship int8 KV.)
+
+    A step whose float top-2 margin is under INT8_NEAR_TIE is a coin
+    flip for any perturbation, XLA:CPU's own reduction order between
+    jax versions included — under jax 0.9.0 seed 2's third token sits
+    on a 6e-4 margin and int8 takes the other side, where the seed's
+    jax happened to agree. Such a step may diverge (at most one across
+    the spread); a divergence anywhere else fails."""
     eng = make_engine(tiny_lm, kv_dtype="int8").start()
+    near_ties = 0
     try:
         for seed in range(6):
             p = prompts(1, rng_seed=seed)[0]
-            out = eng.submit(p, max_new_tokens=6).result(timeout=120)
-            assert out == solo_greedy(tiny_lm, p, 6), \
-                f"int8 KV diverged on seed {seed}"
+            req = eng.submit(p, max_new_tokens=6)
+            out = req.result(timeout=120)
+            assert req.finish_reason == "length", (req.finish_reason,
+                                                   req.error)
+            ref = solo_greedy(tiny_lm, p, 6)
+            if out == ref:
+                continue
+            step = next(i for i, (a, b) in enumerate(zip(out, ref))
+                        if a != b)
+            margin = _float_margin(tiny_lm, list(p) + ref[:step])
+            assert margin < INT8_NEAR_TIE, (
+                f"int8 KV diverged on seed {seed} at step {step}, where "
+                f"the float margin is {margin:.4g}: {out} vs {ref}")
+            near_ties += 1
     finally:
         eng.stop()
+    assert near_ties <= 1, near_ties
 
 
 def test_int8_kv_halves_bf16_page_cost(tiny_lm):
@@ -605,6 +643,20 @@ def test_kv_gauges_and_serve_record_fields(tiny_lm):
 # AOT warm-start of the paged + device-sampled program set
 # ---------------------------------------------------------------------------
 
+
+def _answer(engine, prompt, new_tokens):
+    """Greedy tokens of one request — which must not have ended in
+    error: ``result()`` returns the tokens whatever the finish reason,
+    so a dead engine reads as an empty answer."""
+    req = engine.submit(prompt, max_new_tokens=new_tokens)
+    tokens = req.result(timeout=120)
+    assert req.finish_reason in ("length", "stop"), \
+        (req.finish_reason, req.error, engine.error)
+    assert engine.error is None and not req.error
+    assert len(tokens) == new_tokens
+    return tokens
+
+
 def test_paged_aot_store_roundtrip(tmp_path, tiny_lm):
     """The paged decode + fused-sampling program joins the serialized
     closed set: a second boot deserializes every program ('loaded')
@@ -621,14 +673,14 @@ def test_paged_aot_store_roundtrip(tmp_path, tiny_lm):
 
     eng = Engine(model, variables, cfg, aot_store=store).start()
     try:
-        toks1 = eng.submit(prompt, max_new_tokens=5).result(timeout=120)
+        toks1 = _answer(eng, prompt, 5)
     finally:
         eng.stop()
     assert all(v.startswith("compiled") for v in eng.aot_status.values())
 
     eng2 = Engine(model, variables, cfg, aot_store=store).start()
     try:
-        toks2 = eng2.submit(prompt, max_new_tokens=5).result(timeout=120)
+        toks2 = _answer(eng2, prompt, 5)
     finally:
         eng2.stop()
     assert eng2.aot_status == {"w1": "loaded", "w16": "loaded"}
@@ -643,7 +695,7 @@ def test_paged_aot_store_roundtrip(tmp_path, tiny_lm):
     eng3 = Engine(model, variables, cfg_int8,
                   aot_store=store_int8).start()
     try:
-        eng3.submit(prompt, max_new_tokens=2).result(timeout=120)
+        _answer(eng3, prompt, 2)
     finally:
         eng3.stop()
     assert all(v.startswith("compiled")
@@ -664,12 +716,13 @@ def test_aot_save_is_load_verified(tmp_path, monkeypatch):
                         lambda compiled: (b"blob", None, None))
     monkeypatch.setattr(
         serialize_executable, "deserialize_and_load",
-        lambda *a: (_ for _ in ()).throw(RuntimeError("Symbols not found")))
+        lambda *a, **kw: (_ for _ in ()).throw(
+            RuntimeError("Symbols not found")))
     assert store.save("masked_step", "w16", object()) is False
     assert not list(tmp_path.iterdir())
 
     monkeypatch.setattr(serialize_executable, "deserialize_and_load",
-                        lambda *a: object())
+                        lambda *a, **kw: object())
     assert store.save("masked_step", "w16", object()) is True
     assert any(p.name.endswith(".aotx") for p in tmp_path.iterdir())
 

@@ -11,8 +11,7 @@ them) and rot into wrong numbers in production.
 Detected jit contexts (syntactic):
 
 - ``@jax.jit`` / ``@jit`` / ``@partial(jax.jit, ...)`` decorators;
-- local defs passed to ``jax.jit(f)``, ``shard_map(f, ...)`` (the
-  compat shim included), or as the kernel of ``pl.pallas_call(f, ..)``.
+- local defs passed to ``jax.jit(f)``, ``shard_map(f, ...)``, or as the kernel of ``pl.pallas_call(f, ..)``.
 
 Inside those bodies the rule flags ``print(...)``, ``time.*()`` calls,
 ``global``-declared assignment, and ``np.* (traced-param)`` calls —

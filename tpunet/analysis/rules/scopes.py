@@ -39,8 +39,10 @@ from tpunet.obs.hlo_bytes import KERNEL_SCOPES
 _OPS_PATH_RE = re.compile(r"(^|/)ops/[^/]+\.py$")
 
 #: Assignments whose value wraps a function without renaming its body:
-#: ``X = custom_partitioning(F, ...)`` / ``X = functools.partial(F, ..)``
-_ALIAS_WRAPPERS = ("custom_partitioning", "partial")
+#: ``X = sharded(F, ...)`` (the mesh split of tpunet/ops/partition.py;
+#: ``custom_partitioning(F, ...)`` is the same shape and stays
+#: recognized) / ``X = functools.partial(F, ..)``
+_ALIAS_WRAPPERS = ("sharded", "custom_partitioning", "partial")
 
 
 def _valid_scope_names() -> Set[str]:
@@ -204,9 +206,8 @@ class ScopeRule(Rule):
         # inside a covered caller (and at least one counted site
         # exists — an uncalled function has no scoped context to
         # inherit). Call sites inside functions that are themselves
-        # never called in-module (callbacks handed to the partitioner:
-        # custom_partitioning lower_fns, infer_sharding handlers) are
-        # NOT counted — they execute under the partitioned op's trace
+        # never called in-module (callbacks handed to a partitioner)
+        # are NOT counted — they execute under the partitioned op's trace
         # context, which is the scoped call we already track through
         # the alias; custom_vjp fwd/bwd are invoked by jax machinery
         # and DO count as live callers.

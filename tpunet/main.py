@@ -24,10 +24,13 @@ from tpunet.obs import RunUnhealthyError
 from tpunet.parallel import initialize_distributed, sync_hosts
 from tpunet.train.loop import Trainer
 from tpunet.utils import log0
+from tpunet.utils.cache import (compile_stats_line,
+                                enable_persistent_compile_cache)
 
 
 def main(argv=None) -> int:
     initialize_distributed()
+    enable_persistent_compile_cache()
     cfg = config_from_args(argv)
     # Profiling is owned by the obs subsystem now (tpunet/obs/spans.py
     # WindowedProfiler): --profile-dir alone still traces the whole
@@ -40,8 +43,10 @@ def main(argv=None) -> int:
         # world size (cifar10_mpi_mobilenet_224.py:117 + mpirun -np N).
         cfg = cfg.replace(data=dataclasses.replace(
             cfg.data, batch_size=cfg.data.batch_size * n_proc))
+    dev = jax.devices()[0]
     log0(f"JAX devices: {jax.device_count()} "
-         f"({jax.local_device_count()} local), processes: {n_proc}")
+         f"({jax.local_device_count()} local), processes: {n_proc}, "
+         f"platform: {dev.platform}, device_kind: {dev.device_kind}")
 
     # Dataset fetch gate (reference rank-0 download + barrier, :93-102):
     # process 0 materializes the data first, other hosts wait — and
@@ -78,6 +83,7 @@ def main(argv=None) -> int:
         # flushes checkpoints AND any still-open profiler trace, each
         # independently (Trainer.close's own try/finally).
         trainer.close()
+        log0(compile_stats_line())
     return 0
 
 

@@ -74,7 +74,7 @@ parity).
 Measured on the v5e chip (scripts/bench_lm.py --model lm_pp, T=2048
 B=8 depth=4 hidden=512): 276-290k tok/s at pipe=1 with the flash core
 (--attention flash/auto; inside the pipeline's shard_map the local
-kernel variant runs, outside it the custom_partitioning-wrapped one —
+kernel variant runs, outside it the mesh-split one —
 resolve_block_cores) — 1.85x the
 unrolled DENSE TransformerLM (157k) and within 19% of the unrolled
 flash one (357k); that residual scan-over-layers overhead is the price

@@ -77,7 +77,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
-from tpunet.compat import shard_map
+from jax import shard_map
 
 
 def _route(probs, k: int, e: int, cap: int):

@@ -49,7 +49,7 @@ def resolve_block_cores(attention: str, block: int = 512):
     VARIANT matters: inside the pipeline's shard_map the per-shard
     local kernel is correct (GSPMD is already done), while the
     sequential pipe==1 path runs under the top-level jit where only
-    the custom_partitioning-wrapped entry keeps a batch-sharded mesh
+    the mesh-split entry (tpunet/ops/partition.py) keeps a batch-sharded mesh
     from all-gathering q/k/v at every layer (the failure mode
     tpunet/ops/flash.py's partitioning section documents). Both fall
     back to dense off-TPU.

@@ -64,7 +64,8 @@ _SKIP_OPS = {
 _CALL_OPS = {"call", "while", "conditional", "async-start"}
 
 _SHAPE_RE = re.compile(r"\b([a-z][a-z0-9]{0,15})\[([0-9,]*)\]")
-_INSTR_RE = re.compile(r"^\s+(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(.*)$")
+_INSTR_RE = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
+_OPERAND_RE = re.compile(r"%?([\w.\-]+)")
 _COMP_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _CALLED_RE = re.compile(
@@ -227,12 +228,20 @@ def _computations(hlo_text: str) -> Tuple[Optional[str], Dict[str, List[str]]]:
     return entry, comps
 
 
-def _parse_instr(line: str) -> Optional[Tuple[str, int, int, str]]:
-    """-> (opcode, out_bytes, operand_bytes, op_name) or None."""
+def _parse_instr(line: str, sizes: Dict[str, int]
+                 ) -> Optional[Tuple[str, int, int, str]]:
+    """-> (opcode, out_bytes, operand_bytes, op_name) or None.
+
+    ``sizes`` maps the instruction names seen so far in this
+    computation to their output bytes; this instruction is added. The
+    HLO text jax 0.9.0 prints names operands without their types
+    (``dot(%x.1, %w.1)``), so operand bytes are looked up by name;
+    typed operands (``dot(f32[256,128]{1,0} %x.1, ...)``) are summed
+    as printed."""
     m = _INSTR_RE.match(line)
     if not m:
         return None
-    rest = m.group(1)
+    name, rest = m.group(1), m.group(2)
     # Output type: either a tuple "(...)" or a single token.
     if rest.startswith("("):
         depth = 0
@@ -271,8 +280,12 @@ def _parse_instr(line: str) -> Optional[Tuple[str, int, int, str]]:
     nm = _OPNAME_RE.search(rest[end:])
     if nm:
         op_name = nm.group(1)
-    return (opcode, _shape_bytes(type_str),
-            _shape_bytes(args) if opcode != "constant" else 0, op_name)
+    out_bytes = sizes[name] = _shape_bytes(type_str)
+    in_bytes = 0
+    if opcode != "constant":
+        in_bytes = _shape_bytes(args) or sum(
+            sizes.get(tok, 0) for tok in _OPERAND_RE.findall(args))
+    return opcode, out_bytes, in_bytes, op_name
 
 
 def instruction_bytes(hlo_text: str) -> Iterator[Tuple[str, str, int, str]]:
@@ -287,8 +300,9 @@ def instruction_bytes(hlo_text: str) -> Iterator[Tuple[str, str, int, str]]:
         if name in seen or name not in comps:
             return
         seen.add(name)
+        sizes: Dict[str, int] = {}
         for line in comps[name]:
-            parsed = _parse_instr(line)
+            parsed = _parse_instr(line, sizes)
             if parsed is None:
                 continue
             opcode, out_b, in_b, op_name = parsed

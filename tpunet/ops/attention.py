@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpunet.compat import shard_map
+from jax import shard_map
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp()/grads NaN-free
 

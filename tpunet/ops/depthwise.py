@@ -141,51 +141,19 @@ def _pallas_forward(x: jax.Array, w: jax.Array, stride: int,
 # ---------------------------------------------------------------------------
 # SPMD partitioning: a pallas_call is opaque to GSPMD, so without help the
 # partitioner would all-gather the batch onto every device. The op is
-# trivially parallel over batch and channels (the kernel grids over N and
-# is elementwise in C), so we register exactly that rule and lower to a
-# per-shard pallas call. H/W stay replicated.
+# trivially parallel over batch (the kernel grids over N), so it is split
+# over the step's mesh by batch (tpunet/ops/partition.py) and each
+# device runs a per-shard pallas call. H/W/channels stay replicated.
 # ---------------------------------------------------------------------------
 
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
-from tpunet.compat import def_partition_compat
+from tpunet.ops.partition import sharded
 
+_B4 = P("data", None, None, None)
+_R3 = P(None, None, None)
 
-def _shard_specs(arg_shapes):
-    def spec_of(s):
-        sh = s.sharding
-        return sh.spec if isinstance(sh, NamedSharding) else P()
-    xs = spec_of(arg_shapes[0])
-    xp = list(xs) + [None] * (4 - len(xs))
-    return P(xp[0], None, None, xp[3])
-
-
-def _infer(stride, interpret, mesh, arg_shapes, result_shape):
-    spec = _shard_specs(arg_shapes)
-    return NamedSharding(mesh, spec)
-
-
-def _partition(stride, interpret, mesh, arg_shapes, result_shape):
-    spec = _shard_specs(arg_shapes)
-    arg_shardings = (NamedSharding(mesh, spec),
-                     NamedSharding(mesh, P(None, None, spec[3])))
-    result_sharding = NamedSharding(mesh, spec)
-
-    def lower_fn(x, w):
-        return _pallas_forward(x, w, stride, interpret)
-
-    return mesh, lower_fn, result_sharding, arg_shardings
-
-
-_partitioned = custom_partitioning(_pallas_forward, static_argnums=(2, 3))
-def_partition_compat(
-    _partitioned,
-    partition=_partition,
-    infer_sharding_from_operands=_infer,
-    sharding_rule="n h w c, kh kw c -> n ho wo c",
-    need_replication_factors=("h", "w", "kh", "kw", "ho", "wo"),
-)
+_partitioned = sharded(_pallas_forward, (_B4, _R3), _B4)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -279,8 +247,12 @@ def _bwd_kernel(xp_ref, gp_ref, w_ref, dx_ref, dwp_ref, *,
     else:
         # Zero-dilate in VMEM: G[t] = g[r0 + (t-1)/2] for odd t else 0
         # (p0 = stride*r0 is even, so stripe-local parity == global).
-        gs = gp_ref[0, pl.ds(r0, rows + 1)]           # (rows+1, wo+1, C)
-        z = jnp.zeros_like(gs)
+        # Dilated in float32: Mosaic refuses the unit-dim insert of the
+        # stack on packed 16-bit rows ("unsupported shape cast",
+        # jax 0.9.0 / libtpu 0.0.34), and the taps below are summed in
+        # float32 anyway.
+        gs = gp_ref[0, pl.ds(r0, rows + 1)].astype(jnp.float32)
+        z = jnp.zeros_like(gs)                        # (rows+1, wo+1, C)
         G = jnp.stack([z, gs], axis=2).reshape(rows + 1, -1, c)
         G = jnp.stack([jnp.zeros_like(G), G], axis=1).reshape(
             rows_in + 2, -1, c)
@@ -341,45 +313,9 @@ def _pallas_backward(x: jax.Array, w: jax.Array, g: jax.Array,
     return dx_full[:, :h, :w_in], dwp
 
 
-def _bwd_shard_specs(arg_shapes):
-    def spec_of(s):
-        sh = s.sharding
-        return sh.spec if isinstance(sh, NamedSharding) else P()
-    xs = spec_of(arg_shapes[0])
-    xp = list(xs) + [None] * (4 - len(xs))
-    return P(xp[0], None, None, xp[3])
-
-
-def _bwd_infer(stride, interpret, mesh, arg_shapes, result_shape):
-    spec = _bwd_shard_specs(arg_shapes)
-    return (NamedSharding(mesh, spec),
-            NamedSharding(mesh, P(spec[0], None, None, spec[3])))
-
-
-def _bwd_partition(stride, interpret, mesh, arg_shapes, result_shape):
-    spec = _bwd_shard_specs(arg_shapes)
-    arg_shardings = (NamedSharding(mesh, spec),
-                     NamedSharding(mesh, P(None, None, spec[3])),
-                     NamedSharding(mesh, spec))
-    result_shardings = (NamedSharding(mesh, spec),
-                        NamedSharding(mesh, P(spec[0], None, None,
-                                              spec[3])))
-
-    def lower_fn(x, w, g):
-        return _pallas_backward(x, w, g, stride, interpret)
-
-    return mesh, lower_fn, result_shardings, arg_shardings
-
-
-_partitioned_bwd = custom_partitioning(_pallas_backward,
-                                       static_argnums=(3, 4))
-def_partition_compat(
-    _partitioned_bwd,
-    partition=_bwd_partition,
-    infer_sharding_from_operands=_bwd_infer,
-    sharding_rule="n h w c, kh kw c, n go wog c -> n h w c, n kh kw c",
-    need_replication_factors=("h", "w", "kh", "kw", "go", "wog"),
-)
+# (dx [N,H,W,C], per-image dw partials [N,3,3,C]) — both batch-split.
+_partitioned_bwd = sharded(_pallas_backward, (_B4, _R3, _B4),
+                           (_B4, _B4))
 
 
 def _reference_bwd(x, w, g, stride):

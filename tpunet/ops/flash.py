@@ -339,9 +339,10 @@ def _pallas_forward_res(q, k, v, causal, scale, block_q, block_k,
                         interpret):
     """-> (out [B,Tq,H,D], lse [B,H,Tq]) — the training forward.
 
-    FIXED ARITY: registered with custom_partitioning, where a trailing
-    default parameter would count as an operand slot — the segmented
-    variants below are separate functions for exactly that reason.
+    FIXED ARITY: split over the mesh by operand position
+    (tpunet/ops/partition.py) and wrapped in custom_vjp, where a
+    trailing default parameter would count as an operand slot — the
+    segmented variants below are separate functions for that reason.
     """
     return _forward_impl(q, k, v, causal, scale, block_q, block_k,
                          interpret, with_lse=True)
@@ -571,123 +572,26 @@ def _pallas_backward(q, k, v, out, lse, do,
 
 
 # ---------------------------------------------------------------------------
-# SPMD partitioning: a pallas_call is opaque to GSPMD, so without a rule
+# SPMD partitioning: a pallas_call is opaque to GSPMD, so left alone
 # the partitioner would all-gather the sharded batch onto every device
 # (the same issue tpunet/ops/depthwise.py solves). Flash attention is
 # trivially parallel over batch and heads (the grid's first two axes);
-# seq and head_dim must stay replicated per shard.
+# seq and head_dim must stay replicated per shard. The split is a
+# shard_map over the step's mesh (tpunet/ops/partition.py).
 # ---------------------------------------------------------------------------
 
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
-from tpunet.compat import def_partition_compat
+from tpunet.ops.partition import sharded
 
+_S4 = P("data", None, "model", None)      # q/k/v/out/do: batch, heads
+_S3 = P("data", "model", None)            # lse [B, H, Tq]
+_SSEG = P("data", None)                   # per-token segment ids
 
-def _q_spec_of(arg_shapes) -> P:
-    sh = arg_shapes[0].sharding
-    qs = list(sh.spec) if isinstance(sh, NamedSharding) else []
-    qs += [None] * (4 - len(qs))
-    return P(qs[0], None, qs[2], None)   # batch/head shardable
-
-
-def _shardings(mesh, spec):
-    """(4-D q/k/v/out sharding, 3-D lse/delta sharding) from the spec."""
-    return (NamedSharding(mesh, spec),
-            NamedSharding(mesh, P(spec[0], spec[2], None)))
-
-
-def _infer_fwd(causal, scale, block_q, block_k, interpret, mesh,
-               arg_shapes, result_shape):
-    return _shardings(mesh, _q_spec_of(arg_shapes))[0]
-
-
-def _partition_fwd(causal, scale, block_q, block_k, interpret, mesh,
-                   arg_shapes, result_shape):
-    s4, _ = _shardings(mesh, _q_spec_of(arg_shapes))
-
-    def lower_fn(q, k, v):
-        return _pallas_forward(q, k, v, causal, scale, block_q, block_k,
-                               interpret)
-
-    return mesh, lower_fn, s4, (s4,) * 3
-
-
-def _infer_res(causal, scale, block_q, block_k, interpret, mesh,
-               arg_shapes, result_shape):
-    s4, s3 = _shardings(mesh, _q_spec_of(arg_shapes))
-    return (s4, s3)
-
-
-def _partition_res(causal, scale, block_q, block_k, interpret, mesh,
-                   arg_shapes, result_shape):
-    s4, s3 = _shardings(mesh, _q_spec_of(arg_shapes))
-
-    def lower_fn(q, k, v):
-        return _pallas_forward_res(q, k, v, causal, scale, block_q,
-                                   block_k, interpret)
-
-    return mesh, lower_fn, (s4, s3), (s4,) * 3
-
-
-def _infer_bwd(causal, scale, block_q, block_k, interpret, mesh,
-               arg_shapes, result_shape):
-    s4, _ = _shardings(mesh, _q_spec_of(arg_shapes))
-    return (s4, s4, s4)
-
-
-def _partition_bwd(causal, scale, block_q, block_k, interpret, mesh,
-                   arg_shapes, result_shape):
-    s4, s3 = _shardings(mesh, _q_spec_of(arg_shapes))
-
-    def lower_fn(q, k, v, out, lse, do):
-        return _pallas_backward(q, k, v, out, lse, do, causal, scale,
-                                block_q, block_k, interpret)
-
-    return mesh, lower_fn, (s4, s4, s4), (s4, s4, s4, s4, s3, s4)
-
-
-_STATIC = dict(static_argnums=(3, 4, 5, 6, 7))
-# Shardy wants need_replication factors sorted by introduction order
-# (b, tq, h, d from q, then tk from k).
-_REPL = ("tq", "d", "tk")
-
-_partitioned = custom_partitioning(_pallas_forward, **_STATIC)
-def_partition_compat(
-    _partitioned,
-    partition=_partition_fwd,
-    infer_sharding_from_operands=_infer_fwd,
-    sharding_rule="b tq h d, b tk h d, b tk h d -> b tq h d",
-    need_replication_factors=_REPL,
-)
-
-_partitioned_res = custom_partitioning(_pallas_forward_res, **_STATIC)
-def_partition_compat(
-    _partitioned_res,
-    partition=_partition_res,
-    infer_sharding_from_operands=_infer_res,
-    sharding_rule="b tq h d, b tk h d, b tk h d -> b tq h d, b h tq",
-    need_replication_factors=_REPL,
-)
-
-def _pallas_backward_nog(q, k, v, out, lse, do, causal, scale, block_q,
-                         block_k, interpret):
-    """Fixed-arity wrapper for custom_partitioning (the glse=None
-    default of _pallas_backward would otherwise count as an operand)."""
-    return _pallas_backward(q, k, v, out, lse, do, causal, scale,
-                            block_q, block_k, interpret)
-
-
-_partitioned_bwd = custom_partitioning(
-    _pallas_backward_nog, static_argnums=(6, 7, 8, 9, 10))
-def_partition_compat(
-    _partitioned_bwd,
-    partition=_partition_bwd,
-    infer_sharding_from_operands=_infer_bwd,
-    sharding_rule=("b tq h d, b tk h d, b tk h d, b tq h d, b h tq, "
-                   "b tq h d -> b tq h d, b tk h d, b tk h d"),
-    need_replication_factors=_REPL,
-)
+_partitioned = sharded(_pallas_forward, (_S4,) * 3, _S4)
+_partitioned_res = sharded(_pallas_forward_res, (_S4,) * 3, (_S4, _S3))
+_partitioned_bwd = sharded(_pallas_backward,
+                           (_S4, _S4, _S4, _S4, _S3, _S4), (_S4,) * 3)
 
 
 def _make_flash(fwd_prim, res_prim, bwd_prim):
@@ -730,7 +634,7 @@ _flash_local = _make_flash(_pallas_forward, _pallas_forward_res,
 
 # ---------------------------------------------------------------------------
 # Segmented (packed-sequence) variants: separate FIXED-ARITY primitives
-# — segment ids are real operands, and both custom_partitioning and
+# — segment ids are real operands, and both the mesh split and
 # custom_vjp count every non-static parameter as an operand slot, so
 # the plain primitives cannot grow an optional argument.
 # ---------------------------------------------------------------------------
@@ -757,83 +661,13 @@ def _pallas_backward_seg(q, k, v, qseg, kseg, out, lse, do, causal,
                             segment_ids=(qseg, kseg))
 
 
-def _seg_sharding(mesh, spec):
-    """1-D-per-token operands shard over batch only."""
-    return NamedSharding(mesh, P(spec[0], None))
-
-
-def _partition_fwd_seg(causal, scale, block_q, block_k, interpret, mesh,
-                       arg_shapes, result_shape):
-    s4, _ = _shardings(mesh, _q_spec_of(arg_shapes))
-    sseg = _seg_sharding(mesh, _q_spec_of(arg_shapes))
-
-    def lower_fn(q, k, v, qseg, kseg):
-        return _pallas_forward_seg(q, k, v, qseg, kseg, causal, scale,
-                                   block_q, block_k, interpret)
-
-    return mesh, lower_fn, s4, (s4, s4, s4, sseg, sseg)
-
-
-def _partition_res_seg(causal, scale, block_q, block_k, interpret, mesh,
-                       arg_shapes, result_shape):
-    s4, s3 = _shardings(mesh, _q_spec_of(arg_shapes))
-    sseg = _seg_sharding(mesh, _q_spec_of(arg_shapes))
-
-    def lower_fn(q, k, v, qseg, kseg):
-        return _pallas_forward_res_seg(q, k, v, qseg, kseg, causal,
-                                       scale, block_q, block_k, interpret)
-
-    return mesh, lower_fn, (s4, s3), (s4, s4, s4, sseg, sseg)
-
-
-def _partition_bwd_seg(causal, scale, block_q, block_k, interpret, mesh,
-                       arg_shapes, result_shape):
-    s4, s3 = _shardings(mesh, _q_spec_of(arg_shapes))
-    sseg = _seg_sharding(mesh, _q_spec_of(arg_shapes))
-
-    def lower_fn(q, k, v, qseg, kseg, out, lse, do):
-        return _pallas_backward_seg(q, k, v, qseg, kseg, out, lse, do,
-                                    causal, scale, block_q, block_k,
-                                    interpret)
-
-    return (mesh, lower_fn, (s4, s4, s4),
-            (s4, s4, s4, sseg, sseg, s4, s3, s4))
-
-
-_SEG_STATIC = dict(static_argnums=(5, 6, 7, 8, 9))
-
-_partitioned_seg = custom_partitioning(_pallas_forward_seg, **_SEG_STATIC)
-def_partition_compat(
-    _partitioned_seg,
-    partition=_partition_fwd_seg,
-    infer_sharding_from_operands=_infer_fwd,
-    sharding_rule=("b tq h d, b tk h d, b tk h d, b tq, b tk "
-                   "-> b tq h d"),
-    need_replication_factors=_REPL,
-)
-
-_partitioned_res_seg = custom_partitioning(_pallas_forward_res_seg,
-                                           **_SEG_STATIC)
-def_partition_compat(
-    _partitioned_res_seg,
-    partition=_partition_res_seg,
-    infer_sharding_from_operands=_infer_res,
-    sharding_rule=("b tq h d, b tk h d, b tk h d, b tq, b tk "
-                   "-> b tq h d, b h tq"),
-    need_replication_factors=_REPL,
-)
-
-_partitioned_bwd_seg = custom_partitioning(
-    _pallas_backward_seg, static_argnums=(8, 9, 10, 11, 12))
-def_partition_compat(
-    _partitioned_bwd_seg,
-    partition=_partition_bwd_seg,
-    infer_sharding_from_operands=_infer_bwd,
-    sharding_rule=("b tq h d, b tk h d, b tk h d, b tq, b tk, "
-                   "b tq h d, b h tq, b tq h d "
-                   "-> b tq h d, b tk h d, b tk h d"),
-    need_replication_factors=_REPL,
-)
+_partitioned_seg = sharded(_pallas_forward_seg,
+                           (_S4, _S4, _S4, _SSEG, _SSEG), _S4)
+_partitioned_res_seg = sharded(_pallas_forward_res_seg,
+                               (_S4, _S4, _S4, _SSEG, _SSEG), (_S4, _S3))
+_partitioned_bwd_seg = sharded(
+    _pallas_backward_seg,
+    (_S4, _S4, _S4, _SSEG, _SSEG, _S4, _S3, _S4), (_S4,) * 3)
 
 
 def _make_flash_seg(fwd_prim, res_prim, bwd_prim):
@@ -882,7 +716,7 @@ def local_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           interpret: Optional[bool] = None,
                           segment_ids=None) -> jax.Array:
     """flash_attention for use INSIDE shard_map bodies: per-shard
-    arrays, no custom_partitioning wrapper. Same fallbacks (dense for
+    arrays, no mesh split of its own. Same fallbacks (dense for
     degenerate lengths; dense off-TPU unless interpret=True) and the
     same optional packed-sequence ``segment_ids``."""
     return _entry(_flash_local, _flash_seg_local, q, k, v, causal, scale,

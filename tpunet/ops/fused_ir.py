@@ -68,10 +68,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
-from tpunet.compat import def_partition_compat
+from tpunet.ops.partition import sharded
 
 
 # ---------------------------------------------------------------------------
@@ -190,44 +189,14 @@ def _pallas_forward(x: jax.Array, w: jax.Array, interpret: bool):
 
 # SPMD: the op is trivially parallel over batch (the kernel grids over
 # N); H/W/channels stay replicated (Ci is contracted, Co would need w
-# sharded). Without a rule the partitioner would all-gather the batch.
+# sharded). Split over the step's mesh (tpunet/ops/partition.py);
+# without it the partitioner would all-gather the batch.
 
+_B4 = P("data", None, None, None)
+_B3 = P("data", None, None)
+_R2 = P(None, None)
 
-def _batch_spec(arg_shapes):
-    def spec_of(s):
-        sh = s.sharding
-        return sh.spec if isinstance(sh, NamedSharding) else P()
-    xs = list(spec_of(arg_shapes[0])) + [None] * 4
-    return P(xs[0], None, None, None)
-
-
-def _fwd_infer(interpret, mesh, arg_shapes, result_shape):
-    b = _batch_spec(arg_shapes)[0]
-    return (NamedSharding(mesh, P(b, None, None, None)),
-            NamedSharding(mesh, P(b, None, None)))
-
-
-def _fwd_partition(interpret, mesh, arg_shapes, result_shape):
-    b = _batch_spec(arg_shapes)[0]
-    arg_shardings = (NamedSharding(mesh, P(b, None, None, None)),
-                     NamedSharding(mesh, P(None, None)))
-    result_shardings = (NamedSharding(mesh, P(b, None, None, None)),
-                        NamedSharding(mesh, P(b, None, None)))
-
-    def lower_fn(x, w):
-        return _pallas_forward(x, w, interpret)
-
-    return mesh, lower_fn, result_shardings, arg_shardings
-
-
-_partitioned_fwd = custom_partitioning(_pallas_forward, static_argnums=(2,))
-def_partition_compat(
-    _partitioned_fwd,
-    partition=_fwd_partition,
-    infer_sharding_from_operands=_fwd_infer,
-    sharding_rule="n h w ci, ci co -> n h w co, n stat co",
-    need_replication_factors=("h", "w", "ci", "co", "stat"),
-)
+_partitioned_fwd = sharded(_pallas_forward, (_B4, _R2), (_B4, _B3))
 
 
 # ---------------------------------------------------------------------------
@@ -309,35 +278,8 @@ def _pallas_backward(x: jax.Array, g: jax.Array, y: jax.Array,
     )(x, g, y, w, chan)
 
 
-def _bwd_infer(act, interpret, mesh, arg_shapes, result_shape):
-    b = _batch_spec(arg_shapes)[0]
-    return (NamedSharding(mesh, P(b, None, None, None)),
-            NamedSharding(mesh, P(b, None, None)))
-
-
-def _bwd_partition(act, interpret, mesh, arg_shapes, result_shape):
-    b = _batch_spec(arg_shapes)[0]
-    batched = NamedSharding(mesh, P(b, None, None, None))
-    repl2 = NamedSharding(mesh, P(None, None))
-    arg_shardings = (batched, batched, batched, repl2, repl2)
-    result_shardings = (batched, NamedSharding(mesh, P(b, None, None)))
-
-    def lower_fn(x, g, y, w, chan):
-        return _pallas_backward(x, g, y, w, chan, act, interpret)
-
-    return mesh, lower_fn, result_shardings, arg_shardings
-
-
-_partitioned_bwd = custom_partitioning(_pallas_backward,
-                                       static_argnums=(5, 6))
-def_partition_compat(
-    _partitioned_bwd,
-    partition=_bwd_partition,
-    infer_sharding_from_operands=_bwd_infer,
-    sharding_rule=("n h w ci, n h w co, n h w co, ci co, six co "
-                   "-> n h w ci, n ci co"),
-    need_replication_factors=("h", "w", "ci", "co", "six"),
-)
+_partitioned_bwd = sharded(_pallas_backward,
+                           (_B4, _B4, _B4, _R2, _R2), (_B4, _B3))
 
 
 # ---------------------------------------------------------------------------

@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from tpunet.compat import shard_map
+from jax import shard_map
 
 
 def resolve_vocab_ce(vocab_ce: str, mesh, vocab_size: int) -> str:

@@ -34,7 +34,7 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
     configured = (coordinator_address or num_processes
                   or env.get("JAX_COORDINATOR_ADDRESS")
                   or env.get("JAX_NUM_PROCESSES"))
-    # jax only resolves JAX_COORDINATOR_ADDRESS itself (0.4.x);
+    # jax resolves only JAX_COORDINATOR_ADDRESS itself;
     # num_processes/process_id would fall through to cluster
     # auto-detection and fail on a plain CPU gang — resolve the env
     # vars here so the elastic agent's injected world (and the
@@ -51,18 +51,6 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
                   or env.get("MEGASCALE_COORDINATOR_ADDRESS"))
     if not (configured or on_tpu_pod):
         return
-    if env.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
-        # CPU gangs (tests, the elastic agent's CPU worlds): jax's
-        # cross-process collectives need an explicit implementation —
-        # the flag's env var is not consulted at backend init on this
-        # jax, so without this every cross-process psum dies with
-        # "Multiprocess computations aren't implemented on the CPU
-        # backend". gloo ships inside jaxlib; harmless single-process.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:
-            pass  # older/newer jax without the flag: keep going
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -103,11 +91,8 @@ def sync_hosts(name: str = "barrier") -> None:
 def coordination_client():
     """The jax coordination-service client, or None (single process /
     distributed not initialized)."""
-    try:
-        from jax._src import distributed
-        return distributed.global_state.client
-    except Exception:  # pragma: no cover - jax internals moved
-        return None
+    from jax._src import distributed
+    return distributed.global_state.client
 
 
 _AGREE_TIMEOUT_MS = 300_000

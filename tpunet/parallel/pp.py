@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpunet.compat import shard_map
+from jax import shard_map
 
 
 def gpipe(stage_apply: Callable, stacked_params, x, *,

@@ -9,6 +9,14 @@ a respawned replica deserializes its compiled programs instead of
 recompiling, which is the difference between a seconds-scale and a
 minutes-scale recovery (docs/serving.md "AOT warm-start").
 
+A chip belongs to one process, so every child is started with an
+environment that shows it exactly ONE chip — chip ``index`` of the
+host, through libtpu's visible-chips / process-bounds variables
+(``child_env``). Without that the first child on a multi-chip host
+takes every chip and the rest cannot start. The router parent never
+initialises a jax backend, so it holds no chip itself. Off-TPU (``JAX_PLATFORMS=cpu``)
+the variables are inert.
+
 Stopping is drain-then-kill: SIGTERM triggers the serve entry's
 graceful drain (in-flight streams finish, the final ``obs_serve``
 record flushes), and only a child still alive after ``drain_grace_s``
@@ -109,6 +117,25 @@ class Supervisor:
                 argv += ["--chaos", spec]
         return argv + self.serve_args
 
+    def child_env(self, index: int) -> Dict[str, str]:
+        """The child's environment: the parent's, plus the libtpu
+        variables that make chip ``index`` the only chip the child can
+        see (a 1x1x1 "slice" of its own, with its own runtime port so
+        co-hosted children never meet). An operator who already set
+        ``TPU_VISIBLE_CHIPS`` keeps their own assignment."""
+        env = dict(os.environ)
+        if "TPU_VISIBLE_CHIPS" not in env:
+            port = str(free_port("127.0.0.1"))
+            env.update({
+                "TPU_VISIBLE_CHIPS": str(index),
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+                "TPU_PROCESS_PORT": port,
+                "CLOUD_TPU_TASK_ID": "0",
+            })
+        return env
+
     def spawn(self, index: int,
               port: Optional[int] = None) -> ReplicaProcess:
         """Launch replica ``index`` (an OS-assigned port unless
@@ -129,6 +156,7 @@ class Supervisor:
         try:
             proc = subprocess.Popen(
                 self.child_argv(index, port, run_id),
+                env=self.child_env(index),
                 stdout=stdout, stderr=subprocess.STDOUT,
                 start_new_session=True)
         finally:

@@ -12,6 +12,7 @@ flush telemetry) rather than dropping connections.
 
 from __future__ import annotations
 
+import os
 import signal
 import sys
 
@@ -371,10 +372,15 @@ def main(argv=None) -> int:
     args = build_argparser().parse_args(argv)
     server = build_server(args)
     server.start()
+    import jax
+    dev = jax.devices()[0]
     print(f"tpunet.serve listening on "
           f"http://{args.host}:{server.port} "
           f"(slots={server.engine.slots}, "
-          f"buckets={server.engine.buckets})", flush=True)
+          f"buckets={server.engine.buckets}, "
+          f"platform={dev.platform}, device_kind={dev.device_kind}, "
+          f"visible_chips="
+          f"{os.environ.get('TPU_VISIBLE_CHIPS', 'all')})", flush=True)
 
     import threading
     stop = threading.Event()
@@ -393,6 +399,8 @@ def main(argv=None) -> int:
                   "draining", file=sys.stderr, flush=True)
             stop.set()
     clean = server.drain(args.drain_timeout_s)
+    from tpunet.utils.cache import compile_stats_line
+    print(compile_stats_line(), flush=True)
     print(f"drained ({'clean' if clean else 'forced'})", flush=True)
     return 0 if server.engine.error is None else 2
 

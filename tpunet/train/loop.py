@@ -28,6 +28,7 @@ from tpunet.obs.perf import train_flops_per_unit
 from tpunet.elastic import events as elastic_events
 from tpunet.parallel import (batch_sharding, make_mesh, replicated_sharding,
                              shard_host_batch)
+from tpunet.ops.partition import traced_under
 from tpunet.parallel.mesh import mesh_shape_dict
 from tpunet.parallel.tp import rules_for, state_shardings, tree_shardings
 from tpunet.train import metrics as M
@@ -82,20 +83,10 @@ class Trainer:
         state_sh = state_shardings(
             state, cfg.model, self.mesh, zero1=cfg.mesh.zero1,
             fsdp=cfg.mesh.fsdp)
-        if jax.process_count() > 1:
-            try:
-                self.state = jax.device_put(state, state_sh)
-            except ValueError:
-                # Older jax rejects device_put onto non-addressable
-                # (multi-controller global mesh) shardings; a jitted
-                # identity with pinned out_shardings reaches the same
-                # layout — every process holds the identical host
-                # state (deterministic same-seed init), which is
-                # exactly the replicated-input contract jit assumes.
-                self.state = jax.jit(lambda x: x,
-                                     out_shardings=state_sh)(state)
-        else:
-            self.state = jax.device_put(state, state_sh)
+        # Multi-controller too: every process holds the identical host
+        # state (deterministic same-seed init), which is what
+        # device_put onto a global-mesh sharding assumes.
+        self.state = jax.device_put(state, state_sh)
 
         # out_shardings pinned: without it XLA may propagate shard_map
         # internals (e.g. a 'seq'-sharded pos-embed gradient) onto the
@@ -185,13 +176,15 @@ class Trainer:
                                      gather_params=gather_sh,
                                      packed=packed) if self.is_lm
                    else make_eval_step(cfg.data, gather_params=gather_sh))
+        # Traced under the mesh so the Pallas kernels split over it
+        # (tpunet/ops/partition.py).
         self.train_step = jax.jit(
-            train_fn,
+            traced_under(self.mesh, train_fn),
             in_shardings=(state_sh, bsh, bsh, repl),
             out_shardings=(state_sh, repl),
             donate_argnums=0)
         self.eval_step = jax.jit(
-            eval_fn,
+            traced_under(self.mesh, eval_fn),
             in_shardings=(state_sh, bsh, bsh, bsh))
 
         self._prefetcher = None
