@@ -20,8 +20,11 @@ run's artifact) via ``MetricsLogger.read_records``.
 training phases (fwd / bwd / optimizer / ema / eval) from the
 windowed profiler's xplane under DIR (``--profile-dir``, or
 ``<checkpoint-dir>/profile``) — so a step-time regression names the
-phase that moved instead of one opaque host lap. Needs the ``xprof``
-package (TPU toolchain); without it the section degrades to a note.
+phase that moved instead of one opaque host lap. Read with JAX alone
+(``tpunet/obs/device_time.py``): the trace names each operation's
+instruction, and the programs' texts (``*.hlo.txt``, which the profiler
+window writes beside its trace at the end of the run) give each
+instruction its ``jax.named_scope``.
 """
 
 from __future__ import annotations
@@ -126,7 +129,7 @@ def render(summary: dict) -> list:
 
 
 def render_phases(phases: dict) -> list:
-    """Text lines for a ``trace_phase.phase_times`` dict."""
+    """Text lines for a ``device_time.phase_times`` dict."""
     lines = ["", "== device time by phase (profiled window) =="]
     lines.append(f"{'phase':>10} {'ms/window':>12} {'share':>7}")
     for ph, row in phases.items():
@@ -136,12 +139,13 @@ def render_phases(phases: dict) -> list:
 
 
 def device_phases(trace_dir: str):
-    """-> (phases dict or None, note lines). Degrades to a note when
-    xprof or the trace is unavailable."""
-    from tpunet.obs.trace_phase import hlo_stats_rows, phase_times
+    """-> (phases dict or None, note lines), from the xplane under
+    ``trace_dir`` and the programs' texts (``*.hlo.txt``) the profiler
+    window wrote beside it. A note when either is missing."""
+    from tpunet.obs.device_time import op_rows, phase_times
     try:
-        return phase_times(hlo_stats_rows(trace_dir)), []
-    except Exception as e:  # missing xprof / empty trace / bad xplane
+        return phase_times(op_rows(trace_dir)), []
+    except Exception as e:  # no trace / no program text / bad xplane
         return None, ["", f"device-phase attribution unavailable: {e}"]
 
 
@@ -167,7 +171,8 @@ def main(argv=None) -> int:
                     help="profiler trace dir (--profile-dir or "
                          "<checkpoint-dir>/profile): adds measured "
                          "device time by phase (fwd/bwd/optimizer/"
-                         "ema/eval); needs the xprof package")
+                         "ema/eval), from the trace and the program "
+                         "texts (*.hlo.txt) written beside it")
     args = ap.parse_args(argv)
     path = args.path
     if os.path.isdir(path):

@@ -3,7 +3,8 @@
 
 Traces the exact bench.py workload (MobileNetV2 @224, bf16, full train
 step: augment + fwd + bwd + Adam + metrics) with the JAX profiler on the
-real chip, converts the xplane with xprof's hlo_stats tool, and writes a
+real chip, names each device operation by the step's own HLO text
+(tpunet/obs/device_time.py: JAX alone, no xprof), and writes a
 measured per-op/per-category breakdown of where the step time goes —
 turning the round-4 "residual is unfused BN/elementwise traffic,
 sub-peak bandwidth, depthwise VPU time" *guess* into numbers.
@@ -83,14 +84,6 @@ def trace_step(trainer, gx, gy, steps: int, trace_dir: str) -> float:
     return time.perf_counter() - t0
 
 
-def hlo_stats(trace_dir: str):
-    """Per-HLO-op row dicts from the captured xplane (shared parser:
-    tpunet/obs/trace_phase.py, also behind obs_report.py --trace)."""
-    from tpunet.obs.trace_phase import hlo_stats_rows
-
-    return hlo_stats_rows(trace_dir)
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=512)
@@ -105,10 +98,11 @@ def main() -> None:
                          "was captured for the throughput numbers)")
     args = ap.parse_args()
 
-    bytes_breakdown = None
+    bytes_breakdown = texts = None
     if args.from_trace:
         # Parsing a kept trace needs no chip, and the process that
-        # parses it cannot know which device recorded it.
+        # parses it cannot know which device recorded it; the step's
+        # text lies beside the trace (``*.hlo.txt``).
         trace_dir, wall, trainer = args.from_trace, None, None
         device = {"platform": None, "device_kind": None,
                   "device_count": None}
@@ -117,27 +111,27 @@ def main() -> None:
         trainer, gx, gy = build_step(args.batch, args.image_size)
         # Byte attribution from the optimized module text (same
         # decomposition bench.py ships as bytes_per_image_breakdown);
-        # AOT-compiling here warms the executable the trace reuses.
+        # lowering it here warms the executable the trace reuses.
         from tpunet.obs import hlo_bytes
-        from tpunet.utils.prng import step_key
-        compiled = trainer.train_step.lower(
-            trainer.state, gx, gy, step_key(0, 0)).compile()
-        bytes_breakdown = hlo_bytes.per_image_breakdown(
-            compiled.as_text(), args.batch)
+        texts = trainer.program_texts()
+        (text,) = texts.values()
+        bytes_breakdown = hlo_bytes.per_image_breakdown(text, args.batch)
         trace_dir = tempfile.mkdtemp(prefix="tpunet-roofline-trace-")
         wall = trace_step(trainer, gx, gy, args.steps, trace_dir)
+        if args.keep_trace:
+            from tpunet.obs import device_time
+            device_time.write_program_texts(trace_dir)
         print(f"# traced {args.steps} steps in {wall:.2f}s "
               f"({args.steps * args.batch / wall:.0f} img/s/chip, incl. "
               "profiler overhead)", file=sys.stderr)
 
-    # Everything past the trace runs under try/finally: hlo_stats
-    # parses xprof columns by exact label (version-fragile) and the
-    # output write can fail too — neither may leak the mkdtemp trace
-    # dir this run created, or skip closing the trainer's
-    # checkpointer/threads.
+    # Everything past the trace runs under try/finally: reading the
+    # trace and the output write can fail — neither may leak the
+    # mkdtemp trace dir this run created, or skip closing the
+    # trainer's checkpointer/threads.
     try:
         _attrib_and_write(args, trace_dir, wall, bytes_breakdown,
-                          device)
+                          device, texts)
     finally:
         if args.from_trace or args.keep_trace:
             # Never delete a trace the CALLER owns (--from-trace) or
@@ -152,41 +146,31 @@ def main() -> None:
 
 
 def _attrib_and_write(args, trace_dir: str, wall, bytes_breakdown,
-                      device: dict) -> None:
-    from tpunet.obs.hlo_bytes import phase_of
-
-    rows = hlo_stats(trace_dir)
-
-    def f(row, name, default=0.0):
-        v = row.get(name)
-        try:
-            return float(v)
-        except (TypeError, ValueError):
-            return default
+                      device: dict, texts=None) -> None:
+    from tpunet.obs.device_time import op_rows
+    from tpunet.obs.hlo_bytes import categorize, phase_of
 
     by_cat = {}
     by_src = {}
     by_phase = {}
-    bw_weighted = 0.0
-    hbm_time = 0.0
     ops = []
-    for r in rows:
-        t = f(r, "Total self time (us)")
-        cat = r.get("HLO op category") or "?"
+    for r in op_rows(trace_dir, texts):
+        t = r["Total self time (us)"]
+        name = r["Framework op name"]
+        # the byte table's categories (conv_fwd / bn / optimizer ...):
+        # the instruction's name stands in for its opcode
+        cat = categorize(r["HLO op name"].split(".")[0], name)
+        r["HLO op category"] = cat
         by_cat[cat] = by_cat.get(cat, 0.0) + t
         # attribute to framework source (module/op) for actionability
-        src = (r.get("Framework op name") or "?").split("/")
+        src = (name or "?").split("/")
         src = "/".join(src[1:3]) if len(src) > 2 else "/".join(src)
         by_src[src] = by_src.get(src, 0.0) + t
         # and to the training phase (fwd / bwd / optimizer / ema) —
         # the same classifier scripts/obs_report.py --trace uses, so
         # the time and bytes tables split the step identically.
-        ph = phase_of(r.get("Framework op name") or "")
+        ph = phase_of(name)
         by_phase[ph] = by_phase.get(ph, 0.0) + t
-        bw = f(r, "Measured memory BW (GiB/s)")
-        if r.get("Bound by") == "HBM":
-            hbm_time += t
-            bw_weighted += t * bw
         ops.append((t, r))
     total = sum(by_cat.values()) or 1.0
     ops.sort(key=lambda x: -x[0])
@@ -195,12 +179,9 @@ def _attrib_and_write(args, trace_dir: str, wall, bytes_breakdown,
         return [
             {"pct": round(100.0 * t / total, 2),
              "us_per_step": round(t / args.steps, 1),
-             "category": r.get("HLO op category"),
-             "bound_by": r.get("Bound by"),
-             "measured_bw_gibs": round(f(r, "Measured memory BW (GiB/s)"), 1),
-             "gflops": round(f(r, "Model GFLOP/s"), 1),
-             "op": r.get("HLO op name"),
-             "source": (r.get("Framework op name") or "")[:140]}
+             "category": r["HLO op category"],
+             "op": r["HLO op name"],
+             "source": (r["Framework op name"] or "")[:140]}
             for t, r in ops[:n]]
 
     out = {
@@ -211,9 +192,6 @@ def _attrib_and_write(args, trace_dir: str, wall, bytes_breakdown,
         "img_per_sec_per_chip_traced": wall and round(
             args.steps * args.batch / wall, 1),
         "total_profiled_us_per_step": round(total / args.steps, 1),
-        "hbm_bound_time_pct": round(100.0 * hbm_time / total, 2),
-        "hbm_bound_mean_achieved_bw_gibs": round(
-            bw_weighted / hbm_time, 1) if hbm_time else None,
         "by_phase_pct": {
             k: round(100.0 * v / total, 2)
             for k, v in sorted(by_phase.items(), key=lambda kv: -kv[1])},
@@ -233,9 +211,7 @@ def _attrib_and_write(args, trace_dir: str, wall, bytes_breakdown,
                       ("platform", "device_kind", "device_count",
                        "img_per_sec_per_chip_traced",
                        "total_profiled_us_per_step",
-                       "hbm_bound_time_pct",
-                       "hbm_bound_mean_achieved_bw_gibs",
-                       "by_category_pct")}, indent=1))
+                       "by_phase_pct", "by_category_pct")}, indent=1))
     print(f"# wrote {args.out}", file=sys.stderr)
 
 

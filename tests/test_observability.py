@@ -5,6 +5,7 @@ the non-finite-loss guard (SURVEY.md section 5: the reference has none
 of these — stdout epoch lines are its only observability and a NaN run
 would burn its full walltime)."""
 
+import glob
 import os
 import time
 
@@ -265,9 +266,15 @@ def test_windowed_profiling_captures_only_the_window(tmp_path):
     try:
         trainer.train_one_epoch(1)   # 4 steps; window = steps [1, 3)
         assert not trainer.obs.profiler.running   # closed at step 3
+        assert not glob.glob(os.path.join(trace_dir, "*.hlo.txt"))
     finally:
         trainer.close()
     assert os.path.isdir(trace_dir)
+    # the end of the run leaves the step's text beside the trace, for
+    # scripts/obs_report.py --trace (tpunet/obs/device_time.py)
+    (text,) = glob.glob(os.path.join(trace_dir, "*.hlo.txt"))
+    with open(text) as f:
+        assert "tpunet_fwd_bwd" in f.read()
 
 
 def test_window_ending_at_epoch_boundary_closes_at_the_edge(tmp_path):

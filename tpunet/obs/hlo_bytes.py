@@ -228,16 +228,9 @@ def _computations(hlo_text: str) -> Tuple[Optional[str], Dict[str, List[str]]]:
     return entry, comps
 
 
-def _parse_instr(line: str, sizes: Dict[str, int]
-                 ) -> Optional[Tuple[str, int, int, str]]:
-    """-> (opcode, out_bytes, operand_bytes, op_name) or None.
-
-    ``sizes`` maps the instruction names seen so far in this
-    computation to their output bytes; this instruction is added. The
-    HLO text jax 0.9.0 prints names operands without their types
-    (``dot(%x.1, %w.1)``), so operand bytes are looked up by name;
-    typed operands (``dot(f32[256,128]{1,0} %x.1, ...)``) are summed
-    as printed."""
+def _instr_parts(line: str) -> Optional[Tuple[str, str, str, str, str]]:
+    """-> (name, output type, opcode, operand segment, what follows
+    the operands: attributes and metadata) or None."""
     m = _INSTR_RE.match(line)
     if not m:
         return None
@@ -262,7 +255,6 @@ def _parse_instr(line: str, sizes: Dict[str, int]
     om = re.match(r"([\w\-]+)\(", rest)
     if not om:
         return None
-    opcode = om.group(1)
     # Operand segment: the matching paren after the opcode. metadata/
     # attrs follow it, so quoted strings never reach the shape regex.
     depth, start = 0, om.end() - 1
@@ -275,9 +267,25 @@ def _parse_instr(line: str, sizes: Dict[str, int]
             if depth == 0:
                 end = i
                 break
-    args = rest[start + 1:end]
+    return name, type_str, om.group(1), rest[start + 1:end], rest[end:]
+
+
+def _parse_instr(line: str, sizes: Dict[str, int]
+                 ) -> Optional[Tuple[str, int, int, str]]:
+    """-> (opcode, out_bytes, operand_bytes, op_name) or None.
+
+    ``sizes`` maps the instruction names seen so far in this
+    computation to their output bytes; this instruction is added. The
+    HLO text jax 0.9.0 prints names operands without their types
+    (``dot(%x.1, %w.1)``), so operand bytes are looked up by name;
+    typed operands (``dot(f32[256,128]{1,0} %x.1, ...)``) are summed
+    as printed."""
+    parts = _instr_parts(line)
+    if parts is None:
+        return None
+    name, type_str, opcode, args, tail = parts
     op_name = ""
-    nm = _OPNAME_RE.search(rest[end:])
+    nm = _OPNAME_RE.search(tail)
     if nm:
         op_name = nm.group(1)
     out_bytes = sizes[name] = _shape_bytes(type_str)
@@ -330,6 +338,63 @@ def _called_comps(line: str) -> List[str]:
             out.append(single)
         if many:
             out.extend(t.strip().lstrip("%") for t in many.split(","))
+    return out
+
+
+_FUSED_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def _common_scope(paths: List[str]) -> str:
+    """Longest common prefix of op_name paths, cut at a ``/``."""
+    split = [p.split("/") for p in paths if p]
+    if not split:
+        return ""
+    common = split[0]
+    for parts in split[1:]:
+        n = 0
+        while n < min(len(common), len(parts)) and common[n] == parts[n]:
+            n += 1
+        common = common[:n]
+    return "/".join(common)
+
+
+def op_scopes(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: op_name path} for the instructions a device
+    trace shows (``fusion.59``, ``tpunet_fused_ir_bwd.38``,
+    ``copy.467``): ENTRY and the bodies it calls (while / conditional
+    / call), never the inside of a fusion. A fusion takes its own
+    ``metadata={op_name=...}``; where that is empty, the longest common
+    scope prefix of the instructions in its fused computation. Any
+    other instruction the compiler left without a name (a layout copy,
+    the halves of an async copy) takes its first named operand's: it
+    moves that operation's data. What still has no scope maps to
+    ``""``."""
+    entry, comps = _computations(hlo_text)
+    out: Dict[str, str] = {}
+    todo, seen = [entry] if entry else [], set()
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            parts = _instr_parts(line)
+            if parts is None:
+                continue
+            name, _type, _opcode, args, tail = parts
+            om = _OPNAME_RE.search(tail)
+            path = om.group(1) if om else ""
+            fused = _FUSED_RE.search(tail)
+            if not path and fused:
+                path = _common_scope([
+                    m.group(1) for m in map(_OPNAME_RE.search,
+                                            comps.get(fused.group(1), []))
+                    if m])
+            if not path:
+                path = next((out[tok] for tok in _OPERAND_RE.findall(args)
+                             if out.get(tok)), "")
+            out[name] = path
+            todo.extend(_called_comps(line))
     return out
 
 

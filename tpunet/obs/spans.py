@@ -14,6 +14,8 @@ inside the window would escape it.
 from __future__ import annotations
 
 import contextlib
+import glob
+import os
 
 import jax
 
@@ -94,3 +96,14 @@ class WindowedProfiler:
         if self.running:
             self._stop(sync)
         self._done = True
+        if self.profile_dir and glob.glob(os.path.join(
+                self.profile_dir, "**", "*.xplane.pb"), recursive=True):
+            # The programs' texts beside the trace, so that
+            # scripts/obs_report.py --trace can name its operations
+            # (tpunet/obs/device_time.py). End of run, off every step.
+            try:
+                from tpunet.obs import device_time
+                device_time.write_program_texts(self.profile_dir)
+            except Exception as e:  # noqa: BLE001 — never stop a run
+                print(f"profile: program texts not written: {e}",
+                      flush=True)

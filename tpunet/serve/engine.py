@@ -516,6 +516,50 @@ class Engine:
         # first shared-prefix request prefills only the suffix.
         if self._prefix is not None and self._prefix_store is not None:
             self._warm_start_prefix()
+        # Lazy: nothing is lowered or printed until a reader of the
+        # device trace asks for the programs' texts.
+        from tpunet.obs import device_time
+        device_time.register_programs(self.program_texts)
+
+    def _step_avals(self, width: int) -> list:
+        """The masked step's arguments at token width ``width``, as
+        shapes."""
+        import jax
+
+        def sds(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+        n = self.slots
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
+        f32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32)  # noqa: E731
+        avals = [sds(self.variables["params"]), sds(self._cache),
+                 i32(n, width), i32(n), jax.ShapeDtypeStruct((n,), bool)]
+        if self._paged_kv is not None:
+            avals.append(i32(n, self.pages_per_slot))
+        if self.device_sampling:
+            avals += [i32(n), f32(n), i32(n), f32(n), i32(n), i32(n)]
+        return avals
+
+    def program_texts(self) -> dict:
+        """``{label: optimized HLO text}`` of the masked step at each
+        token width the engine holds (1 = decode, a bucket = prefill):
+        the AOT executable's own text where one was warm-started, else
+        the jit program lowered for the live parameters and pool (a
+        mesh engine's carry their shardings) and the host inputs'
+        shapes — the signature the running program was compiled for,
+        so a width that has run compiles nothing. Registered with
+        tpunet/obs/device_time.py at construction and called only by a
+        reader of the device trace, never by the engine."""
+        texts = {}
+        for width in (1,) + self.buckets:
+            program = self._aot.get(width)
+            if program is None:
+                program = self._step.lower(
+                    self.variables["params"], self._cache,
+                    *self._step_avals(width)[2:]).compile()
+            texts[f"jit_{self._step.__name__}/w{width}"] = program.as_text()
+        return texts
 
     def _warm_start_aot(self, store) -> None:
         """Load (or compile-and-save) every program the pool can run.
@@ -533,16 +577,8 @@ class Engine:
         f32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32)  # noqa: E731
         pos_s = i32(self.slots)
         act_s = jax.ShapeDtypeStruct((self.slots,), bool)
-        extra_s = []
-        if self._paged_kv is not None:
-            extra_s.append(i32(self.slots, self.pages_per_slot))
-        if self.device_sampling:
-            extra_s += [i32(self.slots), f32(self.slots),
-                        i32(self.slots), f32(self.slots),
-                        i32(self.slots), i32(self.slots)]
         for width in (1,) + self.buckets:
             tag = f"w{width}"
-            toks_s = jax.ShapeDtypeStruct((self.slots, width), np.int32)
             program = store.load("masked_step", tag)
             if program is None:
                 # Compile fresh (persistent compile cache off): a
@@ -551,8 +587,7 @@ class Engine:
                 from tpunet.utils.cache import serializable_compile
                 with serializable_compile():
                     program = self._step.lower(
-                        params_s, cache_s, toks_s, pos_s, act_s,
-                        *extra_s).compile()
+                        *self._step_avals(width)).compile()
                 saved = store.save("masked_step", tag, program)
                 self.aot_status[tag] = ("compiled+saved" if saved
                                         else "compiled")

@@ -23,7 +23,7 @@ from tpunet.config import TrainConfig
 from tpunet.data import (eval_batches, get_dataset, steps_per_epoch,
                          timed_batches, train_batches)
 from tpunet.obs import JsonlSink, Observability, RunUnhealthyError
-from tpunet.obs import flightrec
+from tpunet.obs import device_time, flightrec
 from tpunet.obs.perf import train_flops_per_unit
 from tpunet.elastic import events as elastic_events
 from tpunet.parallel import (batch_sharding, make_mesh, replicated_sharding,
@@ -311,6 +311,9 @@ class Trainer:
         self.history: List[Dict[str, float]] = []
         self._hbm_attrib_pending = bool(cfg.obs.enabled
                                         and cfg.obs.hbm_attrib)
+        # Lazy: nothing is lowered until a reader of the device trace
+        # (or --obs-hbm-attrib) asks for the step's text.
+        device_time.register_programs(self.program_texts)
         if cfg.checkpoint.resume:
             self._try_resume()
 
@@ -522,23 +525,39 @@ class Trainer:
                      f"lr {lr:.3e}")
         return M.summarize(acc if acc is not None else M.zeros_metrics())
 
+    def program_texts(self) -> Dict[str, str]:
+        """``{label: optimized HLO text}`` of the train step: the one
+        way to the step's text — the device trace's scope table
+        (tpunet/obs/device_time.py) and --obs-hbm-attrib both read it.
+        Lowered from the live state and one global batch's shape under
+        the step's batch sharding, which is the signature the running
+        step was compiled for: once that has run, nothing compiles."""
+        def batch(rows, dtype):
+            return jax.ShapeDtypeStruct(
+                (self.cfg.data.batch_size,) + rows.shape[1:], dtype,
+                sharding=batch_sharding(self.mesh))
+        text = self.train_step.lower(
+            self.state, batch(self.train_x, self.train_x.dtype),
+            batch(self.train_y, np.int32),
+            step_key(self.cfg.seed, 0)).compile().as_text()
+        return {device_time.module_name(text): text}
+
     def _attribute_hbm_bytes(self, bx, by, rng) -> None:
-        """--obs-hbm-attrib: once, before the first step, AOT-lower
-        the train step and mirror the per-op-category decomposition of
-        its cost-analysis HBM bytes into the hbm_bytes_per_image_*
-        gauges (tpunet/obs/hlo_bytes.py). The extra lowering compiles
-        nothing new when the persistent compile cache is warm; any
-        failure is logged and training proceeds (attribution is
-        observability, never a reason to stop a run)."""
+        """--obs-hbm-attrib: once, before the first step, mirror the
+        per-op-category decomposition of the train step's
+        cost-analysis HBM bytes into the hbm_bytes_per_image_* gauges
+        (tpunet/obs/hlo_bytes.py). The step's arguments are not read
+        (``program_texts`` lowers from the state and the batch's
+        shape); any failure is logged and training proceeds
+        (attribution is observability, never a reason to stop a
+        run)."""
         try:
             from tpunet.obs import hlo_bytes
-            gx, gy = shard_host_batch(self.mesh, bx, by.astype(np.int32))
-            compiled = self.train_step.lower(
-                self.state, gx, gy, rng).compile()
+            (text,) = self.program_texts().values()
             per_chip = max(1, self.cfg.data.batch_size
                            // jax.device_count())
             self.obs.set_hbm_breakdown(hlo_bytes.per_image_breakdown(
-                compiled.as_text(), per_chip))
+                text, per_chip))
         except Exception as e:  # pragma: no cover - backend-specific
             log0(f"hbm byte attribution failed: {e}")
 
