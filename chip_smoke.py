@@ -27,9 +27,12 @@ supports (depth is what the model's reference workload uses):
 
 - kernels: on-chip parity (``interpret=False``) of conv1x1_bn_act,
   flash_attention (plain + segmented) and depthwise_conv3x3 fwd/bwd
-  against the repo's references at the 224px / T=2048 shapes; then the
-  default Trainer's train_step is lowered and its compiled text must
-  hold the Pallas custom calls.
+  against the repo's references at the 224px / T=2048 shapes; one
+  width-1 decode step of a GPT-2 XL-wide LM (25 heads of 64, 16-token
+  pages, bfloat16 pool) through the ``tpunet_paged_decode`` kernel and
+  through the dense gather path, logits compared; then the default
+  Trainer's train_step is lowered and its compiled text must hold the
+  Pallas custom calls.
 - train_vision: ``python train.py`` MobileNetV2 1.0 / 224px / bf16 /
   batch 128 for one short epoch with checkpoints, ``--resume`` for one
   more, ``--eval-only``.
@@ -85,6 +88,18 @@ LM_TRAIN_WIDTH = ["--vit-hidden", "2048", "--vit-depth", "8",
                   "--vit-heads", "16"]
 TINY_LM_WIDTH = ["--vit-hidden", "64", "--vit-depth", "2",
                  "--vit-heads", "4"]
+# The paged decode check: the serve cell's geometry (benchmark/configs/
+# gpt2-xl.json, slots 16, 16-token pages) at the train cell's depth.
+# How far the two paths may differ in a logit. Both are sound, and
+# both round to bfloat16 at every layer: on the chip they differ by
+# 0.0316 where the largest logit is 4.28 — one bfloat16 step there is
+# 0.03125 (my chip run, PR 26). That is the size of the 0.023-0.039 by
+# which a served token's float32 reference logit lies below the best
+# one when the served path is sound, and half the 0.12 at which the
+# benchmark calls it unsound (benchmark/workloads/
+# gpt2-xl.serve-closed16.json, served_logit_gap_max); a kernel that
+# drops a page or a mask is off by tenths.
+PAGED_DECODE_LOGIT_TOL = 0.06
 
 _DEVICES_RE = re.compile(
     r"JAX devices: (\d+) \((\d+) local\), processes: (\d+), "
@@ -807,6 +822,101 @@ def _device_record() -> dict:
             "count": len(jax.devices())}
 
 
+def _paged_decode_check(rehearse: bool, rows: list,
+                        failures: list) -> None:
+    """One width-1 decode step over one pool, through the in-place
+    kernel and through the dense gather path (the dispatch's view of
+    the backend is replaced here, in the smoke; the program has no
+    switch): the pool is filled by a 128-wide prefill, which takes the
+    dense path either way, then ragged rows decode one token."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from tpunet.config import ModelConfig
+    from tpunet.models import create_model, init_variables
+    from tpunet.models.vit import PagedKV
+    from tpunet.ops import paged_decode
+
+    if rehearse:
+        hidden, depth, heads, vocab, max_len, fill = 320, 2, 5, 64, 64, 32
+    else:
+        hidden, depth, heads, vocab, max_len, fill = (1600, 12, 25,
+                                                      50257, 1024, 128)
+    slots, pt = 16, 16
+    pps = max_len // pt
+    model = create_model(ModelConfig(
+        name="lm", vit_hidden=hidden, vit_depth=depth, vit_heads=heads,
+        vocab_size=vocab, max_seq_len=max_len, dropout_rate=0.0,
+        dtype="bfloat16", param_dtype="float32"))
+    params = init_variables(model, jax.random.PRNGKey(3),
+                            seq_len=8)["params"]
+    paged = PagedKV(pages=slots * pps + 1, page_tokens=pt)
+    rng = np.random.default_rng(9)
+    table = jnp.asarray(rng.permutation(
+        np.arange(1, slots * pps + 1)).reshape(slots, pps), jnp.int32)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, max_len), jnp.int32),
+        decode=True, paged_kv=paged, page_table=table))
+    cache = jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype), shapes["cache"])
+
+    def step(params, cache, tokens, positions, active):
+        logits, mutated = model.apply(
+            {"params": params, "cache": cache}, tokens, train=False,
+            decode=True, pos_offset=positions, decode_active=active,
+            paged_kv=paged, page_table=table, mutable=["cache"])
+        return mutated["cache"], logits
+
+    def tokens(width):
+        return jnp.asarray(rng.integers(0, vocab, size=(slots, width)),
+                           jnp.int32)
+
+    everyone = jnp.ones((slots,), bool)
+    cache, _ = jax.jit(step)(params, cache, tokens(fill),
+                             jnp.zeros((slots,), jnp.int32), everyone)
+    # a page boundary and its neighbours, one key, the whole prefill;
+    # every fourth row inactive
+    positions = jnp.asarray(
+        [fill, fill - 1, pt, pt - 1, pt + 1, 1, 0, fill // 2] * 2,
+        jnp.int32)
+    active = jnp.asarray([i % 4 != 3 for i in range(slots)])
+    tok = tokens(1)
+    real = paged_decode._on_tpu
+    out = {}
+    try:
+        for path, on in (("kernel", True), ("dense", False)):
+            paged_decode._on_tpu = lambda on=on: on
+            # a function of its own: jit's trace cache is keyed by it
+            fn = jax.jit(lambda *a: step(*a))
+            text = fn.lower(params, cache, tok, positions,
+                            active).as_text()
+            calls = text.count("tpunet_paged_decode")
+            if bool(calls) != on and not rehearse:   # interpreted: none
+                failures.append({"paged_decode": f"{path} path lowered "
+                                 f"with {calls} kernel mentions"})
+            out[path] = np.asarray(
+                fn(params, cache, tok, positions, active)[1],
+                np.float32)[np.asarray(active), 0]
+    finally:
+        paged_decode._on_tpu = real
+    err = np.abs(out["kernel"] - out["dense"])
+    row = {"kernel": f"paged_decode[{slots}x1 h{heads}x"
+                     f"{hidden // heads} depth{depth} pt{pt} bf16 pool]",
+           "tensor": "logits", "max_abs_err": float(err.max()),
+           "mean_abs_err": float(err.mean()),
+           "max_abs_ref": float(np.abs(out["dense"]).max()),
+           "argmax_agree": float(np.mean(
+               out["kernel"].argmax(-1) == out["dense"].argmax(-1))),
+           "atol": PAGED_DECODE_LOGIT_TOL}
+    rows.append(row)
+    say(row)
+    if not (np.isfinite(out["kernel"]).all()
+            and err.max() <= PAGED_DECODE_LOGIT_TOL):
+        failures.append(row)
+
+
 def _kernels_child(rehearse: bool) -> int:
     import numpy as np
 
@@ -1000,6 +1110,7 @@ def _kernels_child(rehearse: bool) -> int:
         compare(tag, "dx", got[1], want[1], 5e-2, 5e-2)
         compare(tag, "dw", got[2], want[2], 5e-2, 5e-2)
 
+    _paged_decode_check(rehearse, rows, failures)
     kernels_s = time.monotonic() - t0
 
     # -- the default Trainers' own steps hold the Pallas calls -------

@@ -9,7 +9,9 @@ depthwise backward passed all of them and was refused on the chip
 (CHANGES.md PR 21).
 
 Shapes are the ones the smoke (chip_smoke.py) checks for parity on the
-chip: MobileNetV2 224px at batch 128, attention at [4, 2048, 16, D].
+chip: MobileNetV2 224px at batch 128, attention at [4, 2048, 16, D],
+the serve cell's width-1 decode over a paged pool (16 slots, 25 heads of
+64, 16-token pages).
 Nothing runs, so nothing here says anything about results or times.
 
 The topology is described inside a fixture (never at import: only one
@@ -22,7 +24,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from tpunet.ops import depthwise_conv3x3, fused_ir
+from tpunet.ops import depthwise_conv3x3, fused_ir, paged_decode
 from tpunet.ops.flash import flash_attention
 
 
@@ -126,3 +128,73 @@ def test_depthwise_conv3x3_compiles(one_chip, no_compile_cache, stride,
     _compile(fwd if direction == "fwd" else bwd,
              ((128, 112, 112, 96), BF16), ((3, 3, 96), BF16),
              sharding=one_chip)
+
+
+# The serve cell's decode geometry (benchmark/configs/gpt2-xl.json,
+# slots 16, ServeConfig's 16-token pages over max_seq_len 1024).
+SLOTS, HEADS, HEAD_DIM, PAGE_TOKENS, PAGES_PER_SLOT = 16, 25, 64, 16, 64
+POOL_ROWS = (SLOTS * PAGES_PER_SLOT + 1) * PAGE_TOKENS
+
+
+@pytest.mark.parametrize("dtype", [BF16, jnp.float32])
+def test_paged_decode_compiles(one_chip, no_compile_cache, dtype):
+    def attend(q, k_pool, v_pool, table, lengths):
+        return paged_decode.paged_decode_attention(
+            q, k_pool, v_pool, table, lengths, page_tokens=PAGE_TOKENS,
+            interpret=False)
+
+    pool = ((POOL_ROWS, paged_decode.pool_width(HEADS, HEAD_DIM)), dtype)
+    text = _compile(attend, ((SLOTS, HEADS, HEAD_DIM), dtype), pool, pool,
+                    ((SLOTS, PAGES_PER_SLOT), jnp.int32),
+                    ((SLOTS,), jnp.int32), sharding=one_chip)
+    assert "tpunet_paged_decode" in text
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_paged_attention_layer_never_converts_the_pool(
+        one_chip, no_compile_cache, monkeypatch, width):
+    """One attention layer's decode program over the donated pool, as
+    the engine compiles it: the new rows are scattered into the
+    parameter in place and nothing copies a whole pool buffer. (With
+    the pool stored ``[rows, 25, 64]`` or ``[rows, 1600]`` the TPU lays
+    it out rows-minor and every program converts it in and out — 38 ms
+    of a 140 ms GPT-2 XL decode step, PERF.md section 6, PR 26.) The
+    width-1 program holds the kernel, the bucket-wide one does not."""
+    import re
+
+    from tpunet.models.vit import Attention, PagedKV
+
+    monkeypatch.setattr(paged_decode, "_on_tpu", lambda: True)
+    monkeypatch.setattr(paged_decode, "_interpret", lambda: False)
+    paged = PagedKV(pages=SLOTS * PAGES_PER_SLOT + 1,
+                    page_tokens=PAGE_TOKENS)
+    attn = Attention(HEADS, dtype=BF16)
+    hidden = HEADS * HEAD_DIM
+    table = jnp.zeros((SLOTS, PAGES_PER_SLOT), jnp.int32)
+    shapes = jax.eval_shape(lambda: attn.init(
+        jax.random.PRNGKey(0), jnp.zeros((SLOTS, 8, hidden), BF16),
+        decode=True, paged_kv=paged, page_table=table))
+
+    def step(params, cache, x, positions, active, table):
+        y, mutated = attn.apply(
+            {"params": params, "cache": cache}, x, decode=True,
+            positions=positions, active=active, paged_kv=paged,
+            page_table=table, mutable=["cache"])
+        return mutated["cache"], y
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(shapes["params"]), on_chip(shapes["cache"]),
+        sds((SLOTS, width, hidden), BF16), sds((SLOTS,), jnp.int32),
+        sds((SLOTS,), bool),
+        sds((SLOTS, PAGES_PER_SLOT), jnp.int32)).compile().as_text()
+    pool = rf"bf16\[{POOL_ROWS},{paged_decode.pool_width(HEADS, HEAD_DIM)}\]"
+    assert re.search(pool + r"\{1,0[:}]", text)       # row-major
+    assert not re.search(pool + r"\S* copy\(", text)
+    assert ("tpu_custom_call" in text) == (width == 1)

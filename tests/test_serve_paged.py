@@ -518,6 +518,53 @@ def test_prefix_spill_and_warm_start_roundtrip(tmp_path, tiny_lm):
     assert snap2["serve_prefill_tokens_total"] == p2.size - 8
 
 
+def test_prefix_store_of_the_old_row_shape_is_refused(tmp_path, tiny_lm):
+    """A page spilled as ``[page_tokens, H, D]`` rows (the pool's shape
+    before the rows went flat and lane-padded) is never mapped into the
+    ``[page_tokens, W]`` pool: build_prefix_store scopes the new layout
+    by its digest, and an old-shape entry that still turns up under
+    this digest is skipped by its shape — no warm load, no crash, and
+    the request prefills everything and is still solo-greedy-exact."""
+    from tpunet.serve.prefixcache import build_prefix_store
+    from tpunet.serve.prefixcache.store import PrefixStore
+
+    model, variables = tiny_lm
+    cfg = ServeConfig(slots=2, queue_max=8, prefill_buckets=(16,),
+                      default_max_new_tokens=6, emit_every_s=0.0,
+                      kv_pages=12, kv_page_tokens=4)
+    good = build_prefix_store(str(tmp_path / "good"), TINY, cfg)
+    (tmp_path / "good").mkdir()
+    rng = np.random.default_rng(23)
+    prompt = rng.integers(0, TINY.vocab_size, size=11).astype(np.int32)
+    eng = Engine(model, variables, cfg, prefix_store=good).start()
+    try:
+        eng.submit(prompt, max_new_tokens=2).result(timeout=120)
+    finally:
+        eng.stop()
+    entries = list(good.load_all())
+    assert len(entries) >= 2
+    heads, head_dim = TINY.vit_heads, TINY.vit_hidden // TINY.vit_heads
+    assert all(r.shape == (4, 128) for e in entries for r in e["rows"])
+
+    (tmp_path / "old").mkdir()
+    old = PrefixStore(str(tmp_path / "old"), good.store_digest)
+    for e in entries:
+        rows = [r[:, :heads * head_dim].reshape(4, heads, head_dim)
+                for r in e["rows"]]
+        assert old.save(e["digest"], e["parent"], e["depth"], rows)
+    eng2 = Engine(model, variables, cfg, prefix_store=old).start()
+    try:
+        assert eng2.registry.snapshot().get(
+            "serve_prefix_warm_loads_total", 0) == 0
+        out = eng2.submit(prompt, max_new_tokens=5).result(timeout=120)
+    finally:
+        eng2.stop()
+    assert out == solo_greedy(tiny_lm, prompt, 5)
+    snap = eng2.registry.snapshot()
+    assert snap["serve_prefix_hits_total"] == 0
+    assert snap["serve_prefill_tokens_total"] == prompt.size
+
+
 # ---------------------------------------------------------------------------
 # int8 KV parity gate
 # ---------------------------------------------------------------------------
@@ -637,6 +684,8 @@ def test_kv_gauges_and_serve_record_fields(tiny_lm):
     assert rec["kv_pages_total"] == 10
     assert rec["kv_pages_used"] == 0
     assert rec["kv_bytes_per_token"] > 0
+    # off the TPU the decode program attends through the dense path
+    assert snap["serve_decode_attend_kernel"] == 0
 
 
 # ---------------------------------------------------------------------------

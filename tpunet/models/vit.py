@@ -64,11 +64,18 @@ class PagedKV:
     stored alongside the page (per page-row scale — a single scalar
     per page cannot absorb incremental writes without rescaling the
     whole page) and dequantizes on gather.
+
+    ``mesh_sharded`` is what the pool's owner knows and a trace cannot
+    see: the engine sets it when it serves over a mesh (the pool is
+    then split over the 'model' axis and GSPMD partitions the attend);
+    a pool on one device may be read in place by the width-1 decode
+    kernel (tpunet/ops/paged_decode.py ``kernel_applies``).
     """
 
     pages: int            # total pages INCLUDING the reserved page 0
     page_tokens: int      # tokens per page
     dtype: str = "auto"   # auto | bfloat16 | int8
+    mesh_sharded: bool = False
 
     def store_dtype(self, compute_dtype):
         if self.dtype == "auto":
@@ -85,14 +92,14 @@ class PagedKV:
 
 
 def _quantize_kv_rows(x):
-    """Symmetric int8 per-row quantization of ``x`` [N, H, D]: each
-    token row is scaled by its own absmax over (H, D) so one outlier
-    token cannot crush every other row's resolution. Returns
+    """Symmetric int8 per-row quantization of ``x`` [N, W]: each
+    token row is scaled by its own absmax over its columns so one
+    outlier token cannot crush every other row's resolution. Returns
     (int8 rows, float32 scale [N])."""
     xf = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=(1, 2))
+    amax = jnp.max(jnp.abs(xf), axis=1)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(xf / scale[:, None, None]), -127, 127)
+    q = jnp.clip(jnp.round(xf / scale[:, None]), -127, 127)
     return q.astype(jnp.int8), scale
 
 
@@ -216,16 +223,30 @@ class Attention(nn.Module):
     def _paged_decode_attend(self, q, k, v, positions, active,
                              paged_kv, page_table):
         """Paged decode: K/V live in a SHARED flat page pool
-        ``[pages * page_tokens, H, D]`` per layer; each row's logical
+        ``[pages * page_tokens, W]`` per layer, a token's heads side by
+        side in one row and ``W`` = H * D rounded up to the 128-lane
+        tile (``paged_decode.pool_width``; the padding columns hold
+        zeros — the rounding keeps the TPU's device layout of the
+        buffer row-major, so a program that scatters rows into it does
+        not convert the whole pool in and out). Each row's logical
         position p maps to flat row
         ``page_table[b, p // page_tokens] * page_tokens + p %
         page_tokens``. Writes are one scatter over the new rows
         (inactive rows and unallocated positions are redirected into
-        the reserved garbage page 0); the attend gathers the row's
+        the reserved garbage page 0), for every caller alike.
+
+        Two attend paths, chosen at trace time from what the trace
+        sees (``paged_decode.kernel_applies``: one token per row, an
+        unquantized pool on one device, a TPU backend): the Pallas
+        kernel ``tpunet_paged_decode`` walks each row's own pages in
+        the pool up to the row's live length; everything else — the
+        bucket-wide prefill programs, the spec verify and draft
+        programs, int8 pools, a mesh, the CPU — gathers the row's
         pages back into position order and runs the exact dense masked
-        attention math over them — causality (j <= qpos per row) makes
-        garbage beyond each row's own written prefix invisible, the
-        same invariant the dense bucketed prefill already relies on.
+        attention math over them. Causality (j <= qpos per row) makes
+        garbage beyond each row's own written prefix invisible on both,
+        the same invariant the dense bucketed prefill already relies
+        on.
 
         int8 pages carry a float32 scale per page row (written in the
         same scatter) and dequantize on gather. The engine owns page
@@ -245,10 +266,13 @@ class Attention(nn.Module):
         flat_rows = paged_kv.pages * pt
         store_dtype = paged_kv.store_dtype(k.dtype)
         is_init = not self.has_variable("cache", "cached_k")
+        from tpunet.ops import paged_decode
+        hd = heads * head_dim
+        width = paged_decode.pool_width(heads, head_dim)
         ck = self.variable("cache", "cached_k", jnp.zeros,
-                           (flat_rows, heads, head_dim), store_dtype)
+                           (flat_rows, width), store_dtype)
         cv = self.variable("cache", "cached_v", jnp.zeros,
-                           (flat_rows, heads, head_dim), store_dtype)
+                           (flat_rows, width), store_dtype)
         if paged_kv.quantized:
             sk = self.variable("cache", "scale_k", jnp.zeros,
                                (flat_rows,), jnp.float32)
@@ -273,8 +297,9 @@ class Attention(nn.Module):
             # being where()-gated over the whole pool.
             flat_idx = jnp.where(active[:, None], flat_idx, 0)
         flat_idx = flat_idx.reshape(-1)
-        k_rows = k.reshape(b * t, heads, head_dim)
-        v_rows = v.reshape(b * t, heads, head_dim)
+        pad = ((0, 0), (0, width - hd))
+        k_rows = jnp.pad(k.reshape(b * t, hd), pad)
+        v_rows = jnp.pad(v.reshape(b * t, hd), pad)
         if paged_kv.quantized:
             k_q, k_s = _quantize_kv_rows(k_rows)
             v_q, v_s = _quantize_kv_rows(v_rows)
@@ -288,6 +313,16 @@ class Attention(nn.Module):
             cv.value = cv.value.at[flat_idx].set(
                 v_rows.astype(store_dtype))
 
+        if paged_decode.kernel_applies(paged_kv, t, store_dtype):
+            # -- in place: each row's own pages, up to its length ------
+            lengths = positions + 1
+            if active is not None:
+                lengths = jnp.where(active, lengths, 0)
+            y = paged_decode.paged_decode_attention(
+                q[:, 0], ck.value, cv.value, page_table, lengths,
+                page_tokens=pt)
+            return y[:, None]
+
         # -- gather: each row's pages back into position order --------
         n_page_slots = page_table.shape[1]
         rows = (page_table[:, :, None] * pt
@@ -296,11 +331,11 @@ class Attention(nn.Module):
         vf = jnp.take(cv.value, rows, axis=0)
         if paged_kv.quantized:
             kf = kf.astype(jnp.float32) \
-                * jnp.take(sk.value, rows, axis=0)[..., None, None]
+                * jnp.take(sk.value, rows, axis=0)[..., None]
             vf = vf.astype(jnp.float32) \
-                * jnp.take(sv.value, rows, axis=0)[..., None, None]
-        kf = kf.astype(q.dtype)
-        vf = vf.astype(q.dtype)
+                * jnp.take(sv.value, rows, axis=0)[..., None]
+        kf = kf[..., :hd].astype(q.dtype).reshape(b, -1, heads, head_dim)
+        vf = vf[..., :hd].astype(q.dtype).reshape(b, -1, heads, head_dim)
 
         s = jnp.einsum("bqhd,bkhd->bhqk", q, kf,
                        preferred_element_type=jnp.float32)

@@ -110,6 +110,10 @@ KERNEL_SCOPES: Dict[str, Tuple[str, str]] = {
     # custom calls land in ``elementwise`` and its custom_vjp backward
     # (no ``transpose(`` scope) would misattribute to the fwd phase.
     "tpunet_flash": ("matmul", "matmul"),
+    # The serve engine's width-1 decode attention over the paged KV
+    # pool (tpunet/ops/paged_decode.py): inference only, so there is a
+    # _fwd scope and no backward.
+    "tpunet_paged_decode": ("matmul", "matmul"),
 }
 
 # Scopes that mark a training phase directly (train/steps.py et al.).

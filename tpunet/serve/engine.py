@@ -330,7 +330,8 @@ class Engine:
             # never hands it out).
             self._paged_kv = PagedKV(pages=usable + 1,
                                      page_tokens=self.page_tokens,
-                                     dtype=cfg.kv_dtype)
+                                     dtype=cfg.kv_dtype,
+                                     mesh_sharded=mesh is not None)
             self._kv_pages_touched: set = set()
         elif cfg.kv_dtype not in ("auto",):
             raise ValueError(
@@ -415,7 +416,8 @@ class Engine:
             from tpunet.models.vit import PagedKV
             self._drafter_paged_kv = PagedKV(
                 pages=self.kv_pages_usable + 1,
-                page_tokens=self.page_tokens, dtype=cfg.kv_dtype)
+                page_tokens=self.page_tokens, dtype=cfg.kv_dtype,
+                mesh_sharded=mesh is not None)
             if drafter_params is not None:
                 # In-memory drafter weights (bench_serve --spec fits
                 # the drafter to its workload and injects it here).
@@ -832,10 +834,14 @@ class Engine:
                 from jax.sharding import NamedSharding
                 from jax.sharding import PartitionSpec as P
                 tp = self.mesh.shape.get("model", 1)
+                heads = int(getattr(model, "heads", 0))
                 if s.ndim == 4 and tp > 1 and s.shape[2] % tp == 0:
                     spec = P(None, None, "model", None)   # dense pool
-                elif s.ndim == 3 and tp > 1 and s.shape[1] % tp == 0:
-                    spec = P(None, "model", None)         # page pool
+                elif s.ndim == 2 and tp > 1 and heads % tp == 0 \
+                        and s.shape[1] == getattr(model, "hidden", 0):
+                    # page pool [rows, H * D]: whole heads per device
+                    # (a lane-padded row has no head-aligned split)
+                    spec = P(None, "model")
                 else:
                     spec = P()
                 return jnp.zeros(s.shape, s.dtype,
@@ -869,6 +875,15 @@ class Engine:
         if self._paged_kv is not None:
             reg.gauge("serve_kv_pages_total").set(self.kv_pages_usable)
             reg.gauge("serve_kv_pages_used").set(0)
+            # Which attend path the [slots, 1] decode program was built
+            # with (tpunet/ops/paged_decode.py): 1 = the in-place
+            # kernel, 0 = gather + dense. Static: set here, once.
+            import jax
+            from tpunet.ops import paged_decode
+            pool = jax.tree_util.tree_leaves(self._cache)[0]
+            reg.gauge("serve_decode_attend_kernel").set(int(
+                paged_decode.kernel_applies(self._paged_kv, 1,
+                                            pool.dtype)))
         if self._prefix is not None:
             reg.gauge("serve_prefix_pages_cached").set(0)
 

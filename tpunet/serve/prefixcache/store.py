@@ -101,6 +101,11 @@ def build_prefix_store(directory: str, model_cfg,
         "model": dataclasses.asdict(model_cfg),
         "kv_page_tokens": serve_cfg.kv_page_tokens,
         "kv_dtype": serve_cfg.kv_dtype,
+        # how a page's rows are stored (models/vit.py
+        # _paged_decode_attend): a token's heads side by side, columns
+        # rounded up to the lane tile. A store spilled as
+        # [page_tokens, H, D] rows is another store.
+        "kv_row_layout": "heads_flat_lane_padded",
         "jax": jax.__version__,
         "device_kind": jax.devices()[0].device_kind,
     })
