@@ -198,3 +198,56 @@ def test_paged_attention_layer_never_converts_the_pool(
     assert re.search(pool + r"\{1,0[:}]", text)       # row-major
     assert not re.search(pool + r"\S* copy\(", text)
     assert ("tpu_custom_call" in text) == (width == 1)
+
+
+@pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
+def test_latent_attention_decode_never_converts_its_pools(
+        one_chip, no_compile_cache, kind):
+    """One latent-attention layer's width-1 (absorbed) decode program
+    at dots3-note-prev's widths over its donated pools, as the engine
+    compiles it: the lane-rounded pools (576 -> 640 and a float32 128 for a full
+    layer, 1,088 -> 1,152 for a sliding one) are laid out row-major and
+    nothing copies a whole pool (the lesson of PR 26, for the second
+    decoder's cache)."""
+    import re
+
+    from tpunet.models.latent_lm import LatentArch, LatentAttention
+    from tpunet.models.vit import PagedKV
+
+    slots, per_slot, pt = 8, 384, 16
+    arch = LatentArch(hidden_size=5120, num_hidden_layers=1,
+                      layer_types=(kind,), intermediate_size=13824)
+    paged = PagedKV(pages=slots * per_slot + 1, page_tokens=pt)
+    attn = LatentAttention(arch, kind, dtype=BF16, param_dtype=BF16)
+    table = jnp.zeros((slots, per_slot), jnp.int32)
+    shapes = jax.eval_shape(lambda: attn.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 8, 5120), BF16),
+        decode=True, paged_kv=paged, page_table=table))
+
+    def step(params, cache, u, positions, active, table):
+        y, mutated = attn.apply(
+            {"params": params, "cache": cache}, u, True, positions, active,
+            paged, table, mutable=["cache"])
+        return mutated["cache"], y
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(shapes["params"]), on_chip(shapes["cache"]),
+        sds((slots, 1, 5120), BF16), sds((slots,), jnp.int32),
+        sds((slots,), bool),
+        sds((slots, per_slot), jnp.int32)).compile().as_text()
+    rows = (slots * per_slot + 1) * pt
+    pools = ({640: "bf16", 128: "f32"} if kind == "full_attention"
+             else {1152: "bf16"})          # the index keys stay float32
+    assert {leaf.shape for leaf in jax.tree_util.tree_leaves(
+        shapes["cache"])} == {(rows, w) for w in pools}
+    for w, dtype in pools.items():
+        pool = rf"{dtype}\[{rows},{w}\]"
+        assert re.search(pool + r"\{1,0[:}]", text)       # row-major
+        assert not re.search(pool + r"\S* copy\(", text)

@@ -14,7 +14,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import Any, Mapping, Optional, Tuple
 
 # ImageNet normalization statistics — the reference trains with these
 # (cifar10_mpi_mobilenet_224.py:81-82) and its Gradio app wrongly serves
@@ -158,6 +158,12 @@ class ModelConfig:
     # size (max trainable sequence length).
     vocab_size: int = 256
     max_seq_len: int = 1024
+    # Latent-attention decoder (model name "latent_lm",
+    # tpunet/models/latent_lm.py): the published config.json keys of
+    # the architecture (LatentArch names them), plus ``held_experts``,
+    # the routed experts whose weights this chip holds. vocab_size,
+    # max_seq_len, dtype and param_dtype above apply as for "lm".
+    latent: Optional[Mapping[str, Any]] = None
     # Vocab-sharded cross-entropy (tpunet/ops/vocab_ce.py): "auto"
     # shards the tied output projection + CE over the mesh 'model'
     # axis whenever it divides the vocab, so the replicated [B, T, V]

@@ -33,6 +33,9 @@ def create_model(cfg: ModelConfig, mesh=None):
     if cfg.name == "lm_pp":
         from tpunet.models import lm_pp
         return lm_pp.create_model(cfg, mesh=mesh)
+    if cfg.name == "latent_lm":
+        from tpunet.models import latent_lm
+        return latent_lm.create_model(cfg, mesh=mesh)
     if cfg.name == "vit" or cfg.name in VIT_PRESETS:
         return vit.create_model(cfg, mesh=mesh)
     raise ValueError(f"unknown model {cfg.name!r}")

@@ -1,0 +1,685 @@
+"""Decoder-only LM with latent attention — the second decoder block.
+
+Model name ``latent_lm`` (``ModelConfig.latent`` holds the published
+``config.json`` keys; the benchmark's ``dots3-note-prev`` is the worked
+configuration). Beside ``TransformerLM`` (learned positions, LayerNorm,
+GELU, one kind of attention, tied head) this block has RMSNorm, rotary
+positions with a base per layer kind, gated-SiLU MLPs, an untied head,
+and per layer one of two attentions over a *latent* cache:
+
+- ``full_attention``: multi-head latent attention (MLA). A token's
+  cache row is ``(c_kv, k^r)`` — ``kv_lora_rank`` + ``qk_rope_head_dim``
+  numbers, not ``2 * heads * head_dim`` — plus the 128-wide key ``k^I``
+  of a learned sparse indexer that picks, per query, the ``index_topk``
+  cached keys the heads attend to (all of them while the row is
+  shorter).
+- ``sliding_attention``: the same latent attention with its own ranks
+  and head sizes, over the last ``sliding_window_size`` positions (the
+  token itself counts), no indexer.
+
+Both gate each head's output with a sigmoid of the block's normed input
+before the output projection. Layer 0 (``first_k_dense_replace``) has a
+dense gated-SiLU MLP, every later layer ``moe.RoutedShareMlp`` (sigmoid
+routing without capacity, one shared expert, the experts ``held`` by
+this chip).
+
+Two forms of one mathematics, chosen by the call's shape:
+
+- one token per row against the cache (the engine's ``[slots, 1]``
+  decode step): the ABSORBED form — ``W_uk`` is folded into the query
+  and ``W_uv`` into the output, so scores and values are taken against
+  the cached latents directly and no key or value is ever up-projected.
+  A full layer scores the row's ``k^I`` pool, takes the top
+  ``index_topk`` positions and gathers only those latent rows; a
+  sliding layer gathers its last window.
+- anything wider (a bucket-wide prefill, or a plain forward without a
+  cache): the UP-PROJECTED form, one batch row at a time
+  (``moe.by_row``: rows that are not being prefilled are skipped), the
+  row's keys taken in position order — from the page pool through the
+  row's page table after the new rows are written, or from the call's
+  own tokens when there is no cache — and the queries in blocks, so
+  that no ``[heads, T, K]`` score tensor exists. Selection there is a
+  mask: keys whose index score is at least the row's
+  ``index_topk``-th largest.
+
+The cache is flat-row page pools ``[pages * page_tokens, W]`` per layer
+behind the engine's one page table (``models.vit.PagedKV``), ``W``
+rounded up to the 128-lane tile. A sliding layer writes every position
+and reads its window through the same table (an allocator that frees
+pages behind the window is later work).
+
+Precision: products take bfloat16 operands and accumulate in float32;
+the residual stream, the norms, softmax, the router and the WHOLE
+indexer path (its projections from the float32 normed input, its keys
+in the cache, its scores) are float32. The last is not a nicety: the
+indexer's choice of 2,048 keys is discrete, its scores lie ~0.0007 of
+their spread apart at the cut, and with bfloat16 inputs a few dozen keys
+a query change sides — each a random vector in a sum of random vectors,
+so the layer's output moves by the ROOT of the share flipped (PERF.md
+section 6, PR 27: logits off by 1-5 against the float32 reference where
+a dense layer is off by 0.3).
+
+``rescale``: ``apply_mla_qkv_lora_rescale`` is read as a constant
+``sqrt(hidden / rank)`` on each latent after its RMSNorm (the
+benchmark's configuration file lists this reading under ``assumed``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+from jax import lax
+
+from tpunet.config import ModelConfig
+from tpunet.models.moe import RoutedShareMlp, by_row, gated_silu
+from tpunet.ops.attention import _NEG_INF
+
+_LANES = 128
+_Q_BLOCK_FULL = 128      # queries per block, full layers (K = whole row)
+_Q_BLOCK_WINDOW = 512    # queries per block, sliding layers (K = block + window)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentArch:
+    """The published sizes, under the published names."""
+
+    hidden_size: int
+    num_hidden_layers: int
+    layer_types: Tuple[str, ...]
+    intermediate_size: int
+    first_k_dense_replace: int = 1
+    rms_norm_eps: float = 1e-5
+    # full layers
+    num_attention_heads: int = 128
+    q_lora_rank: int = 1024
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 8e7
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    # sliding layers
+    swa_num_attention_heads: int = 64
+    swa_q_lora_rank: int = 1024
+    swa_kv_lora_rank: int = 1024
+    swa_qk_nope_head_dim: int = 192
+    swa_qk_rope_head_dim: int = 64
+    swa_v_head_dim: int = 128
+    swa_rope_theta: float = 5e4
+    sliding_window_size: int = 513
+    apply_mla_qkv_lora_rescale: bool = True
+    # expert layers
+    n_routed_experts: int = 256        # the router's width
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 1536
+    routed_scaling_factor: float = 1.0
+    held_experts: Optional[Tuple[int, ...]] = None   # None = all
+
+    @classmethod
+    def from_mapping(cls, m) -> "LatentArch":
+        known = {f.name for f in dataclasses.fields(cls)}
+        unknown = set(m) - known
+        if unknown:
+            raise ValueError(f"latent_lm: unknown keys {sorted(unknown)}")
+        kw = dict(m)
+        for key in ("layer_types", "held_experts"):
+            if kw.get(key) is not None:
+                kw[key] = tuple(kw[key])
+        arch = cls(**kw)
+        if len(arch.layer_types) != arch.num_hidden_layers:
+            raise ValueError("latent_lm: layer_types must name "
+                             f"{arch.num_hidden_layers} layers")
+        return arch
+
+    def layer(self, kind: str) -> dict:
+        """Sizes of one attention kind: heads, latent ranks, head dims,
+        rotary base, the two latent scales."""
+        p = "" if kind == "full_attention" else "swa_"
+        g = lambda k: getattr(self, p + k)  # noqa: E731
+        rq, rkv = g("q_lora_rank"), g("kv_lora_rank")
+        scale = self.apply_mla_qkv_lora_rescale
+        return {"heads": g("num_attention_heads"), "rq": rq, "rkv": rkv,
+                "dn": g("qk_nope_head_dim"), "dr": g("qk_rope_head_dim"),
+                "dv": g("v_head_dim"), "theta": float(g("rope_theta")),
+                "s_q": math.sqrt(self.hidden_size / rq) if scale else 1.0,
+                "s_kv": math.sqrt(self.hidden_size / rkv) if scale else 1.0}
+
+
+# -- arithmetic ---------------------------------------------------------------
+
+def lane_rounded(width: int) -> int:
+    """A pool row's columns: ``width`` rounded up to the 128-lane tile
+    (``ops/paged_decode.py pool_width`` says why)."""
+    return -(-width // _LANES) * _LANES
+
+
+def rms_norm(x, scale, eps, mult: float = 1.0, dtype=None):
+    """RMSNorm in float32, times the constant ``mult``; the result in
+    ``dtype`` (``x``'s own by default)."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * (mult * scale.astype(jnp.float32))).astype(dtype or x.dtype)
+
+
+def layer_norm(x, scale, bias, eps):
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, -1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), -1, keepdims=True)
+    y = (xf - mean) * lax.rsqrt(var + eps)
+    return (y * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope(x, pos, theta: float):
+    """Rotate-half rotary embedding of ``x`` [..., T, d] or
+    [..., T, H, d] at integer positions ``pos`` [..., T]."""
+    d = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[..., None] * freq           # [..., T, d/2]
+    if x.ndim == ang.ndim + 1:
+        ang = ang[..., None, :]                               # heads axis
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
+
+
+def kth_largest(x, k: int):
+    """The ``k``-th largest of ``x`` [..., K] float32 along the last
+    axis, exactly, by choosing the bits of its order-preserving integer
+    key from the top (32 counting passes, no sort). With fewer than
+    ``k`` entries above -inf the result is at most -inf's key, so
+    ``x >= kth`` keeps them all."""
+    bits = lax.bitcast_convert_type(x, jnp.uint32)
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def body(i, found):
+        cand = found | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(key >= cand[..., None], axis=-1) >= k
+        return jnp.where(enough, cand, found)
+
+    found = lax.fori_loop(0, 32, body,
+                          jnp.zeros(x.shape[:-1], jnp.uint32))
+    back = jnp.where(found >> 31 == 1, found & jnp.uint32(0x7FFFFFFF), ~found)
+    return lax.bitcast_convert_type(back, jnp.float32)
+
+
+def top_k_mask(x, k: int):
+    """Which entries of ``x`` [..., K] ``lax.top_k(x, k)`` would take:
+    those above the ``k``-th largest, and of its equals the first by
+    index that fill the count (the cut's index found by bisection too:
+    a cumulative sum over K would cost more than the attention)."""
+    kth = kth_largest(x, k)[..., None]
+    above, equal = x > kth, x == kth
+    room = k - jnp.sum(above, axis=-1)
+    at = jnp.arange(x.shape[-1])
+
+    def body(_, lo_hi):
+        # the smallest index c with count(equal[..., :c + 1]) >= room
+        lo, hi = lo_hi
+        mid = (lo + hi) // 2
+        enough = jnp.sum(equal & (at <= mid[..., None]), axis=-1) >= room
+        return jnp.where(enough, lo, mid + 1), jnp.where(enough, mid, hi)
+
+    steps = max(1, (x.shape[-1] - 1).bit_length())
+    zero = jnp.zeros(x.shape[:-1], jnp.int32)
+    cut, _ = lax.fori_loop(0, steps, body, (zero, zero + x.shape[-1] - 1))
+    return above | (equal & (at <= cut[..., None]))
+
+
+def index_scores(qi, w, ki):
+    """The indexer's score of every key for every query, float32
+    throughout (see the module's text): ``qi`` [..., Q, Hi, Di], ``w`` [..., Q, Hi] (already
+    over sqrt(Hi)), ``ki`` [..., K, Di] -> [..., Q, K]."""
+    s = jnp.einsum("...qhd,...kd->...qhk", qi.astype(jnp.float32),
+                   ki.astype(jnp.float32), precision=lax.Precision.HIGHEST)
+    s = jnp.maximum(s, 0.0) * w.astype(jnp.float32)[..., None]
+    return jnp.sum(s, axis=-2) * (qi.shape[-1] ** -0.5)
+
+
+def _dot32(x, w):
+    """A float32 (``highest``) product: the indexer's projections."""
+    return jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+
+
+def _block(n: int, target: int) -> int:
+    return target if n % target == 0 else n
+
+
+def _softmax_values(scores, keep, v):
+    """softmax over the kept keys, then the values: ``scores``
+    [H, Q, K] float32, ``keep`` [Q, K], ``v`` [H, K, Dv] -> [Q, H, Dv]
+    float32, normalised after the product. (The barrier keeps the row
+    maximum a reduction of its own: left to fuse with the subtraction,
+    the TPU compiler turns it into a ``reduce-window`` as wide as the
+    row — 5.6 of a 3.7 s prefill call's 7.5 device seconds, PERF.md
+    section 6, PR 27.)"""
+    s = jnp.where(keep[None], scores, _NEG_INF)
+    top = lax.optimization_barrier(jnp.max(s, axis=-1))          # [H, Q]
+    p = jnp.exp(s - top[..., None])
+    o = jnp.einsum("hqk,hkd->hqd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return jnp.swapaxes(o / jnp.sum(p, axis=-1)[..., None], 0, 1)
+
+
+def cache_widths(arch: LatentArch, kind: str) -> dict:
+    """Numbers a token keeps in one layer's cache, by cache kind."""
+    z = arch.layer(kind)
+    if kind == "full_attention":
+        return {"latent": z["rkv"] + z["dr"], "index": arch.index_head_dim}
+    return {"window": z["rkv"] + z["dr"]}
+
+
+# -- the attention layer ------------------------------------------------------
+
+class LatentAttention(nn.Module):
+    """One latent-attention layer (``kind`` = ``full_attention`` or
+    ``sliding_attention``) on the block's normed input ``u``
+    [B, T, C] float32; see the module's text for the two forms."""
+
+    arch: LatentArch
+    kind: str
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u, decode: bool = False, positions=None,
+                 active=None, paged_kv=None, page_table=None):
+        a, z = self.arch, self.arch.layer(self.kind)
+        full = self.kind == "full_attention"
+        u32, u = u, u.astype(self.dtype)     # float32 for the indexer alone
+        b, t, c = u.shape
+        h, dn, dr, dv = z["heads"], z["dn"], z["dr"], z["dv"]
+        rq, rkv, eps, dt = z["rq"], z["rkv"], a.rms_norm_eps, self.dtype
+        init = nn.initializers.normal(stddev=0.02)
+
+        def w(name, *shape):
+            return self.param(name, init, shape, self.param_dtype).astype(dt)
+
+        def ones(name, n):
+            return self.param(name, nn.initializers.ones, (n,),
+                              self.param_dtype)
+
+        dq, q_norm, uq = w("dq", c, rq), ones("q_norm", rq), \
+            w("uq", rq, h * (dn + dr))
+        dkv, kv_norm = w("dkv", c, rkv + dr), ones("kv_norm", rkv)
+        ukv = w("ukv", rkv, h * (dn + dv)).reshape(rkv, h, dn + dv)
+        gate, out = w("gate", c, h), w("out", h * dv, c)
+        if full:
+            hi, di = a.index_n_heads, a.index_head_dim
+            iq, ik, iw = w("iq", rq, hi * di), w("ik", c, di), w("iw", c, hi)
+            ik_scale = ones("ik_norm_scale", di)
+            ik_bias = self.param("ik_norm_bias", nn.initializers.zeros,
+                                 (di,), self.param_dtype)
+        scope = "tpunet_mla_full" if full else "tpunet_mla_window"
+        scale = (dn + dr) ** -0.5
+        window = a.sliding_window_size
+
+        if positions is None:
+            positions = jnp.zeros((b,), jnp.int32)
+        pos_t = positions[:, None] + jnp.arange(t)[None, :]      # [B, T]
+
+        # -- what a token leaves behind: its latent row (and index key)
+        with jax.named_scope(scope):
+            kv = jnp.dot(u, dkv)
+            c_kv = rms_norm(kv[..., :rkv], kv_norm, eps, z["s_kv"])
+            k_r = rope(kv[..., rkv:], pos_t, z["theta"])
+            lat = jnp.concatenate([c_kv, k_r], -1)               # [B,T,rkv+dr]
+        if full:
+            with jax.named_scope("tpunet_indexer"):
+                k_i = layer_norm(_dot32(u32, ik), ik_scale, ik_bias, 1e-5)
+                k_i = jnp.concatenate(
+                    [rope(k_i[..., :dr], pos_t, z["theta"]), k_i[..., dr:]],
+                    -1)
+
+        # -- the query side of one batch row (or of all of them) ------
+        def queries(u_, pos_, u32_=None):
+            c_q = rms_norm(jnp.dot(u_, dq), q_norm, eps, z["s_q"])
+            q = jnp.dot(c_q, uq).reshape(*u_.shape[:-1], h, dn + dr)
+            q_n, q_r = q[..., :dn], rope(q[..., dn:], pos_, z["theta"])
+            g = jax.nn.sigmoid(jnp.dot(u_, gate).astype(jnp.float32))
+            if not full:
+                return q_n, q_r, g, None, None
+            with jax.named_scope("tpunet_indexer"):
+                c_q32 = rms_norm(_dot32(u32_, dq), q_norm, eps, z["s_q"])
+                q_i = _dot32(c_q32, iq).reshape(*u_.shape[:-1], hi, di)
+                q_i = jnp.concatenate(
+                    [rope(q_i[..., :dr], pos_, z["theta"]), q_i[..., dr:]],
+                    -1)
+                w_i = _dot32(u32_, iw) * (hi ** -0.5)
+            return q_n, q_r, g, q_i, w_i
+
+        def project_out(o, g):
+            o = (o * g[..., None]).astype(dt)
+            return jnp.dot(o.reshape(*o.shape[:-2], h * dv), out)
+
+        # -- up-projected form, one row: keys in position order -------
+        def row_attend(u_, start, lat_keys, index_keys, u32_=None):
+            """``u_`` [T, C] at positions start..start+T-1; the row's
+            keys ``lat_keys`` [K, >=rkv+dr] (key j is position j)."""
+            with jax.named_scope(scope):
+                qpos = start + jnp.arange(t)
+                q_n, q_r, g, q_i, w_i = queries(u_, qpos, u32_)
+                k_all = lat_keys.shape[0]
+                bq = _block(t, _Q_BLOCK_FULL if full else _Q_BLOCK_WINDOW)
+                nb = t // bq
+                blocks = lambda x: x.reshape(nb, bq, *x.shape[1:])  # noqa: E731
+                # heads lead every operand (the batch dimension of both
+                # products), and the shared rotary key rides beside each
+                # head's own: one product for the scores, no [K, H, D]
+                # tensor to transpose
+                q_all = jnp.swapaxes(jnp.concatenate([q_n, q_r], -1), 0, 1)
+
+                def up_project(keys):
+                    # (the weights are sliced, not the products: a slice
+                    # of a [H, K, dn + dv] product is re-copied by every
+                    # block of queries that reads it)
+                    c = keys[:, :rkv]
+                    k_up = jnp.einsum("kr,rhd->hkd", c, ukv[..., :dn])
+                    k_rope = jnp.broadcast_to(
+                        keys[None, :, rkv:rkv + dr], (h, keys.shape[0], dr))
+                    return (jnp.concatenate([k_up, k_rope], -1),
+                            jnp.einsum("kr,rhd->hkd", c, ukv[..., dn:]))
+
+                def attend(q_b, k_b, v_b, keep):
+                    s = jnp.einsum("hqd,hkd->hqk", q_b, k_b,
+                                   preferred_element_type=jnp.float32)
+                    return _softmax_values(s * scale, keep, v_b)
+
+                q_blocks = q_all.reshape(h, nb, bq, dn + dr).swapaxes(0, 1)
+                if full:
+                    k_all_up, v_all = up_project(lat_keys)
+                    kpos = jnp.arange(k_all)
+                    topk = a.index_topk
+
+                    def one(args):
+                        q_b, qi_b, wi_b, qpos_b = args
+                        keep = kpos[None, :] <= qpos_b[:, None]
+                        with jax.named_scope("tpunet_indexer"):
+                            score = jnp.where(
+                                keep, index_scores(qi_b, wi_b, index_keys),
+                                -jnp.inf)
+                        if topk < k_all:
+                            with jax.named_scope("tpunet_kv_select"):
+                                # (the barrier: one mask per block of
+                                # queries, made before the heads share it,
+                                # not once more inside each head's softmax)
+                                keep = lax.optimization_barrier(
+                                    keep & top_k_mask(score, topk))
+                        return attend(q_b, k_all_up, v_all, keep)
+
+                    o = lax.map(one, (q_blocks,) + tuple(map(
+                        blocks, (q_i, w_i, qpos))))
+                else:
+                    kb = min(k_all, bq + window - 1)
+
+                    def one(args):
+                        q_b, qpos_b = args
+                        first = jnp.clip(qpos_b[0] - (window - 1), 0,
+                                         k_all - kb)
+                        k_b, v_b = up_project(
+                            lax.dynamic_slice_in_dim(lat_keys, first, kb))
+                        kpos = first + jnp.arange(kb)
+                        keep = ((kpos[None, :] <= qpos_b[:, None])
+                                & (kpos[None, :] > qpos_b[:, None] - window))
+                        return attend(q_b, k_b, v_b, keep)
+
+                    o = lax.map(one, (q_blocks, blocks(qpos)))
+                return project_out(o.reshape(t, h, dv), g)
+
+        if not decode:
+            # plain forward: the call's own tokens are the keys
+            if full:
+                return by_row(row_attend, None, u, positions, lat, k_i, u32)
+            return by_row(lambda u_, s_, l_: row_attend(u_, s_, l_, None),
+                          None, u, positions, lat)
+
+        # -- the paged cache ------------------------------------------
+        if paged_kv is None:
+            raise ValueError("latent_lm keeps its cache in pages: decode "
+                             "needs paged_kv and a page table (the serve "
+                             "engine's default)")
+        pt = paged_kv.page_tokens
+        flat_rows = paged_kv.pages * pt
+        store = paged_kv.store_dtype(dt)
+        if paged_kv.quantized:
+            raise ValueError("latent_lm has no int8 page payload")
+        is_init = not self.has_variable("cache", "latent")
+        wide = lane_rounded(rkv + dr)
+        pool = self.variable("cache", "latent", jnp.zeros,
+                             (flat_rows, wide), store)
+        if full:
+            ipool = self.variable("cache", "index", jnp.zeros,
+                                  (flat_rows, lane_rounded(di)),
+                                  jnp.float32)
+        if is_init:
+            return jnp.zeros_like(u)
+        if page_table is None:
+            raise ValueError("paged decode requires engine-owned per-row "
+                             "positions and a page table")
+
+        def flat_of(pos):
+            """positions [B, N] -> flat pool rows through the table."""
+            page = jnp.take_along_axis(
+                page_table, jnp.clip(pos // pt, 0, page_table.shape[1] - 1),
+                axis=1)
+            return page * pt + pos % pt
+
+        new = flat_of(pos_t)
+        if active is not None:
+            new = jnp.where(active[:, None], new, 0)     # the garbage page
+        new = new.reshape(-1)
+
+        def put(var, rows):
+            rows = rows.reshape(b * t, -1)
+            rows = jnp.pad(rows, ((0, 0), (0, var.value.shape[1]
+                                           - rows.shape[1])))
+            var.value = var.value.at[new].set(rows.astype(var.value.dtype))
+
+        put(pool, lat)
+        if full:
+            put(ipool, k_i)
+        k_max = page_table.shape[1] * pt
+
+        def rows_of(table):
+            """Flat pool rows of positions 0..k_max-1 of one table."""
+            return (table[..., None] * pt + jnp.arange(pt)).reshape(
+                *table.shape[:-1], k_max)
+
+        if t > 1:
+            def cached_row(u_, start, table, u32_):
+                rows = rows_of(table)
+                keys = jnp.take(pool.value, rows, axis=0).astype(dt)
+                ikeys = (jnp.take(ipool.value, rows, axis=0)[:, :di]
+                         if full else None)
+                return row_attend(u_, start, keys, ikeys, u32_)
+            return by_row(cached_row, active, u, positions, page_table, u32)
+
+        # -- absorbed form: one token per row -------------------------
+        # scores q_n . (W_uk c) = (W_uk^T q_n) . c and values
+        # W_uv (sum_s p_s c_s): the same mathematics as the up-projected
+        # form, with the per-key products moved to the query and the
+        # output.
+        with jax.named_scope(scope):
+            q_n, q_r, g, q_i, w_i = queries(u[:, 0], positions, u32[:, 0])
+            if full:
+                with jax.named_scope("tpunet_indexer"):
+                    ikeys = jnp.take(ipool.value, rows_of(page_table),
+                                     axis=0)[..., :di]
+                    score = index_scores(q_i[:, None], w_i[:, None],
+                                         ikeys)[:, 0]            # [B, K]
+                    score = jnp.where(jnp.arange(k_max)[None, :]
+                                      <= positions[:, None], score, -jnp.inf)
+                with jax.named_scope("tpunet_kv_select"):
+                    best, sel = lax.top_k(score, min(a.index_topk, k_max))
+                    keep = best > -jnp.inf
+            else:
+                sel = positions[:, None] - (window - 1) \
+                    + jnp.arange(window)[None, :]
+                keep = sel >= 0
+                sel = jnp.maximum(sel, 0)
+            # (a full layer's gather of its selected rows is the cost of
+            # selection; a sliding layer's window stays under its own scope)
+            with jax.named_scope("tpunet_kv_select" if full else "tpunet_window_gather"):
+                keys = jnp.take(pool.value, flat_of(sel), axis=0).astype(dt)
+            c_keys, r_keys = keys[..., :rkv], keys[..., rkv:rkv + dr]
+            q_abs = jnp.einsum("bhd,rhd->bhr", q_n, ukv[..., :dn])
+            s = (jnp.einsum("bhr,bkr->bhk", q_abs, c_keys,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bhd,bkd->bhk", q_r, r_keys,
+                              preferred_element_type=jnp.float32)) * scale
+            p = jax.nn.softmax(jnp.where(keep[:, None, :], s, _NEG_INF), -1)
+            o_lat = jnp.einsum("bhk,bkr->bhr", p.astype(dt), c_keys)
+            o = jnp.einsum("bhr,rhd->bhd", o_lat, ukv[..., dn:],
+                           preferred_element_type=jnp.float32)
+            return project_out(o, g)[:, None]
+
+
+# -- the model ----------------------------------------------------------------
+
+class LatentBlock(nn.Module):
+    """``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the
+    FFN dense (``dense`` True) or the expert layer. A wide call
+    (T > 1) takes the FFN one batch row at a time, skipping the rows
+    ``row_active`` marks idle."""
+
+    arch: LatentArch
+    kind: str
+    dense: bool
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, decode, positions, active, paged_kv, page_table):
+        a = self.arch
+        c = a.hidden_size
+        wide = x.shape[1] > 1
+        row_active = active if (decode and wide) else None
+
+        def norm(name, v):
+            scale = self.param(name, nn.initializers.ones, (c,),
+                               self.param_dtype)
+            return rms_norm(v, scale, a.rms_norm_eps, dtype=jnp.float32)
+
+        x = x + LatentAttention(a, self.kind, dtype=self.dtype,
+                                param_dtype=self.param_dtype, name="attn")(
+            norm("ln1", x), decode, positions, active, paged_kv,
+            page_table).astype(x.dtype)
+        u = norm("ln2", x)
+        if self.dense:
+            init = nn.initializers.normal(stddev=0.02)
+            f = a.intermediate_size
+            mlp = [self.param(f"mlp_{n}", init, shape, self.param_dtype)
+                   for n, shape in (("gate", (c, f)), ("up", (c, f)),
+                                    ("down", (f, c)))]
+            ffn = lambda u_: gated_silu(u_, *mlp, self.dtype)  # noqa: E731
+            with jax.named_scope("tpunet_dense_mlp"):
+                y = by_row(ffn, row_active, u) if wide else ffn(u)
+        else:
+            y = RoutedShareMlp(
+                a.n_routed_experts, a.moe_intermediate_size,
+                a.num_experts_per_tok, held=a.held_experts,
+                scaling=a.routed_scaling_factor, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="moe")(
+                    u if wide else u[:, 0], row_active)
+            y = y if wide else y[:, None]
+        return x + y.astype(x.dtype)
+
+
+class LatentLM(nn.Module):
+    """tokens [B, T] int32 -> logits [B, T, vocab] float32, with
+    ``TransformerLM``'s call signature (the serve engine's masked step
+    and the page operations take either)."""
+
+    arch: LatentArch
+    vocab_size: int = 256
+    max_len: int = 1024
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    input_kind = "tokens"
+
+    @property
+    def hidden(self) -> int:
+        return self.arch.hidden_size
+
+    @property
+    def heads(self) -> int:
+        return self.arch.num_attention_heads
+
+    def serve_gauges(self) -> dict:
+        """What the serve engine sets once, at construction: bytes a
+        token keeps per cache kind (all layers of the kind, lane-rounded
+        rows as stored), and the experts held of the router's width."""
+        a = self.arch
+        per: dict = {}
+        for kind in a.layer_types:
+            for cache, width in cache_widths(a, kind).items():
+                per[cache] = per.get(cache, 0) + lane_rounded(width) * (
+                    4 if cache == "index" else jnp.dtype(self.dtype).itemsize)
+        out = {f"serve_cache_bytes_per_token_{k}": v for k, v in per.items()}
+        # the engine's gauge asks tpunet_paged_decode's dispatch, which
+        # this block's absorbed decode does not go through
+        out["serve_decode_attend_kernel"] = 0
+        out["serve_experts_total"] = a.n_routed_experts
+        out["serve_experts_held"] = (a.n_routed_experts
+                                     if a.held_experts is None
+                                     else len(a.held_experts))
+        return out
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, decode: bool = False,
+                 pos_offset=0, segment_ids=None,
+                 return_hidden: bool = False, decode_active=None,
+                 paged_kv=None, page_table=None):
+        """As ``TransformerLM.__call__``; ``pos_offset`` is a scalar or
+        an int32 [B] of each row's first position. Packed sequences
+        (``segment_ids``) and training are not built."""
+        if segment_ids is not None or train:
+            raise ValueError("latent_lm is built for serving: no packed "
+                             "sequences, no training")
+        a = self.arch
+        b, t = tokens.shape
+        if t > self.max_len:
+            raise ValueError(f"sequence {t} exceeds max_len {self.max_len}")
+        positions = jnp.broadcast_to(jnp.asarray(pos_offset, jnp.int32), (b,))
+        x = nn.Embed(self.vocab_size, a.hidden_size,
+                     embedding_init=nn.initializers.normal(stddev=0.02),
+                     param_dtype=self.param_dtype,
+                     name="embed")(tokens).astype(jnp.float32)
+        for i, kind in enumerate(a.layer_types):
+            x = LatentBlock(a, kind, dense=i < a.first_k_dense_replace,
+                            dtype=self.dtype, param_dtype=self.param_dtype,
+                            name=f"block{i:02d}")(
+                x, decode, positions, decode_active, paged_kv, page_table)
+        scale = self.param("ln", nn.initializers.ones, (a.hidden_size,),
+                           self.param_dtype)
+        x = rms_norm(x, scale, a.rms_norm_eps, dtype=self.dtype)
+        if return_hidden:
+            return x.astype(jnp.float32)
+        head = self.param("head", nn.initializers.normal(stddev=0.02),
+                          (a.hidden_size, self.vocab_size), self.param_dtype)
+        with jax.named_scope("tpunet_head"):
+            return jnp.dot(x, head.astype(self.dtype),
+                           preferred_element_type=jnp.float32)
+
+
+def create_model(cfg: ModelConfig, mesh=None) -> LatentLM:
+    if mesh is not None:
+        raise ValueError("latent_lm runs on one device (a chip's share of "
+                         "an expert-parallel deployment); no mesh lowering")
+    if not cfg.latent:
+        raise ValueError("model latent_lm needs ModelConfig.latent (the "
+                         "published config keys)")
+    return LatentLM(arch=LatentArch.from_mapping(cfg.latent),
+                    vocab_size=cfg.vocab_size, max_len=cfg.max_seq_len,
+                    dtype=jnp.dtype(cfg.dtype),
+                    param_dtype=jnp.dtype(cfg.param_dtype))

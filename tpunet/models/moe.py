@@ -1,4 +1,22 @@
-"""Mixture-of-Experts MLP with expert parallelism.
+"""Mixture-of-Experts MLPs: two expert layers, for two jobs.
+
+1. ``MoeMlp`` — the capacity-buffer layer that *trains* (models ``lm``,
+   ``lm_pp``, the ViT family; ``--moe-experts``): softmax gates, top-k
+   with a per-expert capacity buffer that DROPS what overflows, GELU
+   experts, a load-balance loss, and the two shard_map expert-parallel
+   lowerings. Everything from the next paragraph to the end of this
+   text describes it.
+2. ``RoutedShareMlp`` / ``routed_share`` — the no-drop layer that
+   *serves* (model ``latent_lm``, the benchmark's ``dots3-note-prev``):
+   sigmoid gates with a selection bias, top-k renormalised over the
+   selected, gated-SiLU experts beside one shared expert, and no
+   capacity: the (token, expert) pairs are sorted by expert and go
+   through one grouped product (``jax.lax.ragged_dot``). The layer is
+   told which experts it ``held`` (one chip's share under expert
+   parallelism): the router keeps all its outputs, the top-k and the
+   normalisation run over all of them, and the layer returns the part
+   of the sum its own experts give plus the shared expert. Nothing
+   stands in for the absent chips.
 
 The reference is a dense CNN (SURVEY.md 2b lists EP/MoE as absent);
 tpunet adds a ViT-MoE-style sparse MLP so expert parallelism is a real,
@@ -405,3 +423,139 @@ class MoeMlp(nn.Module):
                       P("model", None)),
             out_specs=(tok_spec, P()), check_vma=False)
         return fn(x, logits, wi, bi, wo, bo)
+
+
+# -- the no-drop share (serving) ---------------------------------------------
+
+def gated_silu(x, gate, up, down, dtype):
+    """``down(silu(gate x) * up x)`` on ``x`` [n, d]; products in
+    ``dtype`` with float32 accumulation."""
+    x = x.astype(dtype)
+    h = nn.silu(jnp.dot(x, gate.astype(dtype))) * jnp.dot(x, up.astype(dtype))
+    return jnp.dot(h, down.astype(dtype))
+
+
+def route_sigmoid(u, router, bias, top_k: int, scaling: float = 1.0):
+    """Sigmoid routing without auxiliary loss (``noaux_tc``): ``u``
+    [n, d] -> (expert ids [n, k] int32, weights [n, k] float32). The
+    scores ``p = sigmoid(W_r u)`` and everything after them are
+    float32; ``bias`` only chooses (top-k of ``p + bias``), the weights
+    are ``p`` renormalised over the chosen k."""
+    p = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32),
+                               router.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(p + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(p, idx, axis=-1)
+    return idx, scaling * chosen / jnp.sum(chosen, -1, keepdims=True)
+
+
+def by_row(fn, active, *xs):
+    """``fn(*rows)`` on each leading-axis row of ``xs`` in turn
+    (``lax.map``: one row's temporaries at a time), skipping the rows
+    whose ``active`` is False — those return zeros, and on the TPU the
+    skipped branch costs nothing. ``active`` None runs every row. How a
+    bucket-wide ``[slots, T]`` prefill keeps one row's (token, expert)
+    pairs and attention scores alive at a time, and pays only for the
+    slots being prefilled."""
+    if active is None:
+        return jax.lax.map(lambda row: fn(*row), xs)
+
+    def body(args):
+        on, row = args[0], args[1:]
+        zeros = jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), jax.eval_shape(fn, *row))
+        return jax.lax.cond(on, lambda: fn(*row), lambda: zeros)
+    return jax.lax.map(body, (active, *xs))
+
+
+def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
+                 scaling: float = 1.0, dtype=jnp.bfloat16):
+    """One chip's share of a routed expert layer, without capacity.
+
+    ``u`` [n, d]; ``router`` [d, E] and ``bias`` [E] over ALL ``E``
+    experts; ``gate``/``up`` [len(held), d, f] and ``down`` [len(held),
+    f, d] for the experts held here; ``held`` their ids (static).
+    Returns ``(y [n, d] in ``dtype``, stats)`` with ``y = sum over the
+    chosen experts that are held of weight * expert(u)``: the pairs are
+    sorted by held expert (pairs on absent experts last, computed by
+    nobody), the experts run as grouped products over contiguous rows,
+    and each token sums its pairs back in pair order. ``stats`` (float32
+    scalars) counts the routing load: ``held_pair_share`` = pairs on
+    held experts / pairs, ``held_load_max_over_mean`` = largest / mean
+    load over the held experts."""
+    n, d = u.shape
+    h = len(held)
+    e = router.shape[-1]
+    with jax.named_scope("tpunet_moe_router"):
+        idx, weight = route_sigmoid(u, router, bias, top_k, scaling)
+    with jax.named_scope("tpunet_moe_experts"):
+        slot_of = jnp.full((e,), h, jnp.int32).at[jnp.asarray(held)].set(
+            jnp.arange(h, dtype=jnp.int32))
+        slot = slot_of[idx].reshape(-1)                          # [n*k]
+        order = jnp.argsort(slot, stable=True)
+        sizes = jnp.sum(slot[:, None] == jnp.arange(h)[None, :], axis=0,
+                        dtype=jnp.int32)                         # [h]
+        rows = jnp.take(u.astype(dtype), order // top_k, axis=0)
+        a = jax.lax.ragged_dot(rows, gate.astype(dtype), sizes)
+        b = jax.lax.ragged_dot(rows, up.astype(dtype), sizes)
+        y = jax.lax.ragged_dot(nn.silu(a) * b, down.astype(dtype), sizes)
+        y = jnp.where((jnp.take(slot, order) < h)[:, None], y, 0)
+        back = jnp.argsort(order)                                # pair order
+        y = jnp.take(y, back, axis=0).reshape(n, top_k, d)
+        y = jnp.sum(y.astype(jnp.float32) * weight[:, :, None],
+                    axis=1).astype(dtype)
+        load = sizes.astype(jnp.float32)
+        stats = {"held_pair_share": jnp.sum(load) / (n * top_k),
+                 "held_load_max_over_mean":
+                     jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9)}
+    return y, stats
+
+
+class RoutedShareMlp(nn.Module):
+    """``routed_share`` plus the shared expert: the FFN of an expert
+    layer as one chip of an expert-parallel deployment computes it, on
+    ``x`` [n, d] or, one batch row at a time (``by_row``, skipping rows
+    whose ``row_active`` is False), on ``x`` [B, T, d]. ``held`` lists
+    the routed experts whose weights live here (all of them by
+    default). The routing load is ``sow``n into the ``stats``
+    collection (per batch row for a 3-D ``x``): free unless a caller
+    makes it mutable (the serve engine's step does not)."""
+
+    n_experts: int
+    width: int
+    top_k: int
+    held: Any = None                   # tuple of expert ids; None = all
+    scaling: float = 1.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, row_active=None):
+        d, f = x.shape[-1], self.width
+        held = (tuple(range(self.n_experts)) if self.held is None
+                else tuple(self.held))
+        init = nn.initializers.normal(stddev=0.02)
+
+        def w(name, *shape):
+            return self.param(name, init, shape, self.param_dtype)
+
+        router = w("router", d, self.n_experts)
+        bias = self.param("router_bias", nn.initializers.zeros,
+                          (self.n_experts,), self.param_dtype)
+        experts = (w("experts_gate", len(held), d, f),
+                   w("experts_up", len(held), d, f),
+                   w("experts_down", len(held), f, d))
+        shared = (w("shared_gate", d, f), w("shared_up", d, f),
+                  w("shared_down", f, d))
+
+        def ffn(u):
+            y, stats = routed_share(u, router, bias, *experts, held,
+                                    top_k=self.top_k, scaling=self.scaling,
+                                    dtype=self.dtype)
+            with jax.named_scope("tpunet_moe_shared"):
+                y = y + gated_silu(u, *shared, self.dtype)
+            return y, stats
+
+        y, stats = ffn(x) if x.ndim == 2 else by_row(ffn, row_active, x)
+        self.sow("stats", "routing", stats)
+        return y

@@ -886,6 +886,12 @@ class Engine:
                                             pool.dtype)))
         if self._prefix is not None:
             reg.gauge("serve_prefix_pages_cached").set(0)
+        # What a model says of itself once (models/latent_lm.py: bytes
+        # a token keeps per cache kind, experts held of the router's
+        # width); a model without the method adds nothing.
+        for name, value in getattr(self.model, "serve_gauges",
+                                   dict)().items():
+            reg.gauge(name).set(value)
 
     def _update_kv_gauges(self) -> None:
         if self._paged_kv is not None:
