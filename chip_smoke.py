@@ -35,8 +35,9 @@ supports (depth is what the model's reference workload uses):
   Pallas custom calls.
 - latent: the ``latent_lm`` decoder at the widths and the cut of
   ``benchmark/configs/dots3-note-prev.json`` through the serve engine's
-  own masked step (``Engine._step``, the logits form): one
-  ``[8, 4096]`` prefill of two rows (4,096 and 2,304 tokens, six slots
+  own masked step (``Engine._step``, the logits form): two
+  ``[1, 4096]`` prefill calls, as the engine dispatches them over its
+  paged pool (4,096 and 2,304 tokens into slots 0 and 7, six slots
   idle), then 64 width-1 decode steps of both, greedy; every logit row
   kept is compared with the plain reference's full forward over the
   same tokens (``LATENT_LOGIT_TOL``), and the reference's own float8
@@ -1016,19 +1017,19 @@ def _latent_child(rehearse: bool) -> int:
     engine = Engine(model, {"params": params}, ServeConfig(
         slots=slots, prefill_buckets=(bucket,), queue_max=8,
         emit_every_s=0.0, device_sampling=False))
-    toks = np.zeros((slots, bucket), np.int32)
     active = np.zeros((slots,), bool)
-    for slot, prompt in zip(rows_at, prompts):
+    got = [{} for _ in lens]                  # position -> logits row
+    for i, (slot, prompt) in enumerate(zip(rows_at, prompts)):
         check(engine._alloc_pages_for(slot, len(prompt) + steps)
               is not None, "latent: no pages")
-        toks[slot, :len(prompt)] = prompt
+        toks = np.zeros((1, bucket), np.int32)
+        toks[0, :len(prompt)] = prompt
         active[slot] = True
-    engine._cache, logits = engine._dispatch_step(
-        toks, np.zeros((slots,), np.int32), active)
-    got = [{} for _ in lens]                  # position -> logits row
-    for i, (slot, n) in enumerate(zip(rows_at, lens)):
-        for p in inside[i] + [n - 1]:
-            got[i][p] = np.asarray(logits[slot, p], np.float32)
+        engine._cache, logits = engine._dispatch_step(
+            toks, np.zeros((1,), np.int32), np.ones((1,), bool),
+            slot_i=slot)
+        for p in inside[i] + [len(prompt) - 1]:
+            got[i][p] = np.asarray(logits[0, p], np.float32)
     del logits
     prefill_s = time.monotonic() - t0
     seqs = [list(p) for p in prompts]

@@ -150,16 +150,20 @@ def test_paged_decode_compiles(one_chip, no_compile_cache, dtype):
     assert "tpunet_paged_decode" in text
 
 
-@pytest.mark.parametrize("width", [1, 128])
+@pytest.mark.parametrize("rows,width",
+                         [(SLOTS, 1), (SLOTS, 128), (1, 512)])
 def test_paged_attention_layer_never_converts_the_pool(
-        one_chip, no_compile_cache, monkeypatch, width):
+        one_chip, no_compile_cache, monkeypatch, rows, width):
     """One attention layer's decode program over the donated pool, as
     the engine compiles it: the new rows are scattered into the
     parameter in place and nothing copies a whole pool buffer. (With
     the pool stored ``[rows, 25, 64]`` or ``[rows, 1600]`` the TPU lays
     it out rows-minor and every program converts it in and out — 38 ms
     of a 140 ms GPT-2 XL decode step, PERF.md section 6, PR 26.) The
-    width-1 program holds the kernel, the bucket-wide one does not."""
+    width-1 program holds the kernel, the bucket-wide ones do not —
+    neither the ``[slots, bucket]`` one nor the one-row ``[1, bucket]``
+    call a paged engine on one device prefills through (PR 28), which
+    reaches the same whole pool through one row of the page table."""
     import re
 
     from tpunet.models.vit import Attention, PagedKV
@@ -191,9 +195,9 @@ def test_paged_attention_layer_never_converts_the_pool(
         shape, dtype, sharding=one_chip)
     text = jax.jit(step, donate_argnums=(1,)).lower(
         on_chip(shapes["params"]), on_chip(shapes["cache"]),
-        sds((SLOTS, width, hidden), BF16), sds((SLOTS,), jnp.int32),
-        sds((SLOTS,), bool),
-        sds((SLOTS, PAGES_PER_SLOT), jnp.int32)).compile().as_text()
+        sds((rows, width, hidden), BF16), sds((rows,), jnp.int32),
+        sds((rows,), bool),
+        sds((rows, PAGES_PER_SLOT), jnp.int32)).compile().as_text()
     pool = rf"bf16\[{POOL_ROWS},{paged_decode.pool_width(HEADS, HEAD_DIM)}\]"
     assert re.search(pool + r"\{1,0[:}]", text)       # row-major
     assert not re.search(pool + r"\S* copy\(", text)

@@ -303,6 +303,126 @@ def test_paged_vs_dense_engine_outputs_identical(tiny_lm):
 
 
 # ---------------------------------------------------------------------------
+# prefill calls as wide as the row they admit (PR 28)
+# ---------------------------------------------------------------------------
+
+def _token_rows(text, width):
+    """Batch rows of the ``[rows, width]`` int32 token parameter of a
+    compiled masked step's entry computation."""
+    import re
+    found = re.findall(rf"s32\[(\d+),{width}\]\S* parameter\(",
+                       text[text.index("ENTRY"):])
+    assert len(found) == 1, found
+    return int(found[0])
+
+
+def _spy_prefill_calls(eng):
+    """Record every bucket-wide ``_dispatch_step`` call of ``eng``:
+    its token shape, its slot, and whether the pool rows of pages that
+    OTHER slots own came out of the call bit-identical."""
+    calls = []
+    dispatch = eng._dispatch_step
+
+    def pool(cache):
+        return [np.asarray(leaf)
+                for leaf in jax.tree_util.tree_leaves(cache)]
+
+    def spy(toks, positions, active, last_idx=None, slot_i=None):
+        if toks.shape[1] == 1:
+            return dispatch(toks, positions, active, last_idx, slot_i)
+        before = pool(eng._cache) if slot_i is not None else None
+        out = dispatch(toks, positions, active, last_idx, slot_i)
+        frozen = None
+        if slot_i is not None:
+            pt = eng.page_tokens
+            rows = np.concatenate(
+                [np.arange(page * pt, (page + 1) * pt)
+                 for j, slot in enumerate(eng._active)
+                 if slot is not None and j != slot_i
+                 for page in slot.pages] or [np.arange(0)]).astype(int)
+            frozen = all(np.array_equal(b[rows], a[rows])
+                         for b, a in zip(before, pool(out[0])))
+        calls.append({"shape": toks.shape, "slot": slot_i,
+                      "others_frozen": frozen})
+        return out
+
+    eng._dispatch_step = spy
+    return calls
+
+
+@pytest.mark.parametrize("k", [1, 3, 4])
+def test_paged_prefill_is_one_row_call_per_admitted_request(tiny_lm, k):
+    """k requests admitted together (prompts in both buckets): a paged
+    engine on one device dispatches k prefill calls of ``[1, bucket]``
+    tokens, each leaving the pages of every other slot bit-identical;
+    the dense-cache engine still dispatches one ``[slots, bucket]``
+    call per bucket; both serve the tokens the requests get when
+    served one at a time. The gauge and the padded-token counter say
+    which shape an engine runs."""
+    rng = np.random.default_rng(28)
+    ps = [rng.integers(0, TINY.vocab_size, size=n).astype(np.int32)
+          for n in (5, 12, 7, 14)[:k]]
+    buckets = [8 if p.size <= 8 else 16 for p in ps]
+
+    solo = make_engine(tiny_lm).start()
+    try:
+        want = [solo.submit(p, max_new_tokens=5).result(timeout=120)
+                for p in ps]
+    finally:
+        solo.stop()
+    assert want == [solo_greedy(tiny_lm, p, 5) for p in ps]
+
+    for paged in (True, False):
+        eng = make_engine(tiny_lm, paged_kv=paged, prefix_cache=False)
+        calls = _spy_prefill_calls(eng)
+        # queued before the engine thread runs: one admission takes all
+        reqs = [eng.submit(p, max_new_tokens=5) for p in ps]
+        eng.start()
+        try:
+            got = [r.result(timeout=120) for r in reqs]
+        finally:
+            eng.stop()
+        assert eng.error is None and got == want
+        snap = eng.registry.snapshot()
+        assert snap["serve_prefill_tokens_total"] == sum(
+            p.size for p in ps)
+        if paged:
+            # _admit groups by bucket, ascending; admission order within
+            assert [c["shape"] for c in calls] == \
+                [(1, b) for b in sorted(buckets)]
+            assert sorted(c["slot"] for c in calls) == list(range(k))
+            assert all(c["others_frozen"] for c in calls)
+            assert snap["serve_prefill_rows_per_call"] == 1
+            assert snap["serve_prefills_total"] == k
+            assert snap["serve_prefill_padded_tokens_total"] == \
+                sum(buckets)
+        else:
+            assert [c["shape"] for c in calls] == \
+                [(eng.slots, b) for b in sorted(set(buckets))]
+            assert all(c["slot"] is None for c in calls)
+            assert snap["serve_prefill_rows_per_call"] == eng.slots
+            assert snap["serve_prefill_padded_tokens_total"] == \
+                eng.slots * sum(set(buckets))
+
+
+@pytest.mark.parametrize("paged", [True, False])
+def test_program_texts_have_the_rows_the_engine_dispatches(tiny_lm,
+                                                           paged):
+    """``program_texts()`` lowers what ``_dispatch_step`` runs: the
+    decode program is ``[slots, 1]``, the ``w<bucket>`` programs have a
+    one-row token parameter for a paged engine and ``slots`` rows for a
+    dense-cache one — under the labels they always had."""
+    eng = make_engine(tiny_lm, paged_kv=paged)
+    texts = eng.program_texts()
+    assert sorted(texts) == ["jit__masked_step/w1", "jit__masked_step/w16",
+                             "jit__masked_step/w8"]
+    assert _token_rows(texts["jit__masked_step/w1"], 1) == eng.slots
+    for bucket in (8, 16):
+        assert _token_rows(texts[f"jit__masked_step/w{bucket}"],
+                           bucket) == (1 if paged else eng.slots)
+
+
+# ---------------------------------------------------------------------------
 # prefix KV cache: refcounted content-addressed pages (PR 18)
 # ---------------------------------------------------------------------------
 
@@ -734,6 +854,10 @@ def test_paged_aot_store_roundtrip(tmp_path, tiny_lm):
         eng2.stop()
     assert eng2.aot_status == {"w1": "loaded", "w16": "loaded"}
     assert toks2 == toks1 == solo_greedy(tiny_lm, prompt, 5)
+    # the store's own executables: [slots, 1] decode, [1, 16] prefill
+    texts = eng2.program_texts()
+    assert _token_rows(texts["jit__masked_step/w1"], 1) == 2
+    assert _token_rows(texts["jit__masked_step/w16"], 16) == 1
 
     # A different kv_dtype selects a different program set: clean MISS.
     cfg_int8 = ServeConfig(slots=2, queue_max=4, prefill_buckets=(16,),
@@ -749,6 +873,39 @@ def test_paged_aot_store_roundtrip(tmp_path, tiny_lm):
         eng3.stop()
     assert all(v.startswith("compiled")
                for v in eng3.aot_status.values())
+
+
+def test_aot_store_of_slots_row_prefill_programs_is_not_loaded(
+        tmp_path, tiny_lm, monkeypatch):
+    """A store written when every program was ``[slots, bucket]`` (the
+    same configuration, so the same digest and the same ``w16`` tag)
+    holds a prefill executable whose arguments a one-row engine cannot
+    call: the one-row engine misses it and compiles its own; only the
+    ``[slots, 1]`` decode program, which did not change, is loaded."""
+    from tpunet.serve.engine import build_aot_store
+
+    model, variables = tiny_lm
+    cfg = ServeConfig(slots=2, queue_max=4, prefill_buckets=(16,),
+                      default_max_new_tokens=8, emit_every_s=0.0,
+                      kv_pages=12, kv_page_tokens=8)
+    store = build_aot_store(str(tmp_path), TINY, cfg)
+    with monkeypatch.context() as m:
+        m.setattr(Engine, "_rows_at", lambda self, width: self.slots)
+        old = Engine(model, variables, cfg, aot_store=store)
+    assert old.aot_status == {"w1": "compiled+saved",
+                              "w16": "compiled+saved"}
+    assert _token_rows(old.program_texts()["jit__masked_step/w16"],
+                       16) == 2
+
+    eng = Engine(model, variables, cfg, aot_store=store).start()
+    try:
+        prompt = np.arange(5, dtype=np.int32)
+        assert _answer(eng, prompt, 5) == solo_greedy(tiny_lm, prompt, 5)
+    finally:
+        eng.stop()
+    assert eng.aot_status == {"w1": "loaded", "w16": "compiled+saved"}
+    assert _token_rows(eng.program_texts()["jit__masked_step/w16"],
+                       16) == 1
 
 
 def test_aot_save_is_load_verified(tmp_path, monkeypatch):

@@ -8,7 +8,11 @@ iteration feeds every active slot its next token at its own position
 ones free their slot without any recompilation. Prefill runs through
 the same masked path as a chunked multi-token call, padded to one of a
 fixed set of length buckets — the total compile count is bounded at
-``1 + len(prefill_buckets)`` programs for the life of the server.
+``1 + len(prefill_buckets)`` programs for the life of the server. Over
+a paged pool on one device a prefill program is ``[1, bucket]``, one
+call per admitted request (a paged row reaches its tokens only through
+its row of the page table); a dense cache and a mesh keep
+``[slots, bucket]``, one call per admission group.
 
 KV memory is PAGED by default (``ServeConfig.paged_kv``;
 ``--no-paged-kv`` keeps the dense pool): per layer, K/V live in a
@@ -143,6 +147,12 @@ def build_serve_record(reg, *, queue_depth: int, active_slots: int,
             reg.counter("serve_decode_steps_total").value),
         "prefills_total": int(
             reg.counter("serve_prefills_total").value),
+        # Tokens prefill calls embedded for requests / tokens they
+        # computed (rows x bucket a call): the calls' useful share.
+        "prefill_tokens_total": int(
+            reg.counter("serve_prefill_tokens_total").value),
+        "prefill_padded_tokens_total": int(
+            reg.counter("serve_prefill_padded_tokens_total").value),
     }
     for name, key in (("serve_ttft_s", "ttft"),
                       ("serve_token_s", "token_latency"),
@@ -460,12 +470,22 @@ class Engine:
 
         # -- device programs (compiled lazily, one per shape) ----------
         # One callable; jit specializes per token shape: [N, 1] decode
-        # plus one [N, Lb] program per prefill bucket. The cache is
-        # donated — it is the engine's single biggest buffer and every
-        # call replaces it. With device sampling the batched sampler
-        # is FUSED onto the step (the program returns sampled int32
-        # tokens, not logits); with paging the per-slot page table
-        # rides along as one small int32 input.
+        # plus one program per prefill bucket Lb — [1, Lb] over a paged
+        # pool on one device, [N, Lb] otherwise (``_prefill_rows``).
+        # The cache is donated — it is the engine's single biggest
+        # buffer and every call replaces it. With device sampling the
+        # batched sampler is FUSED onto the step (the program returns
+        # sampled int32 tokens, not logits); with paging the page-table
+        # rows of the call's slots ride along as one small int32 input.
+        #
+        # A paged row reaches its tokens only through its row of the
+        # page table, so nothing ties a batch row to a slot: a prefill
+        # call is as wide as the one request it admits. A dense cache
+        # is [slots, max_seq_len] per layer (the batch row IS the
+        # slot) and a mesh pool is partitioned by GSPMD: both keep the
+        # slot axis.
+        self._prefill_rows = (1 if self._paged_kv is not None
+                              and mesh is None else self.slots)
         paged_kv = self._paged_kv
         fuse_sampler = self.device_sampling
 
@@ -503,7 +523,7 @@ class Engine:
             self._build_spec_programs()
         self._init_kv_gauges()
         # AOT warm-start (tpunet/utils/cache.py AotProgramStore): the
-        # engine's program set is closed — [N, 1] decode + one [N, Lb]
+        # engine's program set is closed — [N, 1] decode + one program
         # per bucket — so fully-compiled executables deserialize at
         # boot and the jit path above becomes the fallback for shapes
         # the store has never seen. Single-device only: a sharded pool
@@ -523,6 +543,13 @@ class Engine:
         from tpunet.obs import device_time
         device_time.register_programs(self.program_texts)
 
+    def _rows_at(self, width: int) -> int:
+        """Batch rows of the masked step the engine dispatches at token
+        width ``width``: every slot for the width-1 decode program (a
+        width-1 bucket prefills through it), ``_prefill_rows`` for a
+        bucket-wide one."""
+        return self.slots if width == 1 else self._prefill_rows
+
     def _step_avals(self, width: int) -> list:
         """The masked step's arguments at token width ``width``, as
         shapes."""
@@ -532,7 +559,7 @@ class Engine:
             return jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
-        n = self.slots
+        n = self._rows_at(width)
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
         f32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32)  # noqa: E731
         avals = [sds(self.variables["params"]), sds(self._cache),
@@ -581,7 +608,14 @@ class Engine:
         act_s = jax.ShapeDtypeStruct((self.slots,), bool)
         for width in (1,) + self.buckets:
             tag = f"w{width}"
-            program = store.load("masked_step", tag)
+            # The entry's name carries the row count where it is not
+            # ``slots``: a store written when every program was
+            # [slots, width] holds "masked_step" entries under these
+            # same tags, and a [1, width] engine must miss them.
+            rows = self._rows_at(width)
+            name = ("masked_step" if rows == self.slots
+                    else f"masked_step_r{rows}")
+            program = store.load(name, tag)
             if program is None:
                 # Compile fresh (persistent compile cache off): a
                 # cache-served executable saves a poison blob that
@@ -590,7 +624,7 @@ class Engine:
                 with serializable_compile():
                     program = self._step.lower(
                         *self._step_avals(width)).compile()
-                saved = store.save("masked_step", tag, program)
+                saved = store.save(name, tag, program)
                 self.aot_status[tag] = ("compiled+saved" if saved
                                         else "compiled")
             else:
@@ -644,36 +678,43 @@ class Engine:
                 self.aot_status[f"{name}-{tag}"] = "loaded"
             self._spec_aot[(name, tag)] = program
 
-    def _dispatch_step(self, toks, positions, active, last_idx=None):
+    def _dispatch_step(self, toks, positions, active, last_idx=None,
+                       slot_i=None):
         """Run one masked-step program: the AOT executable for this
         token width when warm-started, the jit fallback otherwise.
-        Returns (cache, logits) host-sampling, (cache, tokens) with
-        the fused device sampler."""
+        Batch row i is slot i, or — ``slot_i`` given — the call's one
+        row is that slot (a [1, bucket] prefill). Returns (cache,
+        logits) host-sampling, (cache, tokens) with the fused device
+        sampler."""
         program = self._aot.get(toks.shape[1])
         if program is None:
             program = self._step
         args = [self.variables["params"], self._cache, toks, positions,
                 active]
         if self._paged_kv is not None:
-            args.append(self._page_table)
+            args.append(self._page_table if slot_i is None
+                        else self._page_table[slot_i:slot_i + 1])
         if self.device_sampling:
             args.extend(self._sampling_args(
-                last_idx if last_idx is not None else self._zero_idx))
+                last_idx if last_idx is not None else self._zero_idx,
+                slot_i))
         return program(*args)
 
-    def _sampling_args(self, last_idx):
-        """Per-slot sampling parameters for the fused device sampler:
+    def _sampling_args(self, last_idx, slot_i=None):
+        """Per-row sampling parameters for the fused device sampler:
         temperature/top-k/top-p/seed from each resident request, plus
         each slot's generated-token count (the per-step key fold — a
         preempted-and-resumed request continues its exact sample
-        stream)."""
-        n = self.slots
+        stream). One row per slot, or the one row of ``slot_i``."""
+        slots = (self._active if slot_i is None
+                 else self._active[slot_i:slot_i + 1])
+        n = len(slots)
         temp = np.zeros(n, np.float32)
         top_k = np.zeros(n, np.int32)
         top_p = np.zeros(n, np.float32)
         seeds = np.zeros(n, np.int32)
         steps = np.zeros(n, np.int32)
-        for i, slot in enumerate(self._active):
+        for i, slot in enumerate(slots):
             if slot is None:
                 continue
             r = slot.req
@@ -884,6 +925,9 @@ class Engine:
             reg.gauge("serve_decode_attend_kernel").set(int(
                 paged_decode.kernel_applies(self._paged_kv, 1,
                                             pool.dtype)))
+        # Rows of a bucket-wide prefill program (1 = a call per admitted
+        # request, ``slots`` = one call per admission group). Static.
+        reg.gauge("serve_prefill_rows_per_call").set(self._prefill_rows)
         if self._prefix is not None:
             reg.gauge("serve_prefix_pages_cached").set(0)
         # What a model says of itself once (models/latent_lm.py: bytes
@@ -1628,43 +1672,60 @@ class Engine:
         return True
 
     def _prefill(self, bucket: int, group) -> None:
-        """One chunked-prefill device call for every admitted request
-        padded to this bucket; K/V land in each slot's cache rows (or
-        pages) and the next token is sampled from the last REAL
-        position — on device when the sampler is fused, else from the
-        transferred logits row. The padded tail writes garbage K/V
-        beyond the prompt — masked invariant: a decode query at
-        position p attends only j <= p and overwrites position p
-        first, so padding is never visible. ``group`` rows are
+        """Prefill every admitted request padded to this bucket: one
+        [slots, bucket] device call for the group, or — a paged pool on
+        one device (``_prefill_rows`` 1) — one [1, bucket] call per
+        request, in admission order, each request's first token pushed
+        as soon as its own call returns. ``group`` rows are
         ``(slot_i, req, resume_tokens, pages, start, pinned)``;
         resume_tokens is prompt+generated for a preempted request
         resuming mid-stream, ``start`` is the first position NOT
-        covered by pinned prefix-cache pages — only the suffix
-        ``resume[start:]`` is embedded, at ``positions = start``, so
-        the scatter never touches a pinned page (writes go to
-        positions >= start only) while the attend reads the pinned
-        K/V through the page table."""
-        t0 = time.perf_counter()
-        toks = np.zeros((self.slots, bucket), np.int32)
-        active = np.zeros((self.slots,), bool)
-        last_idx = np.zeros((self.slots,), np.int32)
-        positions = np.zeros((self.slots,), np.int32)
-        for slot_i, req, resume, pages, start, pinned in group:
-            n = int(resume.size)
-            toks[slot_i, :n - start] = resume[start:]
-            active[slot_i] = True
-            last_idx[slot_i] = n - start - 1
-            positions[slot_i] = start
-            # Slot the request BEFORE the device call: if the step
-            # raises, the engine's failure handler finds (and fails)
-            # it in _active instead of stranding a popped request.
+        covered by pinned prefix-cache pages."""
+        for slot_i, req, resume, pages, _, pinned in group:
+            # Slot every request BEFORE the first device call: if a
+            # step raises, the engine's failure handler finds (and
+            # fails) them in _active instead of stranding popped
+            # requests.
             self._admit_seq += 1
-            slot = _Slot(req, pos=n, next_token=0,
+            slot = _Slot(req, pos=int(resume.size), next_token=0,
                          generated=len(req.tokens) + 1,
                          seq=self._admit_seq)
             slot.pages = pages
             slot.pinned = pinned
             self._active[slot_i] = slot
+        if self._rows_at(bucket) == self.slots:
+            self._prefill_call(bucket, group)
+        else:
+            for row in group:
+                self._prefill_call(bucket, [row], row[0])
+
+    def _prefill_call(self, bucket: int, group, slot_i=None) -> None:
+        """One chunked-prefill device call: every row of ``group`` at
+        its slot's batch row, or the one request of ``group`` as the
+        only row of a [1, bucket] call over slot ``slot_i``'s pages.
+        K/V land in each slot's cache rows (or pages) and the next
+        token is sampled from the last REAL position — on device when
+        the sampler is fused, else from the transferred logits row.
+        The padded tail writes garbage K/V beyond the prompt — masked
+        invariant: a decode query at position p attends only j <= p
+        and overwrites position p first, so padding is never visible.
+        Only the suffix ``resume[start:]`` is embedded, at
+        ``positions = start``, so the scatter never touches a pinned
+        page (writes go to positions >= start only) while the attend
+        reads the pinned K/V through the page table."""
+        t0 = time.perf_counter()
+        rows = self.slots if slot_i is None else 1
+        toks = np.zeros((rows, bucket), np.int32)
+        active = np.zeros((rows,), bool)
+        last_idx = np.zeros((rows,), np.int32)
+        positions = np.zeros((rows,), np.int32)
+        for s_i, req, resume, _, start, _ in group:
+            n = int(resume.size)
+            row = s_i if slot_i is None else 0
+            toks[row, :n - start] = resume[start:]
+            active[row] = True
+            last_idx[row] = n - start - 1
+            positions[row] = start
         from tpunet.obs import flightrec
         for _, req, resume, _, start, _ in group:
             # A resume-prefill (preempt-resume or cross-replica
@@ -1692,36 +1753,35 @@ class Engine:
         with _ring_span("tpunet/serve_prefill"):
             if self.device_sampling:
                 self._cache, sampled = self._dispatch_step(
-                    toks, positions, active, last_idx)
+                    toks, positions, active, last_idx, slot_i)
                 sampled = np.asarray(sampled)
                 logits = None
             else:
-                self._cache, logits = self._dispatch_step(toks,
-                                                          positions,
-                                                          active)
+                self._cache, logits = self._dispatch_step(
+                    toks, positions, active, slot_i=slot_i)
                 logits = np.asarray(logits)
         reg = self.registry
         # Adopt freshly-written full prompt pages into the prefix
         # cache (and spill them) BEFORE the finish checks below can
         # release a short request's pages.
         if self._prefix is not None:
-            for slot_i, req, resume, pages, start, pinned in group:
-                slot = self._active[slot_i]
+            for s_i, req, resume, pages, start, pinned in group:
+                slot = self._active[s_i]
                 if slot is not None:
-                    self._adopt_prefix_pages(slot_i, slot, resume)
+                    self._adopt_prefix_pages(s_i, slot, resume)
             self._update_kv_gauges()
         prefill_done = time.perf_counter()
-        for slot_i, req, resume, _, start, _ in group:
+        for s_i, req, resume, _, start, _ in group:
             n = int(resume.size)
+            row = s_i if slot_i is None else 0
             if req.prefill_done_t is None:
                 req.prefill_done_t = prefill_done
             if self.device_sampling:
-                first = int(sampled[slot_i])
+                first = int(sampled[row])
             else:
-                first = sample_token(logits[slot_i, n - start - 1],
-                                     req)
+                first = sample_token(logits[row, n - start - 1], req)
             fresh = req.first_token_t is None
-            self._active[slot_i].next_token = first
+            self._active[s_i].next_token = first
             req.push_token(first)
             if fresh:
                 flightrec.record("req", f"first_token {req.id}")
@@ -1733,14 +1793,17 @@ class Engine:
             if self.chaos is not None:
                 self.chaos.on_token()   # kill/stall@tokens (post-push:
                 #                         the token reached the stream)
-            self._slot_maybe_finish(slot_i, first)
+            self._slot_maybe_finish(s_i, first)
         reg.counter("serve_prefills_total").inc()
         # Suffix tokens only: with a prefix hit this is the REAL
         # prefill compute — bench_serve's prefill_tokens_per_request
         # dropping to ~the suffix length is the tentpole's measured
-        # win.
+        # win. Padded tokens are what the call computed (rows x
+        # bucket): their ratio is the call's useful share.
         reg.counter("serve_prefill_tokens_total").inc(
             sum(int(r.size) - st for _, _, r, _, st, _ in group))
+        reg.counter("serve_prefill_padded_tokens_total").inc(
+            rows * bucket)
         reg.histogram("serve_prefill_s").observe(
             time.perf_counter() - t0)
 
