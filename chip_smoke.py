@@ -34,11 +34,11 @@ supports (depth is what the model's reference workload uses):
   Trainer's train_step is lowered and its compiled text must hold the
   Pallas custom calls.
 - latent: the ``latent_lm`` decoder at the widths and the cut of
-  ``benchmark/configs/dots3-note-prev.json`` through the serve engine's
-  own masked step (``Engine._step``, the logits form): two
-  ``[1, 4096]`` prefill calls, as the engine dispatches them over its
-  paged pool (4,096 and 2,304 tokens into slots 0 and 7, six slots
-  idle), then 64 width-1 decode steps of both, greedy; every logit row
+  ``benchmark/configs/dots3-note-prev.json`` over the serve engine's
+  own pool and page table (``model.apply`` as ``Engine._masked_step``
+  calls it, returning the logits the engine's step samples from): two
+  ``[1, 4096]`` prefill calls, as the engine dispatches them (4,096
+  and 2,304 tokens into slots 0 and 7, six slots idle), then 64 width-1 decode steps of both, greedy; every logit row
   kept is compared with the plain reference's full forward over the
   same tokens (``LATENT_LOGIT_TOL``), and the reference's own float8
   control has to fail that tolerance.
@@ -1016,7 +1016,17 @@ def _latent_child(rehearse: bool) -> int:
                                dtype=model_kw["param_dtype"])
     engine = Engine(model, {"params": params}, ServeConfig(
         slots=slots, prefill_buckets=(bucket,), queue_max=8,
-        emit_every_s=0.0, device_sampling=False))
+        emit_every_s=0.0))
+    paged = engine._paged_kv
+
+    def step(params, cache, tokens, positions, active, page_table):
+        logits, mutated = model.apply(
+            {"params": params, "cache": cache}, tokens, train=False,
+            decode=True, pos_offset=positions, decode_active=active,
+            paged_kv=paged, page_table=page_table, mutable=["cache"])
+        return mutated["cache"], logits
+
+    step = jax.jit(step, donate_argnums=(1,))
     active = np.zeros((slots,), bool)
     got = [{} for _ in lens]                  # position -> logits row
     for i, (slot, prompt) in enumerate(zip(rows_at, prompts)):
@@ -1025,9 +1035,9 @@ def _latent_child(rehearse: bool) -> int:
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :len(prompt)] = prompt
         active[slot] = True
-        engine._cache, logits = engine._dispatch_step(
-            toks, np.zeros((1,), np.int32), np.ones((1,), bool),
-            slot_i=slot)
+        engine._cache, logits = step(
+            params, engine._cache, toks, np.zeros((1,), np.int32),
+            np.ones((1,), bool), engine._page_table[slot:slot + 1])
         for p in inside[i] + [len(prompt) - 1]:
             got[i][p] = np.asarray(logits[0, p], np.float32)
     del logits
@@ -1039,8 +1049,9 @@ def _latent_child(rehearse: bool) -> int:
         for i, slot in enumerate(rows_at):
             seqs[i].append(int(np.argmax(got[i][len(seqs[i]) - 1])))
             tok[slot, 0], pos[slot] = seqs[i][-1], len(seqs[i]) - 1
-        engine._cache, logits = engine._dispatch_step(tok, pos.copy(),
-                                                      active)
+        engine._cache, logits = step(params, engine._cache, tok,
+                                     pos.copy(), active,
+                                     engine._page_table)
         logits = np.asarray(logits, np.float32)
         for i, slot in enumerate(rows_at):
             got[i][len(seqs[i]) - 1] = logits[slot, 0]

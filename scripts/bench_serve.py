@@ -321,23 +321,18 @@ def run_cold_start_bench(args) -> dict:
 
 
 def _lever_overrides(args) -> dict:
-    """ServeConfig overrides from the paged-KV / sampling lever flags
-    (None = keep the config default, so the default bench measures the
-    shipping configuration)."""
-    over = {"kv_pages": args.kv_pages,
+    """ServeConfig overrides from the paged-KV lever flags."""
+    return {"kv_pages": args.kv_pages,
             "kv_page_tokens": args.kv_page_tokens,
             "kv_dtype": args.kv_dtype}
-    if args.paged_kv is not None:
-        over["paged_kv"] = args.paged_kv
-    if args.device_sampling is not None:
-        over["device_sampling"] = args.device_sampling
-    return over
 
 
 def run_slots_sweep(args, model, variables) -> dict:
     """Fixed-KV-pool-bytes capacity sweep (the paging acceptance
-    measurement): take the DENSE pool's byte footprint at
-    ``--slots`` slots as the budget, size a paged (+ optionally int8)
+    measurement): take as the budget what ``--slots`` slots pin when
+    each holds ``max_seq_len`` tokens at the compute dtype (the
+    auto-sized pool, ``kv_pages=0``, less its garbage page: the
+    record's ``dense_*`` fields), size a paged (+ optionally int8)
     pool to AT MOST those bytes, then drive ascending offered
     concurrency through it and report tokens/s + the admitted-slot
     high-water mark per level. ``slot_capacity`` is the analytic
@@ -349,17 +344,17 @@ def run_slots_sweep(args, model, variables) -> dict:
 
     bucket = 1 << max(4, (args.prompt_len - 1).bit_length())
     bucket = min(bucket, args.max_seq_len)
-    dense_cfg = ServeConfig(slots=args.slots, queue_max=1024,
-                            prefill_buckets=(bucket,), emit_every_s=0.0,
-                            paged_kv=False, device_sampling=False)
-    dense_engine = Engine(model, variables, dense_cfg)
-    pool_budget = dense_engine.kv_pool_bytes()
-    dense_bytes_per_slot = pool_budget / args.slots
-    del dense_engine
-
-    # Probe the paged per-page byte cost (pool bytes are linear in
-    # pages+garbage), then size the pool to the dense budget.
     pt = args.kv_page_tokens
+    full = Engine(model, variables, ServeConfig(
+        slots=args.slots, queue_max=1, prefill_buckets=(bucket,),
+        emit_every_s=0.0, kv_pages=0, kv_page_tokens=pt))
+    pool_budget = (full.kv_pool_bytes() * full.kv_pages_usable
+                   / (full.kv_pages_usable + 1))
+    dense_bytes_per_slot = pool_budget / args.slots
+    del full
+
+    # Probe the per-page byte cost at --kv-dtype (pool bytes are linear
+    # in pages+garbage), then size the pool to that budget.
     kv_dtype = args.kv_dtype
     probe = Engine(model, variables, ServeConfig(
         slots=1, queue_max=1, prefill_buckets=(bucket,),
@@ -372,12 +367,10 @@ def run_slots_sweep(args, model, variables) -> dict:
     pages_per_req = -(-req_tokens // pt)
     slot_capacity = max(1, usable // pages_per_req)
     sweep_slots = min(slot_capacity, 4 * args.slots)
-    sampling = (args.device_sampling if args.device_sampling is not None
-                else ServeConfig.device_sampling)
     cfg = ServeConfig(slots=sweep_slots, queue_max=4096,
                       prefill_buckets=(bucket,), emit_every_s=0.0,
                       kv_pages=usable, kv_page_tokens=pt,
-                      kv_dtype=kv_dtype, device_sampling=sampling)
+                      kv_dtype=kv_dtype)
     engine = Engine(model, variables, cfg).start()
     levels = sorted({max(1, sweep_slots // 4), sweep_slots // 2,
                      sweep_slots} - {0})
@@ -402,7 +395,6 @@ def run_slots_sweep(args, model, variables) -> dict:
     return {
         "mode": "slots_sweep",
         "device": jax.devices()[0].device_kind,
-        "device_sampling": sampling,
         "kv_dtype": kv_dtype,
         "kv_page_tokens": pt,
         "prompt_len": args.prompt_len,
@@ -945,27 +937,20 @@ def main() -> None:
                          "sigkill)")
     ap.add_argument("--slots-sweep", action="store_true",
                     help="fixed-KV-pool-bytes capacity sweep: size a "
-                         "paged pool to the DENSE pool's bytes, then "
+                         "pool to the bytes --slots slots pin at full "
+                         "length, then "
                          "report tokens/s and admitted-slot count vs "
                          "offered concurrency — the concurrent-slot "
                          "multiplier paging buys at constant HBM")
-    ap.add_argument("--paged-kv", default=None,
-                    action=argparse.BooleanOptionalAction,
-                    help="engine paged-KV lever for A/Bs (default: "
-                         "the ServeConfig default, ON)")
     ap.add_argument("--kv-pages", type=int, default=0,
-                    help="usable KV pages (0 = dense-equivalent "
-                         "capacity)")
+                    help="usable KV pages (0 = every slot at full "
+                         "length)")
     ap.add_argument("--kv-page-tokens", type=int, default=16,
                     help="tokens per KV page")
     ap.add_argument("--kv-dtype", default="auto",
                     choices=("auto", "bf16", "int8"),
                     help="KV page payload dtype (int8 = quantized "
                          "pages, per-row scale)")
-    ap.add_argument("--device-sampling", default=None,
-                    action=argparse.BooleanOptionalAction,
-                    help="fused on-device sampling lever for A/Bs "
-                         "(default: the ServeConfig default, ON)")
     ap.add_argument("--prefix-frac", type=float, default=0.0,
                     help="shared-prompt workload: this fraction of "
                          "requests share one prompt prefix; > 0 "
@@ -1091,11 +1076,6 @@ def main() -> None:
                                    seq_len=16)
 
     if args.prefix_frac > 0:
-        if args.paged_kv is False:
-            print("--no-paged-kv is incompatible with --prefix-frac "
-                  "(the prefix cache lives in the paged pool); drop "
-                  "one of the flags", file=sys.stderr)
-            sys.exit(2)
         out = run_prefix_bench(args, model, variables, max(levels))
         print(json.dumps(out, indent=1))
         if args.out:
@@ -1112,14 +1092,6 @@ def main() -> None:
         return
 
     if args.spec:
-        if args.paged_kv is False or args.device_sampling is False:
-            # The engine would raise the same complaint at build time;
-            # exit 2 with the reason before any compile work starts.
-            print("--spec requires paged KV and device sampling "
-                  "(rejection is a page-table rewind; acceptance "
-                  "compares against the fused sampler); drop the "
-                  "--no-* flags", file=sys.stderr)
-            sys.exit(2)
         out = run_spec_bench(args, model_cfg, model, variables,
                              max(levels))
         print(json.dumps(out, indent=1))
@@ -1137,15 +1109,6 @@ def main() -> None:
         return
 
     if args.slots_sweep:
-        if args.paged_kv is False:
-            # The sweep IS the paged-capacity measurement; silently
-            # benchmarking the paged pool under a dense flag would
-            # mislabel the record — refuse loudly.
-            print("--no-paged-kv is incompatible with --slots-sweep "
-                  "(the sweep measures paged capacity against the "
-                  "dense byte budget); drop one of the flags",
-                  file=sys.stderr)
-            sys.exit(2)
         out = run_slots_sweep(args, model, variables)
         print(json.dumps(out, indent=1))
         if args.out:
@@ -1188,9 +1151,7 @@ def main() -> None:
         "slots": args.slots,
         "prompt_len": args.prompt_len,
         "new_tokens": args.new_tokens,
-        "paged_kv": engine._paged_kv is not None,
         "kv_dtype": cfg.kv_dtype,
-        "device_sampling": engine.device_sampling,
         # KV capacity telemetry: pool bytes pinned per slot and per
         # cacheable token (the serve_budget.json kv_bytes_per_token
         # ceiling gates the latter against silent pool bloat).

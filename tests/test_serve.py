@@ -1,12 +1,11 @@
 """Serving engine tests (tpunet/serve/): continuous batching over the
 KV-slot pool on a tiny CPU LM — slot reuse, mid-flight admission token
 parity with solo greedy decode, backpressure, deadlines, cancellation,
-drain, and the host-side sampler's parity with filter_logits."""
+drain."""
 
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -14,7 +13,7 @@ from tpunet.config import ModelConfig, ServeConfig
 from tpunet.models import create_model, init_variables
 from tpunet.models.lm import generate
 from tpunet.serve import (Engine, GenerateRequest, PromptTooLongError,
-                          QueueFullError, RequestQueue, sample_token)
+                          QueueFullError, RequestQueue)
 from tpunet.serve.scheduler import DrainingError
 
 TINY = ModelConfig(name="lm", vit_hidden=32, vit_depth=2, vit_heads=2,
@@ -116,7 +115,7 @@ def test_streamed_events_arrive_in_order(tiny_lm):
 
 
 def test_sampled_generation_deterministic_per_seed(tiny_lm):
-    """Sampling is host-side with a per-request seeded generator: the
+    """Sampling keys are per request, folded from its seed: the
     same seed reproduces the same tokens, a different seed (almost
     surely) differs, and all tokens stay in-vocab."""
     eng = make_engine(tiny_lm).start()
@@ -394,35 +393,20 @@ def test_engine_failure_fails_requests_and_health(tiny_lm):
         eng.submit(prompts(1)[0])
 
 
-# ---------------------------------------------------------------------------
-# host-side sampler parity
-# ---------------------------------------------------------------------------
-
-def test_sample_token_greedy_is_argmax():
-    req = GenerateRequest([1], max_new_tokens=1, temperature=0.0)
-    logits = np.asarray([0.1, 3.0, -1.0, 2.9])
-    assert sample_token(logits, req) == 1
-
-
-def test_sample_token_filters_match_filter_logits():
-    """The host sampler's support (post top-k/top-p) must equal
-    filter_logits' support — the serving path may not admit tokens the
-    training-side sampler would have filtered out."""
-    from tpunet.models.lm import filter_logits
-    rng = np.random.default_rng(3)
-    for _ in range(10):
-        logits = rng.normal(size=16).astype(np.float32) * 2
-        for top_k, top_p in ((3, 0.0), (0, 0.7), (5, 0.8)):
-            ref = np.asarray(filter_logits(
-                jnp.asarray(logits)[None] / 0.8, top_k=top_k,
-                top_p=top_p))[0]
-            allowed = set(np.nonzero(np.isfinite(ref))[0].tolist())
-            seen = set()
-            for seed in range(40):
-                req = GenerateRequest([1], max_new_tokens=1,
-                                      temperature=0.8, top_k=top_k,
-                                      top_p=top_p, seed=seed)
-                seen.add(sample_token(logits, req))
-            assert seen <= allowed, (top_k, top_p, seen - allowed)
-            # the argmax survives every filter and must be reachable
-            assert int(np.argmax(logits)) in allowed
+@pytest.mark.parametrize("surface,name", [
+    ("config", "paged_kv"), ("config", "device_sampling"),
+    ("cli", "--no-paged-kv"), ("cli", "--no-device-sampling")])
+def test_cache_layout_and_sampler_are_not_options(surface, name, capsys):
+    """An engine holds a paged pool and samples on the device, always:
+    the dataclass and argparse reject the two options that used to
+    select otherwise by themselves (no shim, nothing silently
+    ignored)."""
+    if surface == "config":
+        with pytest.raises(TypeError, match=name):
+            ServeConfig(**{name: True})
+        return
+    from tpunet.serve.__main__ import main
+    with pytest.raises(SystemExit) as exit_info:
+        main([name])
+    assert exit_info.value.code == 2
+    assert f"unrecognized arguments: {name}" in capsys.readouterr().err

@@ -1,17 +1,20 @@
 """Paged KV cache + device-side batched sampling + int8 KV (PR 12).
 
-Covers the serve engine's rebuilt memory and sampling hot paths on a
-tiny CPU LM: device-vs-host sampler parity (greedy bit-identical;
-seeded stochastic draws stay inside filter_logits' support and are
-deterministic per (seed, step)), page-recycling/fragmentation stress
+Covers the serve engine's memory and sampling hot paths on a
+tiny CPU LM: the device sampler against argmax and filter_logits
+(greedy bit-identical; seeded stochastic draws stay inside
+filter_logits' support and are deterministic per (seed, step)),
+page-recycling/fragmentation stress
 (churn until every page has been reused; no stale-KV bleed across slot
 reuse), pool-exhaustion preemption resuming token-identically, the
 int8 eval-parity gate, the effective-budget satellite, and AOT
 cold-start of the paged+fused program set. PR 18 extends the stress
 and parity coverage to the prefix KV cache: refcounted/COW page
 semantics, suffix-only prefill on hits, eviction under pool pressure,
-cache-on/off/dense greedy parity, and the shared-filesystem
-spill/warm-start round trip.
+cache-on/off greedy parity, and the shared-filesystem
+spill/warm-start round trip. One engine here serves over a mesh
+(``model=2`` of the forced CPU devices): the only ``[slots, bucket]``
+prefill path left.
 """
 
 import numpy as np
@@ -37,14 +40,25 @@ def tiny_lm():
     return model, variables
 
 
-def make_engine(tiny_lm, **cfg_kw):
+def make_engine(tiny_lm, mesh=None, **cfg_kw):
     model, variables = tiny_lm
     cfg_kw.setdefault("slots", 4)
     cfg_kw.setdefault("queue_max", 16)
     cfg_kw.setdefault("prefill_buckets", (8, 16))
     cfg_kw.setdefault("default_max_new_tokens", 6)
     cfg_kw.setdefault("emit_every_s", 0.0)
-    return Engine(model, variables, ServeConfig(**cfg_kw))
+    return Engine(model, variables, ServeConfig(**cfg_kw), mesh=mesh)
+
+
+def make_mesh_engine(tiny_lm, **cfg_kw):
+    """The same engine served tensor-parallel over two of the CPU
+    devices conftest.py forces (heads and MLP split over 'model')."""
+    from tpunet.config import MeshConfig
+    from tpunet.infer.generate import load_lm
+    from tpunet.parallel import make_mesh
+    mesh = make_mesh(MeshConfig(data=1, model=2))
+    return make_engine(load_lm(TINY, variables=tiny_lm[1], mesh=mesh),
+                       mesh=mesh, **cfg_kw)
 
 
 def prompts(n, rng_seed=0, lo=2, hi=9):
@@ -62,12 +76,12 @@ def solo_greedy(tiny_lm, prompt, n_new):
 
 
 # ---------------------------------------------------------------------------
-# device-vs-host sampler parity
+# the device sampler against argmax and filter_logits
 # ---------------------------------------------------------------------------
 
 def test_batched_sample_greedy_is_bitwise_argmax():
     """Greedy rows (temperature <= 0) of the device sampler must equal
-    the host sampler's np.argmax on the same float32 logits — the
+    np.argmax on the same float32 logits — the
     invariant that keeps greedy serve output token-identical to solo
     generate."""
     from tpunet.serve.sampling import batched_sample
@@ -152,9 +166,8 @@ def test_batched_sample_deterministic_per_seed_and_step():
 
 def test_seed_validated_at_admission():
     """A bad seed is a client error at admission (the frontend maps
-    ValueError to HTTP 400), never an engine-thread death on the host
-    sampler (numpy rejects negatives) or a silent int32 stream
-    collision on the device path (seeds past bit 31)."""
+    ValueError to HTTP 400), never a silent int32 stream collision
+    in the device sampler's key fold (seeds past bit 31)."""
     with pytest.raises(ValueError, match="seed"):
         GenerateRequest(np.arange(1, 4), max_new_tokens=2, seed=-3)
     with pytest.raises(ValueError, match="seed"):
@@ -162,22 +175,18 @@ def test_seed_validated_at_admission():
     GenerateRequest(np.arange(1, 4), max_new_tokens=2, seed=2 ** 31 - 1)
 
 
-def test_engine_host_sampler_fallback_matches_device_greedy(tiny_lm):
-    """--no-device-sampling keeps the host sampler as the live parity
-    reference: greedy output through both engine paths is identical
-    (and equals solo generate)."""
+def test_engine_fused_sampler_greedy_matches_solo_generate(tiny_lm):
+    """Four co-resident greedy requests through the default engine
+    (the sampler fused onto the step) each get solo generate's
+    tokens."""
     ps = prompts(4, rng_seed=11)
-    outs = {}
-    for label, dev in (("device", True), ("host", False)):
-        eng = make_engine(tiny_lm, device_sampling=dev).start()
-        try:
-            reqs = [eng.submit(p, max_new_tokens=5) for p in ps]
-            outs[label] = [r.result(timeout=120) for r in reqs]
-        finally:
-            eng.stop()
-    assert outs["device"] == outs["host"]
-    for p, o in zip(ps, outs["device"]):
-        assert o == solo_greedy(tiny_lm, p, 5)
+    eng = make_engine(tiny_lm).start()
+    try:
+        reqs = [eng.submit(p, max_new_tokens=5) for p in ps]
+        outs = [r.result(timeout=120) for r in reqs]
+    finally:
+        eng.stop()
+    assert outs == [solo_greedy(tiny_lm, p, 5) for p in ps]
 
 
 # ---------------------------------------------------------------------------
@@ -281,25 +290,23 @@ def test_request_that_cannot_fit_pool_rejected_up_front(tiny_lm):
     assert eng.registry.snapshot()["serve_requests_rejected"] == 1
 
 
-def test_paged_vs_dense_engine_outputs_identical(tiny_lm):
-    """The dense fallback (--no-paged-kv) and the paged default are
-    the same math: identical greedy tokens across a mid-flight
-    admission pattern."""
+def test_paged_engine_mid_flight_admission_matches_solo_generate(tiny_lm):
+    """The paged pool is the same math as solo generate's module-
+    clocked cache: identical greedy tokens across a mid-flight
+    admission pattern (six requests through two slots)."""
     import time
-    outs = {}
-    for label, paged in (("paged", True), ("dense", False)):
-        eng = make_engine(tiny_lm, slots=2, paged_kv=paged).start()
-        try:
-            ps = prompts(6, rng_seed=42)
-            reqs = []
-            for i, p in enumerate(ps):
-                reqs.append(eng.submit(p, max_new_tokens=5))
-                if i % 2 == 1:
-                    time.sleep(0.01)
-            outs[label] = [r.result(timeout=120) for r in reqs]
-        finally:
-            eng.stop()
-    assert outs["paged"] == outs["dense"]
+    eng = make_engine(tiny_lm, slots=2).start()
+    try:
+        ps = prompts(6, rng_seed=42)
+        reqs = []
+        for i, p in enumerate(ps):
+            reqs.append(eng.submit(p, max_new_tokens=5))
+            if i % 2 == 1:
+                time.sleep(0.01)
+        outs = [r.result(timeout=120) for r in reqs]
+    finally:
+        eng.stop()
+    assert outs == [solo_greedy(tiny_lm, p, 5) for p in ps]
 
 
 # ---------------------------------------------------------------------------
@@ -327,7 +334,7 @@ def _spy_prefill_calls(eng):
         return [np.asarray(leaf)
                 for leaf in jax.tree_util.tree_leaves(cache)]
 
-    def spy(toks, positions, active, last_idx=None, slot_i=None):
+    def spy(toks, positions, active, last_idx, slot_i=None):
         if toks.shape[1] == 1:
             return dispatch(toks, positions, active, last_idx, slot_i)
         before = pool(eng._cache) if slot_i is not None else None
@@ -350,76 +357,102 @@ def _spy_prefill_calls(eng):
     return calls
 
 
+MESHES = pytest.mark.parametrize(
+    "build", [make_engine, make_mesh_engine], ids=["one_device", "model2"])
+
+
+@MESHES
 @pytest.mark.parametrize("k", [1, 3, 4])
-def test_paged_prefill_is_one_row_call_per_admitted_request(tiny_lm, k):
-    """k requests admitted together (prompts in both buckets): a paged
+def test_paged_prefill_is_one_row_call_per_admitted_request(tiny_lm, k,
+                                                            build):
+    """k requests admitted together (prompts in both buckets): an
     engine on one device dispatches k prefill calls of ``[1, bucket]``
     tokens, each leaving the pages of every other slot bit-identical;
-    the dense-cache engine still dispatches one ``[slots, bucket]``
-    call per bucket; both serve the tokens the requests get when
-    served one at a time. The gauge and the padded-token counter say
-    which shape an engine runs."""
+    an engine over a mesh dispatches one ``[slots, bucket]`` call per
+    bucket; both serve solo generate's tokens. The gauge and the
+    padded-token counter say which shape an engine runs."""
     rng = np.random.default_rng(28)
     ps = [rng.integers(0, TINY.vocab_size, size=n).astype(np.int32)
           for n in (5, 12, 7, 14)[:k]]
     buckets = [8 if p.size <= 8 else 16 for p in ps]
 
-    solo = make_engine(tiny_lm).start()
+    eng = build(tiny_lm, prefix_cache=False)
+    calls = _spy_prefill_calls(eng)
+    # queued before the engine thread runs: one admission takes all
+    reqs = [eng.submit(p, max_new_tokens=5) for p in ps]
+    eng.start()
     try:
-        want = [solo.submit(p, max_new_tokens=5).result(timeout=120)
-                for p in ps]
+        got = [r.result(timeout=120) for r in reqs]
     finally:
-        solo.stop()
-    assert want == [solo_greedy(tiny_lm, p, 5) for p in ps]
-
-    for paged in (True, False):
-        eng = make_engine(tiny_lm, paged_kv=paged, prefix_cache=False)
-        calls = _spy_prefill_calls(eng)
-        # queued before the engine thread runs: one admission takes all
-        reqs = [eng.submit(p, max_new_tokens=5) for p in ps]
-        eng.start()
-        try:
-            got = [r.result(timeout=120) for r in reqs]
-        finally:
-            eng.stop()
-        assert eng.error is None and got == want
-        snap = eng.registry.snapshot()
-        assert snap["serve_prefill_tokens_total"] == sum(
-            p.size for p in ps)
-        if paged:
-            # _admit groups by bucket, ascending; admission order within
-            assert [c["shape"] for c in calls] == \
-                [(1, b) for b in sorted(buckets)]
-            assert sorted(c["slot"] for c in calls) == list(range(k))
-            assert all(c["others_frozen"] for c in calls)
-            assert snap["serve_prefill_rows_per_call"] == 1
-            assert snap["serve_prefills_total"] == k
-            assert snap["serve_prefill_padded_tokens_total"] == \
-                sum(buckets)
-        else:
-            assert [c["shape"] for c in calls] == \
-                [(eng.slots, b) for b in sorted(set(buckets))]
-            assert all(c["slot"] is None for c in calls)
-            assert snap["serve_prefill_rows_per_call"] == eng.slots
-            assert snap["serve_prefill_padded_tokens_total"] == \
-                eng.slots * sum(set(buckets))
+        eng.stop()
+    assert eng.error is None
+    assert got == [solo_greedy(tiny_lm, p, 5) for p in ps]
+    snap = eng.registry.snapshot()
+    assert snap["serve_prefill_tokens_total"] == sum(p.size for p in ps)
+    if eng.mesh is None:
+        # _admit groups by bucket, ascending; admission order within
+        assert [c["shape"] for c in calls] == \
+            [(1, b) for b in sorted(buckets)]
+        assert sorted(c["slot"] for c in calls) == list(range(k))
+        assert all(c["others_frozen"] for c in calls)
+        assert snap["serve_prefill_rows_per_call"] == 1
+        assert snap["serve_prefills_total"] == k
+        assert snap["serve_prefill_padded_tokens_total"] == sum(buckets)
+    else:
+        assert [c["shape"] for c in calls] == \
+            [(eng.slots, b) for b in sorted(set(buckets))]
+        assert all(c["slot"] is None for c in calls)
+        assert snap["serve_prefill_rows_per_call"] == eng.slots
+        assert snap["serve_prefill_padded_tokens_total"] == \
+            eng.slots * sum(set(buckets))
 
 
-@pytest.mark.parametrize("paged", [True, False])
+@MESHES
 def test_program_texts_have_the_rows_the_engine_dispatches(tiny_lm,
-                                                           paged):
+                                                           build):
     """``program_texts()`` lowers what ``_dispatch_step`` runs: the
     decode program is ``[slots, 1]``, the ``w<bucket>`` programs have a
-    one-row token parameter for a paged engine and ``slots`` rows for a
-    dense-cache one — under the labels they always had."""
-    eng = make_engine(tiny_lm, paged_kv=paged)
+    one-row token parameter on one device and ``slots`` rows over a
+    mesh — under the labels they always had."""
+    eng = build(tiny_lm)
     texts = eng.program_texts()
     assert sorted(texts) == ["jit__masked_step/w1", "jit__masked_step/w16",
                              "jit__masked_step/w8"]
     assert _token_rows(texts["jit__masked_step/w1"], 1) == eng.slots
     for bucket in (8, 16):
         assert _token_rows(texts[f"jit__masked_step/w{bucket}"],
-                           bucket) == (1 if paged else eng.slots)
+                           bucket) == (1 if eng.mesh is None
+                                       else eng.slots)
+
+
+@MESHES
+def test_step_avals_state_the_masked_steps_signature(tiny_lm, build):
+    """``_step_avals`` is the one statement of ``_masked_step``'s
+    signature: twelve entries in its parameters' order, ``slots`` rows
+    at width 1 and ``_prefill_rows`` at a bucket — and the jit program
+    traces at exactly those shapes."""
+    import inspect
+    eng = build(tiny_lm)
+    names = list(inspect.signature(eng._step).parameters)
+    assert names == ["params", "cache", "tokens", "positions", "active",
+                     "page_table", "last_idx", "temp", "top_k", "top_p",
+                     "seeds", "steps"]
+    for width, rows in ((1, eng.slots),
+                        (16, 1 if eng.mesh is None else eng.slots)):
+        avals = eng._step_avals(width)
+        assert len(avals) == len(names)
+        by_name = dict(zip(names, avals))
+        assert by_name["tokens"].shape == (rows, width)
+        assert by_name["page_table"].shape == (rows, eng.pages_per_slot)
+        assert by_name["active"].dtype == bool
+        for name in names[3:5] + names[6:]:
+            assert by_name[name].shape == (rows,), name
+        assert [by_name[n].dtype for n in ("temp", "top_p")] == \
+            [np.float32, np.float32]
+        cache, toks = jax.eval_shape(eng._step, *avals)
+        assert toks.shape == (rows,) and toks.dtype == np.int32
+        assert jax.tree_util.tree_structure(cache) == \
+            jax.tree_util.tree_structure(eng._cache)
 
 
 # ---------------------------------------------------------------------------
@@ -570,9 +603,9 @@ def test_prefix_churn_stress_refcounted_pages_no_stale_bleed(tiny_lm):
         eng.stop()
 
 
-def test_prefix_cache_parity_on_off_dense(tiny_lm):
+def test_prefix_cache_parity_on_off(tiny_lm):
     """Greedy output over a shared-prefix workload is identical with
-    the cache on, the cache off, and the dense (--no-paged-kv) path —
+    the cache on and the cache off, and is solo generate's —
     the cache is a pure compute-elision, never a math change."""
     rng = np.random.default_rng(31)
     shared = rng.integers(0, TINY.vocab_size, size=8).astype(np.int32)
@@ -581,15 +614,14 @@ def test_prefix_cache_parity_on_off_dense(tiny_lm):
         for k in (3, 2, 5, 1)]
     outs = {}
     for label, kw in (("cache", {}),
-                      ("nocache", {"prefix_cache": False}),
-                      ("dense", {"paged_kv": False})):
+                      ("nocache", {"prefix_cache": False})):
         eng = make_engine(tiny_lm, slots=2, **kw).start()
         try:
             outs[label] = [eng.submit(p, max_new_tokens=5)
                            .result(timeout=120) for p in ps]
         finally:
             eng.stop()
-    assert outs["cache"] == outs["nocache"] == outs["dense"]
+    assert outs["cache"] == outs["nocache"]
     for p, o in zip(ps, outs["cache"]):
         assert o == solo_greedy(tiny_lm, p, 5)
 
@@ -757,11 +789,6 @@ def test_int8_kv_halves_bf16_page_cost(tiny_lm):
     assert sizes["bf16"] == pytest.approx(sizes["auto"] / 2)
 
 
-def test_int8_requires_paged_kv(tiny_lm):
-    with pytest.raises(ValueError):
-        make_engine(tiny_lm, paged_kv=False, kv_dtype="int8")
-
-
 # ---------------------------------------------------------------------------
 # effective-budget satellite
 # ---------------------------------------------------------------------------
@@ -809,7 +836,7 @@ def test_kv_gauges_and_serve_record_fields(tiny_lm):
 
 
 # ---------------------------------------------------------------------------
-# AOT warm-start of the paged + device-sampled program set
+# AOT warm-start of the engine's program set
 # ---------------------------------------------------------------------------
 
 
@@ -875,27 +902,45 @@ def test_paged_aot_store_roundtrip(tmp_path, tiny_lm):
                for v in eng3.aot_status.values())
 
 
-def test_aot_store_of_slots_row_prefill_programs_is_not_loaded(
-        tmp_path, tiny_lm, monkeypatch):
-    """A store written when every program was ``[slots, bucket]`` (the
-    same configuration, so the same digest and the same ``w16`` tag)
-    holds a prefill executable whose arguments a one-row engine cannot
-    call: the one-row engine misses it and compiles its own; only the
-    ``[slots, 1]`` decode program, which did not change, is loaded."""
+def test_aot_store_written_before_the_options_went_is_a_clean_miss(
+        tmp_path, tiny_lm):
+    """A store whose digest still held ``paged_kv`` and
+    ``device_sampling`` (any ``--aot-cache`` directory written before
+    they were deleted, ``masked_step_r1`` prefill entries and all) is
+    keyed apart from today's: the engine loads nothing from it,
+    compiles once and saves under the one ``masked_step`` name; the
+    boot after that loads."""
+    import dataclasses
+    import os
+
     from tpunet.serve.engine import build_aot_store
+    from tpunet.utils.cache import AotProgramStore, serializable_compile
 
     model, variables = tiny_lm
     cfg = ServeConfig(slots=2, queue_max=4, prefill_buckets=(16,),
                       default_max_new_tokens=8, emit_every_s=0.0,
                       kv_pages=12, kv_page_tokens=8)
+    old = AotProgramStore(str(tmp_path), AotProgramStore.digest({
+        "model": dataclasses.asdict(TINY), "slots": cfg.slots,
+        "prefill_buckets": list(cfg.prefill_buckets),
+        "paged_kv": True, "kv_pages": cfg.kv_pages,
+        "kv_page_tokens": cfg.kv_page_tokens, "kv_dtype": cfg.kv_dtype,
+        "device_sampling": True, "spec_decode": False, "spec_k": 4,
+        "spec_draft_width_mult": 0.5}))
     store = build_aot_store(str(tmp_path), TINY, cfg)
-    with monkeypatch.context() as m:
-        m.setattr(Engine, "_rows_at", lambda self, width: self.slots)
-        old = Engine(model, variables, cfg, aot_store=store)
-    assert old.aot_status == {"w1": "compiled+saved",
-                              "w16": "compiled+saved"}
-    assert _token_rows(old.program_texts()["jit__masked_step/w16"],
-                       16) == 2
+    assert store.config_digest != old.config_digest
+    # what such a store holds: the same programs under the old names
+    writer = Engine(model, variables, cfg)
+    for width, name in ((1, "masked_step"), (16, "masked_step_r1")):
+        with serializable_compile():
+            program = writer._step.lower(
+                *writer._step_avals(width)).compile()
+        assert old.save(name, f"w{width}", program)
+    def entries():
+        return {f for f in os.listdir(tmp_path) if f.endswith(".aotx")}
+
+    before = entries()
+    assert len(before) == 2
 
     eng = Engine(model, variables, cfg, aot_store=store).start()
     try:
@@ -903,9 +948,14 @@ def test_aot_store_of_slots_row_prefill_programs_is_not_loaded(
         assert _answer(eng, prompt, 5) == solo_greedy(tiny_lm, prompt, 5)
     finally:
         eng.stop()
-    assert eng.aot_status == {"w1": "loaded", "w16": "compiled+saved"}
-    assert _token_rows(eng.program_texts()["jit__masked_step/w16"],
-                       16) == 1
+    assert eng.aot_status == {"w1": "compiled+saved",
+                              "w16": "compiled+saved"}
+    added = sorted(entries() - before)
+    assert [f.split("-")[:2] for f in added] == \
+        [["masked_step", "w1"], ["masked_step", "w16"]]
+    assert all(store.config_digest in f for f in added)
+    assert Engine(model, variables, cfg, aot_store=store).aot_status == \
+        {"w1": "loaded", "w16": "loaded"}
 
 
 def test_aot_save_is_load_verified(tmp_path, monkeypatch):
@@ -962,11 +1012,10 @@ def _pool_clean(eng):
     return len(eng._free_pages) + cached == eng.kv_pages_usable
 
 
-def test_spec_config_requires_paged_and_device_sampling(tiny_lm):
-    """Drafting runs against the paged pool and samples on-device;
-    both fallbacks are config errors, not silent downgrades."""
-    for bad in (dict(paged_kv=False), dict(device_sampling=False),
-                dict(spec_k=0), dict(spec_draft_width_mult=0.0)):
+def test_spec_config_rejects_no_drafts_and_no_drafter_width(tiny_lm):
+    """A burst of no draft tokens and a drafter of no width are config
+    errors, not silent downgrades."""
+    for bad in (dict(spec_k=0), dict(spec_draft_width_mult=0.0)):
         with pytest.raises(ValueError):
             make_engine(tiny_lm, spec_decode=True, **bad)
 

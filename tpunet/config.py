@@ -419,16 +419,14 @@ class ServeConfig:
     # the compile count is len(buckets), not one per prompt length.
     # Prompts longer than the largest bucket are rejected.
     prefill_buckets: Tuple[int, ...] = (32, 128, 512)
-    # Paged KV cache (default ON; --no-paged-kv restores the dense
-    # [slots, max_seq_len] pool): per layer, K/V live in a SHARED pool
-    # of fixed-size pages addressed through per-slot page tables, so a
+    # The KV cache is paged: per layer, K/V live in a SHARED pool of
+    # fixed-size pages addressed through per-slot page tables, so a
     # slot pins HBM proportional to its prompt+generated length — the
     # concurrent-slot multiplier at fixed HBM (docs/serving.md "Paged
     # KV cache & device-side sampling").
-    paged_kv: bool = True
     # Usable data pages in the pool (0 = auto: slots *
-    # ceil(max_seq_len / kv_page_tokens), i.e. dense-equivalent
-    # capacity). Size it DOWN to oversubscribe slots against typical
+    # ceil(max_seq_len / kv_page_tokens), every slot at full length).
+    # Size it DOWN to oversubscribe slots against typical
     # request lengths; exhaustion defers admissions and, when nothing
     # can advance, preempts the youngest slot back to the queue with
     # its progress kept.
@@ -441,17 +439,10 @@ class ServeConfig:
     # "bf16" halves float32 payloads; "int8" quantizes each written
     # token row against its own absmax (float32 scale stored with the
     # page, dequantized on gather; eval-parity-gated in
-    # tests/test_serve_paged.py). Requires paged_kv.
+    # tests/test_serve_paged.py).
     kv_dtype: str = "auto"
-    # Device-side batched sampling (default ON; --no-device-sampling
-    # restores the host loop): temperature/top-k/top-p and the
-    # categorical draw run as one [slots]-wide jitted step fused onto
-    # decode (per-slot PRNG keys folded per step) — only sampled
-    # tokens cross the host boundary. Greedy output is token-identical
-    # either way (parity-tested).
-    device_sampling: bool = True
-    # Prefix KV cache (default ON with paged_kv; --no-prefix-cache
-    # disables): finished prefill pages stay in the pool as immutable,
+    # Prefix KV cache (default ON; --no-prefix-cache disables):
+    # finished prefill pages stay in the pool as immutable,
     # content-addressed, refcounted objects keyed by token-prefix
     # digest at page granularity. Admission pins the longest cached
     # page-aligned prefix into the new slot's table and re-prefills
@@ -524,7 +515,7 @@ class ServeConfig:
     # spec-off and sampled output stays deterministic per (seed, step)
     # (failover/replay safe). Rejection rewinds the slot's page-table
     # cursor to the last accepted position and recycles the tail
-    # pages. Requires paged_kv AND device_sampling.
+    # pages.
     spec_decode: bool = False
     # Draft tokens proposed per verify cycle (the K in draft-then-
     # verify). Higher K amortizes the verify gather over more tokens

@@ -60,13 +60,13 @@ class TransformerLM(nn.Module):
                  paged_kv=None, page_table=None):
         """``decode=True``: incremental step against the KV cache (one
         token per call after cache init); ``pos_offset`` is the absolute
-        position of ``tokens[:, 0]`` in the sequence — a scalar, or an
-        int32 [B] array giving each batch row its OWN position (the
-        tpunet/serve slot-pool engine: rows are independent requests at
-        different depths; T > 1 then runs a chunked causal prefill that
-        writes K/V for all T positions in one pass). ``decode_active``
-        [B] bool gates per-row cache writes (inactive slots stay
-        bit-frozen). ``segment_ids`` [B, T] enables packed-sequence
+        position of ``tokens[:, 0]`` in the sequence — a scalar (solo
+        ``generate``), or with a page table an int32 [B] array giving
+        each batch row its OWN position (the tpunet/serve engine: rows
+        are independent requests at different depths; T > 1 then runs
+        a chunked causal prefill that writes K/V for all T positions
+        in one pass) and ``decode_active`` [B] bool gating per-row
+        cache writes. ``segment_ids`` [B, T] enables packed-sequence
         training: attention is masked to same-segment tokens (composed
         with causality in the core). ``return_hidden=True`` returns the
         final-LN hidden states [B, T, C] float32 instead of logits —
@@ -75,8 +75,9 @@ class TransformerLM(nn.Module):
         materializing the [B, T, V] logits. ``paged_kv`` (a
         ``models.vit.PagedKV``) + ``page_table`` [B, pages-per-row]
         int32 switch the decode KV cache to the shared page pool
-        (tpunet/serve paged continuous batching; needs per-row
-        ``pos_offset``)."""
+        (tpunet/serve continuous batching; needs per-row
+        ``pos_offset``, and per-row ``pos_offset`` needs it: the
+        attention raises on one without the other)."""
         b, t = tokens.shape
         if t > self.max_len:
             raise ValueError(f"sequence {t} exceeds max_len {self.max_len}")

@@ -43,9 +43,7 @@ AttnFn = Callable[..., jax.Array]  # (q, k, v) BTHD -> BTHD
 class PagedKV:
     """Paged KV-cache geometry (tpunet/serve continuous batching).
 
-    The dense decode cache pins ``[B, max_seq_len]`` K/V rows per
-    layer for every slot regardless of how far the slot has actually
-    decoded. Paged mode replaces it with a SHARED page pool: K/V live
+    The serve engine's cache is a SHARED page pool: K/V live
     in ``pages`` fixed-size pages of ``page_tokens`` tokens each, and
     every batch row addresses its tokens through a per-row page table
     (``page_table`` [B, ceil(max_seq_len/page_tokens)] int32 page
@@ -114,16 +112,18 @@ class Attention(nn.Module):
     with ``decode=True``; the injected attn_fn is bypassed in this mode
     (single-query attention is computed inline).
 
-    Serving hooks (tpunet/serve continuous batching): ``positions``
-    [B] int32 gives each batch row its OWN cache write index (rows
-    advance independently — the slot-pool engine keeps requests at
-    different depths in one batch), and generalizes the call to T >= 1
-    queries per row (chunked prefill: K/V for positions
-    ``positions[b] .. positions[b]+T-1`` are written in one pass,
-    causally masked). ``active`` [B] bool gates the cache write per
-    row — an inactive slot's cache is bit-frozen through any number of
-    steps. With ``positions`` given, the module's own ``cache_index``
-    is neither read nor advanced: the engine owns the clock."""
+    That module-clocked cache is solo ``models.lm.generate``'s (the
+    tests' reference). Serving (tpunet/serve continuous batching)
+    passes ``paged_kv`` + ``page_table`` and decodes against a shared
+    page pool instead (``_paged_decode_attend``): ``positions`` [B]
+    int32 gives each batch row its OWN write index (rows advance
+    independently — the engine keeps requests at different depths in
+    one batch) and generalizes the call to T >= 1 queries per row
+    (chunked prefill: K/V for positions ``positions[b] ..
+    positions[b]+T-1`` are written in one pass, causally masked);
+    ``active`` [B] bool gates the write per row. The module's own
+    ``cache_index`` is then neither created nor read: the engine owns
+    the clock."""
 
     heads: int
     attn_fn: AttnFn = dense_attention
@@ -179,44 +179,30 @@ class Attention(nn.Module):
             # and sharded cores would impose mesh divisibility on the
             # dummy shape — decode steps never call it).
             return jnp.zeros_like(q)
-        b, t = q.shape[0], q.shape[1]
-        module_clock = positions is None
-        if module_clock:
-            # Legacy single-clock path (models.lm.generate): one shared
-            # index, one token per call, module-owned advance.
-            if t != 1:
-                raise ValueError(
-                    f"decode processes one token per call, got {t}")
-            positions = jnp.broadcast_to(ci.value, (b,))
-
-        # Per-row write of the new K/V at positions[b] .. positions[b]
-        # + t - 1 (vmapped dynamic_update_slice lowers to one scatter);
-        # inactive rows keep their cache bit-identical.
-        def write_row(cache_row, new_row, start):
-            return jax.lax.dynamic_update_slice(cache_row, new_row,
-                                                (start, 0, 0))
-        new_k = jax.vmap(write_row)(ck.value, k, positions)
-        new_v = jax.vmap(write_row)(cv.value, v, positions)
-        if active is not None:
-            gate = active[:, None, None, None]
-            new_k = jnp.where(gate, new_k, ck.value)
-            new_v = jnp.where(gate, new_v, cv.value)
-        ck.value, cv.value = new_k, new_v
-        if module_clock:
-            ci.value = ci.value + t
-        kf, vf = ck.value, cv.value
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kf,
+        # Solo decode (models.lm.generate): one clock for the whole
+        # batch, one token per call, module-owned advance.
+        if positions is not None or active is not None:
+            raise ValueError(
+                "per-row positions and an active mask address a paged "
+                "pool: pass paged_kv and a page_table (TransformerLM "
+                "passes them only with one)")
+        if q.shape[1] != 1:
+            raise ValueError(
+                f"decode processes one token per call, got {q.shape[1]}")
+        ck.value = jax.lax.dynamic_update_slice(
+            ck.value, k, (0, ci.value, 0, 0))
+        cv.value = jax.lax.dynamic_update_slice(
+            cv.value, v, (0, ci.value, 0, 0))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, ck.value,
                        preferred_element_type=jnp.float32)
         s = s * (q.shape[-1] ** -0.5)
-        # query i of row b sits at positions[b] + i; only cache entries
-        # at or before it are real (causality per row).
+        # only cache entries at or before the clock are real
         from tpunet.ops.attention import _NEG_INF
-        qpos = positions[:, None] + jnp.arange(t)[None, :]        # [B, T]
-        valid = (jnp.arange(kf.shape[1])[None, None, :]
-                 <= qpos[:, :, None])                             # [B,T,K]
-        s = jnp.where(valid[:, None, :, :], s, _NEG_INF)
+        valid = jnp.arange(ck.value.shape[1]) <= ci.value          # [K]
+        s = jnp.where(valid[None, None, None, :], s, _NEG_INF)
+        ci.value = ci.value + 1
         p = jax.nn.softmax(s, axis=-1)
-        y = jnp.einsum("bhqk,bkhd->bqhd", p, vf,
+        y = jnp.einsum("bhqk,bkhd->bqhd", p, cv.value,
                        preferred_element_type=jnp.float32)
         return y.astype(q.dtype)
 
@@ -244,9 +230,8 @@ class Attention(nn.Module):
         programs, int8 pools, a mesh, the CPU — gathers the row's
         pages back into position order and runs the exact dense masked
         attention math over them. Causality (j <= qpos per row) makes
-        garbage beyond each row's own written prefix invisible on both,
-        the same invariant the dense bucketed prefill already relies
-        on.
+        garbage beyond each row's own written prefix invisible on
+        both.
 
         int8 pages carry a float32 scale per page row (written in the
         same scatter) and dequantize on gather. The engine owns page
@@ -280,7 +265,7 @@ class Attention(nn.Module):
                                (flat_rows,), jnp.float32)
         if is_init:
             # Cache-creation pass (positions legitimately absent):
-            # buffers sized above, attention skipped like the dense
+            # buffers sized above, attention skipped like the solo
             # init path.
             return jnp.zeros_like(q)
         if positions is None or page_table is None:

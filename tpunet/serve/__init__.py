@@ -31,7 +31,7 @@ contract.
 from __future__ import annotations
 
 from tpunet.serve.classify import ClassifyBatcher
-from tpunet.serve.engine import Engine, PromptTooLongError, sample_token
+from tpunet.serve.engine import Engine, PromptTooLongError
 from tpunet.serve.frontend import ServeServer
 from tpunet.serve.scheduler import (DrainingError, GenerateRequest,
                                     QueueFullError, RequestQueue)
@@ -39,5 +39,5 @@ from tpunet.serve.scheduler import (DrainingError, GenerateRequest,
 __all__ = [
     "ClassifyBatcher", "DrainingError", "Engine", "GenerateRequest",
     "PromptTooLongError", "QueueFullError", "RequestQueue",
-    "ServeServer", "sample_token",
+    "ServeServer",
 ]

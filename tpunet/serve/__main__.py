@@ -78,16 +78,9 @@ def build_argparser():
         str(b) for b in d.prefill_buckets),
         help="comma-separated padded prompt-length buckets (compile "
              "count = number of buckets)")
-    p.add_argument("--paged-kv", default=d.paged_kv,
-                   action=argparse.BooleanOptionalAction,
-                   help="paged KV cache (default on): K/V in a shared "
-                        "page pool with per-slot page tables, so a "
-                        "slot costs prompt-proportional HBM; "
-                        "--no-paged-kv restores the dense "
-                        "[slots, max_seq_len] pool")
     p.add_argument("--kv-pages", type=int, default=d.kv_pages,
                    help="usable KV pages in the shared pool (0 = "
-                        "dense-equivalent capacity: slots x "
+                        "every slot at full length: slots x "
                         "ceil(max-seq-len / kv-page-tokens)); size it "
                         "down to oversubscribe slots against typical "
                         "request lengths")
@@ -151,12 +144,6 @@ def build_argparser():
                         "but drafts nothing useful — fit one against "
                         "real traffic with tpunet.serve.spec."
                         "fit_drafter")
-    p.add_argument("--device-sampling", default=d.device_sampling,
-                   action=argparse.BooleanOptionalAction,
-                   help="batched temperature/top-k/top-p sampling "
-                        "fused onto the decode step on device "
-                        "(default on); --no-device-sampling restores "
-                        "the host-side per-slot sampler")
     p.add_argument("--max-new-tokens", type=int,
                    default=d.default_max_new_tokens,
                    help="default per-request generation budget")
@@ -276,9 +263,8 @@ def build_server(args):
     cfg = ServeConfig(
         host=args.host, port=args.port, slots=args.slots,
         queue_max=args.queue_max, prefill_buckets=buckets,
-        paged_kv=args.paged_kv, kv_pages=args.kv_pages,
+        kv_pages=args.kv_pages,
         kv_page_tokens=args.kv_page_tokens, kv_dtype=args.kv_dtype,
-        device_sampling=args.device_sampling,
         prefix_cache=args.prefix_cache,
         prefix_cache_pages=args.prefix_cache_pages,
         prefix_store=args.prefix_store,
@@ -314,7 +300,7 @@ def build_server(args):
         from tpunet.serve.engine import build_aot_store
         aot_store = build_aot_store(cfg.aot_cache, model_cfg, cfg)
     prefix_store = None
-    if cfg.prefix_store and cfg.prefix_cache and cfg.paged_kv:
+    if cfg.prefix_store and cfg.prefix_cache:
         from tpunet.serve.prefixcache import build_prefix_store
         prefix_store = build_prefix_store(cfg.prefix_store, model_cfg,
                                           cfg)

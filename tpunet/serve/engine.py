@@ -8,17 +8,16 @@ iteration feeds every active slot its next token at its own position
 ones free their slot without any recompilation. Prefill runs through
 the same masked path as a chunked multi-token call, padded to one of a
 fixed set of length buckets — the total compile count is bounded at
-``1 + len(prefill_buckets)`` programs for the life of the server. Over
-a paged pool on one device a prefill program is ``[1, bucket]``, one
-call per admitted request (a paged row reaches its tokens only through
-its row of the page table); a dense cache and a mesh keep
-``[slots, bucket]``, one call per admission group.
+``1 + len(prefill_buckets)`` programs for the life of the server. On
+one device a prefill program is ``[1, bucket]``, one call per admitted
+request (a row reaches its tokens only through its row of the page
+table); over a mesh it is ``[slots, bucket]``, one call per admission
+group.
 
-KV memory is PAGED by default (``ServeConfig.paged_kv``;
-``--no-paged-kv`` keeps the dense pool): per layer, K/V live in a
+KV memory is PAGED: per layer, K/V live in a
 shared pool of ``kv_pages`` pages of ``kv_page_tokens`` tokens each,
 addressed through per-slot page tables the engine owns host-side. A
-slot costs HBM proportional to its prompt+generated length instead of
+slot costs HBM proportional to its prompt+generated length, not
 ``max_seq_len`` — pages are allocated on advance, freed on finish, and
 recycled; when the pool is exhausted the YOUNGEST blocked slot is
 preempted back to the queue (its progress is kept and resumed by
@@ -26,8 +25,8 @@ re-prefilling prompt+generated, token streams never restart). int8
 page payloads (``kv_dtype``, per page-row scale, eval-parity-gated)
 halve the bf16 page cost again.
 
-Prefix KV cache (``ServeConfig.prefix_cache``, on by default with
-paging; tpunet/serve/prefixcache/): finished prefill pages become
+Prefix KV cache (``ServeConfig.prefix_cache``, on by default;
+tpunet/serve/prefixcache/): finished prefill pages become
 immutable, content-addressed, refcounted objects inside the SAME
 pool. Admission pins the longest cached page-aligned prefix into the
 new slot's page table (zero prefill compute for those tokens),
@@ -37,15 +36,12 @@ LRU-evicts. With ``--prefix-store`` the pages spill to a shared
 filesystem (fsatomic first-writer-wins) and a respawned replica warms
 from the fleet's prefix set at boot.
 
-Sampling is DEVICE-side by default (``ServeConfig.device_sampling``):
-one ``[slots]``-wide batched temperature/top-k/top-p step
+Sampling runs on the DEVICE: one batched temperature/top-k/top-p step
 (tpunet/serve/sampling.py, per-slot PRNG keys folded per step) is
-fused onto the decode program, so only sampled int32 tokens cross the
-host boundary — the per-slot host loop (and the ``[slots, V]`` logits
-transfer feeding it) leaves the token path. ``sample_token`` below is
-the surviving host-side parity reference (and the
-``--no-device-sampling`` fallback); greedy output is token-identical
-to ``models.lm.generate`` through either sampler (engine parity test).
+fused onto every masked-step program, so only sampled int32 tokens
+cross the host boundary. Greedy output is token-identical to
+``models.lm.generate`` (engine parity tests); the filters are held to
+``models.lm.filter_logits`` (tests/test_serve_paged.py).
 
 Obs wiring: SLO counters/gauges/histograms land in a ``tpunet.obs``
 ``Registry`` (serve_* names incl. the ``serve_kv_*`` page-pool
@@ -90,35 +86,6 @@ def _ring_span(name: str):
             yield
     finally:
         flightrec.record("span_end", name)
-
-
-def sample_token(logits: np.ndarray, req: GenerateRequest) -> int:
-    """Host-side next-token choice from one row of logits [V].
-
-    Greedy (temperature <= 0) is exact argmax. Sampling mirrors
-    ``models.lm.filter_logits``: top-k truncation first, then nucleus
-    over the renormalized post-top-k distribution; the draw uses the
-    request's own seeded numpy Generator (deterministic per request,
-    independent across slots).
-    """
-    if req.temperature <= 0:
-        return int(np.argmax(logits))
-    lg = logits.astype(np.float64) / req.temperature
-    v = lg.shape[-1]
-    if req.top_k > 0 and req.top_k < v:
-        kth = np.sort(lg)[-req.top_k]
-        lg = np.where(lg >= kth, lg, -np.inf)
-    if 0.0 < req.top_p < 1.0:
-        srt = np.sort(lg)[::-1]
-        probs = np.exp(srt - srt.max())
-        probs /= probs.sum()
-        keep = np.cumsum(probs) - probs < req.top_p
-        cutoff = srt[keep].min()
-        lg = np.where(lg >= cutoff, lg, -np.inf)
-    lg -= lg.max()
-    p = np.exp(lg)
-    p /= p.sum()
-    return int(req.rng().choice(v, p=p))
 
 
 def build_serve_record(reg, *, queue_depth: int, active_slots: int,
@@ -171,8 +138,8 @@ def build_serve_record(reg, *, queue_depth: int, active_slots: int,
                 round(v, 6) for v in hist.export_sample()]
             if summ.get("approx"):
                 record[f"{key}_approx"] = 1
-    # Paged-KV pool state (serve_kv_* gauges; zeros on a dense pool):
-    # the capacity signal a fleet operator sizes --kv-pages from.
+    # Paged-KV pool state (serve_kv_* gauges): the capacity signal a
+    # fleet operator sizes --kv-pages from.
     for gauge_name, field in (("serve_kv_pages_total", "kv_pages_total"),
                               ("serve_kv_pages_used", "kv_pages_used")):
         val = reg.gauge(gauge_name).value
@@ -240,15 +207,12 @@ def build_aot_store(directory: str, model_cfg, serve_cfg):
         "model": dataclasses.asdict(model_cfg),
         "slots": serve_cfg.slots,
         "prefill_buckets": list(serve_cfg.prefill_buckets),
-        # The paged-KV + sampling levers each select a DIFFERENT
-        # compiled program (pool layout, fused sampler, page dtype):
-        # fold them in so flipping a flag is a clean miss, never a
-        # stale executable.
-        "paged_kv": serve_cfg.paged_kv,
+        # The pool's geometry and page dtype each select a DIFFERENT
+        # compiled program: fold them in so flipping a flag is a clean
+        # miss, never a stale executable.
         "kv_pages": serve_cfg.kv_pages,
         "kv_page_tokens": serve_cfg.kv_page_tokens,
         "kv_dtype": serve_cfg.kv_dtype,
-        "device_sampling": serve_cfg.device_sampling,
         # Spec-decode levers select a different program SET (drafter
         # width changes the drafter executables, K changes the verify
         # width): spec-on and spec-off engines must never share blobs.
@@ -258,6 +222,13 @@ def build_aot_store(directory: str, model_cfg, serve_cfg):
             serve_cfg, "spec_draft_width_mult", 0.5),
     })
     return AotProgramStore(directory, digest)
+
+
+def _shapes_of(tree):
+    """``tree`` with every array replaced by its shape and dtype."""
+    import jax
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
 
 class _Slot:
@@ -315,50 +286,41 @@ class Engine:
         self._active: List[Optional[_Slot]] = [None] * self.slots
 
         # -- paged KV geometry (host-owned allocator) ------------------
-        self.device_sampling = bool(cfg.device_sampling)
+        from tpunet.models.vit import PagedKV
         self.page_tokens = int(cfg.kv_page_tokens)
         if self.page_tokens < 1:
             raise ValueError(
                 f"kv_page_tokens must be >= 1, got {cfg.kv_page_tokens}")
         self.pages_per_slot = -(-self.max_seq_len // self.page_tokens)
-        self._paged_kv = None
-        if cfg.paged_kv:
-            from tpunet.models.vit import PagedKV
-            usable = int(cfg.kv_pages) or self.slots * self.pages_per_slot
-            if usable < 1:
-                raise ValueError(f"kv_pages must be >= 1, got "
-                                 f"{cfg.kv_pages}")
-            self.kv_pages_usable = usable
-            # Free list yields ascending page ids (pop from the end);
-            # freed pages re-enter at the end, so recycling is LIFO —
-            # a just-freed hot page is the next one handed out.
-            self._free_pages = list(range(usable, 0, -1))
-            self._page_table = np.zeros(
-                (self.slots, self.pages_per_slot), np.int32)
-            # pages + 1: page 0 is the reserved garbage page (inactive
-            # rows and padded prefill tails write there; the allocator
-            # never hands it out).
-            self._paged_kv = PagedKV(pages=usable + 1,
-                                     page_tokens=self.page_tokens,
-                                     dtype=cfg.kv_dtype,
-                                     mesh_sharded=mesh is not None)
-            self._kv_pages_touched: set = set()
-        elif cfg.kv_dtype not in ("auto",):
-            raise ValueError(
-                f"kv_dtype={cfg.kv_dtype!r} requires the paged KV "
-                "cache (drop --no-paged-kv or use kv_dtype auto)")
+        usable = int(cfg.kv_pages) or self.slots * self.pages_per_slot
+        if usable < 1:
+            raise ValueError(f"kv_pages must be >= 1, got "
+                             f"{cfg.kv_pages}")
+        self.kv_pages_usable = usable
+        # Free list yields ascending page ids (pop from the end);
+        # freed pages re-enter at the end, so recycling is LIFO —
+        # a just-freed hot page is the next one handed out.
+        self._free_pages = list(range(usable, 0, -1))
+        self._page_table = np.zeros(
+            (self.slots, self.pages_per_slot), np.int32)
+        # pages + 1: page 0 is the reserved garbage page (inactive
+        # rows and padded prefill tails write there; the allocator
+        # never hands it out).
+        self._paged_kv = PagedKV(pages=usable + 1,
+                                 page_tokens=self.page_tokens,
+                                 dtype=cfg.kv_dtype,
+                                 mesh_sharded=mesh is not None)
+        self._kv_pages_touched: set = set()
         # -- prefix KV cache (tpunet/serve/prefixcache/) ---------------
         # Refcounted content-addressed pages INSIDE the page pool:
         # admission pins the longest cached page-aligned prefix into
         # the new slot's table (zero prefill compute for those pages)
         # and re-prefills only the suffix. Bounded below the pool so
         # paying slots always have headroom; LRU-evicted back to the
-        # free list under pool pressure. Requires paging (the dense
-        # pool has no page identity to share).
+        # free list under pool pressure.
         self._prefix = None
         self._prefix_store = None
-        if self._paged_kv is not None \
-                and getattr(cfg, "prefix_cache", False):
+        if getattr(cfg, "prefix_cache", False):
             cap = int(getattr(cfg, "prefix_cache_pages", 0))
             if cap <= 0:
                 cap = self.kv_pages_usable // 2
@@ -385,17 +347,6 @@ class Engine:
         self._draft_cache = None
         self._drafter_paged_kv = None
         if self.spec_decode:
-            if self._paged_kv is None:
-                raise ValueError(
-                    "spec_decode requires the paged KV cache (drop "
-                    "--no-paged-kv): rejection is a page-table cursor "
-                    "rewind")
-            if not self.device_sampling:
-                raise ValueError(
-                    "spec_decode requires device sampling (drop "
-                    "--no-device-sampling): acceptance compares the "
-                    "drafter against the fused sampler's per-"
-                    "(seed, step) choices")
             if self.spec_k < 1:
                 raise ValueError(
                     f"spec_k must be >= 1, got {cfg.spec_k}")
@@ -423,7 +374,6 @@ class Engine:
                          // heads * heads)
                 self._drafter_model = model.clone(hidden=dh)
                 self._drafter_params = None   # resolved below
-            from tpunet.models.vit import PagedKV
             self._drafter_paged_kv = PagedKV(
                 pages=self.kv_pages_usable + 1,
                 page_tokens=self.page_tokens, dtype=cfg.kv_dtype,
@@ -470,41 +420,31 @@ class Engine:
 
         # -- device programs (compiled lazily, one per shape) ----------
         # One callable; jit specializes per token shape: [N, 1] decode
-        # plus one program per prefill bucket Lb — [1, Lb] over a paged
-        # pool on one device, [N, Lb] otherwise (``_prefill_rows``).
-        # The cache is donated — it is the engine's single biggest
-        # buffer and every call replaces it. With device sampling the
-        # batched sampler is FUSED onto the step (the program returns
-        # sampled int32 tokens, not logits); with paging the page-table
-        # rows of the call's slots ride along as one small int32 input.
+        # plus one program per prefill bucket Lb — [1, Lb] on one
+        # device, [N, Lb] over a mesh (``_prefill_rows``). The cache is
+        # donated — it is the engine's single biggest buffer and every
+        # call replaces it. The page-table rows of the call's slots
+        # ride along as one small int32 input, and the batched sampler
+        # is FUSED onto the step: the program returns sampled int32
+        # tokens, not logits.
         #
-        # A paged row reaches its tokens only through its row of the
-        # page table, so nothing ties a batch row to a slot: a prefill
-        # call is as wide as the one request it admits. A dense cache
-        # is [slots, max_seq_len] per layer (the batch row IS the
-        # slot) and a mesh pool is partitioned by GSPMD: both keep the
-        # slot axis.
-        self._prefill_rows = (1 if self._paged_kv is not None
-                              and mesh is None else self.slots)
+        # A row reaches its tokens only through its row of the page
+        # table, so nothing ties a batch row to a slot: a prefill call
+        # is as wide as the one request it admits. Over a mesh GSPMD
+        # partitions the pool, and the [slots, bucket] group call is
+        # the only path that platform has (no cell measures it).
+        self._prefill_rows = 1 if mesh is None else self.slots
         paged_kv = self._paged_kv
-        fuse_sampler = self.device_sampling
 
         def _masked_step(params, cache, tokens, positions, active,
-                         *extra):
-            i = 0
-            page_table = None
-            if paged_kv is not None:
-                page_table = extra[i]
-                i += 1
+                         page_table, last_idx, temp, top_k, top_p,
+                         seeds, steps):
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, tokens, train=False,
                 decode=True, pos_offset=positions, decode_active=active,
                 paged_kv=paged_kv, page_table=page_table,
                 mutable=["cache"])
-            if not fuse_sampler:
-                return mutated["cache"], logits
             from tpunet.serve.sampling import batched_sample
-            last_idx, temp, top_k, top_p, seeds, steps = extra[i:i + 6]
             rows = jnp.take_along_axis(
                 logits, last_idx[:, None, None],
                 axis=1)[:, 0].astype(jnp.float32)
@@ -551,24 +491,22 @@ class Engine:
         return self.slots if width == 1 else self._prefill_rows
 
     def _step_avals(self, width: int) -> list:
-        """The masked step's arguments at token width ``width``, as
-        shapes."""
+        """``_masked_step``'s twelve arguments at token width
+        ``width``, as shapes: the one statement of its signature."""
         import jax
-
-        def sds(tree):
-            return jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
         n = self._rows_at(width)
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
         f32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32)  # noqa: E731
-        avals = [sds(self.variables["params"]), sds(self._cache),
-                 i32(n, width), i32(n), jax.ShapeDtypeStruct((n,), bool)]
-        if self._paged_kv is not None:
-            avals.append(i32(n, self.pages_per_slot))
-        if self.device_sampling:
-            avals += [i32(n), f32(n), i32(n), f32(n), i32(n), i32(n)]
-        return avals
+        return [_shapes_of(self.variables["params"]),
+                _shapes_of(self._cache),
+                i32(n, width),                          # tokens
+                i32(n),                                 # positions
+                jax.ShapeDtypeStruct((n,), bool),       # active
+                i32(n, self.pages_per_slot),            # page_table
+                i32(n),                                 # last_idx
+                f32(n), i32(n), f32(n),                 # temp, top_k, top_p
+                i32(n), i32(n)]                         # seeds, steps
 
     def program_texts(self) -> dict:
         """``{label: optimized HLO text}`` of the masked step at each
@@ -595,27 +533,9 @@ class Engine:
         Deserialization skips tracing/lowering/XLA entirely — the
         compile-bound replica cold-start becomes an mmap + relink."""
         import jax
-
-        def sds(tree):
-            return jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
-
-        params_s = sds(self.variables["params"])
-        cache_s = sds(self._cache)
-        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
-        f32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32)  # noqa: E731
-        pos_s = i32(self.slots)
-        act_s = jax.ShapeDtypeStruct((self.slots,), bool)
         for width in (1,) + self.buckets:
             tag = f"w{width}"
-            # The entry's name carries the row count where it is not
-            # ``slots``: a store written when every program was
-            # [slots, width] holds "masked_step" entries under these
-            # same tags, and a [1, width] engine must miss them.
-            rows = self._rows_at(width)
-            name = ("masked_step" if rows == self.slots
-                    else f"masked_step_r{rows}")
-            program = store.load(name, tag)
+            program = store.load("masked_step", tag)
             if program is None:
                 # Compile fresh (persistent compile cache off): a
                 # cache-served executable saves a poison blob that
@@ -624,7 +544,7 @@ class Engine:
                 with serializable_compile():
                     program = self._step.lower(
                         *self._step_avals(width)).compile()
-                saved = store.save(name, tag, program)
+                saved = store.save("masked_step", tag, program)
                 self.aot_status[tag] = ("compiled+saved" if saved
                                         else "compiled")
             else:
@@ -637,10 +557,13 @@ class Engine:
         # the [slots, K+1] verify — a spec-on replica cold-starts
         # without tracing just like a spec-off one. The store digest
         # folds the spec levers, so spec-on/off never share blobs.
-        dparams_s = sds(self._drafter_params)
-        dcache_s = sds(self._draft_cache)
-        samp_s = (f32(self.slots), i32(self.slots), f32(self.slots),
-                  i32(self.slots), i32(self.slots))
+        avals = self._step_avals(1)
+        params_s, cache_s, pos_s, act_s = (avals[0], avals[1], avals[3],
+                                           avals[4])
+        samp_s = tuple(avals[7:])    # temp, top_k, top_p, seeds, steps
+        dparams_s = _shapes_of(self._drafter_params)
+        dcache_s = _shapes_of(self._draft_cache)
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
         k = self.spec_k
         programs = []
         # Burst/verify are compiled per attention-window bucket (the
@@ -678,27 +601,21 @@ class Engine:
                 self.aot_status[f"{name}-{tag}"] = "loaded"
             self._spec_aot[(name, tag)] = program
 
-    def _dispatch_step(self, toks, positions, active, last_idx=None,
+    def _dispatch_step(self, toks, positions, active, last_idx,
                        slot_i=None):
         """Run one masked-step program: the AOT executable for this
         token width when warm-started, the jit fallback otherwise.
         Batch row i is slot i, or — ``slot_i`` given — the call's one
         row is that slot (a [1, bucket] prefill). Returns (cache,
-        logits) host-sampling, (cache, tokens) with the fused device
-        sampler."""
+        sampled tokens)."""
         program = self._aot.get(toks.shape[1])
         if program is None:
             program = self._step
-        args = [self.variables["params"], self._cache, toks, positions,
-                active]
-        if self._paged_kv is not None:
-            args.append(self._page_table if slot_i is None
-                        else self._page_table[slot_i:slot_i + 1])
-        if self.device_sampling:
-            args.extend(self._sampling_args(
-                last_idx if last_idx is not None else self._zero_idx,
-                slot_i))
-        return program(*args)
+        return program(
+            self.variables["params"], self._cache, toks, positions,
+            active, (self._page_table if slot_i is None
+                     else self._page_table[slot_i:slot_i + 1]),
+            *self._sampling_args(last_idx, slot_i))
 
     def _sampling_args(self, last_idx, slot_i=None):
         """Per-row sampling parameters for the fused device sampler:
@@ -858,17 +775,13 @@ class Engine:
         model = model if model is not None else self.model
         if paged_kv is None:
             paged_kv = self._paged_kv
-        init_kw = {}
-        if paged_kv is not None:
-            init_kw = dict(
-                paged_kv=paged_kv,
-                page_table=jnp.zeros((self.slots, self.pages_per_slot),
-                                     jnp.int32))
         shapes = jax.eval_shape(
             lambda: model.init(
                 jax.random.PRNGKey(0),
                 jnp.zeros((self.slots, self.max_seq_len), jnp.int32),
-                decode=True, **init_kw))
+                decode=True, paged_kv=paged_kv,
+                page_table=jnp.zeros((self.slots, self.pages_per_slot),
+                                     jnp.int32)))
 
         def zeros(s):
             if self.mesh is not None:
@@ -876,9 +789,7 @@ class Engine:
                 from jax.sharding import PartitionSpec as P
                 tp = self.mesh.shape.get("model", 1)
                 heads = int(getattr(model, "heads", 0))
-                if s.ndim == 4 and tp > 1 and s.shape[2] % tp == 0:
-                    spec = P(None, None, "model", None)   # dense pool
-                elif s.ndim == 2 and tp > 1 and heads % tp == 0 \
+                if s.ndim == 2 and tp > 1 and heads % tp == 0 \
                         and s.shape[1] == getattr(model, "hidden", 0):
                     # page pool [rows, H * D]: whole heads per device
                     # (a lane-padded row has no head-aligned split)
@@ -892,9 +803,9 @@ class Engine:
         return jax.tree_util.tree_map(zeros, shapes["cache"])
 
     def kv_pool_bytes(self) -> int:
-        """Resident bytes of the KV cache tree (page pool + scales
-        when paged; the dense [slots, max_seq_len] pool otherwise) —
-        the capacity number ``bench_serve.py`` reports per slot."""
+        """Resident bytes of the KV cache tree (the page pool and its
+        scale sidecars) — the capacity number ``bench_serve.py``
+        reports per slot."""
         import jax
         return int(sum(leaf.nbytes
                        for leaf in jax.tree_util.tree_leaves(
@@ -902,29 +813,24 @@ class Engine:
 
     def kv_bytes_per_token(self) -> float:
         """KV bytes pinned per cacheable token position across the
-        whole pool (pages incl. scale sidecars / dense rows)."""
-        if self._paged_kv is not None:
-            rows = self._paged_kv.pages * self.page_tokens
-        else:
-            rows = self.slots * self.max_seq_len
-        return self.kv_pool_bytes() / max(1, rows)
+        whole pool (pages incl. scale sidecars)."""
+        return self.kv_pool_bytes() / (self._paged_kv.pages
+                                       * self.page_tokens)
 
     def _init_kv_gauges(self) -> None:
         reg = self.registry
         reg.gauge("serve_kv_bytes_per_token").set(
             round(self.kv_bytes_per_token(), 2))
-        if self._paged_kv is not None:
-            reg.gauge("serve_kv_pages_total").set(self.kv_pages_usable)
-            reg.gauge("serve_kv_pages_used").set(0)
-            # Which attend path the [slots, 1] decode program was built
-            # with (tpunet/ops/paged_decode.py): 1 = the in-place
-            # kernel, 0 = gather + dense. Static: set here, once.
-            import jax
-            from tpunet.ops import paged_decode
-            pool = jax.tree_util.tree_leaves(self._cache)[0]
-            reg.gauge("serve_decode_attend_kernel").set(int(
-                paged_decode.kernel_applies(self._paged_kv, 1,
-                                            pool.dtype)))
+        reg.gauge("serve_kv_pages_total").set(self.kv_pages_usable)
+        reg.gauge("serve_kv_pages_used").set(0)
+        # Which attend path the [slots, 1] decode program was built
+        # with (tpunet/ops/paged_decode.py): 1 = the in-place
+        # kernel, 0 = gather + dense. Static: set here, once.
+        import jax
+        from tpunet.ops import paged_decode
+        pool = jax.tree_util.tree_leaves(self._cache)[0]
+        reg.gauge("serve_decode_attend_kernel").set(int(
+            paged_decode.kernel_applies(self._paged_kv, 1, pool.dtype)))
         # Rows of a bucket-wide prefill program (1 = a call per admitted
         # request, ``slots`` = one call per admission group). Static.
         reg.gauge("serve_prefill_rows_per_call").set(self._prefill_rows)
@@ -938,9 +844,8 @@ class Engine:
             reg.gauge(name).set(value)
 
     def _update_kv_gauges(self) -> None:
-        if self._paged_kv is not None:
-            self.registry.gauge("serve_kv_pages_used").set(
-                self.kv_pages_usable - len(self._free_pages))
+        self.registry.gauge("serve_kv_pages_used").set(
+            self.kv_pages_usable - len(self._free_pages))
 
     # -- paged-KV page allocator (engine thread only) -------------------
 
@@ -992,8 +897,6 @@ class Engine:
         refcount (the pages stay cached — eviction, not release,
         returns them to the pool), and its table row resets to the
         garbage page."""
-        if self._paged_kv is None:
-            return
         self._free_pages.extend(slot.pages)
         slot.pages = []
         if slot.pinned:
@@ -1271,30 +1174,16 @@ class Engine:
                     raise PromptTooLongError(
                         f"prompt of {n} tokens leaves no room to "
                         f"generate (max_seq_len {self.max_seq_len})")
-            if self._paged_kv is not None:
-                # Completability guard: a request whose FULL length
-                # cannot fit the page pool even alone would preempt
-                # itself forever — reject it up front instead.
-                worst = -(-(n + req.max_new_tokens) // self.page_tokens)
-                if worst > self.kv_pages_usable:
-                    raise PromptTooLongError(
-                        f"request needs {worst} KV pages at full "
-                        f"length but the pool has "
-                        f"{self.kv_pages_usable}; lower "
-                        "max_new_tokens or grow --kv-pages")
-            if req.resume_offset and req.temperature > 0 \
-                    and not self.device_sampling:
-                # The sampled-continuation determinism guarantee rests
-                # on the device sampler's counter-based (seed, step)
-                # keys. The host sampler draws from a STATEFUL
-                # generator — a resume would restart it at draw 0 and
-                # diverge from the uninterrupted stream. Reject loudly
-                # (the router degrades to the honest error frame)
-                # rather than continue wrong.
-                raise ValueError(
-                    "sampled resume_tokens require device-side "
-                    "sampling (counter-based per-(seed, step) keys); "
-                    "this replica runs --no-device-sampling")
+            # Completability guard: a request whose FULL length
+            # cannot fit the page pool even alone would preempt
+            # itself forever — reject it up front instead.
+            worst = -(-(n + req.max_new_tokens) // self.page_tokens)
+            if worst > self.kv_pages_usable:
+                raise PromptTooLongError(
+                    f"request needs {worst} KV pages at full "
+                    f"length but the pool has "
+                    f"{self.kv_pages_usable}; lower "
+                    "max_new_tokens or grow --kv-pages")
             if req.resume_offset and req.stop_token is not None \
                     and req.stop_token in req.tokens:
                 # The journal already contains the stop token: the
@@ -1529,8 +1418,8 @@ class Engine:
 
     def _admit(self) -> bool:
         """Admit waiting requests into free slots and prefill them,
-        grouped by bucket so each group is one device call. Paged KV:
-        admission is FIFO and all-or-nothing per request — when the
+        grouped by bucket. Admission is FIFO and all-or-nothing per
+        request — when the
         pool cannot cover the next request's prompt, it (and everyone
         behind it) goes back to the queue head until pages free up."""
         import collections
@@ -1578,57 +1467,54 @@ class Engine:
                 continue
             start = 0
             pinned: List = []
-            if self._paged_kv is not None:
-                cow_src = None
-                if self._prefix is not None:
-                    from tpunet.serve.prefixcache import keys as pk
-                    # Pin cap (n-1)//page_tokens: at least one suffix
-                    # token is always re-prefilled — the logits at
-                    # position n-1 come from compute, never from
-                    # cached K/V (pages store only K/V rows).
-                    pinned = self._prefix.lookup(
-                        resume, (n - 1) // self.page_tokens)
-                    start = len(pinned) * self.page_tokens
-                    if n % self.page_tokens == 0 and pinned \
-                            and start == n - self.page_tokens:
-                        # Full page-aligned match: the divergence page
-                        # is cached too. COW it below instead of
-                        # re-prefilling its whole page.
-                        cow_src = self._prefix.get(
-                            pk.token_prefix_digest(resume, n))
-                    # Pin BEFORE allocating: allocation may evict
-                    # unpinned cache pages, and the chain (and COW
-                    # source) must survive until mapped/copied.
-                    if cow_src is not None:
-                        self._prefix.pin(pinned + [cow_src])
-                    elif pinned:
-                        self._prefix.pin(pinned)
-                pages = self._alloc_pages_for(slot_i, n,
-                                              first_index=len(pinned))
-                if pages is None:
-                    if cow_src is not None:
-                        self._prefix.unpin(pinned + [cow_src])
-                    elif pinned:
-                        self._prefix.unpin(pinned)
-                    break            # pool pressure: FIFO order holds
-                # Map the pinned prefix pages into the slot's table
-                # (indices 0..k-1): the suffix prefill and every
-                # decode step read them through the gather; nothing
-                # ever writes them (positions >= start only).
-                for j, node in enumerate(pinned):
-                    self._page_table[slot_i, j] = node.page
+            cow_src = None
+            if self._prefix is not None:
+                from tpunet.serve.prefixcache import keys as pk
+                # Pin cap (n-1)//page_tokens: at least one suffix
+                # token is always re-prefilled — the logits at
+                # position n-1 come from compute, never from
+                # cached K/V (pages store only K/V rows).
+                pinned = self._prefix.lookup(
+                    resume, (n - 1) // self.page_tokens)
+                start = len(pinned) * self.page_tokens
+                if n % self.page_tokens == 0 and pinned \
+                        and start == n - self.page_tokens:
+                    # Full page-aligned match: the divergence page
+                    # is cached too. COW it below instead of
+                    # re-prefilling its whole page.
+                    cow_src = self._prefix.get(
+                        pk.token_prefix_digest(resume, n))
+                # Pin BEFORE allocating: allocation may evict
+                # unpinned cache pages, and the chain (and COW
+                # source) must survive until mapped/copied.
                 if cow_src is not None:
-                    # Copy-on-write at the divergence page: seed the
-                    # private copy from its cached twin, then prefill
-                    # only the final token (which overwrites its own
-                    # row in the copy — the shared page stays
-                    # immutable).
-                    self._copy_page(cow_src.page, pages[0])
-                    self._prefix.unpin([cow_src])
-                    start = n - 1
-                    self.registry.counter("serve_prefix_cow_total").inc()
-            else:
-                pages = []
+                    self._prefix.pin(pinned + [cow_src])
+                elif pinned:
+                    self._prefix.pin(pinned)
+            pages = self._alloc_pages_for(slot_i, n,
+                                          first_index=len(pinned))
+            if pages is None:
+                if cow_src is not None:
+                    self._prefix.unpin(pinned + [cow_src])
+                elif pinned:
+                    self._prefix.unpin(pinned)
+                break            # pool pressure: FIFO order holds
+            # Map the pinned prefix pages into the slot's table
+            # (indices 0..k-1): the suffix prefill and every
+            # decode step read them through the gather; nothing
+            # ever writes them (positions >= start only).
+            for j, node in enumerate(pinned):
+                self._page_table[slot_i, j] = node.page
+            if cow_src is not None:
+                # Copy-on-write at the divergence page: seed the
+                # private copy from its cached twin, then prefill
+                # only the final token (which overwrites its own
+                # row in the copy — the shared page stays
+                # immutable).
+                self._copy_page(cow_src.page, pages[0])
+                self._prefix.unpin([cow_src])
+                start = n - 1
+                self.registry.counter("serve_prefix_cow_total").inc()
             pending.popleft()
             if start:
                 # The suffix picks the bucket: a 500-token prompt with
@@ -1673,10 +1559,10 @@ class Engine:
 
     def _prefill(self, bucket: int, group) -> None:
         """Prefill every admitted request padded to this bucket: one
-        [slots, bucket] device call for the group, or — a paged pool on
-        one device (``_prefill_rows`` 1) — one [1, bucket] call per
-        request, in admission order, each request's first token pushed
-        as soon as its own call returns. ``group`` rows are
+        [1, bucket] call per request (``_prefill_rows`` 1), in
+        admission order, each request's first token pushed as soon as
+        its own call returns, or — over a mesh — one [slots, bucket]
+        device call for the group. ``group`` rows are
         ``(slot_i, req, resume_tokens, pages, start, pinned)``;
         resume_tokens is prompt+generated for a preempted request
         resuming mid-stream, ``start`` is the first position NOT
@@ -1703,9 +1589,8 @@ class Engine:
         """One chunked-prefill device call: every row of ``group`` at
         its slot's batch row, or the one request of ``group`` as the
         only row of a [1, bucket] call over slot ``slot_i``'s pages.
-        K/V land in each slot's cache rows (or pages) and the next
-        token is sampled from the last REAL position — on device when
-        the sampler is fused, else from the transferred logits row.
+        K/V land in each slot's pages and the next token is sampled
+        on the device from the last REAL position.
         The padded tail writes garbage K/V beyond the prompt — masked
         invariant: a decode query at position p attends only j <= p
         and overwrites position p first, so padding is never visible.
@@ -1751,15 +1636,9 @@ class Engine:
         if self.chaos is not None:
             self.chaos.on_prefill()     # kill@prefill injection point
         with _ring_span("tpunet/serve_prefill"):
-            if self.device_sampling:
-                self._cache, sampled = self._dispatch_step(
-                    toks, positions, active, last_idx, slot_i)
-                sampled = np.asarray(sampled)
-                logits = None
-            else:
-                self._cache, logits = self._dispatch_step(
-                    toks, positions, active, slot_i=slot_i)
-                logits = np.asarray(logits)
+            self._cache, sampled = self._dispatch_step(
+                toks, positions, active, last_idx, slot_i)
+            sampled = np.asarray(sampled)
         reg = self.registry
         # Adopt freshly-written full prompt pages into the prefix
         # cache (and spill them) BEFORE the finish checks below can
@@ -1776,10 +1655,7 @@ class Engine:
             row = s_i if slot_i is None else 0
             if req.prefill_done_t is None:
                 req.prefill_done_t = prefill_done
-            if self.device_sampling:
-                first = int(sampled[row])
-            else:
-                first = sample_token(logits[row, n - start - 1], req)
+            first = int(sampled[row])
             fresh = req.first_token_t is None
             self._active[s_i].next_token = first
             req.push_token(first)
@@ -1857,7 +1733,7 @@ class Engine:
     def _decode_iteration(self) -> bool:
         """One masked decode step across the whole pool: every active
         slot consumes its pending token at its own position and samples
-        the next one (fused on device by default). Paged KV: each
+        the next one (fused on the device). Each
         slot's next write page is allocated here (allocate-on-advance);
         a slot the pool cannot extend sits the iteration out, and when
         NOTHING can advance the youngest blocked slot is preempted back
@@ -1868,22 +1744,18 @@ class Engine:
                 if s is not None]
         if not live:
             return False
-        if self._paged_kv is not None:
-            ready = []
-            blocked = []
-            for i, slot in live:
-                if self._ensure_page_capacity(i, slot):
-                    ready.append((i, slot))
-                else:
-                    blocked.append((i, slot))
-            if blocked and not ready:
-                self._preempt_slot(self._choose_preempt_victim(blocked))
-                return True          # freed pages; retry next iteration
-            self._update_kv_gauges()
-            live = ready
-            if not live:
-                return False
-        self._decode_width1(live)
+        ready = []
+        blocked = []
+        for i, slot in live:
+            if self._ensure_page_capacity(i, slot):
+                ready.append((i, slot))
+            else:
+                blocked.append((i, slot))
+        if blocked and not ready:
+            self._preempt_slot(self._choose_preempt_victim(blocked))
+            return True              # freed pages; retry next iteration
+        self._update_kv_gauges()
+        self._decode_width1(ready)
         return True
 
     def _decode_width1(self, live) -> None:
@@ -1899,16 +1771,9 @@ class Engine:
             positions[i] = slot.pos
             active[i] = True
         with _ring_span("tpunet/serve_decode"):
-            if self.device_sampling:
-                self._cache, sampled = self._dispatch_step(
-                    toks, positions, active, self._zero_idx)
-                sampled = np.asarray(sampled)
-                logits = None
-            else:
-                self._cache, logits = self._dispatch_step(toks,
-                                                          positions,
-                                                          active)
-                logits = np.asarray(logits)
+            self._cache, sampled = self._dispatch_step(
+                toks, positions, active, self._zero_idx)
+            sampled = np.asarray(sampled)
         lap = time.perf_counter() - t0
         reg = self.registry
         reg.counter("serve_decode_steps_total").inc()
@@ -1917,10 +1782,7 @@ class Engine:
         # live slot, each of which waited the full iteration.
         reg.histogram("serve_token_s").observe(lap)
         for i, slot in live:
-            if self.device_sampling:
-                nxt = int(sampled[i])
-            else:
-                nxt = sample_token(logits[i, 0], slot.req)
+            nxt = int(sampled[i])
             slot.pos += 1
             slot.next_token = nxt
             slot.generated += 1

@@ -1,12 +1,8 @@
 """Device-side batched sampling for the continuous-batching engine.
 
-The engine's original token path sampled on the HOST: one full-vocab
-logits row ferried off-device per slot per step, then a Python loop of
-numpy top-k/top-p/categorical per request. At serving batch widths
-that loop (and the [slots, V] transfer feeding it) caps tokens/s long
-before the device does. ``batched_sample`` moves the whole choice
-on-device as ONE ``[slots]``-wide jitted computation fused onto the
-decode step — the host loop leaves the token path and only the sampled
+``batched_sample`` makes every row's choice on the device, as ONE
+batch-wide computation fused onto the engine's masked step: no
+full-vocab logits row is ferried to the host, and only the sampled
 int32 tokens cross the boundary.
 
 Semantics mirror ``models.lm.filter_logits`` exactly (sequential
@@ -14,8 +10,7 @@ HF-warper order: top-k truncation first, then the nucleus over the
 RENORMALIZED post-top-k distribution), generalized to PER-ROW
 parameters: every slot carries its own temperature/top_k/top_p/seed,
 because co-resident requests disagree about all four. Greedy rows
-(temperature <= 0) are exact ``argmax`` over the raw float32 logits —
-bit-identical to the host sampler's ``np.argmax`` on the same array,
+(temperature <= 0) are exact ``argmax`` over the raw float32 logits,
 which is what keeps greedy serve output token-identical to solo
 ``models.lm.generate``.
 
@@ -26,9 +21,6 @@ between steps (nothing to checkpoint, nothing to desync), the stream
 is deterministic per (seed, step) — a preempted-and-resumed request
 continues its exact sample sequence — and rows are independent across
 slots by construction.
-
-The host sampler (``engine.sample_token``) stays as the parity
-reference and the ``--no-device-sampling`` fallback.
 """
 
 from __future__ import annotations

@@ -88,10 +88,8 @@ class GenerateRequest:
         self.top_k = int(top_k)
         self.top_p = float(top_p)
         self.seed = int(seed)
-        # Both sampler backends need this range: numpy's Generator
-        # rejects negatives (an engine-thread raise marks the whole
-        # engine dead) and the device path folds the seed into an
-        # int32 lane (values past bit 31 would silently collide).
+        # The device sampler folds the seed into an int32 lane:
+        # values past bit 31 would silently collide.
         if not 0 <= self.seed < 2 ** 31:
             raise ValueError(
                 f"seed must be in [0, 2**31), got {seed}")
@@ -133,15 +131,8 @@ class GenerateRequest:
         self._events: "queue.Queue" = queue.Queue()
         self._done = threading.Event()
         self._cancelled = threading.Event()
-        self._rng = None  # lazily-built numpy Generator (sampled reqs)
 
     # -- engine side ----------------------------------------------------
-
-    def rng(self):
-        if self._rng is None:
-            import numpy as np
-            self._rng = np.random.default_rng(self.seed)
-        return self._rng
 
     def expired(self, now: Optional[float] = None) -> bool:
         return (self.deadline_t is not None
