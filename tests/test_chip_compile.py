@@ -204,6 +204,75 @@ def test_paged_attention_layer_never_converts_the_pool(
     assert ("tpu_custom_call" in text) == (width == 1)
 
 
+@pytest.mark.parametrize("tree", ["resident", "given"])
+def test_block_decode_reads_no_float32_product_weight(
+        one_chip, no_compile_cache, monkeypatch, tree):
+    """One ``EncoderBlock``'s width-1 decode program over the paged
+    pool at GPT-2 XL's widths, compiled from the avals of the tree the
+    engine holds (tpunet/serve/resident.py): no float32 product weight
+    anywhere in it and no conversion of a ``params`` argument —
+    the step reads each weight once, at two bytes. From the avals of
+    the tree the engine is GIVEN it has both (what this guards: 5.9 GB
+    of float32 read and rounded on every GPT-2 XL decode step, PERF.md
+    section 6, PR 30)."""
+    import re
+
+    from tpunet.models.vit import EncoderBlock, PagedKV
+    from tpunet.serve.resident import held_types
+
+    monkeypatch.setattr(paged_decode, "_on_tpu", lambda: True)
+    monkeypatch.setattr(paged_decode, "_interpret", lambda: False)
+    paged = PagedKV(pages=SLOTS * PAGES_PER_SLOT + 1,
+                    page_tokens=PAGE_TOKENS)
+    hidden = HEADS * HEAD_DIM
+    block = EncoderBlock(HEADS, 4 * hidden, dtype=BF16)
+    shapes = jax.eval_shape(lambda: block.init(
+        jax.random.PRNGKey(0), jnp.zeros((SLOTS, 8, hidden), BF16),
+        decode=True, paged_kv=paged,
+        page_table=jnp.zeros((SLOTS, PAGES_PER_SLOT), jnp.int32)))
+
+    def apply(params, cache, x, positions, active, table):
+        return block.apply(
+            {"params": params, "cache": cache}, x, False, True, None,
+            positions, active, paged, table, mutable=["cache"])
+
+    def step(params, cache, x, positions, active, table):
+        y, mutated = apply(params, cache, x, positions, active, table)
+        return mutated["cache"], y
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    cache = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                                   shapes["cache"])
+    inputs = (sds((SLOTS, 1, hidden), BF16), sds((SLOTS,), jnp.int32),
+              sds((SLOTS,), bool), sds((SLOTS, PAGES_PER_SLOT), jnp.int32))
+    leaves, treedef = jax.tree_util.tree_flatten(shapes["params"])
+    types = held_types(apply, shapes["params"], [(cache,) + inputs])
+    assert sum(t is not None for t in types) == 8
+    if tree == "given":
+        types = [None] * len(leaves)
+    params = jax.tree_util.tree_unflatten(treedef, [
+        sds(a.shape, a.dtype if t is None else t)
+        for a, t in zip(leaves, types)])
+    lowered = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, *inputs)
+    # as traced: conversions of a ``params`` argument in the entry
+    # function (the private ones number their own arguments)
+    traced = lowered.as_text().split("func.func private")[0]
+    converted = {int(i) for i in re.findall(
+        r"stablehlo\.convert %arg(\d+)\b", traced) if int(i) < len(leaves)}
+    # as the TPU compiler leaves it: float32 weight matrices anywhere
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    wide = [w for w in ("f32[1600,6400]", "f32[6400,1600]",
+                        "f32[1600,4800]", "f32[1600,1600]") if w in text]
+    if tree == "resident":
+        assert not converted and not wide
+        assert re.search(r"bf16\[1600,6400\]\S* parameter\(", text)
+    else:
+        assert len(converted) == 8 and len(wide) == 4
+
+
 @pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
 def test_latent_attention_decode_never_converts_its_pools(
         one_chip, no_compile_cache, kind):

@@ -306,6 +306,10 @@ def build_server(args):
                                           cfg)
     engine = Engine(model, variables, cfg, mesh=mesh,
                     aot_store=aot_store, prefix_store=prefix_store)
+    # The engine serves from its own resident tree and keeps no
+    # reference to this one: drop ours, or a float32 checkpoint stays
+    # on the device beside the copy the steps read.
+    del variables
     if engine.aot_status:
         print(f"aot warm-start: {engine.aot_status}", flush=True)
     registry = engine.registry

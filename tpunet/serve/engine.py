@@ -43,6 +43,15 @@ cross the host boundary. Greedy output is token-identical to
 ``models.lm.generate`` (engine parity tests); the filters are held to
 ``models.lm.filter_logits`` (tests/test_serve_paged.py).
 
+Weights are RESIDENT in the type the step uses them in: at
+construction the engine derives, from the tree it is given, the tree
+its programs read (tpunet/serve/resident.py) — each leaf whose only
+use is a narrowing conversion is held converted, so a bfloat16 step
+over float32 parameters reads two bytes a product weight, not four,
+and rounds nothing per step. The rounding is the step's own, done
+once; the caller's tree is left alone and the engine keeps no
+reference to it.
+
 Obs wiring: SLO counters/gauges/histograms land in a ``tpunet.obs``
 ``Registry`` (serve_* names incl. the ``serve_kv_*`` page-pool
 gauges, docs/metrics_schema.md ``obs_serve``), prefill/decode phases
@@ -147,6 +156,14 @@ def build_serve_record(reg, *, queue_depth: int, active_slots: int,
     bpt = reg.gauge("serve_kv_bytes_per_token").value
     record["kv_bytes_per_token"] = (round(float(bpt), 2)
                                     if bpt is not None else 0)
+    # What the engine was handed and what it holds (tpunet/serve/
+    # resident.py): set once, at construction.
+    for gauge_name, field in (
+            ("serve_weight_bytes_given", "weight_bytes_given"),
+            ("serve_weight_bytes_resident", "weight_bytes_resident"),
+            ("serve_weight_leaves_precast", "weight_leaves_precast")):
+        val = reg.gauge(gauge_name).value
+        record[field] = int(val) if val is not None else 0
     # Prefix KV cache (serve_prefix_* instruments; zeros when the
     # cache is off): hit rate is THE steering signal — the router's
     # affinity and the fleet's shared-prefix traffic shape show up
@@ -213,6 +230,10 @@ def build_aot_store(directory: str, model_cfg, serve_cfg):
         "kv_pages": serve_cfg.kv_pages,
         "kv_page_tokens": serve_cfg.kv_page_tokens,
         "kv_dtype": serve_cfg.kv_dtype,
+        # What the programs take as ``params``: the resident tree
+        # (tpunet/serve/resident.py), not the given one. A store
+        # written for the given tree's types misses whole.
+        "params": "resident",
         # Spec-decode levers select a different program SET (drafter
         # width changes the drafter executables, K changes the verify
         # width): spec-on and spec-off engines must never share blobs.
@@ -360,8 +381,8 @@ class Engine:
                 # (still with its own pool — it runs ahead of the
                 # verified cursor). 100% acceptance by construction;
                 # useful for parity tests, never a throughput win.
-                self._drafter_model = model
-                self._drafter_params = variables["params"]
+                self._drafter_model = model   # params: the resident
+                #                               tree, once it exists
             else:
                 if not hasattr(model, "hidden") \
                         or not hasattr(model, "heads"):
@@ -373,7 +394,6 @@ class Engine:
                 dh = max(heads, int(int(model.hidden) * wm)
                          // heads * heads)
                 self._drafter_model = model.clone(hidden=dh)
-                self._drafter_params = None   # resolved below
             self._drafter_paged_kv = PagedKV(
                 pages=self.kv_pages_usable + 1,
                 page_tokens=self.page_tokens, dtype=cfg.kv_dtype,
@@ -382,7 +402,7 @@ class Engine:
                 # In-memory drafter weights (bench_serve --spec fits
                 # the drafter to its workload and injects it here).
                 self._drafter_params = drafter_params
-            elif self._drafter_params is None:
+            elif wm != 1.0:
                 import jax as _jax
                 from tpunet.models import init_variables
                 template = init_variables(
@@ -454,12 +474,33 @@ class Engine:
 
         self._step = jax.jit(_masked_step, donate_argnums=(1,))
         self._cache = self._make_cache()
+        # Every program is traced for the RESIDENT tree: each weight
+        # held in the type the step converts it to (tpunet/serve/
+        # resident.py), so no step reads a weight at full width to
+        # round it again. The engine keeps no reference to the tree it
+        # was given; the caller's arrays are the caller's.
+        params, precast = self._resident(model, variables["params"],
+                                         self._cache, paged_kv)
+        self.variables = {**variables, "params": params}
+        from tpunet.serve.resident import tree_bytes
+        for name, value in (
+                ("serve_weight_bytes_given",
+                 tree_bytes(variables["params"])),
+                ("serve_weight_bytes_resident", tree_bytes(params)),
+                ("serve_weight_leaves_precast", precast)):
+            self.registry.gauge(name).set(value)
         self._inactive_tok = np.zeros((self.slots, 1), np.int32)
         self._zero_idx = np.zeros((self.slots,), np.int32)
         if self._drafter_model is not None:
             self._draft_cache = self._make_cache(
                 model=self._drafter_model,
                 paged_kv=self._drafter_paged_kv)
+            if self._drafter_params is None:    # self-speculation
+                self._drafter_params = params
+            else:
+                self._drafter_params, _ = self._resident(
+                    self._drafter_model, self._drafter_params,
+                    self._draft_cache, self._drafter_paged_kv)
             self._build_spec_programs()
         self._init_kv_gauges()
         # AOT warm-start (tpunet/utils/cache.py AotProgramStore): the
@@ -482,6 +523,27 @@ class Engine:
         # device trace asks for the programs' texts.
         from tpunet.obs import device_time
         device_time.register_programs(self.program_texts)
+
+    def _resident(self, model, params, cache, paged_kv):
+        """``(tree, leaves precast)``: ``params`` as the engine holds
+        them (tpunet/serve/resident.py), judged on ``model``'s own
+        apply over ``cache`` at the decode width and at the widest
+        bucket — the two paths a model takes (one token against the
+        pool; a chunk of them), each traced once, nothing run."""
+        from tpunet.serve.resident import resident_params
+
+        def apply(params, cache, tokens, positions, active, page_table):
+            return model.apply(
+                {"params": params, "cache": cache}, tokens, train=False,
+                decode=True, pos_offset=positions, decode_active=active,
+                paged_kv=paged_kv, page_table=page_table,
+                mutable=["cache"])
+
+        cache_s = _shapes_of(cache)
+        return resident_params(
+            apply, params,
+            [(cache_s, *self._step_avals(width)[2:6])
+             for width in (1, self.buckets[-1])])
 
     def _rows_at(self, width: int) -> int:
         """Batch rows of the masked step the engine dispatches at token
@@ -760,12 +822,9 @@ class Engine:
         reported separately from ``kv_pool_bytes`` because the drafter
         pool is the spec lever's EXTRA memory cost (width 0.5 ≈ +50%
         KV bytes), and the bench must account for it honestly."""
-        import jax
-        if self._draft_cache is None:
-            return 0
-        return int(sum(leaf.nbytes
-                       for leaf in jax.tree_util.tree_leaves(
-                           self._draft_cache)))
+        from tpunet.serve.resident import tree_bytes
+        return 0 if self._draft_cache is None \
+            else tree_bytes(self._draft_cache)
 
     # -- pool construction ---------------------------------------------
 
@@ -806,10 +865,8 @@ class Engine:
         """Resident bytes of the KV cache tree (the page pool and its
         scale sidecars) — the capacity number ``bench_serve.py``
         reports per slot."""
-        import jax
-        return int(sum(leaf.nbytes
-                       for leaf in jax.tree_util.tree_leaves(
-                           self._cache)))
+        from tpunet.serve.resident import tree_bytes
+        return tree_bytes(self._cache)
 
     def kv_bytes_per_token(self) -> float:
         """KV bytes pinned per cacheable token position across the
