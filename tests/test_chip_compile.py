@@ -21,6 +21,8 @@ all these tests stay in this one file so one worker owns the library.
 
 import jax
 import jax.numpy as jnp
+import re
+
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -82,6 +84,66 @@ def test_flash_attention_compiles(one_chip, no_compile_cache, d,
     qkv = [((4, 2048, 16, d), BF16)] * 3
     _compile(fwd if direction == "fwd" else bwd, *qkv,
              sharding=one_chip)
+
+
+def test_latent_attention_trains_through_flash(one_chip, no_compile_cache,
+                                               monkeypatch):
+    """One latent-attention layer at glm-4.7-flash's widths (20 heads,
+    queries, keys and values all 256 wide) on 1 x 8192 tokens, forward
+    and backward as the Trainer's step takes it: the three flash
+    kernels at D = 256, and no [heads, T, T] tensor."""
+    from tpunet.models.latent_lm import LatentArch, LatentAttention
+
+    arch = LatentArch(
+        hidden_size=2048, num_hidden_layers=1, intermediate_size=10240,
+        layer_types=("full_attention",), num_attention_heads=20,
+        q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, rope_theta=1e6,
+        attention_gate_type=None)
+    attn = LatentAttention(arch, "full_attention", dtype=BF16)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # dispatch
+    shapes = jax.eval_shape(lambda: attn.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048), jnp.float32)))
+    assert set(shapes["params"]) == {"dq", "q_norm", "uq", "dkv", "kv_norm",
+                                     "ukv", "out"}
+
+    def loss(params, u):
+        y = attn.apply({"params": params}, u, train=True)
+        return jnp.sum(y.astype(jnp.float32))
+
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        on_chip(shapes["params"]), jax.ShapeDtypeStruct(
+            (1, 8192, 2048), jnp.float32, sharding=one_chip)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "8192,8192" not in text
+
+
+def test_no_drop_expert_layer_compiles_forward_and_backward(
+        one_chip, no_compile_cache):
+    """``routed_share`` at glm-4.7-flash's share — 8,192 tokens, top-4
+    of a router 64 wide, 8 held experts of width 1536 — under
+    ``jax.grad``: the grouped products and their transposes (a ragged
+    contracting dimension for the weights' gradient) are taken by the
+    TPU compiler as such."""
+    from tpunet.models.moe import routed_share
+
+    n, d, f, e, held = 8192, 2048, 1536, 64, 8
+
+    def loss(u, router, bias, gate, up, down):
+        y, _ = routed_share(u, router, bias, gate, up, down,
+                            tuple(range(held)), top_k=4, scaling=1.8)
+        return jnp.sum(y.astype(jnp.float32))
+
+    sds = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.float32, sharding=one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        sds(n, d), sds(d, e), sds(e), sds(held, d, f), sds(held, d, f),
+        sds(held, f, d)).compile().as_text()
+    assert len(set(re.findall(r"ragged-dot-none[.\d]*", text))) >= 9
 
 
 def test_segmented_flash_attention_compiles(one_chip, no_compile_cache):
@@ -289,7 +351,8 @@ def test_latent_attention_decode_never_converts_its_pools(
 
     slots, per_slot, pt = 8, 384, 16
     arch = LatentArch(hidden_size=5120, num_hidden_layers=1,
-                      layer_types=(kind,), intermediate_size=13824)
+                      layer_types=(kind,), intermediate_size=13824,
+                      index_topk=2048, apply_mla_qkv_lora_rescale=True)
     paged = PagedKV(pages=slots * per_slot + 1, page_tokens=pt)
     attn = LatentAttention(arch, kind, dtype=BF16, param_dtype=BF16)
     table = jnp.zeros((slots, per_slot), jnp.int32)

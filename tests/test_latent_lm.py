@@ -287,7 +287,10 @@ def test_what_is_not_built_says_so(tiny):
     toks = jnp.zeros((1, 4), jnp.int32)
     with pytest.raises(ValueError, match="pages"):
         model.apply({"params": params}, toks, decode=True, mutable=["cache"])
-    with pytest.raises(ValueError, match="serving"):
+    with pytest.raises(ValueError, match="packed"):
+        model.apply({"params": params}, toks, segment_ids=toks)
+    # an indexer's selection and sliding layers have no backward
+    with pytest.raises(ValueError, match="without an indexer"):
         model.apply({"params": params}, toks, train=True)
     with pytest.raises(ValueError, match="unknown keys"):
         latent_lm.LatentArch.from_mapping({"hidden": 4})

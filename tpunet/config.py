@@ -164,6 +164,9 @@ class ModelConfig:
     # the routed experts whose weights this chip holds. vocab_size,
     # max_seq_len, dtype and param_dtype above apply as for "lm".
     latent: Optional[Mapping[str, Any]] = None
+    # Weight of the multi-token-prediction loss where the model has
+    # such a module (the train step adds it to the next-token loss).
+    mtp_loss_weight: float = 0.3
     # Vocab-sharded cross-entropy (tpunet/ops/vocab_ce.py): "auto"
     # shards the tied output projection + CE over the mesh 'model'
     # axis whenever it divides the vocab, so the replicated [B, T, V]

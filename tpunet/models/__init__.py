@@ -48,9 +48,12 @@ def init_variables(model, rng: jax.Array, image_size: int = 224,
 
     ``batch_size`` (and ``seq_len`` for token models) matters only for
     models whose attention runs under shard_map (ring): the init batch
-    must divide the mesh's batch/seq axes.
+    must divide the mesh's batch/seq axes. A token model none of whose
+    parameters' shapes depend on the sequence names a short
+    ``init_seq_len`` of its own.
     """
     if getattr(model, "input_kind", "image") == "tokens":
+        seq_len = getattr(model, "init_seq_len", seq_len)
         dummy = jnp.zeros((batch_size, seq_len), jnp.int32)
     else:
         dummy = jnp.zeros((batch_size, image_size, image_size, 3),

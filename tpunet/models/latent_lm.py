@@ -1,8 +1,8 @@
 """Decoder-only LM with latent attention — the second decoder block.
 
 Model name ``latent_lm`` (``ModelConfig.latent`` holds the published
-``config.json`` keys; the benchmark's ``dots3-note-prev`` is the worked
-configuration). Beside ``TransformerLM`` (learned positions, LayerNorm,
+``config.json`` keys; the benchmark's ``dots3-note-prev`` (served) and
+``glm-4.7-flash`` (trained) are the worked configurations). Beside ``TransformerLM`` (learned positions, LayerNorm,
 GELU, one kind of attention, tied head) this block has RMSNorm, rotary
 positions with a base per layer kind, gated-SiLU MLPs, an untied head,
 and per layer one of two attentions over a *latent* cache:
@@ -17,14 +17,27 @@ and per layer one of two attentions over a *latent* cache:
   and head sizes, over the last ``sliding_window_size`` positions (the
   token itself counts), no indexer.
 
-Both gate each head's output with a sigmoid of the block's normed input
-before the output projection. Layer 0 (``first_k_dense_replace``) has a
-dense gated-SiLU MLP, every later layer ``moe.RoutedShareMlp`` (sigmoid
-routing without capacity, one shared expert, the experts ``held`` by
-this chip).
+The indexer (``index_topk``), a sigmoid gate per head from the block's
+normed input before the output projection (``attention_gate_type``) and
+the latent rescale are properties of the published config: an
+architecture that has none of them has none of their parameters, and a
+full layer is then plain causal latent attention. Layer 0
+(``first_k_dense_replace``) has a dense gated-SiLU MLP, every later
+layer ``moe.RoutedShareMlp`` (sigmoid routing without capacity, one
+shared expert, the experts ``held`` by this chip).
+``num_nextn_predict_layers`` 1 adds a multi-token-prediction module
+(``MtpModule``) that shares the trunk's embedding and head.
 
-Two forms of one mathematics, chosen by the call's shape:
+Three forms of one mathematics, chosen by the call:
 
+- ``train=True`` (the ``Trainer``'s step): every row of the batch at
+  once, UP-PROJECTED, through the flash kernel (``ops/flash.py``) — the
+  shared rotary key rides beside each head's own, so queries, keys and
+  values have one head size, no ``[heads, T, T]`` tensor exists and
+  nothing past the diagonal is computed; the FFN takes every token of
+  the batch in one call; blocks are recomputed in the backward where
+  ``remat``. Built for full layers without an indexer whose
+  ``qk_nope_head_dim + qk_rope_head_dim == v_head_dim``.
 - one token per row against the cache (the engine's ``[slots, 1]``
   decode step): the ABSORBED form — ``W_uk`` is folded into the query
   and ``W_uv`` into the output, so scores and values are taken against
@@ -104,7 +117,11 @@ class LatentArch:
     rope_theta: float = 8e7
     index_n_heads: int = 64
     index_head_dim: int = 128
-    index_topk: int = 2048
+    index_topk: Optional[int] = None   # None: no indexer, plain causal
+    # (the one property an absent key does not switch off: the
+    # configuration that has the gate does not name it in its program
+    # section, so a model without one says null)
+    attention_gate_type: Optional[str] = "headwise"
     # sliding layers
     swa_num_attention_heads: int = 64
     swa_q_lora_rank: int = 1024
@@ -114,13 +131,16 @@ class LatentArch:
     swa_v_head_dim: int = 128
     swa_rope_theta: float = 5e4
     sliding_window_size: int = 513
-    apply_mla_qkv_lora_rescale: bool = True
+    swa_attention_gate_type: Optional[str] = "headwise"
+    apply_mla_qkv_lora_rescale: bool = False
     # expert layers
     n_routed_experts: int = 256        # the router's width
     num_experts_per_tok: int = 8
     moe_intermediate_size: int = 1536
     routed_scaling_factor: float = 1.0
     held_experts: Optional[Tuple[int, ...]] = None   # None = all
+    # multi-token prediction modules after the trunk (0 or 1)
+    num_nextn_predict_layers: int = 0
 
     @classmethod
     def from_mapping(cls, m) -> "LatentArch":
@@ -136,18 +156,28 @@ class LatentArch:
         if len(arch.layer_types) != arch.num_hidden_layers:
             raise ValueError("latent_lm: layer_types must name "
                              f"{arch.num_hidden_layers} layers")
+        for gate in (arch.attention_gate_type, arch.swa_attention_gate_type):
+            if gate not in (None, "headwise"):
+                raise ValueError(f"latent_lm: unknown gate type {gate!r}")
+        if arch.num_nextn_predict_layers not in (0, 1):
+            raise ValueError("latent_lm builds one multi-token-prediction "
+                             "module at most")
         return arch
 
     def layer(self, kind: str) -> dict:
         """Sizes of one attention kind: heads, latent ranks, head dims,
-        rotary base, the two latent scales."""
-        p = "" if kind == "full_attention" else "swa_"
+        rotary base, the two latent scales, whether its heads are gated
+        and how many keys its indexer keeps (None: it has none)."""
+        full = kind == "full_attention"
+        p = "" if full else "swa_"
         g = lambda k: getattr(self, p + k)  # noqa: E731
         rq, rkv = g("q_lora_rank"), g("kv_lora_rank")
         scale = self.apply_mla_qkv_lora_rescale
         return {"heads": g("num_attention_heads"), "rq": rq, "rkv": rkv,
                 "dn": g("qk_nope_head_dim"), "dr": g("qk_rope_head_dim"),
                 "dv": g("v_head_dim"), "theta": float(g("rope_theta")),
+                "gated": g("attention_gate_type") == "headwise",
+                "topk": self.index_topk if full else None,
                 "s_q": math.sqrt(self.hidden_size / rq) if scale else 1.0,
                 "s_kv": math.sqrt(self.hidden_size / rkv) if scale else 1.0}
 
@@ -273,9 +303,10 @@ def _softmax_values(scores, keep, v):
 def cache_widths(arch: LatentArch, kind: str) -> dict:
     """Numbers a token keeps in one layer's cache, by cache kind."""
     z = arch.layer(kind)
-    if kind == "full_attention":
-        return {"latent": z["rkv"] + z["dr"], "index": arch.index_head_dim}
-    return {"window": z["rkv"] + z["dr"]}
+    if kind != "full_attention":
+        return {"window": z["rkv"] + z["dr"]}
+    index = {} if z["topk"] is None else {"index": arch.index_head_dim}
+    return {"latent": z["rkv"] + z["dr"], **index}
 
 
 # -- the attention layer ------------------------------------------------------
@@ -292,9 +323,11 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, u, decode: bool = False, positions=None,
-                 active=None, paged_kv=None, page_table=None):
+                 active=None, paged_kv=None, page_table=None,
+                 train: bool = False):
         a, z = self.arch, self.arch.layer(self.kind)
         full = self.kind == "full_attention"
+        indexed, gated = z["topk"] is not None, z["gated"]
         u32, u = u, u.astype(self.dtype)     # float32 for the indexer alone
         b, t, c = u.shape
         h, dn, dr, dv = z["heads"], z["dn"], z["dr"], z["dv"]
@@ -312,8 +345,9 @@ class LatentAttention(nn.Module):
             w("uq", rq, h * (dn + dr))
         dkv, kv_norm = w("dkv", c, rkv + dr), ones("kv_norm", rkv)
         ukv = w("ukv", rkv, h * (dn + dv)).reshape(rkv, h, dn + dv)
-        gate, out = w("gate", c, h), w("out", h * dv, c)
-        if full:
+        gate = w("gate", c, h) if gated else None
+        out = w("out", h * dv, c)
+        if indexed:
             hi, di = a.index_n_heads, a.index_head_dim
             iq, ik, iw = w("iq", rq, hi * di), w("ik", c, di), w("iw", c, hi)
             ik_scale = ones("ik_norm_scale", di)
@@ -333,7 +367,7 @@ class LatentAttention(nn.Module):
             c_kv = rms_norm(kv[..., :rkv], kv_norm, eps, z["s_kv"])
             k_r = rope(kv[..., rkv:], pos_t, z["theta"])
             lat = jnp.concatenate([c_kv, k_r], -1)               # [B,T,rkv+dr]
-        if full:
+        if indexed:
             with jax.named_scope("tpunet_indexer"):
                 k_i = layer_norm(_dot32(u32, ik), ik_scale, ik_bias, 1e-5)
                 k_i = jnp.concatenate(
@@ -345,8 +379,9 @@ class LatentAttention(nn.Module):
             c_q = rms_norm(jnp.dot(u_, dq), q_norm, eps, z["s_q"])
             q = jnp.dot(c_q, uq).reshape(*u_.shape[:-1], h, dn + dr)
             q_n, q_r = q[..., :dn], rope(q[..., dn:], pos_, z["theta"])
-            g = jax.nn.sigmoid(jnp.dot(u_, gate).astype(jnp.float32))
-            if not full:
+            g = (jax.nn.sigmoid(jnp.dot(u_, gate).astype(jnp.float32))
+                 if gated else None)
+            if not indexed:
                 return q_n, q_r, g, None, None
             with jax.named_scope("tpunet_indexer"):
                 c_q32 = rms_norm(_dot32(u32_, dq), q_norm, eps, z["s_q"])
@@ -358,8 +393,27 @@ class LatentAttention(nn.Module):
             return q_n, q_r, g, q_i, w_i
 
         def project_out(o, g):
-            o = (o * g[..., None]).astype(dt)
+            o = (o if g is None else o * g[..., None]).astype(dt)
             return jnp.dot(o.reshape(*o.shape[:-2], h * dv), out)
+
+        # -- training: every row at once, causal, through flash -------
+        if train:
+            if decode or indexed or not full or dn + dr != dv:
+                raise ValueError(
+                    "latent_lm trains full layers without an indexer whose "
+                    "queries, keys and values share one head size (the "
+                    "flash kernel's one D); sliding layers, the indexer's "
+                    "selection and unequal head sizes have no backward here")
+            from tpunet.ops.flash import flash_attention
+            with jax.named_scope(scope):
+                q_n, q_r, g, _, _ = queries(u, pos_t)
+                kv_up = jnp.einsum("btr,rhd->bthd", c_kv, ukv)
+                k = jnp.concatenate([kv_up[..., :dn], jnp.broadcast_to(
+                    k_r[:, :, None, :], (b, t, h, dr))], -1)
+                o = flash_attention(jnp.concatenate([q_n, q_r], -1), k,
+                                    kv_up[..., dn:], causal=True,
+                                    scale=scale)
+                return project_out(o, g)
 
         # -- up-projected form, one row: keys in position order -------
         def row_attend(u_, start, lat_keys, index_keys, u32_=None):
@@ -398,11 +452,13 @@ class LatentAttention(nn.Module):
                 if full:
                     k_all_up, v_all = up_project(lat_keys)
                     kpos = jnp.arange(k_all)
-                    topk = a.index_topk
+                    topk = z["topk"]
 
                     def one(args):
                         q_b, qi_b, wi_b, qpos_b = args
                         keep = kpos[None, :] <= qpos_b[:, None]
+                        if not indexed:
+                            return attend(q_b, k_all_up, v_all, keep)
                         with jax.named_scope("tpunet_indexer"):
                             score = jnp.where(
                                 keep, index_scores(qi_b, wi_b, index_keys),
@@ -416,8 +472,9 @@ class LatentAttention(nn.Module):
                                     keep & top_k_mask(score, topk))
                         return attend(q_b, k_all_up, v_all, keep)
 
-                    o = lax.map(one, (q_blocks,) + tuple(map(
-                        blocks, (q_i, w_i, qpos))))
+                    o = lax.map(one, (q_blocks,) + tuple(
+                        blocks(x) if indexed else None
+                        for x in (q_i, w_i)) + (blocks(qpos),))
                 else:
                     kb = min(k_all, bq + window - 1)
 
@@ -437,7 +494,7 @@ class LatentAttention(nn.Module):
 
         if not decode:
             # plain forward: the call's own tokens are the keys
-            if full:
+            if indexed:
                 return by_row(row_attend, None, u, positions, lat, k_i, u32)
             return by_row(lambda u_, s_, l_: row_attend(u_, s_, l_, None),
                           None, u, positions, lat)
@@ -456,7 +513,7 @@ class LatentAttention(nn.Module):
         wide = lane_rounded(rkv + dr)
         pool = self.variable("cache", "latent", jnp.zeros,
                              (flat_rows, wide), store)
-        if full:
+        if indexed:
             ipool = self.variable("cache", "index", jnp.zeros,
                                   (flat_rows, lane_rounded(di)),
                                   jnp.float32)
@@ -485,7 +542,7 @@ class LatentAttention(nn.Module):
             var.value = var.value.at[new].set(rows.astype(var.value.dtype))
 
         put(pool, lat)
-        if full:
+        if indexed:
             put(ipool, k_i)
         k_max = page_table.shape[1] * pt
 
@@ -499,7 +556,7 @@ class LatentAttention(nn.Module):
                 rows = rows_of(table)
                 keys = jnp.take(pool.value, rows, axis=0).astype(dt)
                 ikeys = (jnp.take(ipool.value, rows, axis=0)[:, :di]
-                         if full else None)
+                         if indexed else None)
                 return row_attend(u_, start, keys, ikeys, u32_)
             return by_row(cached_row, active, u, positions, page_table, u32)
 
@@ -510,7 +567,7 @@ class LatentAttention(nn.Module):
         # output.
         with jax.named_scope(scope):
             q_n, q_r, g, q_i, w_i = queries(u[:, 0], positions, u32[:, 0])
-            if full:
+            if indexed:
                 with jax.named_scope("tpunet_indexer"):
                     ikeys = jnp.take(ipool.value, rows_of(page_table),
                                      axis=0)[..., :di]
@@ -521,14 +578,20 @@ class LatentAttention(nn.Module):
                 with jax.named_scope("tpunet_kv_select"):
                     best, sel = lax.top_k(score, min(a.index_topk, k_max))
                     keep = best > -jnp.inf
+            elif full:                   # every cached position, in order
+                sel = jnp.broadcast_to(jnp.arange(k_max)[None, :], (b, k_max))
+                keep = sel <= positions[:, None]
             else:
                 sel = positions[:, None] - (window - 1) \
                     + jnp.arange(window)[None, :]
                 keep = sel >= 0
                 sel = jnp.maximum(sel, 0)
-            # (a full layer's gather of its selected rows is the cost of
-            # selection; a sliding layer's window stays under its own scope)
-            with jax.named_scope("tpunet_kv_select" if full else "tpunet_window_gather"):
+            # (an indexed layer's gather of its selected rows is the cost
+            # of selection; a sliding layer's window stays under its own
+            # scope, a plain full layer's rows under the layer's)
+            with jax.named_scope("tpunet_kv_select" if indexed else
+                                 "tpunet_window_gather" if not full else
+                                 "tpunet_kv_gather"):
                 keys = jnp.take(pool.value, flat_of(sel), axis=0).astype(dt)
             c_keys, r_keys = keys[..., :rkv], keys[..., rkv:rkv + dr]
             q_abs = jnp.einsum("bhd,rhd->bhr", q_n, ukv[..., :dn])
@@ -549,7 +612,8 @@ class LatentBlock(nn.Module):
     """``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the
     FFN dense (``dense`` True) or the expert layer. A wide call
     (T > 1) takes the FFN one batch row at a time, skipping the rows
-    ``row_active`` marks idle."""
+    ``row_active`` marks idle; a training call (``train``) takes it
+    over every token of the batch at once."""
 
     arch: LatentArch
     kind: str
@@ -558,10 +622,11 @@ class LatentBlock(nn.Module):
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, decode, positions, active, paged_kv, page_table):
+    def __call__(self, x, decode, positions, active, paged_kv, page_table,
+                 train: bool = False):
         a = self.arch
-        c = a.hidden_size
-        wide = x.shape[1] > 1
+        b, t, c = x.shape
+        wide = t > 1 and not train
         row_active = active if (decode and wide) else None
 
         def norm(name, v):
@@ -572,8 +637,10 @@ class LatentBlock(nn.Module):
         x = x + LatentAttention(a, self.kind, dtype=self.dtype,
                                 param_dtype=self.param_dtype, name="attn")(
             norm("ln1", x), decode, positions, active, paged_kv,
-            page_table).astype(x.dtype)
+            page_table, train).astype(x.dtype)
         u = norm("ln2", x)
+        if train:                # every token a row of its own, as in decode
+            u = u.reshape(b * t, 1, c)
         if self.dense:
             init = nn.initializers.normal(stddev=0.02)
             f = a.intermediate_size
@@ -591,7 +658,49 @@ class LatentBlock(nn.Module):
                 param_dtype=self.param_dtype, name="moe")(
                     u if wide else u[:, 0], row_active)
             y = y if wide else y[:, None]
-        return x + y.astype(x.dtype)
+        return x + y.reshape(b, t, c).astype(x.dtype)
+
+
+def _block_class(remat: bool):
+    """``LatentBlock``, recomputed in the backward where ``remat`` (its
+    two flags are static: argnums count self as 0)."""
+    return (nn.remat(LatentBlock, static_argnums=(2, 7)) if remat
+            else LatentBlock)
+
+
+class MtpModule(nn.Module):
+    """Multi-token prediction, depth 1 (arXiv:2412.19437 section 2.2):
+    position i joins the trunk's last block output there with the
+    embedding of token i+1 — ``W_eh [RMSNorm_e(Emb(t_{i+1}));
+    RMSNorm_h(h_i)]`` — and passes one more whole expert block and a
+    final RMSNorm of its own; the trunk's head then predicts token
+    i+2. ``x`` [B, T, C] float32, ``nxt`` the embeddings of the tokens
+    one to the right; returns the normed state [B, T, C]."""
+
+    arch: LatentArch
+    remat: bool = False
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, nxt, positions, train: bool = False):
+        a = self.arch
+        c = a.hidden_size
+
+        def norm(name, v):
+            scale = self.param(name, nn.initializers.ones, (c,),
+                               self.param_dtype)
+            return rms_norm(v, scale, a.rms_norm_eps, dtype=self.dtype)
+
+        eh = self.param("eh_proj", nn.initializers.normal(stddev=0.02),
+                        (2 * c, c), self.param_dtype)
+        y = jnp.dot(jnp.concatenate([norm("enorm", nxt), norm("hnorm", x)],
+                                    -1), eh.astype(self.dtype))
+        y = _block_class(self.remat and train)(
+            a, a.layer_types[-1], dense=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, name="block")(
+            y.astype(jnp.float32), False, positions, None, None, None, train)
+        return norm("ln", y)
 
 
 class LatentLM(nn.Module):
@@ -602,10 +711,13 @@ class LatentLM(nn.Module):
     arch: LatentArch
     vocab_size: int = 256
     max_len: int = 1024
+    remat: bool = False                # recompute each block in the backward
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
     input_kind = "tokens"
+    # no parameter's shape depends on the sequence: init runs this short
+    init_seq_len = 8
 
     @property
     def hidden(self) -> int:
@@ -614,6 +726,18 @@ class LatentLM(nn.Module):
     @property
     def heads(self) -> int:
         return self.arch.num_attention_heads
+
+    def expert_gauges(self, prefix: str) -> dict:
+        """``<prefix>_experts_held`` of ``<prefix>_experts_total``."""
+        a = self.arch
+        held = (a.n_routed_experts if a.held_experts is None
+                else len(a.held_experts))
+        return {f"{prefix}_experts_total": a.n_routed_experts,
+                f"{prefix}_experts_held": held}
+
+    def train_gauges(self) -> dict:
+        """What the trainer sets once, at construction."""
+        return self.expert_gauges("train")
 
     def serve_gauges(self) -> dict:
         """What the serve engine sets once, at construction: bytes a
@@ -629,11 +753,7 @@ class LatentLM(nn.Module):
         # the engine's gauge asks tpunet_paged_decode's dispatch, which
         # this block's absorbed decode does not go through
         out["serve_decode_attend_kernel"] = 0
-        out["serve_experts_total"] = a.n_routed_experts
-        out["serve_experts_held"] = (a.n_routed_experts
-                                     if a.held_experts is None
-                                     else len(a.held_experts))
-        return out
+        return {**out, **self.expert_gauges("serve")}
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, decode: bool = False,
@@ -641,39 +761,59 @@ class LatentLM(nn.Module):
                  return_hidden: bool = False, decode_active=None,
                  paged_kv=None, page_table=None):
         """As ``TransformerLM.__call__``; ``pos_offset`` is a scalar or
-        an int32 [B] of each row's first position. Packed sequences
-        (``segment_ids``) and training are not built."""
-        if segment_ids is not None or train:
-            raise ValueError("latent_lm is built for serving: no packed "
-                             "sequences, no training")
+        an int32 [B] of each row's first position. ``train`` takes the
+        batch-wide causal path and, where the architecture has a
+        multi-token-prediction module, returns ``(logits, logits of the
+        token after next)``. Packed sequences (``segment_ids``) are not
+        built."""
+        if segment_ids is not None:
+            raise ValueError("latent_lm has no packed sequences")
+        if train and decode:
+            raise ValueError("latent_lm trains without a cache")
         a = self.arch
         b, t = tokens.shape
         if t > self.max_len:
             raise ValueError(f"sequence {t} exceeds max_len {self.max_len}")
         positions = jnp.broadcast_to(jnp.asarray(pos_offset, jnp.int32), (b,))
-        x = nn.Embed(self.vocab_size, a.hidden_size,
-                     embedding_init=nn.initializers.normal(stddev=0.02),
-                     param_dtype=self.param_dtype,
-                     name="embed")(tokens).astype(jnp.float32)
+        embed = nn.Embed(self.vocab_size, a.hidden_size,
+                         embedding_init=nn.initializers.normal(stddev=0.02),
+                         param_dtype=self.param_dtype, name="embed")
+        x = embed(tokens).astype(jnp.float32)
+        Block = _block_class(self.remat and train)
         for i, kind in enumerate(a.layer_types):
-            x = LatentBlock(a, kind, dense=i < a.first_k_dense_replace,
-                            dtype=self.dtype, param_dtype=self.param_dtype,
-                            name=f"block{i:02d}")(
-                x, decode, positions, decode_active, paged_kv, page_table)
+            x = Block(a, kind, dense=i < a.first_k_dense_replace,
+                      dtype=self.dtype, param_dtype=self.param_dtype,
+                      name=f"block{i:02d}")(
+                x, decode, positions, decode_active, paged_kv, page_table,
+                train)
         scale = self.param("ln", nn.initializers.ones, (a.hidden_size,),
                            self.param_dtype)
-        x = rms_norm(x, scale, a.rms_norm_eps, dtype=self.dtype)
+        h = rms_norm(x, scale, a.rms_norm_eps, dtype=self.dtype)
         if return_hidden:
-            return x.astype(jnp.float32)
+            return h.astype(jnp.float32)
         head = self.param("head", nn.initializers.normal(stddev=0.02),
                           (a.hidden_size, self.vocab_size), self.param_dtype)
-        with jax.named_scope("tpunet_head"):
-            return jnp.dot(x, head.astype(self.dtype),
-                           preferred_element_type=jnp.float32)
+
+        def logits_of(h_):
+            with jax.named_scope("tpunet_head"):
+                return jnp.dot(h_, head.astype(self.dtype),
+                               preferred_element_type=jnp.float32)
+
+        if not (a.num_nextn_predict_layers
+                and (train or self.is_initializing())):
+            return logits_of(h)
+        # the trunk's embedding and head are the module's too; the last
+        # position has no next token (it takes the row's first, and the
+        # loss leaves it out)
+        with jax.named_scope("tpunet_mtp"):
+            h_next = MtpModule(a, self.remat, dtype=self.dtype,
+                               param_dtype=self.param_dtype, name="mtp")(
+                x, embed(jnp.roll(tokens, -1, axis=1)), positions, train)
+        return logits_of(h), logits_of(h_next)
 
 
 def create_model(cfg: ModelConfig, mesh=None) -> LatentLM:
-    if mesh is not None:
+    if mesh is not None and mesh.size > 1:
         raise ValueError("latent_lm runs on one device (a chip's share of "
                          "an expert-parallel deployment); no mesh lowering")
     if not cfg.latent:
@@ -681,5 +821,5 @@ def create_model(cfg: ModelConfig, mesh=None) -> LatentLM:
                          "published config keys)")
     return LatentLM(arch=LatentArch.from_mapping(cfg.latent),
                     vocab_size=cfg.vocab_size, max_len=cfg.max_seq_len,
-                    dtype=jnp.dtype(cfg.dtype),
+                    remat=cfg.remat, dtype=jnp.dtype(cfg.dtype),
                     param_dtype=jnp.dtype(cfg.param_dtype))

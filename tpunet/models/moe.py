@@ -425,7 +425,7 @@ class MoeMlp(nn.Module):
         return fn(x, logits, wi, bi, wo, bo)
 
 
-# -- the no-drop share (serving) ---------------------------------------------
+# -- the no-drop share (serving, and training under the Trainer) -------------
 
 def gated_silu(x, gate, up, down, dtype):
     """``down(silu(gate x) * up x)`` on ``x`` [n, d]; products in
@@ -468,6 +468,29 @@ def by_row(fn, active, *xs):
     return jax.lax.map(body, (active, *xs))
 
 
+@jax.custom_vjp
+def _held_rows(x, valid):
+    """``x`` [m, n] as it is (no operation going forward); coming back,
+    the rows that ``valid`` [m, 1] marks False get a zero cotangent by
+    a select. ``jax.lax.ragged_dot`` and its transposes leave the rows
+    outside every group unwritten on the TPU: going forward those rows
+    are dropped by the caller's own select, and coming back whatever
+    stands there (it can be NaN, and 0 x NaN is NaN) must not reach the
+    gradients through the SiLU's product or the gather's scatter-add."""
+    return x
+
+
+def _held_rows_fwd(x, valid):
+    return x, valid
+
+
+def _held_rows_bwd(valid, g):
+    return jnp.where(valid, g, 0), None
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
 def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
                  scaling: float = 1.0, dtype=jnp.bfloat16):
     """One chip's share of a routed expert layer, without capacity.
@@ -479,7 +502,9 @@ def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
     chosen experts that are held of weight * expert(u)``: the pairs are
     sorted by held expert (pairs on absent experts last, computed by
     nobody), the experts run as grouped products over contiguous rows,
-    and each token sums its pairs back in pair order. ``stats`` (float32
+    and each token sums its pairs back in pair order. Differentiable in
+    ``u``, ``router`` and the experts' weights; ``bias`` only chooses,
+    so its gradient is zero. ``stats`` (float32
     scalars) counts the routing load: ``held_pair_share`` = pairs on
     held experts / pairs, ``held_load_max_over_mean`` = largest / mean
     load over the held experts."""
@@ -495,11 +520,15 @@ def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
         order = jnp.argsort(slot, stable=True)
         sizes = jnp.sum(slot[:, None] == jnp.arange(h)[None, :], axis=0,
                         dtype=jnp.int32)                         # [h]
-        rows = jnp.take(u.astype(dtype), order // top_k, axis=0)
-        a = jax.lax.ragged_dot(rows, gate.astype(dtype), sizes)
-        b = jax.lax.ragged_dot(rows, up.astype(dtype), sizes)
+        on_held = (jnp.take(slot, order) < h)[:, None]
+        rows = _held_rows(jnp.take(u.astype(dtype), order // top_k, axis=0),
+                          on_held)
+        a = _held_rows(jax.lax.ragged_dot(rows, gate.astype(dtype), sizes),
+                       on_held)
+        b = _held_rows(jax.lax.ragged_dot(rows, up.astype(dtype), sizes),
+                       on_held)
         y = jax.lax.ragged_dot(nn.silu(a) * b, down.astype(dtype), sizes)
-        y = jnp.where((jnp.take(slot, order) < h)[:, None], y, 0)
+        y = jnp.where(on_held, y, 0)
         back = jnp.argsort(order)                                # pair order
         y = jnp.take(y, back, axis=0).reshape(n, top_k, d)
         y = jnp.sum(y.astype(jnp.float32) * weight[:, :, None],
@@ -519,7 +548,8 @@ class RoutedShareMlp(nn.Module):
     the routed experts whose weights live here (all of them by
     default). The routing load is ``sow``n into the ``stats``
     collection (per batch row for a 3-D ``x``): free unless a caller
-    makes it mutable (the serve engine's step does not)."""
+    makes it mutable (the serve engine's step does not; the LM train
+    step does, and sums it into the trainer's gauges)."""
 
     n_experts: int
     width: int

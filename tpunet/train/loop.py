@@ -41,6 +41,10 @@ from tpunet.utils.preemption import PreemptionGuard
 from tpunet.utils.prng import root_key, step_key
 
 
+# Models whose batches are rows of tokens (targets = the row shifted).
+TOKEN_MODELS = ("lm", "lm_pp", "latent_lm")
+
+
 class Trainer:
     """Owns the mesh, state, jitted steps, and the epoch loop."""
 
@@ -53,12 +57,12 @@ class Trainer:
         if self.spe == 0:
             raise ValueError("batch size larger than training set")
 
-        self.is_lm = cfg.model.name in ("lm", "lm_pp")
+        self.is_lm = cfg.model.name in TOKEN_MODELS
         is_token_data = cfg.data.dataset in ("synthetic_lm", "text_lm")
         if self.is_lm != is_token_data:
             raise ValueError(
                 f"model {cfg.model.name!r} and dataset "
-                f"{cfg.data.dataset!r} are different families (the 'lm' "
+                f"{cfg.data.dataset!r} are different families (a token "
                 "model needs token data, e.g. --dataset synthetic_lm)")
         if self.is_lm and cfg.model.vocab_size != cfg.data.vocab_size:
             raise ValueError(
@@ -230,9 +234,18 @@ class Trainer:
             ident = self.obs.registry.identity()
             self.obs.registry.set_identity(
                 **ident, config_fingerprint=train_fingerprint(cfg))
-        from tpunet.models import num_params
+        from tpunet.models import create_model, num_params
         self.obs.set_flops_per_unit(train_flops_per_unit(
             cfg.model, cfg.data, n_params=num_params(state.params)))
+        if self.obs.enabled:
+            # Set once: the bytes of parameters this process trains and
+            # what the model says of itself (latent_lm: experts held).
+            gauges = {"train_params_resident_bytes": sum(
+                p.nbytes for p in jax.tree_util.tree_leaves(state.params))}
+            gauges.update(getattr(create_model(cfg.model, mesh=self.mesh),
+                                  "train_gauges", dict)())
+            for name, value in gauges.items():
+                self.obs.registry.gauge(name).set(value)
         self.ckpt = Checkpointer(cfg.checkpoint, obs=self.obs)
         self.guard = PreemptionGuard(deadline_s=cfg.preempt_grace_s)
         # Fault injection (--chaos): armed process-globally so the
@@ -523,7 +536,18 @@ class Trainer:
                 log0(f"  step {self.global_step} "
                      f"loss {sm['loss']:.4f} acc {sm['accuracy']:.4f} "
                      f"lr {lr:.3e}")
-        return M.summarize(acc if acc is not None else M.zeros_metrics())
+        acc = acc if acc is not None else M.zeros_metrics()
+        summary = M.summarize(acc)        # the chunk's device fence
+        means = M.summarize_step_means(acc)
+        if means and obs.enabled:
+            # losses apart and the no-drop experts' routing load, as
+            # gauges and as one record per pass over the data
+            for name, value in means.items():
+                obs.registry.gauge("train_" + name).set(value)
+            obs.registry.emit("obs_train_means", {
+                "epoch": epoch, "step": self.global_step,
+                **{"train_" + k: round(v, 6) for k, v in means.items()}})
+        return summary
 
     def program_texts(self) -> Dict[str, str]:
         """``{label: optimized HLO text}`` of the train step: the one

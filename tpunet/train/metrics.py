@@ -34,6 +34,38 @@ def accumulate(acc: Metrics, new: Metrics) -> Metrics:
     return jax.tree_util.tree_map(jnp.add, acc, new)
 
 
+# What a step may add to the triple, each a per-step mean; ``steps``
+# counts the steps summed (train/steps.py ``_step_means``).
+STEP_MEANS = ("main_loss", "mtp_loss", "moe_held_pair_share",
+              "moe_held_load_max_over_mean")
+
+# Process-wide sums of the step means over every chunk a trainer of
+# this process has summarized, for a reader that runs after the
+# trainer is closed (as utils/cache._COMPILES counts compiles).
+STEP_MEAN_TOTALS: Dict[str, float] = {}
+
+
+def with_step_means(metrics: Metrics, means: Dict[str, jax.Array]) -> Metrics:
+    """``metrics`` plus one step's ``means`` as summable entries."""
+    if not means:
+        return metrics
+    extra = {k: jnp.asarray(v, jnp.float32) for k, v in means.items()}
+    return {**metrics, **extra, "steps": jnp.float32(1.0)}
+
+
+def summarize_step_means(acc: Metrics) -> Dict[str, float]:
+    """Means over the accumulated steps of whatever of ``STEP_MEANS``
+    the steps reported ({} where none did), also added to
+    ``STEP_MEAN_TOTALS``."""
+    if "steps" not in acc:
+        return {}
+    steps = float(acc["steps"])
+    sums = {k: float(acc[k]) for k in STEP_MEANS if k in acc}
+    for k, v in {**sums, "steps": steps}.items():
+        STEP_MEAN_TOTALS[k] = STEP_MEAN_TOTALS.get(k, 0.0) + v
+    return {k: v / max(steps, 1.0) for k, v in sums.items()}
+
+
 def summarize(acc: Metrics) -> Dict[str, float]:
     """Device scalars -> python floats {loss, accuracy, count}."""
     count = max(float(acc["count"]), 1.0)

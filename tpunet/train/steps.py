@@ -50,6 +50,22 @@ def _with_aux(loss, mutated, aux_weight: float):
     return loss + _aux_term(mutated, aux_weight)
 
 
+def _routing_load(mutated) -> dict:
+    """Mean over the expert layers of the routing load that
+    ``moe.RoutedShareMlp`` sows into ``stats`` ({} where none did).
+    Every layer routes the same pairs, so the mean of their shares is
+    pairs on held experts over pairs."""
+    def is_load(s):
+        return hasattr(s, "keys") and "held_pair_share" in s
+
+    found = [s for s in jax.tree_util.tree_leaves(
+        mutated.get("stats", {}), is_leaf=is_load) if is_load(s)]
+    if not found:
+        return {}
+    return {"moe_" + k: sum(jnp.mean(s[k]) for s in found) / len(found)
+            for k in ("held_pair_share", "held_load_max_over_mean")}
+
+
 def _steps_from_micro(micro: Callable, accum: int, mesh,
                       gather_params=None, ema_decay: float = 0.0,
                       count_fn: Optional[Callable] = None) -> Callable:
@@ -143,7 +159,10 @@ def _steps_from_micro(micro: Callable, accum: int, mesh,
                 grads, stats, m = micro(params, stats, state.apply_fn,
                                         mx, my, mr)
             gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
-            return (stats, gsum, M.accumulate(msum, m)), None
+            # (a step's extra means, metrics.STEP_MEANS, ride only the
+            # unscanned step: the carry is the triple)
+            return (stats, gsum, M.accumulate(
+                msum, {k: m[k] for k in msum})), None
 
         gzero = jax.tree_util.tree_map(jnp.zeros_like, state.params)
         with jax.named_scope("tpunet_fwd_bwd"):
@@ -249,8 +268,16 @@ def make_lm_train_step(optim_cfg: OptimConfig,
     axis > 1 dividing the vocab) the model returns final-LN hidden
     states and the CE runs vocab-sharded against the tied embedding —
     the replicated [B, T, V] float32 logits never materialize
-    (tpunet/ops/vocab_ce.py)."""
+    (tpunet/ops/vocab_ce.py).
+
+    A model whose training forward returns ``(logits, logits of the
+    token after next)`` (a multi-token-prediction module) has the
+    second cross-entropy added at ``model_cfg.mtp_loss_weight``, each
+    a mean over the positions that have its target; both losses and
+    the routing load a no-drop expert layer sows into ``stats`` ride
+    the step's metrics (train/metrics.py ``STEP_MEANS``)."""
     aux_weight = model_cfg.moe_aux_weight
+    mtp_weight = model_cfg.mtp_loss_weight
     smoothing = optim_cfg.label_smoothing
     from tpunet.ops.vocab_ce import resolve_vocab_ce, vocab_parallel_ce
     sharded_ce = (resolve_vocab_ce(model_cfg.vocab_ce, mesh,
@@ -263,6 +290,7 @@ def make_lm_train_step(optim_cfg: OptimConfig,
         def loss_fn(params):
             kwargs = {"segment_ids": segs} if packed else {}
             tgt = tokens[:, 1:]
+            means = {}
             if sharded_ce:
                 h, mutated = apply_fn(
                     {"params": params, "batch_stats": batch_stats},
@@ -277,11 +305,19 @@ def make_lm_train_step(optim_cfg: OptimConfig,
                     {"params": params, "batch_stats": batch_stats},
                     tokens, train=True,
                     rngs={"dropout": rng},
-                    mutable=["batch_stats", "losses"], **kwargs)
+                    mutable=["batch_stats", "losses", "stats"], **kwargs)
+                if isinstance(logits, tuple):
+                    if packed:
+                        raise ValueError("no multi-token-prediction loss "
+                                         "over packed sequences")
+                    logits, ahead = logits
+                    means["mtp_loss"] = _ce_loss(
+                        ahead[:, :-2], tokens[:, 2:], smoothing).mean()
                 lg = logits[:, :-1]
                 ce = _ce_loss(lg, tgt, smoothing)
                 hit = (jnp.argmax(lg, -1) == tgt).astype(jnp.float32)
             aux = _aux_term(mutated, aux_weight)
+            means.update(_routing_load(mutated))
             if packed:
                 wt = _packed_target_weights(segs)
                 ce_sum = jnp.sum(ce * wt)
@@ -304,11 +340,14 @@ def make_lm_train_step(optim_cfg: OptimConfig,
                     loss_sum = ce_sum + aux * total / accum
             else:
                 loss = ce.mean() + aux
+                if "mtp_loss" in means:
+                    means["main_loss"] = ce.mean()
+                    loss = loss + mtp_weight * means["mtp_loss"]
                 loss_sum = loss * tgt.size
             return loss, (hit, mutated.get("batch_stats", {}),
-                          loss_sum)
+                          loss_sum, means)
 
-        (_, (hit, new_stats, loss_sum)), grads = jax.value_and_grad(
+        (_, (hit, new_stats, loss_sum, means)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
         if packed:
             wt = _packed_target_weights(segs)
@@ -317,7 +356,8 @@ def make_lm_train_step(optim_cfg: OptimConfig,
         else:
             n = hit.size
             correct = jnp.sum(hit)
-        return grads, new_stats, M.from_batch(loss_sum, correct, n)
+        return grads, new_stats, M.with_step_means(
+            M.from_batch(loss_sum, correct, n), means)
 
     def packed_count(y):
         return jnp.maximum(jnp.sum(_packed_target_weights(y)), 1.0)
