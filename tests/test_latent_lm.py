@@ -185,6 +185,35 @@ def test_engine_serves_the_references_best_tokens(tiny):
         assert gap.max() < 1e-4, gap
 
 
+@pytest.mark.parametrize("sampling", ["greedy", "seeded"])
+def test_engine_one_step_ahead_serves_the_sequential_loops_tokens(tiny,
+                                                                  sampling):
+    """The second decoder behind the same loop: with step N+1 dispatched
+    before step N's tokens are read, every request's tokens are bit-equal
+    to a loop that reads each step before it builds the next
+    (tests/_serve_script.py) — unequal lengths, admitted at different
+    iterations, greedy and seeded."""
+    from _serve_script import (SAMPLING, drive, sequential_tokens,
+                               staggered_script)
+    model, params, _ = tiny
+
+    def build():
+        return Engine(model, {"params": params}, ServeConfig(
+            slots=3, queue_max=8, prefill_buckets=(8, 24),
+            kv_page_tokens=4, prefix_cache=False, emit_every_s=0.0))
+
+    script = staggered_script(SAMPLING[sampling], VOCAB)
+    want = sequential_tokens(build(), script)
+    engine = build()
+    reqs = drive(engine, script)
+    assert [r.tokens for r in reqs] == want
+    assert [len(t) for t in want] == [9, 4, 12, 6]
+    snap = engine.registry.snapshot()
+    assert snap["serve_decode_steps_overlapped_total"] \
+        >= snap["serve_decode_steps_total"] - 4 - 1
+    assert snap.get("serve_decode_rows_discarded_total", 0) == 0
+
+
 # -- the expert layer's share --------------------------------------------------
 
 def _layer(held):

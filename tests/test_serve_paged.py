@@ -17,6 +17,8 @@ spill/warm-start round trip. One engine here serves over a mesh
 prefill path left.
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -27,6 +29,9 @@ from tpunet.config import ModelConfig, ServeConfig
 from tpunet.models import create_model, init_variables
 from tpunet.models.lm import filter_logits, generate
 from tpunet.serve import Engine, GenerateRequest, PromptTooLongError
+
+from _serve_script import (SAMPLING, drive, sequential_tokens,
+                           staggered_script)
 
 TINY = ModelConfig(name="lm", vit_hidden=32, vit_depth=2, vit_heads=2,
                    dropout_rate=0.0, dtype="float32", vocab_size=31,
@@ -334,9 +339,10 @@ def _spy_prefill_calls(eng):
         return [np.asarray(leaf)
                 for leaf in jax.tree_util.tree_leaves(cache)]
 
-    def spy(toks, positions, active, last_idx, slot_i=None):
+    def spy(toks, positions, active, last_idx, slot_i=None, **kw):
         if toks.shape[1] == 1:
-            return dispatch(toks, positions, active, last_idx, slot_i)
+            return dispatch(toks, positions, active, last_idx, slot_i,
+                            **kw)
         before = pool(eng._cache) if slot_i is not None else None
         out = dispatch(toks, positions, active, last_idx, slot_i)
         frozen = None
@@ -428,7 +434,7 @@ def test_program_texts_have_the_rows_the_engine_dispatches(tiny_lm,
 @MESHES
 def test_step_avals_state_the_masked_steps_signature(tiny_lm, build):
     """``_step_avals`` is the one statement of ``_masked_step``'s
-    signature: twelve entries in its parameters' order, ``slots`` rows
+    signature: fourteen entries in its parameters' order, ``slots`` rows
     at width 1 and ``_prefill_rows`` at a bucket — and the jit program
     traces at exactly those shapes."""
     import inspect
@@ -436,7 +442,7 @@ def test_step_avals_state_the_masked_steps_signature(tiny_lm, build):
     names = list(inspect.signature(eng._step).parameters)
     assert names == ["params", "cache", "tokens", "positions", "active",
                      "page_table", "last_idx", "temp", "top_k", "top_p",
-                     "seeds", "steps"]
+                     "seeds", "steps", "prev", "from_prev"]
     for width, rows in ((1, eng.slots),
                         (16, 1 if eng.mesh is None else eng.slots)):
         avals = eng._step_avals(width)
@@ -444,7 +450,8 @@ def test_step_avals_state_the_masked_steps_signature(tiny_lm, build):
         by_name = dict(zip(names, avals))
         assert by_name["tokens"].shape == (rows, width)
         assert by_name["page_table"].shape == (rows, eng.pages_per_slot)
-        assert by_name["active"].dtype == bool
+        assert by_name["active"].dtype == by_name["from_prev"].dtype \
+            == bool
         for name in names[3:5] + names[6:]:
             assert by_name[name].shape == (rows,), name
         assert [by_name[n].dtype for n in ("temp", "top_p")] == \
@@ -1165,3 +1172,291 @@ def test_spec_serve_record_and_instruments(tiny_lm):
     assert rec["spec_accepted_tokens_per_verify"] > 0
     assert eng.registry.snapshot()[
         "serve_spec_acceptance_rate"] == 1.0
+
+
+# ---------------------------------------------------------------------------
+# one decode step in flight (PR 32): step N+1 is dispatched before step
+# N's tokens are read. The engine is driven on the test's thread
+# (tests/_serve_script.py), so each script is one fixed order of calls.
+# ---------------------------------------------------------------------------
+
+@MESHES
+@pytest.mark.parametrize("sampling", sorted(SAMPLING))
+def test_lookahead_tokens_equal_the_sequential_loops(tiny_lm, build,
+                                                     sampling):
+    """The engine's tokens, one step ahead of the host, are bit-equal
+    to a loop that reads every step before it builds the next — greedy
+    and seeded rows alike (the sampler's step comes from the slot's own
+    count, which a token in flight has already advanced)."""
+    script = staggered_script(SAMPLING[sampling], TINY.vocab_size)
+    want = sequential_tokens(build(tiny_lm, slots=3, prefix_cache=False),
+                             script)
+    eng = build(tiny_lm, slots=3)
+    reqs = drive(eng, script)
+    assert [r.tokens for r in reqs] == want
+    assert [len(t) for t in want] == [9, 4, 12, 6]
+    assert all(r.finish_reason == "length" for r in reqs)
+    snap = eng.registry.snapshot()
+    assert snap["serve_tokens_total"] == sum(len(t) for t in want)
+    assert snap["serve_decode_steps_overlapped_total"] > 0
+    assert snap.get("serve_decode_rows_discarded_total", 0) == 0
+    assert eng._in_flight is None and _pool_clean(eng)
+    # one program a width: the forwarded tokens are a device array
+    # from the first call on (a mesh engine traces its bucket-8 program
+    # twice, for the pool as built and as a step returns it, with or
+    # without a step in flight)
+    assert eng._step._cache_size() == (3 if eng.mesh is None else 4)
+
+
+def _stream(tiny_lm, prompt, n_new, **kw):
+    eng = make_engine(tiny_lm, **kw)
+    (req,) = drive(eng, [(0, prompt, dict(max_new_tokens=n_new))])
+    return req.tokens
+
+
+def _first_seen_at(stream, lo):
+    """An index >= lo whose token did not occur before it."""
+    return next(k for k in range(lo, len(stream))
+                if stream[k] not in stream[:k])
+
+
+def test_stop_token_ends_the_stream_and_discards_the_row_ahead(tiny_lm):
+    """A stop token read from step N was sampled after step N+1 went
+    out with the row live: the stream ends at the stop token, that row
+    of N+1 is counted as discarded and never pushed, the slot's pages
+    return to the free list once, and the next request admitted into
+    them serves what it serves alone."""
+    p, q = prompts(2, rng_seed=5, lo=5, hi=6)
+    alone = _stream(tiny_lm, p, 12, slots=1, prefix_cache=False)
+    k = _first_seen_at(alone, 2)
+    eng = make_engine(tiny_lm, slots=1, prefix_cache=False)
+    reqs = drive(eng, [(0, p, dict(max_new_tokens=12,
+                                   stop_token=alone[k])),
+                       (1, q, dict(max_new_tokens=8))])
+    assert reqs[0].tokens == alone[:k + 1]
+    assert reqs[0].finish_reason == "stop"
+    snap = eng.registry.snapshot()
+    assert snap["serve_decode_rows_discarded_total"] == 1
+    assert snap["serve_tokens_total"] == k + 1 + 8
+    assert sorted(eng._free_pages) == \
+        list(range(1, eng.kv_pages_usable + 1))
+    assert reqs[1].tokens == solo_greedy(tiny_lm, q, 8)
+
+
+@pytest.mark.parametrize("how,reason", [("cancel", "cancelled"),
+                                        ("deadline", "deadline")])
+def test_cancel_and_deadline_mid_stream_discard_the_row_in_flight(
+        tiny_lm, how, reason):
+    """A cancel or a deadline is seen at the reap after a step went out
+    with the row live: the request ends with what had been pushed, the
+    row in flight is discarded, the neighbour's stream is untouched."""
+    p, q = prompts(2, rng_seed=6, lo=4, hi=8)
+    eng = make_engine(tiny_lm, slots=2, prefix_cache=False)
+
+    def after(k, reqs):
+        if k == 3:
+            assert eng._in_flight is not None
+            if how == "cancel":
+                reqs[0].cancel()
+            else:
+                reqs[0].deadline_t = time.perf_counter()
+
+    reqs = drive(eng, [(0, p, dict(max_new_tokens=20)),
+                       (0, q, dict(max_new_tokens=9))], after=after)
+    assert reqs[0].finish_reason == reason
+    got = reqs[0].tokens
+    assert 0 < len(got) < 20 and got == solo_greedy(tiny_lm, p, 20)[:len(got)]
+    assert reqs[1].tokens == solo_greedy(tiny_lm, q, 9)
+    snap = eng.registry.snapshot()
+    assert snap["serve_decode_rows_discarded_total"] == 1
+    assert snap["serve_tokens_total"] == len(got) + 9
+    assert _pool_clean(eng)
+
+
+def test_finish_at_max_seq_len_is_known_at_dispatch(tiny_lm):
+    """A request that runs into the KV length ends by count: its last
+    token's row is not dispatched again, nothing is discarded, and the
+    tokens are the sequential loop's."""
+    p = prompts(1, rng_seed=7, lo=14, hi=15)[0]
+    script = [(0, p, dict(max_new_tokens=200))]
+    want = sequential_tokens(make_engine(tiny_lm, prefix_cache=False),
+                             script)
+    eng = make_engine(tiny_lm)
+    (req,) = drive(eng, script)
+    assert req.tokens == want[0]
+    assert len(req.tokens) == TINY.max_seq_len - p.size
+    assert req.finish_reason == "length"
+    snap = eng.registry.snapshot()
+    assert snap.get("serve_decode_rows_discarded_total", 0) == 0
+    assert snap["serve_decode_steps_total"] == len(req.tokens) - 1
+
+
+def test_preemption_with_a_step_in_flight_resumes_token_identically(
+        tiny_lm):
+    """Page pressure with a step in flight: the step is read before the
+    victim is re-queued (its prompt + EVERY generated token), so the
+    resumed request's tokens equal an unpreempted run's."""
+    eng = make_engine(tiny_lm, slots=2, kv_pages=5, kv_page_tokens=4,
+                      default_max_new_tokens=12)
+    ps = prompts(4, rng_seed=1, lo=6, hi=7)
+    preempted_with_flight = []
+    preempt = eng._preempt_slot
+
+    def spy(slot_i):
+        preempted_with_flight.append(eng._in_flight is not None)
+        preempt(slot_i)
+
+    eng._preempt_slot = spy
+    reqs = drive(eng, [(0, p, dict(max_new_tokens=12)) for p in ps])
+    assert [r.tokens for r in reqs] == \
+        [solo_greedy(tiny_lm, p, 12) for p in ps]
+    assert preempted_with_flight and not any(preempted_with_flight)
+    assert sum(r.preemptions for r in reqs) >= 1
+    assert eng.registry.snapshot()["serve_tokens_total"] == 4 * 12
+
+
+def test_shared_prefix_is_unchanged_by_a_neighbours_overrun(tiny_lm):
+    """Prefix cache on: a stop-token overrun in a neighbouring slot
+    writes one K/V row past that slot's stream, on a private page —
+    the adopted pages of a shared prefix hold the same bits after it,
+    and a later request behind that prefix serves what it serves
+    alone."""
+    rng = np.random.default_rng(8)
+    shared = rng.integers(0, TINY.vocab_size, size=8).astype(np.int32)
+    first = np.concatenate([shared, [3, 4]]).astype(np.int32)
+    later = np.concatenate([shared, [5]]).astype(np.int32)
+    other = prompts(1, rng_seed=9, lo=6, hi=7)[0]
+    alone = _stream(tiny_lm, other, 10, slots=1, kv_page_tokens=4)
+    k = _first_seen_at(alone, 2)
+    eng = make_engine(tiny_lm, slots=2, kv_page_tokens=4)
+    bits = {}
+
+    def after(k_iter, reqs):
+        if k_iter == 0:
+            bits["before"] = [eng._read_page_rows(n.page)
+                              for n in eng._active[0].pinned]
+
+    reqs = drive(eng, [(0, first, dict(max_new_tokens=14)),
+                       (0, other, dict(max_new_tokens=10,
+                                       stop_token=alone[k])),
+                       (40, later, dict(max_new_tokens=6))], after=after)
+    assert len(bits["before"]) == 2              # two adopted pages
+    pinned = eng._prefix.lookup(later, 2)
+    after_bits = [eng._read_page_rows(n.page) for n in pinned]
+    for b, a in zip(bits["before"], after_bits):
+        assert all(np.array_equal(x, y) for x, y in zip(b, a))
+    assert reqs[1].tokens == alone[:k + 1]
+    snap = eng.registry.snapshot()
+    assert snap["serve_decode_rows_discarded_total"] == 1
+    assert snap["serve_prefix_hits_total"] >= 1
+    assert reqs[0].tokens == solo_greedy(tiny_lm, first, 14)
+    assert reqs[2].tokens == solo_greedy(tiny_lm, later, 6)
+
+
+@pytest.mark.parametrize("how,reason", [("drain", "drain"),
+                                        ("stop", "cancelled")])
+def test_drain_and_stop_push_the_step_in_flight_once(tiny_lm, how, reason):
+    """``drain`` and ``stop`` read the step in flight before they
+    finish the survivors: every token the device computed for an
+    unfinished request is pushed, exactly once."""
+    ps = prompts(3, rng_seed=10)
+    eng = make_engine(tiny_lm, prefix_cache=False)
+    reqs = [eng.submit(p, max_new_tokens=30) for p in ps]
+    for _ in range(4):
+        eng._iterate()
+    assert eng._in_flight is not None
+    sampled = [s.generated for s in eng._active[:3]]
+    assert [len(r.tokens) for r in reqs] == [n - 1 for n in sampled]
+    if how == "drain":
+        assert eng.drain(timeout=0.0) is False
+    else:
+        eng.stop()
+    assert eng._in_flight is None
+    for p, r, n in zip(ps, reqs, sampled):
+        assert r.finish_reason == reason
+        assert r.tokens == solo_greedy(tiny_lm, p, 30)[:n]
+        events = [v for kind, v in r.events(timeout=5) if kind == "token"]
+        assert events == r.tokens
+    assert eng.registry.snapshot()["serve_tokens_total"] == sum(sampled)
+
+
+def test_a_call_in_flight_owns_its_host_buffers(tiny_lm):
+    """The allocator rewrites ``_page_table`` in place while a call may
+    still be in flight (and the CPU backend may alias host memory): no
+    numpy argument of a dispatch shares memory with engine state, and
+    a table zeroed right after the dispatch changes no token."""
+    p, q = prompts(2, rng_seed=11, lo=5, hi=9)
+    eng = make_engine(tiny_lm, slots=2, prefix_cache=False)
+    step, shared = eng._step, []
+
+    def spy(*args):
+        shared.extend(
+            a for a in args if isinstance(a, np.ndarray)
+            and any(np.shares_memory(a, mine) for mine in
+                    (eng._page_table, eng._inactive_tok)))
+        return step(*args)
+
+    eng._step = spy
+    reqs = [eng.submit(x, max_new_tokens=8) for x in (p, q)]
+    eng._iterate()                       # prefill both, dispatch step 1
+    for _ in range(3):
+        eng._iterate()                   # dispatch N+1, read N
+        assert eng._in_flight is not None
+        table = eng._page_table.copy()
+        eng._page_table[:] = 0           # N+1 is in flight
+        eng._drain_decode()
+        eng._page_table[:] = table
+    while not all(r.done for r in reqs):
+        eng._iterate()
+    assert not shared
+    assert [r.tokens for r in reqs] == [solo_greedy(tiny_lm, x, 8)
+                                        for x in (p, q)]
+
+
+def test_one_decode_span_a_step_and_the_overlapped_share(tiny_lm,
+                                                         monkeypatch):
+    """On a fixed script: exactly one ``tpunet/serve_decode`` span per
+    ``serve_decode_steps_total``, each read inside a
+    ``tpunet/serve_decode_wait`` span, and every step overlapped but
+    the first after an admission (or a drain)."""
+    import contextlib
+    from tpunet.serve import engine as engine_mod
+    from tpunet.serve.engine import build_serve_record
+
+    opened, depth = [], []
+
+    @contextlib.contextmanager
+    def counting(name):
+        opened.append((name, tuple(depth)))
+        depth.append(name)
+        try:
+            yield
+        finally:
+            depth.pop()
+
+    monkeypatch.setattr(engine_mod, "_ring_span", counting)
+    script = staggered_script(SAMPLING["greedy"], TINY.vocab_size)
+    eng = make_engine(tiny_lm, slots=3, prefix_cache=False)
+    reqs = drive(eng, script)
+    snap = eng.registry.snapshot()
+    steps = int(snap["serve_decode_steps_total"])
+    admissions = int(snap["serve_prefills_total"])
+    assert admissions == 4
+    names = [n for n, _ in opened]
+    assert names.count("tpunet/serve_decode") == steps
+    assert names.count("tpunet/serve_decode_wait") == steps
+    # a read happens under the dispatch of the next step, behind a
+    # prefill call, or alone where nothing is left to dispatch
+    assert {under[-1:] for n, under in opened
+            if n == "tpunet/serve_decode_wait"} <= {
+        ("tpunet/serve_decode",), ("tpunet/serve_prefill",), ()}
+    overlapped = int(snap["serve_decode_steps_overlapped_total"])
+    assert steps - admissions - 1 <= overlapped < steps
+    assert overlapped / steps >= 0.75
+    assert int(snap["serve_tokens_total"]) == \
+        sum(len(r.tokens) for r in reqs)
+    rec = build_serve_record(eng.registry, queue_depth=0, active_slots=0,
+                             slots=3, uptime_s=1.0, window_s=1.0)
+    assert rec["decode_steps_overlapped_total"] == overlapped
+    assert rec["decode_rows_discarded_total"] == 0
+    assert rec["token_latency_count"] == steps

@@ -95,7 +95,8 @@ def _step_inputs(eng, width, seed):
             np.zeros(rows, np.int32), np.ones(rows, bool), table,
             np.full(rows, width - 1, np.int32), np.zeros(rows, np.float32),
             np.zeros(rows, np.int32), np.zeros(rows, np.float32),
-            np.zeros(rows, np.int32), np.zeros(rows, np.int32))
+            np.zeros(rows, np.int32), np.zeros(rows, np.int32),
+            np.zeros(rows, np.int32), np.zeros(rows, bool))
 
 
 def _every_leaf_rounded(params):

@@ -36,6 +36,15 @@ LRU-evicts. With ``--prefix-store`` the pages spill to a shared
 filesystem (fsatomic first-writer-wins) and a respawned replica warms
 from the fleet's prefix set at boot.
 
+The decode loop keeps ONE STEP IN FLIGHT: it dispatches step N+1
+before it reads step N's sampled tokens, and N+1 takes its continuing
+rows' tokens from N's output on the device, so the host's work between
+two steps (pushing tokens, reaping, admitting, the next arguments)
+runs beside the device instead of in front of it. Counts decide the
+bookkeeping at dispatch; what only a token's value or the clock
+decides (stop token, cancel, deadline) is seen one step late, and that
+row of the step ahead is discarded (``Engine._decode_iteration``).
+
 Sampling runs on the DEVICE: one batched temperature/top-k/top-p step
 (tpunet/serve/sampling.py, per-slot PRNG keys folded per step) is
 fused onto every masked-step program, so only sampled int32 tokens
@@ -121,6 +130,14 @@ def build_serve_record(reg, *, queue_depth: int, active_slots: int,
         "tokens_total": int(reg.counter("serve_tokens_total").value),
         "decode_steps_total": int(
             reg.counter("serve_decode_steps_total").value),
+        # Steps dispatched while the previous step's tokens were still
+        # unread (over decode_steps_total: the share of steps the host
+        # stayed off the device's path), and rows computed for a
+        # request that a stop token, cancel or deadline had ended.
+        "decode_steps_overlapped_total": int(
+            reg.counter("serve_decode_steps_overlapped_total").value),
+        "decode_rows_discarded_total": int(
+            reg.counter("serve_decode_rows_discarded_total").value),
         "prefills_total": int(
             reg.counter("serve_prefills_total").value),
         # Tokens prefill calls embedded for requests / tokens they
@@ -234,6 +251,10 @@ def build_aot_store(directory: str, model_cfg, serve_cfg):
         # (tpunet/serve/resident.py), not the given one. A store
         # written for the given tree's types misses whole.
         "params": "resident",
+        # The step takes a row's token from the previous step's output
+        # or from the host (``prev``, ``from_prev``): a store written
+        # for the twelve-argument step misses whole.
+        "step": "tokens forwarded",
         # Spec-decode levers select a different program SET (drafter
         # width changes the drafter executables, K changes the verify
         # width): spec-on and spec-off engines must never share blobs.
@@ -259,16 +280,34 @@ class _Slot:
                  "pinned", "seq")
 
     def __init__(self, req: GenerateRequest, pos: int, next_token: int,
-                 generated: int = 1, seq: int = 0):
+                 generated: int = 0, seq: int = 0):
         self.req = req
-        self.pos = pos            # next cache write position
-        self.next_token = next_token
-        self.generated = generated  # tokens produced (resume-aware)
+        self.pos = pos            # next cache write position, counted
+        #                           at DISPATCH: a step in flight has
+        #                           already advanced it
+        self.next_token = next_token  # last token READ for this slot
+        self.generated = generated  # tokens sampled for the request,
+        #                             read or in flight (resume-aware):
+        #                             the sampler's next step
         self.pages: List[int] = []  # PRIVATE paged-KV pages (table
         #                             indices from len(pinned) up)
         self.pinned: List = []    # prefix-cache nodes this slot maps
         #                           read-only (table indices 0..k-1)
         self.seq = seq            # admission ordinal (preempt youngest)
+
+
+class _Step:
+    """One dispatched decode step whose sampled tokens the host has
+    not read yet."""
+
+    __slots__ = ("sampled", "rows", "t0")
+
+    def __init__(self, sampled, rows, t0: float):
+        self.sampled = sampled    # [slots] int32, on the device
+        self.rows = rows          # [(slot_i, slot, last)]: ``last`` =
+        #                           this token ends the request by its
+        #                           length, known at dispatch
+        self.t0 = t0              # host clock when the dispatch began
 
 
 class Engine:
@@ -279,6 +318,16 @@ class Engine:
     created sharded over the mesh 'model' axis to match the attention's
     head-sharded writes). The engine owns a single background thread;
     ``submit`` is thread-safe and non-blocking (bounded queue).
+
+    The thread's loop is reap -> admit (prefill) -> decode, and the
+    decode keeps one step in flight (``_decode_iteration``): a step is
+    dispatched before the previous one's tokens are read, so a token
+    can be "sampled" (counted in ``slot.generated``, its K/V position
+    taken) one step before it is "read" (pushed to the request). The
+    drain points — preemption, ``drain``/``stop``, the error path, the
+    final record, the spec cycle — read the step in flight first
+    (``_drain_decode``); a row computed for a request that a stop
+    token, cancel or deadline had already ended is discarded there.
     """
 
     def __init__(self, model, variables, cfg, *, registry=None,
@@ -458,7 +507,13 @@ class Engine:
 
         def _masked_step(params, cache, tokens, positions, active,
                          page_table, last_idx, temp, top_k, top_p,
-                         seeds, steps):
+                         seeds, steps, prev, from_prev):
+            # A row that continues takes the token the previous decode
+            # step sampled for it straight from that step's output,
+            # which the host may not have read yet; ``tokens`` carries
+            # what only the host knows (a prompt, a slot just
+            # prefilled or resumed).
+            tokens = jnp.where(from_prev[:, None], prev[:, None], tokens)
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, tokens, train=False,
                 decode=True, pos_offset=positions, decode_active=active,
@@ -491,6 +546,22 @@ class Engine:
             self.registry.gauge(name).set(value)
         self._inactive_tok = np.zeros((self.slots, 1), np.int32)
         self._zero_idx = np.zeros((self.slots,), np.int32)
+        # -- one decode step in flight (docs/serving.md) ---------------
+        # The plain decode loop dispatches step N+1 before it reads
+        # step N's tokens. ``_in_flight`` is the step dispatched and
+        # not yet read; ``_sampled`` the newest decode step's [slots]
+        # output on the device, which the next step takes its
+        # continuing rows' tokens from (a device array from the first
+        # call on, so the program is compiled for one kind of input).
+        self._in_flight: Optional[_Step] = None
+        self._last_read_t = 0.0
+        replicated = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
+            replicated = NamedSharding(mesh, P())
+        self._sampled = jnp.zeros((self.slots,), jnp.int32,
+                                  device=replicated)
         if self._drafter_model is not None:
             self._draft_cache = self._make_cache(
                 model=self._drafter_model,
@@ -553,7 +624,7 @@ class Engine:
         return self.slots if width == 1 else self._prefill_rows
 
     def _step_avals(self, width: int) -> list:
-        """``_masked_step``'s twelve arguments at token width
+        """``_masked_step``'s fourteen arguments at token width
         ``width``, as shapes: the one statement of its signature."""
         import jax
 
@@ -568,7 +639,9 @@ class Engine:
                 i32(n, self.pages_per_slot),            # page_table
                 i32(n),                                 # last_idx
                 f32(n), i32(n), f32(n),                 # temp, top_k, top_p
-                i32(n), i32(n)]                         # seeds, steps
+                i32(n), i32(n),                         # seeds, steps
+                i32(n),                                 # prev
+                jax.ShapeDtypeStruct((n,), bool)]       # from_prev
 
     def program_texts(self) -> dict:
         """``{label: optimized HLO text}`` of the masked step at each
@@ -664,27 +737,44 @@ class Engine:
             self._spec_aot[(name, tag)] = program
 
     def _dispatch_step(self, toks, positions, active, last_idx,
-                       slot_i=None):
-        """Run one masked-step program: the AOT executable for this
-        token width when warm-started, the jit fallback otherwise.
-        Batch row i is slot i, or — ``slot_i`` given — the call's one
-        row is that slot (a [1, bucket] prefill). Returns (cache,
-        sampled tokens)."""
+                       slot_i=None, from_prev=None):
+        """Dispatch one masked-step program: the AOT executable for
+        this token width when warm-started, the jit fallback
+        otherwise. Batch row i is slot i, or — ``slot_i`` given — the
+        call's one row is that slot (a [1, bucket] prefill). Rows set
+        in ``from_prev`` (decode only) take their token from the
+        newest decode step's output on the device, not from ``toks``.
+        Returns (cache, sampled tokens) without waiting for either.
+
+        The call may still be in flight when the host next touches its
+        own state, so every numpy argument is a buffer of the call's
+        own: the page table is copied (the allocator rewrites
+        ``_page_table`` in place, and the CPU backend may alias host
+        memory), the rest are built per call."""
         program = self._aot.get(toks.shape[1])
         if program is None:
             program = self._step
+        rows = toks.shape[0]
+        if from_prev is None:
+            prev = np.zeros((rows,), np.int32)
+            from_prev = np.zeros((rows,), bool)
+        else:
+            prev = self._sampled
+        table = (self._page_table if slot_i is None
+                 else self._page_table[slot_i:slot_i + 1]).copy()
         return program(
             self.variables["params"], self._cache, toks, positions,
-            active, (self._page_table if slot_i is None
-                     else self._page_table[slot_i:slot_i + 1]),
-            *self._sampling_args(last_idx, slot_i))
+            active, table, *self._sampling_args(last_idx, slot_i),
+            prev, from_prev)
 
     def _sampling_args(self, last_idx, slot_i=None):
         """Per-row sampling parameters for the fused device sampler:
         temperature/top-k/top-p/seed from each resident request, plus
-        each slot's generated-token count (the per-step key fold — a
+        each slot's sampled-token count (the per-step key fold — a
         preempted-and-resumed request continues its exact sample
-        stream). One row per slot, or the one row of ``slot_i``."""
+        stream). The count is the SLOT's, not ``len(req.tokens)``: a
+        token in flight is sampled and not yet pushed. One row per
+        slot, or the one row of ``slot_i``."""
         slots = (self._active if slot_i is None
                  else self._active[slot_i:slot_i + 1])
         n = len(slots)
@@ -701,7 +791,7 @@ class Engine:
             top_k[i] = r.top_k
             top_p[i] = r.top_p
             seeds[i] = r.seed    # admission-validated into [0, 2**31)
-            steps[i] = len(r.tokens)
+            steps[i] = slot.generated
         return [np.asarray(last_idx, np.int32), temp, top_k, top_p,
                 seeds, steps]
 
@@ -1147,7 +1237,9 @@ class Engine:
         blocked request back to the HEAD of the queue with its
         progress intact (tokens already streamed stay valid; on
         re-admission the engine re-prefills prompt+generated and the
-        sample stream continues at its per-step key fold)."""
+        sample stream continues at its per-step key fold). Called
+        with no decode step in flight: ``req.tokens`` is everything
+        the device has sampled."""
         slot = self._active[slot_i]
         self._active[slot_i] = None
         self._release_pages(slot_i, slot)
@@ -1290,8 +1382,11 @@ class Engine:
 
     def _kill_survivors(self, reason: str) -> None:
         """Finish every in-flight and still-queued request with
-        ``reason``, through the shared accounting. Only safe from the
-        engine thread, or once it can no longer run."""
+        ``reason``, through the shared accounting — after the decode
+        step in flight has been read, so every token the device
+        computed for an unfinished request is pushed, once. Only safe
+        from the engine thread, or once it can no longer run."""
+        self._drain_decode()
         for i, slot in enumerate(self._active):
             if slot is not None:
                 self._finish_slot(i, reason)
@@ -1392,6 +1487,14 @@ class Engine:
             # request fast rather than hanging clients.
             self.error = f"{type(e).__name__}: {e}"
             flightrec.record("serve", f"engine error: {e}")
+            try:
+                # Tokens of a step that did finish reach their clients
+                # before the error does.
+                self._drain_decode()
+            except Exception:  # noqa: BLE001 — the step in flight is
+                # what failed, or fell with the device: the requests
+                # fail below either way.
+                self._in_flight = None
             for slot in self._active:
                 if slot is not None:
                     slot.req.finish(FINISH_ERROR, error=self.error)
@@ -1401,8 +1504,11 @@ class Engine:
             self._drained.set()
 
     def _iterate(self) -> bool:
-        """One engine iteration: reap -> admit(prefill) -> decode.
-        Returns False when there was nothing to do (caller sleeps)."""
+        """One engine iteration: reap -> admit(prefill) -> decode
+        (dispatch the next step, read the one in flight: the reap and
+        the admission of the NEXT iteration then run beside the step
+        just dispatched). Returns False when there was nothing to do
+        (caller sleeps)."""
         if self._drain_kill.is_set():
             # Drain timeout expired: everything still alive finishes
             # with reason 'drain' (the shutdown took it, not a client).
@@ -1631,7 +1737,7 @@ class Engine:
             # requests.
             self._admit_seq += 1
             slot = _Slot(req, pos=int(resume.size), next_token=0,
-                         generated=len(req.tokens) + 1,
+                         generated=len(req.tokens),
                          seq=self._admit_seq)
             slot.pages = pages
             slot.pinned = pinned
@@ -1695,6 +1801,15 @@ class Engine:
         with _ring_span("tpunet/serve_prefill"):
             self._cache, sampled = self._dispatch_step(
                 toks, positions, active, last_idx, slot_i)
+            for s_i, *_ in group:
+                self._active[s_i].generated += 1
+            # The call queues behind a decode step in flight (the pool
+            # orders them on the device). That step finishes first:
+            # read it first, so its tokens reach their clients when it
+            # ends and not a prefill later. This call's own first
+            # token is then read synchronously — one bubble a call,
+            # refilled by the next decode dispatch.
+            self._drain_decode()
             sampled = np.asarray(sampled)
         reg = self.registry
         # Adopt freshly-written full prompt pages into the prefix
@@ -1773,34 +1888,73 @@ class Engine:
                 (self._drafter_params, self._draft_cache, toks,
                  positions, active, self._page_table[:, :win]))
 
-    def _slot_maybe_finish(self, slot_i: int, token: int) -> bool:
-        """Stop checks after a sampled token; True when the slot was
+    def _ends_by_length(self, slot: _Slot) -> bool:
+        """Whether the token the slot's newest dispatch samples is its
+        request's last by count: the budget is spent or the KV length
+        is. Counts alone decide it, so it is known at dispatch."""
+        return (slot.generated >= slot.req.max_new_tokens
+                or slot.pos + 1 > self.max_seq_len)
+
+    def _slot_maybe_finish(self, slot_i: int, token: int,
+                           last: Optional[bool] = None) -> bool:
+        """Stop checks after a token was read and pushed; ``last`` is
+        ``_ends_by_length`` as of the dispatch that sampled it (left
+        out by a caller that reads each token before it dispatches
+        again: the counts as they stand). True when the slot was
         freed."""
         slot = self._active[slot_i]
         req = slot.req
         if req.stop_token is not None and token == req.stop_token:
             self._finish_slot(slot_i, FINISH_STOP)
             return True
-        if slot.generated >= req.max_new_tokens \
-                or slot.pos + 1 > self.max_seq_len:
+        if self._ends_by_length(slot) if last is None else last:
             self._finish_slot(slot_i, FINISH_LENGTH)
             return True
         return False
 
     def _decode_iteration(self) -> bool:
-        """One masked decode step across the whole pool: every active
-        slot consumes its pending token at its own position and samples
-        the next one (fused on the device). Each
-        slot's next write page is allocated here (allocate-on-advance);
-        a slot the pool cannot extend sits the iteration out, and when
-        NOTHING can advance the youngest blocked slot is preempted back
-        to the queue so the others drain and free pages."""
+        """One masked decode step across the whole pool, ONE STEP
+        AHEAD of the host: dispatch step N+1, then read step N.
+
+        Every active slot consumes its pending token at its own
+        position and samples the next one (fused on the device). What
+        a step needs of its predecessor that the host does not already
+        know is each row's sampled token, and that is on the device:
+        a continuing row takes it from there (``_masked_step``'s
+        ``prev``), so the host's work for step N+2 — pushing N's
+        tokens, ``_reap``, ``_admit``, page capacity, the arguments —
+        runs while N+1 does. Counts, not token values, decide the
+        bookkeeping (``slot.pos``, ``slot.generated``, the sampler's
+        step, the length finish), so it is done at dispatch. A row
+        whose LAST token is in flight is not dispatched again; it
+        keeps its slot and pages until that token is read and pushed.
+
+        What only a token's value or the clock decides — a stop token,
+        a cancel, a deadline — is seen after N+1 went out with the row
+        live. That row of N+1 is DISCARDED when read: never pushed,
+        never counted. The slot's pages were released by host code
+        that ran after N+1's dispatch, so whatever program next writes
+        them runs after N+1 on the device, and the one K/V row N+1
+        wrote there is padding under ``_prefill_call``'s masked
+        invariant. It cannot lie in a page the prefix cache adopted:
+        the cache adopts only full pages of the PROMPT, decode writes
+        at positions past the prompt, on the slot's private pages.
+
+        Each slot's next write page is allocated here
+        (allocate-on-advance); a slot the pool cannot extend sits the
+        iteration out, and when NOTHING can advance the youngest
+        blocked slot is preempted back to the queue so the others
+        drain and free pages — after the step in flight has been read:
+        preemption re-queues prompt + generated and needs every token
+        on the host."""
         if self._drafter_model is not None:
             return self._spec_decode_iteration()
+        flight = self._in_flight
+        # A resident slot that ends by its counts has its last token in
+        # flight: a read would have freed it (counts move at dispatch
+        # only, and a prefill frees such a slot itself).
         live = [(i, s) for i, s in enumerate(self._active)
-                if s is not None]
-        if not live:
-            return False
+                if s is not None and not self._ends_by_length(s)]
         ready = []
         blocked = []
         for i, slot in live:
@@ -1808,46 +1962,96 @@ class Engine:
                 ready.append((i, slot))
             else:
                 blocked.append((i, slot))
-        if blocked and not ready:
-            self._preempt_slot(self._choose_preempt_victim(blocked))
-            return True              # freed pages; retry next iteration
+        if not ready:
+            if flight is not None:
+                # Nothing to dispatch behind it: its rows end with it,
+                # or wait for the pages it may free.
+                self._drain_decode()
+                return True
+            if blocked:
+                self._preempt_slot(self._choose_preempt_victim(blocked))
+                return True          # freed pages; retry next iteration
+            return False
         self._update_kv_gauges()
         self._decode_width1(ready)
         return True
 
     def _decode_width1(self, live) -> None:
-        """One [slots, 1] masked decode call for ``live`` slots (page
-        capacity already ensured by the caller). Shared by the normal
-        path and the spec path's tail fallback."""
+        """Dispatch one [slots, 1] masked decode call for ``live``
+        slots (page capacity already ensured by the caller), then read
+        the step that was in flight before it; the new one stays in
+        flight. One ``tpunet/serve_decode`` span per dispatched step
+        covers both. The spec path's tail fallback reads its step at
+        once (``_drain_decode``): that cycle stays synchronous."""
         t0 = time.perf_counter()
+        flight = self._in_flight
+        ahead = ({i: slot for i, slot, _ in flight.rows}
+                 if flight is not None else {})
         toks = self._inactive_tok.copy()
         positions = np.zeros((self.slots,), np.int32)
         active = np.zeros((self.slots,), bool)
+        from_prev = np.zeros((self.slots,), bool)
         for i, slot in live:
-            toks[i, 0] = slot.next_token
+            if ahead.get(i) is slot:
+                from_prev[i] = True      # its token is still unread
+            else:
+                toks[i, 0] = slot.next_token
             positions[i] = slot.pos
             active[i] = True
-        with _ring_span("tpunet/serve_decode"):
-            self._cache, sampled = self._dispatch_step(
-                toks, positions, active, self._zero_idx)
-            sampled = np.asarray(sampled)
-        lap = time.perf_counter() - t0
         reg = self.registry
-        reg.counter("serve_decode_steps_total").inc()
+        with _ring_span("tpunet/serve_decode"):
+            self._cache, self._sampled = self._dispatch_step(
+                toks, positions, active, self._zero_idx,
+                from_prev=from_prev)
+            rows = []
+            for i, slot in live:
+                slot.pos += 1
+                slot.generated += 1
+                rows.append((i, slot, self._ends_by_length(slot)))
+            self._in_flight = _Step(self._sampled, rows, t0)
+            reg.counter("serve_decode_steps_total").inc()
+            if flight is not None:
+                reg.counter("serve_decode_steps_overlapped_total").inc()
+                self._read_decode(flight)
+
+    def _drain_decode(self) -> None:
+        """Read the decode step in flight, if there is one: every
+        token the device has computed is then on the host and pushed.
+        Called before anything that needs that (preemption, the final
+        record, the kill paths, the spec cycle) and where waiting for
+        it costs nothing (behind a prefill call, an empty pool)."""
+        flight, self._in_flight = self._in_flight, None
+        if flight is not None:
+            self._read_decode(flight)
+
+    def _read_decode(self, step: _Step) -> None:
+        """Block until ``step`` has finished, then push its tokens and
+        run the checks a token's value decides. A row whose slot was
+        freed since the dispatch (stop token, cancel, deadline) is
+        discarded."""
+        with _ring_span("tpunet/serve_decode_wait"):
+            sampled = np.asarray(step.sampled)
+        now = time.perf_counter()
+        # The period between two consecutive reads; from its own
+        # dispatch for a step that was not dispatched ahead.
+        lap = now - max(step.t0, self._last_read_t)
+        self._last_read_t = now
+        reg = self.registry
         reg.histogram("serve_decode_iter_s").observe(lap)
-        # per-token latency: the iteration produced one token for each
-        # live slot, each of which waited the full iteration.
+        # per-token latency: the step produced one token for each
+        # live slot, each of which waited that period.
         reg.histogram("serve_token_s").observe(lap)
-        for i, slot in live:
+        for i, slot, last in step.rows:
+            if self._active[i] is not slot:
+                reg.counter("serve_decode_rows_discarded_total").inc()
+                continue
             nxt = int(sampled[i])
-            slot.pos += 1
             slot.next_token = nxt
-            slot.generated += 1
             slot.req.push_token(nxt)
             reg.counter("serve_tokens_total").inc()
             if self.chaos is not None:
                 self.chaos.on_token()   # kill/stall@tokens (post-push)
-            self._slot_maybe_finish(i, nxt)
+            self._slot_maybe_finish(i, nxt, last)
 
     # -- speculative decode path (docs/serving.md) ----------------------
 
@@ -1901,6 +2105,7 @@ class Engine:
             # docstring's acceptance-vs-correctness argument.
             self._decode_width1([(i, s) for i, s in seq_ready
                                  if self._active[i] is s])
+            self._drain_decode()
         return True
 
     def _spec_burst(self, burst) -> None:
@@ -2017,7 +2222,11 @@ class Engine:
 
     def _emit_record(self, final: bool = False) -> None:
         """One ``obs_serve`` record (docs/metrics_schema.md) per window:
-        cumulative counters + window histograms, then a fresh window."""
+        cumulative counters + window histograms, then a fresh window.
+        The final record counts every token computed: the decode step
+        in flight is read first."""
+        if final:
+            self._drain_decode()
         reg = self.registry
         now = time.perf_counter()
         window = now - self._last_emit
