@@ -212,6 +212,26 @@ def test_paged_decode_compiles(one_chip, no_compile_cache, dtype):
     assert "tpunet_paged_decode" in text
 
 
+@pytest.mark.parametrize("dtype", [BF16, jnp.float32])
+def test_grouped_paged_decode_compiles(one_chip, no_compile_cache, dtype):
+    """16 query heads over 2 KV heads of 256 (qwen3-next-80b-a3b's full
+    layers) at the cell's geometry: 32 slots of 640 16-token pages, pool
+    rows 512 wide."""
+    slots, per_slot, heads, kv_heads, d = 32, 640, 16, 2, 256
+
+    def attend(q, k_pool, v_pool, table, lengths):
+        return paged_decode.paged_decode_attention(
+            q, k_pool, v_pool, table, lengths, page_tokens=PAGE_TOKENS,
+            interpret=False, kv_heads=kv_heads)
+
+    pool = (((slots * per_slot + 1) * PAGE_TOKENS,
+             paged_decode.pool_width(kv_heads, d)), dtype)
+    text = _compile(attend, ((slots, heads, d), dtype), pool, pool,
+                    ((slots, per_slot), jnp.int32), ((slots,), jnp.int32),
+                    sharding=one_chip)
+    assert "tpunet_paged_decode" in text
+
+
 @pytest.mark.parametrize("rows,width",
                          [(SLOTS, 1), (SLOTS, 128), (1, 512)])
 def test_paged_attention_layer_never_converts_the_pool(
@@ -386,4 +406,70 @@ def test_latent_attention_decode_never_converts_its_pools(
     for w, dtype in pools.items():
         pool = rf"{dtype}\[{rows},{w}\]"
         assert re.search(pool + r"\{1,0[:}]", text)       # row-major
+        assert not re.search(pool + r"\S* copy\(", text)
+
+
+# -- the hybrid decoder's two programs at published widths ---------------------
+
+V5E_BYTES_LIMIT = 16_909_336_064     # memory_stats()["bytes_limit"], one v5e
+
+
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    """The serve engine of ``qwen3-next-80b-a3b.serve-closed32-ctx8k`` as
+    the benchmark's runner builds it, never run: the 3.67 B parameters
+    are zero-stride views of one zero (their shapes and bytes are real,
+    their memory is not), the page and state pools real zeros."""
+    import numpy as np
+
+    from benchmark import harness, weights
+    from tpunet.config import ModelConfig, ServeConfig
+    from tpunet.models import create_model
+    from tpunet.serve.engine import Engine
+
+    cell = harness.load_cell("qwen3-next-80b-a3b.serve-closed32-ctx8k")
+    config = cell["config"]
+    spec = harness.load_reference(cell).param_spec(config, "serve")
+    zero = np.zeros((), jnp.bfloat16)
+    params = weights.nest({path: np.broadcast_to(zero, shape)
+                           for path, (shape, _, _) in spec.items()})
+    serve = dict(cell["cell"]["program"]["serve"])
+    serve["prefill_buckets"] = tuple(serve["prefill_buckets"])
+    return Engine(create_model(ModelConfig(**config["program"]["model"])),
+                  {"params": params}, ServeConfig(**serve))
+
+
+@pytest.mark.parametrize("width", [1, 8192])
+def test_hybrid_masked_step_fits_the_chip(one_chip, no_compile_cache,
+                                          monkeypatch, hybrid_engine, width):
+    """The engine's own masked step, ``[32, 1]`` and ``[1, 8192]``, over
+    7.33 GB of weights, 32 x 10,240 tokens of K/V pages and 32 state
+    rows: compiled for the chip with at least 1 GiB to spare; the
+    width-1 program attends through ``tpunet_paged_decode`` (grouped),
+    the bucket-wide one through the flash kernel; neither copies a page
+    pool or the state pool."""
+    import re
+
+    monkeypatch.setattr(paged_decode, "_on_tpu", lambda: True)
+    monkeypatch.setattr(paged_decode, "_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # dispatch
+    eng = hybrid_engine
+    assert eng.state_pool_bytes() == 32 * 6 * (32 * 128 * 128 + 3 * 8192) * 4
+    assert eng.kv_pool_bytes() == 2 * 2 * (32 * 640 + 1) * 16 * 512 * 2
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._step_avals(width))
+    compiled = eng._step.lower(*avals).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert total + (1 << 30) <= V5E_BYTES_LIMIT, total
+    assert m.alias_size_in_bytes >= eng.kv_pool_bytes() \
+        + eng.state_pool_bytes()                  # the pools are donated
+    text = compiled.as_text()
+    assert ("tpunet_paged_decode" in text) == (width == 1)
+    assert ("tpunet_flash_fwd" in text) == (width > 1)
+    for pool in (r"bf16\[327696,512\]", r"f32\[32,32,128,128\]",
+                 r"f32\[32,3,8192\]"):
+        assert re.search(pool, text)
         assert not re.search(pool + r"\S* copy\(", text)

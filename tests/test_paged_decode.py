@@ -52,9 +52,12 @@ def make_pool(rng, heads, head_dim, dtype, slots, garbage=0.0):
             table)
 
 
-def dense_reference(q, k_pool, v_pool, table, lengths):
-    """What the dense path computes, in float64 on the host."""
-    b, heads, head_dim = q.shape
+def dense_reference(q, k_pool, v_pool, table, lengths, kv_heads=None):
+    """What the dense path computes, in float64 on the host; grouped
+    (``kv_heads`` < the query heads), query head h reads KV head
+    ``h // group``."""
+    b, q_heads, head_dim = q.shape
+    heads = q_heads if kv_heads is None else kv_heads
     hd = heads * head_dim
     q = np.asarray(q, np.float64)
     out = np.zeros(q.shape, np.float64)
@@ -67,6 +70,7 @@ def dense_reference(q, k_pool, v_pool, table, lengths):
             n, heads, head_dim)
         v = np.asarray(v_pool, np.float64)[rows, :hd].reshape(
             n, heads, head_dim)
+        k, v = (np.repeat(x, q_heads // heads, axis=1) for x in (k, v))
         s = np.einsum("hd,khd->hk", q[i], k) * head_dim ** -0.5
         p = np.exp(s - s.max(-1, keepdims=True))
         p /= p.sum(-1, keepdims=True)
@@ -74,11 +78,42 @@ def dense_reference(q, k_pool, v_pool, table, lengths):
     return out
 
 
-def run_kernel(q, k_pool, v_pool, table, lengths):
+def run_kernel(q, k_pool, v_pool, table, lengths, **kw):
     return np.asarray(paged_decode_attention(
         q, k_pool, v_pool, jnp.asarray(table),
         jnp.asarray(lengths, jnp.int32), page_tokens=PT,
-        interpret=True), np.float64)
+        interpret=True, **kw), np.float64)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("q_heads,kv_heads,head_dim",
+                         [(16, 2, 256), (6, 3, 128), (4, 1, 128)])
+def test_grouped_heads_match_dense(q_heads, kv_heads, head_dim, dtype):
+    """Fewer KV heads than query heads over one pool row: the ragged
+    lengths of the ungrouped test, inactive rows between them, NaN in
+    the garbage page."""
+    rng = np.random.default_rng(q_heads)
+    lengths = np.array((0,) + LENGTHS + (0,), np.int32)
+    k_pool, v_pool, table = make_pool(rng, kv_heads, head_dim, dtype,
+                                      len(lengths), garbage=np.nan)
+    q = jnp.asarray(rng.normal(size=(len(lengths), q_heads, head_dim)),
+                    dtype)
+    got = run_kernel(q, k_pool, v_pool, table, lengths, kv_heads=kv_heads)
+    want = dense_reference(q, k_pool, v_pool, table, lengths, kv_heads)
+    assert got.shape == (len(lengths), q_heads, head_dim)
+    np.testing.assert_allclose(got, want, atol=TOL[dtype], rtol=0)
+    assert not got[[0, -1]].any()
+
+
+def test_grouped_heads_need_whole_groups_and_lane_wide_heads():
+    rng = np.random.default_rng(0)
+    k_pool, v_pool, table = make_pool(rng, 2, 64, "float32", 1)
+    q = jnp.zeros((1, 4, 64), jnp.float32)
+    with pytest.raises(ValueError, match="lanes"):
+        run_kernel(q, k_pool, v_pool, table, [4], kv_heads=2)
+    with pytest.raises(ValueError, match="whole"):
+        run_kernel(jnp.zeros((1, 5, 64)), k_pool, v_pool, table, [4],
+                   kv_heads=2)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

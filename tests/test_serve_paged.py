@@ -434,7 +434,7 @@ def test_program_texts_have_the_rows_the_engine_dispatches(tiny_lm,
 @MESHES
 def test_step_avals_state_the_masked_steps_signature(tiny_lm, build):
     """``_step_avals`` is the one statement of ``_masked_step``'s
-    signature: fourteen entries in its parameters' order, ``slots`` rows
+    signature: fifteen entries in its parameters' order, ``slots`` rows
     at width 1 and ``_prefill_rows`` at a bucket — and the jit program
     traces at exactly those shapes."""
     import inspect
@@ -442,7 +442,7 @@ def test_step_avals_state_the_masked_steps_signature(tiny_lm, build):
     names = list(inspect.signature(eng._step).parameters)
     assert names == ["params", "cache", "tokens", "positions", "active",
                      "page_table", "last_idx", "temp", "top_k", "top_p",
-                     "seeds", "steps", "prev", "from_prev"]
+                     "seeds", "steps", "prev", "from_prev", "state_rows"]
     for width, rows in ((1, eng.slots),
                         (16, 1 if eng.mesh is None else eng.slots)):
         avals = eng._step_avals(width)

@@ -96,7 +96,8 @@ def _step_inputs(eng, width, seed):
             np.full(rows, width - 1, np.int32), np.zeros(rows, np.float32),
             np.zeros(rows, np.int32), np.zeros(rows, np.float32),
             np.zeros(rows, np.int32), np.zeros(rows, np.int32),
-            np.zeros(rows, np.int32), np.zeros(rows, bool))
+            np.zeros(rows, np.int32), np.zeros(rows, bool),
+            np.arange(rows, dtype=np.int32))
 
 
 def _every_leaf_rounded(params):

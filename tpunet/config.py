@@ -163,6 +163,9 @@ class ModelConfig:
     # the architecture (LatentArch names them), plus ``held_experts``,
     # the routed experts whose weights this chip holds. vocab_size,
     # max_seq_len, dtype and param_dtype above apply as for "lm".
+    # ``model_type`` "qwen3_next" among them selects the hybrid family
+    # (tpunet/models/hybrid_mixers.py: linear attention with a per-slot
+    # state beside grouped-query attention).
     latent: Optional[Mapping[str, Any]] = None
     # Weight of the multi-token-prediction loss where the model has
     # such a module (the train step adds it to the next-token loss).

@@ -28,6 +28,16 @@ shared expert, the experts ``held`` by this chip).
 ``num_nextn_predict_layers`` 1 adds a multi-token-prediction module
 (``MtpModule``) that shares the trunk's embedding and head.
 
+The block takes its mixer BY LAYER KIND (``mixer_class``), and each mixer
+states what it keeps in the serve engine's cache (``cache_spec``: numbers
+a token keeps in page pools, arrays a slot keeps whatever its length).
+``model_type`` ``qwen3_next`` puts another family under this block and LM
+shell (``models/hybrid_mixers.py``; the benchmark's
+``qwen3-next-80b-a3b``, served): ``linear_attention`` layers by the gated
+delta rule, whose per-slot state lives in the engine's state pool, beside
+gated grouped-query ``full_attention`` layers; zero-centred norm weights;
+a softmax router and a gated shared expert in ``RoutedShareMlp``.
+
 Three forms of one mathematics, chosen by the call:
 
 - ``train=True`` (the ``Trainer``'s step): every row of the batch at
@@ -141,14 +151,30 @@ class LatentArch:
     held_experts: Optional[Tuple[int, ...]] = None   # None = all
     # multi-token prediction modules after the trunk (0 or 1)
     num_nextn_predict_layers: int = 0
+    # -- the hybrid family (``model_type`` "qwen3_next",
+    # models/hybrid_mixers.py): ``linear_attention`` layers by the gated
+    # delta rule beside gated grouped-query ``full_attention`` layers,
+    # zero-centred norm weights (``1 + w``), a softmax router without
+    # bias, the shared expert behind a sigmoid gate
+    model_type: Optional[str] = None
+    num_key_value_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    partial_rotary_factor: float = 1.0
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
 
     @classmethod
     def from_mapping(cls, m) -> "LatentArch":
-        known = {f.name for f in dataclasses.fields(cls)}
+        known = {f.name for f in dataclasses.fields(cls)} | {"num_experts"}
         unknown = set(m) - known
         if unknown:
             raise ValueError(f"latent_lm: unknown keys {sorted(unknown)}")
         kw = dict(m)
+        if "num_experts" in kw:          # the hybrid family's name for it
+            kw["n_routed_experts"] = kw.pop("num_experts")
         for key in ("layer_types", "held_experts"):
             if kw.get(key) is not None:
                 kw[key] = tuple(kw[key])
@@ -162,7 +188,33 @@ class LatentArch:
         if arch.num_nextn_predict_layers not in (0, 1):
             raise ValueError("latent_lm builds one multi-token-prediction "
                              "module at most")
+        if arch.model_type not in (None, "qwen3_next"):
+            raise ValueError(f"latent_lm: unknown model_type "
+                             f"{arch.model_type!r}")
+        kinds = (("full_attention", "linear_attention") if arch.hybrid
+                 else ("full_attention", "sliding_attention"))
+        if set(arch.layer_types) - set(kinds):
+            raise ValueError(f"latent_lm: layer_types of model_type "
+                             f"{arch.model_type!r} are {kinds}")
+        if arch.hybrid and (
+                not arch.num_key_value_heads or not arch.head_dim
+                or arch.num_attention_heads % arch.num_key_value_heads
+                or arch.linear_num_value_heads % arch.linear_num_key_heads):
+            raise ValueError(
+                "latent_lm: qwen3_next needs head_dim and a "
+                "num_key_value_heads that divides num_attention_heads, and "
+                "linear_num_key_heads dividing linear_num_value_heads")
         return arch
+
+    @property
+    def hybrid(self) -> bool:
+        return self.model_type == "qwen3_next"
+
+    @property
+    def norm_offset(self) -> float:
+        """What a norm adds to its weight: the hybrid family's are
+        zero-centred (``1 + w``, initial ``w`` 0)."""
+        return 1.0 if self.hybrid else 0.0
 
     def layer(self, kind: str) -> dict:
         """Sizes of one attention kind: heads, latent ranks, head dims,
@@ -190,12 +242,24 @@ def lane_rounded(width: int) -> int:
     return -(-width // _LANES) * _LANES
 
 
-def rms_norm(x, scale, eps, mult: float = 1.0, dtype=None):
+def rms_norm(x, scale, eps, mult: float = 1.0, dtype=None,
+             offset: float = 0.0):
     """RMSNorm in float32, times the constant ``mult``; the result in
-    ``dtype`` (``x``'s own by default)."""
+    ``dtype`` (``x``'s own by default). ``offset`` 1 is the zero-centred
+    weight ``1 + scale``."""
     xf = x.astype(jnp.float32)
     y = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return (y * (mult * scale.astype(jnp.float32))).astype(dtype or x.dtype)
+    scale = scale.astype(jnp.float32)
+    if offset:
+        scale = offset + scale
+    return (y * (mult * scale)).astype(dtype or x.dtype)
+
+
+def norm_param(module, name: str, n: int, arch: "LatentArch", dtype):
+    """A block or final norm's weight: ones, or zeros where the weight
+    is zero-centred."""
+    init = nn.initializers.zeros if arch.norm_offset else nn.initializers.ones
+    return module.param(name, init, (n,), dtype)
 
 
 def layer_norm(x, scale, bias, eps):
@@ -300,13 +364,20 @@ def _softmax_values(scores, keep, v):
     return jnp.swapaxes(o / jnp.sum(p, axis=-1)[..., None], 0, 1)
 
 
-def cache_widths(arch: LatentArch, kind: str) -> dict:
-    """Numbers a token keeps in one layer's cache, by cache kind."""
-    z = arch.layer(kind)
-    if kind != "full_attention":
-        return {"window": z["rkv"] + z["dr"]}
-    index = {} if z["topk"] is None else {"index": arch.index_head_dim}
-    return {"latent": z["rkv"] + z["dr"], **index}
+def mixer_class(arch: LatentArch, kind: str):
+    """The module that mixes positions in a layer of ``kind``. Every
+    mixer takes the block's normed input and the same cache arguments,
+    and states what it keeps with ``cache_spec(arch, kind, dtype)``:
+    ``paged`` = ``{cache: (numbers a token keeps in that cache's page
+    pools, lane-rounded rows as stored; their dtype)}``, ``state`` =
+    ``{name: (shape, dtype)}`` of what a SLOT keeps whatever its
+    length, ``decode_kernel`` = whether its one-token call attends
+    through ``tpunet_paged_decode`` where that kernel applies."""
+    if not arch.hybrid:
+        return LatentAttention
+    from tpunet.models import hybrid_mixers
+    return (hybrid_mixers.GatedDeltaNet if kind == "linear_attention"
+            else hybrid_mixers.GatedAttention)
 
 
 # -- the attention layer ------------------------------------------------------
@@ -321,10 +392,26 @@ class LatentAttention(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
+    @classmethod
+    def cache_spec(cls, a: LatentArch, kind: str, dtype) -> dict:
+        z = a.layer(kind)
+        row = (lane_rounded(z["rkv"] + z["dr"]), dtype)
+        if kind != "full_attention":
+            paged = {"window": row}
+        elif z["topk"] is None:
+            paged = {"latent": row}
+        else:
+            paged = {"latent": row,
+                     "index": (lane_rounded(a.index_head_dim), jnp.float32)}
+        # (the absorbed decode reads latents, not heads: no kernel)
+        return {"paged": paged, "state": {}, "decode_kernel": False}
+
     @nn.compact
     def __call__(self, u, decode: bool = False, positions=None,
                  active=None, paged_kv=None, page_table=None,
-                 train: bool = False):
+                 train: bool = False, state_rows=None, lengths=None):
+        # (``state_rows`` / ``lengths`` address a per-slot state: this
+        # mixer keeps none)
         a, z = self.arch, self.arch.layer(self.kind)
         full = self.kind == "full_attention"
         indexed, gated = z["topk"] is not None, z["gated"]
@@ -623,21 +710,23 @@ class LatentBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, decode, positions, active, paged_kv, page_table,
-                 train: bool = False):
+                 train: bool = False, state_rows=None, lengths=None):
         a = self.arch
         b, t, c = x.shape
         wide = t > 1 and not train
         row_active = active if (decode and wide) else None
 
         def norm(name, v):
-            scale = self.param(name, nn.initializers.ones, (c,),
-                               self.param_dtype)
-            return rms_norm(v, scale, a.rms_norm_eps, dtype=jnp.float32)
+            return rms_norm(v, norm_param(self, name, c, a, self.param_dtype),
+                            a.rms_norm_eps, dtype=jnp.float32,
+                            offset=a.norm_offset)
 
-        x = x + LatentAttention(a, self.kind, dtype=self.dtype,
-                                param_dtype=self.param_dtype, name="attn")(
+        x = x + mixer_class(a, self.kind)(
+            a, self.kind, dtype=self.dtype, param_dtype=self.param_dtype,
+            name="linear_attn" if self.kind == "linear_attention"
+            else "attn")(
             norm("ln1", x), decode, positions, active, paged_kv,
-            page_table, train).astype(x.dtype)
+            page_table, train, state_rows, lengths).astype(x.dtype)
         u = norm("ln2", x)
         if train:                # every token a row of its own, as in decode
             u = u.reshape(b * t, 1, c)
@@ -654,7 +743,9 @@ class LatentBlock(nn.Module):
             y = RoutedShareMlp(
                 a.n_routed_experts, a.moe_intermediate_size,
                 a.num_experts_per_tok, held=a.held_experts,
-                scaling=a.routed_scaling_factor, dtype=self.dtype,
+                scaling=a.routed_scaling_factor,
+                scoring="softmax" if a.hybrid else "sigmoid",
+                shared_gate=a.hybrid, dtype=self.dtype,
                 param_dtype=self.param_dtype, name="moe")(
                     u if wide else u[:, 0], row_active)
             y = y if wide else y[:, None]
@@ -739,29 +830,52 @@ class LatentLM(nn.Module):
         """What the trainer sets once, at construction."""
         return self.expert_gauges("train")
 
+    def cache_specs(self) -> list:
+        """Each layer's ``cache_spec``, as its mixer states it."""
+        a = self.arch
+        return [mixer_class(a, kind).cache_spec(a, kind, self.dtype)
+                for kind in a.layer_types]
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes a slot keeps whatever its length (all layers): the
+        engine's state pool holds ``slots`` such rows. 0 = every cache
+        of this model is paged."""
+        return sum(math.prod(shape) * jnp.dtype(dtype).itemsize
+                   for spec in self.cache_specs()
+                   for shape, dtype in spec["state"].values())
+
     def serve_gauges(self) -> dict:
         """What the serve engine sets once, at construction: bytes a
         token keeps per cache kind (all layers of the kind, lane-rounded
-        rows as stored), and the experts held of the router's width."""
-        a = self.arch
+        rows as stored), bytes a slot keeps beside its pages, and the
+        experts held of the router's width. Where no layer's one-token
+        call goes through ``tpunet_paged_decode``, the gauge that the
+        engine set from that kernel's dispatch reads 0."""
+        specs = self.cache_specs()
         per: dict = {}
-        for kind in a.layer_types:
-            for cache, width in cache_widths(a, kind).items():
-                per[cache] = per.get(cache, 0) + lane_rounded(width) * (
-                    4 if cache == "index" else jnp.dtype(self.dtype).itemsize)
+        for spec in specs:
+            for cache, (width, dtype) in spec["paged"].items():
+                per[cache] = per.get(cache, 0) \
+                    + width * jnp.dtype(dtype).itemsize
         out = {f"serve_cache_bytes_per_token_{k}": v for k, v in per.items()}
-        # the engine's gauge asks tpunet_paged_decode's dispatch, which
-        # this block's absorbed decode does not go through
-        out["serve_decode_attend_kernel"] = 0
+        if self.state_bytes_per_slot:
+            out["serve_state_bytes_per_slot"] = self.state_bytes_per_slot
+        if not any(spec["decode_kernel"] for spec in specs):
+            out["serve_decode_attend_kernel"] = 0
         return {**out, **self.expert_gauges("serve")}
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, decode: bool = False,
                  pos_offset=0, segment_ids=None,
                  return_hidden: bool = False, decode_active=None,
-                 paged_kv=None, page_table=None):
+                 paged_kv=None, page_table=None, state_rows=None,
+                 lengths=None):
         """As ``TransformerLM.__call__``; ``pos_offset`` is a scalar or
-        an int32 [B] of each row's first position. ``train`` takes the
+        an int32 [B] of each row's first position. ``state_rows`` [B]
+        names each row's row of the per-slot state pool and ``lengths``
+        [B] how many of the call's positions are real (mixers that keep
+        a state; see ``mixer_class``). ``train`` takes the
         batch-wide causal path and, where the architecture has a
         multi-token-prediction module, returns ``(logits, logits of the
         token after next)``. Packed sequences (``segment_ids``) are not
@@ -785,10 +899,10 @@ class LatentLM(nn.Module):
                       dtype=self.dtype, param_dtype=self.param_dtype,
                       name=f"block{i:02d}")(
                 x, decode, positions, decode_active, paged_kv, page_table,
-                train)
-        scale = self.param("ln", nn.initializers.ones, (a.hidden_size,),
-                           self.param_dtype)
-        h = rms_norm(x, scale, a.rms_norm_eps, dtype=self.dtype)
+                train, state_rows, lengths)
+        h = rms_norm(x, norm_param(self, "ln", a.hidden_size, a,
+                                   self.param_dtype),
+                     a.rms_norm_eps, dtype=self.dtype, offset=a.norm_offset)
         if return_hidden:
             return h.astype(jnp.float32)
         head = self.param("head", nn.initializers.normal(stddev=0.02),

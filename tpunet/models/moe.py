@@ -449,6 +449,18 @@ def route_sigmoid(u, router, bias, top_k: int, scaling: float = 1.0):
     return idx, scaling * chosen / jnp.sum(chosen, -1, keepdims=True)
 
 
+def route_softmax(u, router, top_k: int):
+    """Softmax routing: ``u`` [n, d] -> (expert ids [n, k] int32,
+    weights [n, k] float32). ``p = softmax(W_r u)`` over ALL experts in
+    float32, the ``top_k`` largest chosen, their ``p`` renormalised
+    over the chosen k; no bias, no scaling."""
+    p = jax.nn.softmax(jnp.dot(u.astype(jnp.float32),
+                               router.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST), -1)
+    chosen, idx = jax.lax.top_k(p, top_k)
+    return idx, chosen / jnp.sum(chosen, -1, keepdims=True)
+
+
 def by_row(fn, active, *xs):
     """``fn(*rows)`` on each leading-axis row of ``xs`` in turn
     (``lax.map``: one row's temporaries at a time), skipping the rows
@@ -496,7 +508,8 @@ def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
     """One chip's share of a routed expert layer, without capacity.
 
     ``u`` [n, d]; ``router`` [d, E] and ``bias`` [E] over ALL ``E``
-    experts; ``gate``/``up`` [len(held), d, f] and ``down`` [len(held),
+    experts (``bias`` None: softmax routing, ``route_softmax``, which
+    has neither bias nor scaling); ``gate``/``up`` [len(held), d, f] and ``down`` [len(held),
     f, d] for the experts held here; ``held`` their ids (static).
     Returns ``(y [n, d] in ``dtype``, stats)`` with ``y = sum over the
     chosen experts that are held of weight * expert(u)``: the pairs are
@@ -512,7 +525,10 @@ def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
     h = len(held)
     e = router.shape[-1]
     with jax.named_scope("tpunet_moe_router"):
-        idx, weight = route_sigmoid(u, router, bias, top_k, scaling)
+        if bias is None:
+            idx, weight = route_softmax(u, router, top_k)
+        else:
+            idx, weight = route_sigmoid(u, router, bias, top_k, scaling)
     with jax.named_scope("tpunet_moe_experts"):
         slot_of = jnp.full((e,), h, jnp.int32).at[jnp.asarray(held)].set(
             jnp.arange(h, dtype=jnp.int32))
@@ -546,7 +562,10 @@ class RoutedShareMlp(nn.Module):
     ``x`` [n, d] or, one batch row at a time (``by_row``, skipping rows
     whose ``row_active`` is False), on ``x`` [B, T, d]. ``held`` lists
     the routed experts whose weights live here (all of them by
-    default). The routing load is ``sow``n into the ``stats``
+    default). ``scoring`` "softmax" routes by ``route_softmax`` (no
+    ``router_bias`` parameter then, ``scaling`` unused); ``shared_gate``
+    multiplies the shared expert's output by ``sigmoid(x . w)``, one
+    number a token (``shared_expert_gate`` [d, 1]). The routing load is ``sow``n into the ``stats``
     collection (per batch row for a 3-D ``x``): free unless a caller
     makes it mutable (the serve engine's step does not; the LM train
     step does, and sums it into the trainer's gauges)."""
@@ -556,6 +575,8 @@ class RoutedShareMlp(nn.Module):
     top_k: int
     held: Any = None                   # tuple of expert ids; None = all
     scaling: float = 1.0
+    scoring: str = "sigmoid"           # sigmoid | softmax
+    shared_gate: bool = False
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -570,20 +591,30 @@ class RoutedShareMlp(nn.Module):
             return self.param(name, init, shape, self.param_dtype)
 
         router = w("router", d, self.n_experts)
-        bias = self.param("router_bias", nn.initializers.zeros,
-                          (self.n_experts,), self.param_dtype)
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown scoring {self.scoring!r}")
+        bias = (self.param("router_bias", nn.initializers.zeros,
+                           (self.n_experts,), self.param_dtype)
+                if self.scoring == "sigmoid" else None)
         experts = (w("experts_gate", len(held), d, f),
                    w("experts_up", len(held), d, f),
                    w("experts_down", len(held), f, d))
         shared = (w("shared_gate", d, f), w("shared_up", d, f),
                   w("shared_down", f, d))
+        open_w = w("shared_expert_gate", d, 1) if self.shared_gate else None
 
         def ffn(u):
             y, stats = routed_share(u, router, bias, *experts, held,
                                     top_k=self.top_k, scaling=self.scaling,
                                     dtype=self.dtype)
             with jax.named_scope("tpunet_moe_shared"):
-                y = y + gated_silu(u, *shared, self.dtype)
+                y_shared = gated_silu(u, *shared, self.dtype)
+                if open_w is not None:
+                    y_shared = (y_shared * jax.nn.sigmoid(jnp.dot(
+                        u.astype(jnp.float32), open_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST))
+                    ).astype(self.dtype)
+                y = y + y_shared
             return y, stats
 
         y, stats = ffn(x) if x.ndim == 2 else by_row(ffn, row_active, x)

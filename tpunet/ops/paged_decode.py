@@ -102,7 +102,7 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
             kbuf, vbuf, sems, work_row, work_chunk, qbd_ref, m_ref,
             l_ref, acc_ref, *, slots: int, pages_per_slot: int,
             page_tokens: int, pages_per_chunk: int, heads: int,
-            head_dim: int, scale: float, split_p: bool):
+            head_dim: int, scale: float, split_p: bool, group: int = 1):
     pt, ppc = page_tokens, pages_per_chunk
     ct = pt * ppc                          # keys per chunk
     hp, w = acc_ref.shape
@@ -137,11 +137,18 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
                         hbm.at[src], buf.at[slot, dst], sem), act)()
 
     o_ref[...] = jnp.zeros_like(o_ref)
-    # Head h owns columns [h * head_dim, (h + 1) * head_dim).
+    # Head h owns columns [h * head_dim, (h + 1) * head_dim); grouped,
+    # query head h reads the columns of KV head h // group.
     row = lax.broadcasted_iota(jnp.int32, (hp, w), 0)
     col = lax.broadcasted_iota(jnp.int32, (hp, w), 1)
-    own = (col >= row * head_dim) & (col < (row + 1) * head_dim) \
-        & (row < heads)
+    if group == 1:
+        own = (col >= row * head_dim) & (col < (row + 1) * head_dim) \
+            & (row < heads)
+    else:
+        own = row < 0
+        for n in range(heads // group):     # comparisons only: no vector
+            own = own | ((row >= n * group) & (row < (n + 1) * group)  # div
+                         & (col >= n * head_dim) & (col < (n + 1) * head_dim))
 
     @pl.when(n_work > 0)
     def _():
@@ -160,9 +167,12 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
         def _():
             # Selects run on 32-bit lanes (Mosaic cannot carry a
             # mask between the 32-bit and the packed 16-bit tiling).
-            q = q_ref[b].astype(jnp.float32)                 # [1, W]
-            qbd_ref[...] = jnp.where(own, jnp.broadcast_to(q, (hp, w)),
-                                     0.0).astype(qbd_ref.dtype)
+            q = q_ref[b].astype(jnp.float32)      # [1, W]; grouped [hp, D]
+            if group == 1:
+                q = jnp.broadcast_to(q, (hp, w))
+            else:                # every head's q under every KV block
+                q = jnp.concatenate([q] * (heads // group), axis=1)
+            qbd_ref[...] = jnp.where(own, q, 0.0).astype(qbd_ref.dtype)
             m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -217,8 +227,13 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
         @pl.when((c + 1) * ct >= length)
         def _():
             out = jnp.where(own, acc_ref[...] / l_ref[:, :1], 0.0)
-            o_ref[b] = jnp.sum(out, axis=0,
-                               keepdims=True).astype(o_ref.dtype)
+            if group == 1:
+                o_ref[b] = jnp.sum(out, axis=0,
+                                   keepdims=True).astype(o_ref.dtype)
+            else:                # row h's values lie in its KV block
+                o_ref[b] = sum(
+                    out[:, n * head_dim:(n + 1) * head_dim]
+                    for n in range(heads // group)).astype(o_ref.dtype)
         return carry
 
     lax.fori_loop(0, n_work, step, 0)
@@ -228,11 +243,16 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, page_table: jax.Array,
                            lengths: jax.Array, *, page_tokens: int,
                            scale: Optional[float] = None,
-                           interpret: Optional[bool] = None) -> jax.Array:
+                           interpret: Optional[bool] = None,
+                           kv_heads: Optional[int] = None) -> jax.Array:
     """One query token per row against its own pages of the pool.
 
     ``q`` [B, H, D]; ``k_pool`` / ``v_pool`` [pool_rows, pool_width(H, D)]
     (a page = ``page_tokens`` consecutive rows, columns past H * D zero);
+    with ``kv_heads`` < H (grouped-query attention: query head h reads
+    KV head ``h // (H / kv_heads)``) the pools are
+    ``pool_width(kv_heads, D)`` wide and ``D`` a multiple of the lane
+    tile;
     ``page_table`` [B, pages_per_slot] int32 page ids; ``lengths`` [B]
     int32 live keys per row (0 = inactive: nothing read, zeros out).
     Returns [B, H, D] in ``q``'s dtype — softmax(q k^T * scale) v over
@@ -242,9 +262,16 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         interpret = _interpret()
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    heads = q.shape[1]
+    kv_heads = heads if kv_heads is None else int(kv_heads)
+    if heads % kv_heads or (kv_heads != heads and q.shape[-1] % _LANES):
+        raise ValueError(f"{heads} query heads of {q.shape[-1]} over "
+                         f"{kv_heads} KV heads: the group must be whole and "
+                         f"a grouped head a multiple of {_LANES} lanes")
+    # (group 1 traces the ungrouped program, as it always was)
     return _attend(q, k_pool, v_pool, page_table, lengths,
                    page_tokens=page_tokens, scale=float(scale),
-                   interpret=bool(interpret))
+                   interpret=bool(interpret), group=heads // kv_heads)
 
 
 # jit: a model calls this once per layer with one signature, and an
@@ -252,14 +279,15 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 # separate lowerings of the kernel body cost a GPT-2 XL engine 74 s of
 # set-up in every process, compile cache or not; my chip runs, PR 26).
 @functools.partial(jax.jit, static_argnames=("page_tokens", "scale",
-                                             "interpret"))
+                                             "interpret", "group"))
 def _attend(q, k_pool, v_pool, page_table, lengths, *, page_tokens,
-            scale, interpret):
+            scale, interpret, group=1):
     b, heads, head_dim = q.shape
-    w = pool_width(heads, head_dim)
+    w = pool_width(heads // group, head_dim)
     if k_pool.shape[1] != w or v_pool.shape != k_pool.shape:
         raise ValueError(f"pool {k_pool.shape} / {v_pool.shape} is not "
-                         f"[rows, {w}] for {heads} heads of {head_dim}")
+                         f"[rows, {w}] for {heads // group} heads of "
+                         f"{head_dim}")
     pages_per_slot = page_table.shape[1]
     # K and V chunks, double-buffered, stay inside half of the 16 MiB
     # of VMEM a kernel may scope (a very wide pool takes fewer pages).
@@ -269,12 +297,16 @@ def _attend(q, k_pool, v_pool, page_table, lengths, *, page_tokens,
     ct = ppc * page_tokens
     hp = -(-heads // 16) * 16            # a sublane tile of any dtype
     split_p = (jnp.dtype(v_pool.dtype).itemsize < 4)
-    qf = jnp.pad(q.reshape(b, 1, heads * head_dim),
-                 ((0, 0), (0, 0), (0, w - heads * head_dim)))
+    if group == 1:
+        qf = jnp.pad(q.reshape(b, 1, heads * head_dim),
+                     ((0, 0), (0, 0), (0, w - heads * head_dim)))
+    else:                    # a row per query head, padded to the tile
+        qf = jnp.pad(q, ((0, 0), (0, hp - heads), (0, 0)))
+    io = qf.shape[1:]        # [1, W], or grouped [hp, D]
     kern = functools.partial(
         _kernel, slots=b, pages_per_slot=pages_per_slot,
         page_tokens=page_tokens, pages_per_chunk=ppc, heads=heads,
-        head_dim=head_dim, scale=scale, split_p=split_p)
+        head_dim=head_dim, scale=scale, split_p=split_p, group=group)
     max_work = b * (-(-pages_per_slot // ppc))
     whole = lambda i, *_: (0, 0, 0)      # noqa: E731 — one invocation
     # Scope: hlo_bytes.KERNEL_SCOPES' convention (<prefix>_fwd); the
@@ -286,10 +318,10 @@ def _attend(q, k_pool, v_pool, page_table, lengths, *, page_tokens,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(1,),
-                in_specs=[pl.BlockSpec((b, 1, w), whole),
+                in_specs=[pl.BlockSpec((b, *io), whole),
                           pl.BlockSpec(memory_space=pl.ANY),
                           pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=pl.BlockSpec((b, 1, w), whole),
+                out_specs=pl.BlockSpec((b, *io), whole),
                 scratch_shapes=[
                     pltpu.VMEM((2, ct, w), k_pool.dtype),
                     pltpu.VMEM((2, ct, w), v_pool.dtype),
@@ -301,8 +333,10 @@ def _attend(q, k_pool, v_pool, page_table, lengths, *, page_tokens,
                     pltpu.VMEM((hp, _LANES), jnp.float32),  # running sum
                     pltpu.VMEM((hp, w), jnp.float32),     # accumulator
                 ]),
-            out_shape=jax.ShapeDtypeStruct((b, 1, w), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, *io), q.dtype),
             interpret=interpret,
         )(lengths.astype(jnp.int32),
           page_table.reshape(-1).astype(jnp.int32), qf, k_pool, v_pool)
+    if group > 1:
+        return out[:, :heads]
     return out[:, 0, :heads * head_dim].reshape(b, heads, head_dim)

@@ -252,9 +252,10 @@ def build_aot_store(directory: str, model_cfg, serve_cfg):
         # written for the given tree's types misses whole.
         "params": "resident",
         # The step takes a row's token from the previous step's output
-        # or from the host (``prev``, ``from_prev``): a store written
-        # for the twelve-argument step misses whole.
-        "step": "tokens forwarded",
+        # or from the host (``prev``, ``from_prev``) and names each
+        # row's state row (``state_rows``): a store written for a
+        # twelve- or fourteen-argument step misses whole.
+        "step": "tokens forwarded, state rows named",
         # Spec-decode levers select a different program SET (drafter
         # width changes the drafter executables, K changes the verify
         # width): spec-on and spec-off engines must never share blobs.
@@ -390,7 +391,30 @@ class Engine:
         # free list under pool pressure.
         self._prefix = None
         self._prefix_store = None
-        if getattr(cfg, "prefix_cache", False):
+        # -- fixed state beside the pages (models/hybrid_mixers.py) ----
+        # A model whose mixers keep a per-slot state (a recurrence's
+        # matrix, a convolution's tail) declares its bytes; the cache
+        # tree then holds a state pool of ``slots`` rows beside the
+        # page pools, and batch row i of a call names its state row as
+        # it names its pages. A trie of KV pages cannot pin a
+        # recurrence (an adopted prefix would skip the tokens the state
+        # needs) and a rejected draft cannot rewind one: no prefix
+        # cache is built, and speculative decoding is refused.
+        self._state_bytes_per_slot = int(
+            getattr(model, "state_bytes_per_slot", 0))
+        if self._state_bytes_per_slot and getattr(cfg, "spec_decode",
+                                                  False):
+            raise ValueError(
+                f"{type(model).__name__} keeps a fixed state per slot: a "
+                "rejected draft cannot rewind it (spec_decode=True is "
+                "refused)")
+        if self._state_bytes_per_slot and getattr(cfg, "prefix_cache",
+                                                  False):
+            import logging
+            logging.getLogger(__name__).info(
+                "no prefix cache: %s keeps a fixed state per slot, which "
+                "cached KV pages cannot restore", type(model).__name__)
+        elif getattr(cfg, "prefix_cache", False):
             cap = int(getattr(cfg, "prefix_cache_pages", 0))
             if cap <= 0:
                 cap = self.kv_pages_usable // 2
@@ -498,27 +522,38 @@ class Engine:
         # tokens, not logits.
         #
         # A row reaches its tokens only through its row of the page
-        # table, so nothing ties a batch row to a slot: a prefill call
-        # is as wide as the one request it admits. Over a mesh GSPMD
+        # table and its fixed state (if the model keeps one) through
+        # ``state_rows``, so nothing ties a batch row to a slot: a
+        # prefill call is as wide as the one request it admits. A call
+        # with a row per slot IS the pool in slot order (row i = slot
+        # i), and says so to the model by naming no rows: the state
+        # pool is then updated where it lies. Over a mesh GSPMD
         # partitions the pool, and the [slots, bucket] group call is
         # the only path that platform has (no cell measures it).
         self._prefill_rows = 1 if mesh is None else self.slots
         paged_kv = self._paged_kv
 
+        fixed_state, slots = bool(self._state_bytes_per_slot), self.slots
+
         def _masked_step(params, cache, tokens, positions, active,
                          page_table, last_idx, temp, top_k, top_p,
-                         seeds, steps, prev, from_prev):
+                         seeds, steps, prev, from_prev, state_rows):
             # A row that continues takes the token the previous decode
             # step sampled for it straight from that step's output,
             # which the host may not have read yet; ``tokens`` carries
             # what only the host knows (a prompt, a slot just
             # prefilled or resumed).
             tokens = jnp.where(from_prev[:, None], prev[:, None], tokens)
+            state = {}
+            if fixed_state:
+                # positions past a row's last real token change no state
+                state = {"lengths": last_idx + 1, "state_rows": (
+                    None if tokens.shape[0] == slots else state_rows)}
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, tokens, train=False,
                 decode=True, pos_offset=positions, decode_active=active,
                 paged_kv=paged_kv, page_table=page_table,
-                mutable=["cache"])
+                mutable=["cache"], **state)
             from tpunet.serve.sampling import batched_sample
             rows = jnp.take_along_axis(
                 logits, last_idx[:, None, None],
@@ -624,7 +659,7 @@ class Engine:
         return self.slots if width == 1 else self._prefill_rows
 
     def _step_avals(self, width: int) -> list:
-        """``_masked_step``'s fourteen arguments at token width
+        """``_masked_step``'s fifteen arguments at token width
         ``width``, as shapes: the one statement of its signature."""
         import jax
 
@@ -641,7 +676,8 @@ class Engine:
                 f32(n), i32(n), f32(n),                 # temp, top_k, top_p
                 i32(n), i32(n),                         # seeds, steps
                 i32(n),                                 # prev
-                jax.ShapeDtypeStruct((n,), bool)]       # from_prev
+                jax.ShapeDtypeStruct((n,), bool),       # from_prev
+                i32(n)]                                 # state_rows
 
     def program_texts(self) -> dict:
         """``{label: optimized HLO text}`` of the masked step at each
@@ -762,10 +798,12 @@ class Engine:
             prev = self._sampled
         table = (self._page_table if slot_i is None
                  else self._page_table[slot_i:slot_i + 1]).copy()
+        state_rows = (np.arange(rows, dtype=np.int32) if slot_i is None
+                      else np.full((1,), slot_i, np.int32))
         return program(
             self.variables["params"], self._cache, toks, positions,
             active, table, *self._sampling_args(last_idx, slot_i),
-            prev, from_prev)
+            prev, from_prev, state_rows)
 
     def _sampling_args(self, last_idx, slot_i=None):
         """Per-row sampling parameters for the fused device sampler:
@@ -954,9 +992,16 @@ class Engine:
     def kv_pool_bytes(self) -> int:
         """Resident bytes of the KV cache tree (the page pool and its
         scale sidecars) — the capacity number ``bench_serve.py``
-        reports per slot."""
+        reports per slot. The state pool is not in it
+        (``state_pool_bytes``)."""
         from tpunet.serve.resident import tree_bytes
-        return tree_bytes(self._cache)
+        return tree_bytes(self._cache) - self.state_pool_bytes()
+
+    def state_pool_bytes(self) -> int:
+        """Resident bytes of the per-slot state pool: ``slots`` rows of
+        what the model's mixers declare (0 for a model whose every
+        cache is paged)."""
+        return self._state_bytes_per_slot * self.slots
 
     def kv_bytes_per_token(self) -> float:
         """KV bytes pinned per cacheable token position across the
@@ -983,6 +1028,9 @@ class Engine:
         reg.gauge("serve_prefill_rows_per_call").set(self._prefill_rows)
         if self._prefix is not None:
             reg.gauge("serve_prefix_pages_cached").set(0)
+        if self._state_bytes_per_slot:
+            reg.gauge("serve_state_pool_bytes").set(self.state_pool_bytes())
+            reg.gauge("serve_prefix_cache_enabled").set(0)
         # What a model says of itself once (models/latent_lm.py: bytes
         # a token keeps per cache kind, experts held of the router's
         # width); a model without the method adds nothing.
@@ -1843,6 +1891,9 @@ class Engine:
                 #                         the token reached the stream)
             self._slot_maybe_finish(s_i, first)
         reg.counter("serve_prefills_total").inc()
+        if self._state_bytes_per_slot:
+            # each row started at position 0: its state row began anew
+            reg.counter("serve_state_rows_reset_total").inc(len(group))
         # Suffix tokens only: with a prefix hit this is the REAL
         # prefill compute — bench_serve's prefill_tokens_per_request
         # dropping to ~the suffix length is the tentpole's measured
