@@ -28,7 +28,7 @@ import chip_smoke
 from benchmark import harness, weights
 from tpunet.config import (CheckpointConfig, DataConfig, ModelConfig,
                            OptimConfig, ServeConfig, TrainConfig)
-from tpunet.models import create_model
+from tpunet.models import create_model, moe
 from tpunet.models.moe import RoutedShareMlp
 from tpunet.train import metrics as M
 from tpunet.train.state import create_train_state
@@ -47,6 +47,7 @@ DOTS3 = harness.load_module(
 CONFIG = bench_tiny_glm.shrink(harness.load_json(
     "benchmark", "configs", "glm-4.7-flash.json", root=REPO))
 SEED, BATCH, SEQ = 2000000011, 2, 32
+PAIR_CHUNK = 16         # rows of sorted pairs a pass, for ``stepped``
 B1 = CONFIG["optimizer"]["b1"]
 
 
@@ -90,6 +91,11 @@ def stepped(request, seeded, tmp_path_factory):
                       dataset=(x, y, x, y))
     sink = MemorySink()
     trainer.obs.add_sink(sink)
+    # a row's (token, expert) pairs outnumber a chunk, as at the real
+    # size: the step's expert layers walk them in chunks and stop after
+    # the held ones, forward, recomputed and coming back
+    chunked = pytest.MonkeyPatch()
+    chunked.setattr(moe, "PAIR_CHUNK", PAIR_CHUNK)
     try:
         trainer.state = trainer.state.replace(params=jax.device_put(
             params, jax.tree_util.tree_map(lambda a: a.sharding,
@@ -110,6 +116,7 @@ def stepped(request, seeded, tmp_path_factory):
                 "records": sink.by_kind("obs_train_means"),
                 "totals": dict(M.STEP_MEAN_TOTALS)}
     finally:
+        chunked.undo()
         trainer.close()
 
 
@@ -236,6 +243,18 @@ def test_counters_and_gauges_of_the_step(stepped):
         a.size for a in stepped["before"].values())
     assert 0.3 < g["train_moe_held_pair_share"] < 0.7      # 4 of 8 held
     assert g["train_moe_held_load_max_over_mean"] >= 1.0
+    # a row's chunks run up to the last held pair: ceil(held / chunk) of
+    # ceil(pairs / chunk), so from the held share to a chunk over it
+    # (the prediction module's rows are a token short of a whole chunk)
+    top_k = CONFIG["program"]["model"]["latent"]["num_experts_per_tok"]
+    chunks = -(-SEQ * top_k // PAIR_CHUNK)
+    assert chunks > 1
+    assert ((SEQ - 1) * top_k / (chunks * PAIR_CHUNK)
+            * g["train_moe_held_pair_share"]
+            <= g["train_moe_chunks_run_share"]
+            < min(1.0, g["train_moe_held_pair_share"] + 1.0 / chunks))
+    assert stepped["totals"]["moe_chunks_run_share"] == pytest.approx(
+        g["train_moe_chunks_run_share"], abs=1e-6)
     assert g["train_main_loss"] > 0 and g["train_mtp_loss"] > 0
     (record,) = stepped["records"]
     assert record["train_moe_held_pair_share"] == pytest.approx(

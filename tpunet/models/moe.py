@@ -503,6 +503,133 @@ def _held_rows_bwd(valid, g):
 _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 
+# Rows of sorted (token, expert) pairs one pass of the grouped products
+# takes. A layer with more pairs than this walks them in chunks of this
+# many and stops after the last pair on a held expert; a layer with no
+# more (a decode step) makes one call over all of them.
+PAIR_CHUNK = 8192
+
+
+def _gated_experts(rows, valid, sizes, gate, up, down):
+    """``down_g(silu(gate_g x) * up_g x)`` on ``rows`` [m, d], the first
+    ``sizes[0]`` of them through expert 0 of ``gate``/``up`` [h, d, f]
+    and ``down`` [h, f, d], the next ``sizes[1]`` through expert 1, and
+    so on: three grouped products. ``valid`` [m, 1] marks the rows the
+    groups cover; the others come out zero, and give no gradient."""
+    rows = _held_rows(rows, valid)
+    a = _held_rows(jax.lax.ragged_dot(rows, gate, sizes), valid)
+    b = _held_rows(jax.lax.ragged_dot(rows, up, sizes), valid)
+    y = jax.lax.ragged_dot(nn.silu(a) * b, down, sizes)
+    return jnp.where(valid, y, 0)
+
+
+def _chunk(j, plan):
+    """Chunk ``j`` of the sorted pairs: its first row, its rows' tokens,
+    which of its rows lie in a group, and each group's rows inside it."""
+    lo = j * PAIR_CHUNK
+    sizes = plan["sizes"]
+    ends = jnp.cumsum(sizes)
+    valid = (lo + jnp.arange(PAIR_CHUNK) < ends[-1])[:, None]
+    inside = jnp.clip(jnp.minimum(ends, lo + PAIR_CHUNK)
+                      - jnp.maximum(ends - sizes, lo), 0)
+    return (lo, jax.lax.dynamic_slice_in_dim(plan["tok"], lo, PAIR_CHUNK),
+            valid, inside)
+
+
+def _live_chunks(sizes):
+    """Chunks that start before the last pair of the groups ``sizes``."""
+    return (jnp.sum(sizes) + PAIR_CHUNK - 1) // PAIR_CHUNK
+
+
+def _sum_pairs(y, back, weight):
+    """[n, d]: each token's weighted sum over its ``k`` pairs, in
+    float32. ``y`` holds a row a SORTED pair, ``back`` [n * k] a pair's
+    sorted row, ``weight`` [n, k] is in pair order."""
+    n, k = weight.shape
+    # gathered pair-major: [k, n, d] splits the rows into whole tiles,
+    # [n, k, d] would pad every token's k rows to a tile and copy
+    y = jnp.take(y, back.reshape(n, k).T.reshape(-1), axis=0)
+    return jnp.sum(y.reshape(k, n, -1).astype(jnp.float32)
+                   * weight.T[:, :, None], axis=0).astype(y.dtype)
+
+
+@jax.custom_vjp
+def _experts_of_held(u, weight, gate, up, down, plan):
+    """``_sum_pairs`` of ``_gated_experts`` on the sorted pairs' rows
+    ``u[tok]``, for a layer with more pairs than ``PAIR_CHUNK``.
+    ``plan`` holds the pairs' places: ``tok`` / ``order`` (token and
+    pair of each sorted row, padded to whole chunks), ``back`` and
+    ``sizes`` [h].
+
+    The grouped products end at the last group by themselves; what a
+    single call pays for every pair, held or not, is all around them:
+    the row gather, the selects and element-wise passes and, coming
+    back, a scatter over all pairs for the un-sort, a scatter-add for
+    the row gather and a float32 ``[n * k, d]`` cotangent of the sum.
+    So the sorted rows go through a chunk of ``PAIR_CHUNK`` at a time
+    and only the chunks that start before the groups end: a loop as
+    long as the held pairs, whatever their number; the chunks past it
+    stay zero. Coming back the same chunks are walked again: each
+    gathers its tokens' rows of the cotangent and weighs them (which
+    is the transpose of sum and un-sort together, for its rows),
+    recomputes its own forward, and adds to the cotangents of the
+    weights, summed in the parameters' own dtype; the cotangent of
+    ``u`` is each token's sum over its pairs' rows, in float32."""
+    return _experts_of_held_fwd(u, weight, gate, up, down, plan)[0]
+
+
+def _experts_of_held_fwd(u, weight, gate, up, down, plan):
+    experts = tuple(w.astype(u.dtype) for w in (gate, up, down))
+
+    def body(j, y):
+        lo, tok, valid, inside = _chunk(j, plan)
+        out = _gated_experts(jnp.take(u, tok, axis=0), valid, inside,
+                             *experts)
+        return jax.lax.dynamic_update_slice_in_dim(y, out, lo, 0)
+
+    y = jax.lax.fori_loop(
+        0, _live_chunks(plan["sizes"]), body,
+        jnp.zeros(plan["tok"].shape + u.shape[1:], u.dtype))
+    return (_sum_pairs(y, plan["back"], weight),
+            (u, weight, gate, up, down, plan))
+
+
+def _experts_of_held_bwd(res, g):
+    u, weight, gate, up, down, plan = res
+    experts = tuple(w.astype(u.dtype) for w in (gate, up, down))
+    g = g.astype(jnp.float32)
+
+    def body(j, acc):
+        lo, tok, valid, inside = _chunk(j, plan)
+        pair = jax.lax.dynamic_slice_in_dim(plan["order"], lo, PAIR_CHUNK)
+        out, pull = jax.vjp(
+            lambda rows, *w: _gated_experts(rows, valid, inside, *w),
+            jnp.take(u, tok, axis=0), *experts)
+        g_tok = jnp.take(g, tok, axis=0)
+        d_rows, *d_w = pull(
+            (g_tok * jnp.take(weight.reshape(-1), pair)[:, None]
+             ).astype(out.dtype))
+        return (jax.lax.dynamic_update_slice_in_dim(acc[0], d_rows, lo, 0),
+                jax.lax.dynamic_update_slice_in_dim(
+                    acc[1], jnp.sum(g_tok * out.astype(jnp.float32), axis=-1),
+                    lo, 0),
+                *(a + d.astype(a.dtype) for a, d in zip(acc[2:], d_w)))
+
+    d_rows, d_weight, *d_w = jax.lax.fori_loop(
+        0, _live_chunks(plan["sizes"]), body,
+        (jnp.zeros(plan["tok"].shape + u.shape[1:], u.dtype),
+         jnp.zeros(plan["tok"].shape, jnp.float32),
+         *(jnp.zeros_like(w) for w in (gate, up, down))))
+    # a token's rows summed as going forward: a scatter-add here is
+    # expanded into a loop over rows whose operations carry no scope
+    return (_sum_pairs(d_rows, plan["back"], jnp.ones_like(weight)),
+            jnp.take(d_weight, plan["back"]).reshape(weight.shape), *d_w,
+            None)
+
+
+_experts_of_held.defvjp(_experts_of_held_fwd, _experts_of_held_bwd)
+
+
 def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
                  scaling: float = 1.0, dtype=jnp.bfloat16):
     """One chip's share of a routed expert layer, without capacity.
@@ -514,45 +641,57 @@ def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
     Returns ``(y [n, d] in ``dtype``, stats)`` with ``y = sum over the
     chosen experts that are held of weight * expert(u)``: the pairs are
     sorted by held expert (pairs on absent experts last, computed by
-    nobody), the experts run as grouped products over contiguous rows,
-    and each token sums its pairs back in pair order. Differentiable in
+    nobody), the experts run as grouped products over contiguous rows
+    (``_gated_experts``), and each token sums its pairs back in pair
+    order. A layer with more pairs than ``PAIR_CHUNK`` does all of that
+    over the held pairs alone (``_experts_of_held``); one with fewer (a
+    decode step) in one call over all of them. Differentiable in
     ``u``, ``router`` and the experts' weights; ``bias`` only chooses,
     so its gradient is zero. ``stats`` (float32
     scalars) counts the routing load: ``held_pair_share`` = pairs on
     held experts / pairs, ``held_load_max_over_mean`` = largest / mean
-    load over the held experts."""
+    load over the held experts, ``held_chunks_run_share`` = chunks of
+    sorted pairs that went through the products / chunks (1 where one
+    call takes all pairs)."""
     n, d = u.shape
     h = len(held)
-    e = router.shape[-1]
+    m = n * top_k
     with jax.named_scope("tpunet_moe_router"):
         if bias is None:
             idx, weight = route_softmax(u, router, top_k)
         else:
             idx, weight = route_sigmoid(u, router, bias, top_k, scaling)
     with jax.named_scope("tpunet_moe_experts"):
-        slot_of = jnp.full((e,), h, jnp.int32).at[jnp.asarray(held)].set(
-            jnp.arange(h, dtype=jnp.int32))
-        slot = slot_of[idx].reshape(-1)                          # [n*k]
+        # a pair's place among the held experts, h where nobody here
+        # holds its expert: compares, a table lookup is a gather a pair
+        slot = h + jnp.sum(
+            jnp.where(idx.reshape(-1, 1) == jnp.asarray(held, jnp.int32),
+                      jnp.arange(h, dtype=jnp.int32) - h, 0), axis=1)  # [n*k]
         order = jnp.argsort(slot, stable=True)
         sizes = jnp.sum(slot[:, None] == jnp.arange(h)[None, :], axis=0,
                         dtype=jnp.int32)                         # [h]
-        on_held = (jnp.take(slot, order) < h)[:, None]
-        rows = _held_rows(jnp.take(u.astype(dtype), order // top_k, axis=0),
-                          on_held)
-        a = _held_rows(jax.lax.ragged_dot(rows, gate.astype(dtype), sizes),
-                       on_held)
-        b = _held_rows(jax.lax.ragged_dot(rows, up.astype(dtype), sizes),
-                       on_held)
-        y = jax.lax.ragged_dot(nn.silu(a) * b, down.astype(dtype), sizes)
-        y = jnp.where(on_held, y, 0)
+        tok = order // top_k
         back = jnp.argsort(order)                                # pair order
-        y = jnp.take(y, back, axis=0).reshape(n, top_k, d)
-        y = jnp.sum(y.astype(jnp.float32) * weight[:, :, None],
-                    axis=1).astype(dtype)
+        chunks = -(-m // PAIR_CHUNK)
+        if chunks == 1:
+            on_held = (jnp.take(slot, order) < h)[:, None]
+            y = _gated_experts(jnp.take(u.astype(dtype), tok, axis=0),
+                               on_held, sizes, gate.astype(dtype),
+                               up.astype(dtype), down.astype(dtype))
+            y = _sum_pairs(y, back, weight)
+            run = jnp.float32(1.0)
+        else:
+            pad = (0, chunks * PAIR_CHUNK - m)
+            y = _experts_of_held(
+                u.astype(dtype), weight, gate, up, down,
+                {"tok": jnp.pad(tok, pad), "order": jnp.pad(order, pad),
+                 "back": back, "sizes": sizes})
+            run = _live_chunks(sizes).astype(jnp.float32) / chunks
         load = sizes.astype(jnp.float32)
-        stats = {"held_pair_share": jnp.sum(load) / (n * top_k),
+        stats = {"held_pair_share": jnp.sum(load) / m,
                  "held_load_max_over_mean":
-                     jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9)}
+                     jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
+                 "held_chunks_run_share": run}
     return y, stats
 
 

@@ -37,7 +37,7 @@ def accumulate(acc: Metrics, new: Metrics) -> Metrics:
 # What a step may add to the triple, each a per-step mean; ``steps``
 # counts the steps summed (train/steps.py ``_step_means``).
 STEP_MEANS = ("main_loss", "mtp_loss", "moe_held_pair_share",
-              "moe_held_load_max_over_mean")
+              "moe_held_load_max_over_mean", "moe_chunks_run_share")
 
 # Process-wide sums of the step means over every chunk a trainer of
 # this process has summarized, for a reader that runs after the

@@ -54,7 +54,8 @@ def _routing_load(mutated) -> dict:
     """Mean over the expert layers of the routing load that
     ``moe.RoutedShareMlp`` sows into ``stats`` ({} where none did).
     Every layer routes the same pairs, so the mean of their shares is
-    pairs on held experts over pairs."""
+    pairs on held experts over pairs (and chunks of sorted pairs that
+    went through the grouped products over chunks)."""
     def is_load(s):
         return hasattr(s, "keys") and "held_pair_share" in s
 
@@ -62,8 +63,11 @@ def _routing_load(mutated) -> dict:
         mutated.get("stats", {}), is_leaf=is_load) if is_load(s)]
     if not found:
         return {}
-    return {"moe_" + k: sum(jnp.mean(s[k]) for s in found) / len(found)
-            for k in ("held_pair_share", "held_load_max_over_mean")}
+    return {name: sum(jnp.mean(s[k]) for s in found) / len(found)
+            for k, name in (
+                ("held_pair_share", "moe_held_pair_share"),
+                ("held_load_max_over_mean", "moe_held_load_max_over_mean"),
+                ("held_chunks_run_share", "moe_chunks_run_share"))}
 
 
 def _steps_from_micro(micro: Callable, accum: int, mesh,
