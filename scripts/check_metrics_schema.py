@@ -233,10 +233,15 @@ def collect_serve_records() -> list:
     for name in ("serve_prefix_cow_total", "serve_prefix_spills_total",
                  "serve_prefix_warm_loads_total"):
         reg.counter(name).inc()
+    # the engine thread's phases, as ``Engine._emit_record`` sets them
+    for phase, seconds in (("prefix_adopt", 1.1), ("decode_wait", 7.5)):
+        reg.gauge("serve_host_s_" + phase).set(seconds)
+        reg.gauge("serve_host_max_s_" + phase).set(seconds / 10)
     record = build_serve_record(
         reg, queue_depth=1, active_slots=2, slots=4,
         uptime_s=12.0, window_s=3.0, final=True)
     assert record["prefix_hit_rate"] > 0
+    assert record["host_s"] == {"prefix_adopt": 1.1, "decode_wait": 7.5}
     reg.emit("obs_serve", record)
     return sink.records
 

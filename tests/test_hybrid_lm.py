@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from benchmark import harness, weights
 from tpunet.config import ModelConfig, ServeConfig
-from tpunet.models import create_model, hybrid_mixers, moe
+from tpunet.models import create_model, hybrid_mixers
 from tpunet.models.latent_lm import LatentArch
 from tpunet.models.moe import RoutedShareMlp
 from tpunet.serve import Engine
@@ -390,7 +390,7 @@ def test_engine_over_a_fixed_state_builds_no_prefix_cache(tiny):
         v.nbytes for v in state_leaves(eng).values())
     assert snap["serve_cache_bytes_per_token_kv"] == 2 * 128 * 4
     assert (snap["serve_experts_held"], snap["serve_experts_total"]) == (4, 8)
-    assert snap["serve_moe_chunk_rows"] == moe.PAIR_CHUNK
+    assert "serve_moe_chunk_rows" not in snap    # no reader: PR 37
     assert snap["serve_decode_attend_kernel"] == 0        # off the TPU
     assert eng.kv_pool_bytes() == sum(
         v.nbytes for v in jax.tree_util.tree_leaves(eng._cache)) \

@@ -1426,7 +1426,7 @@ def test_one_decode_span_a_step_and_the_overlapped_share(tiny_lm,
     opened, depth = [], []
 
     @contextlib.contextmanager
-    def counting(name):
+    def counting(name, **args):
         opened.append((name, tuple(depth)))
         depth.append(name)
         try:
@@ -1434,7 +1434,8 @@ def test_one_decode_span_a_step_and_the_overlapped_share(tiny_lm,
         finally:
             depth.pop()
 
-    monkeypatch.setattr(engine_mod, "_ring_span", counting)
+    # the trace annotation every ``Engine._phase`` opens
+    monkeypatch.setattr(engine_mod, "span", counting)
     script = staggered_script(SAMPLING["greedy"], TINY.vocab_size)
     eng = make_engine(tiny_lm, slots=3, prefix_cache=False)
     reqs = drive(eng, script)

@@ -99,7 +99,6 @@ from flax import linen as nn
 from jax import lax
 
 from tpunet.config import ModelConfig
-from tpunet.models import moe
 from tpunet.models.moe import RoutedShareMlp, by_row, gated_silu
 from tpunet.ops.attention import _NEG_INF
 
@@ -850,10 +849,7 @@ class LatentLM(nn.Module):
         """What the serve engine sets once, at construction: bytes a
         token keeps per cache kind (all layers of the kind, lane-rounded
         rows as stored), bytes a slot keeps beside its pages, and the
-        experts held of the router's width with the rows of sorted pairs
-        an expert layer's grouped products take at a time (the serve
-        step keeps ``stats`` immutable, so how many of a call's chunks
-        ran is not counted). Where no layer's one-token
+        experts held of the router's width. Where no layer's one-token
         call goes through ``tpunet_paged_decode``, the gauge that the
         engine set from that kernel's dispatch reads 0."""
         specs = self.cache_specs()
@@ -867,8 +863,7 @@ class LatentLM(nn.Module):
             out["serve_state_bytes_per_slot"] = self.state_bytes_per_slot
         if not any(spec["decode_kernel"] for spec in specs):
             out["serve_decode_attend_kernel"] = 0
-        return {**out, **self.expert_gauges("serve"),
-                "serve_moe_chunk_rows": moe.PAIR_CHUNK}
+        return {**out, **self.expert_gauges("serve")}
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, decode: bool = False,

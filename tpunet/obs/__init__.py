@@ -52,35 +52,14 @@ from tpunet.obs import perf
 from tpunet.obs.health import RunUnhealthyError, Watchdog
 from tpunet.obs.registry import (Counter, Gauge, Histogram, JsonlSink,
                                  MemorySink, Registry)
-from tpunet.obs.spans import NULL_SPAN, WindowedProfiler, span, step_span
+from tpunet.obs.spans import (NULL_SPAN, Span, WindowedProfiler, span,
+                              step_span)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "JsonlSink", "MemorySink",
     "NULL_SPAN", "Observability", "Registry", "RunUnhealthyError",
     "Watchdog", "WindowedProfiler", "perf", "span", "step_span",
 ]
-
-
-class _RecordedSpan:
-    """A trace span that also drops begin/end events into the flight
-    recorder's ring — the crash tail's "which phase were we in".
-    One object + two ring writes per span (~2-3 us); only built when
-    a recorder is armed."""
-
-    __slots__ = ("_inner", "_name", "_rec")
-
-    def __init__(self, inner, name: str, rec):
-        self._inner = inner
-        self._name = name
-        self._rec = rec
-
-    def __enter__(self):
-        self._rec.record("span", self._name)
-        return self._inner.__enter__()
-
-    def __exit__(self, *exc):
-        self._rec.record("span_end", self._name)
-        return self._inner.__exit__(*exc)
 
 
 class Observability:
@@ -223,15 +202,14 @@ class Observability:
         if self.flightrec is not None:
             # Span begin/end also lands in the flight-recorder ring:
             # on a crash, the tail says which phase the run died in.
-            return _RecordedSpan(span(name), name, self.flightrec)
+            return Span(span(name), name, self.flightrec)
         return span(name)
 
     def step_span(self, step: int):
         if not self.hot:
             return NULL_SPAN
         if self.flightrec is not None:
-            return _RecordedSpan(step_span(step), f"step {step}",
-                                 self.flightrec)
+            return Span(step_span(step), f"step {step}", self.flightrec)
         return step_span(step)
 
     # -- per-step hooks (called only when ``hot``) ----------------------

@@ -2,7 +2,7 @@
 
 Every run already records its host story into the crash-durable event
 ring (``tpunet/obs/flightrec/``): span begin/end pairs
-(``_RecordedSpan`` — step, data-wait, eval, checkpoint, serve prefill/
+(``spans.Span`` — step, data-wait, eval, checkpoint, serve prefill/
 decode phases), host-thread busy/idle transitions (``ThreadHandle``
 state flips), serve request lifecycles (submit -> prefill ->
 first_token -> finish), alerts, and epoch marks — each slot stamped

@@ -16,6 +16,7 @@ from __future__ import annotations
 import contextlib
 import glob
 import os
+import time
 
 import jax
 
@@ -24,14 +25,100 @@ import jax
 NULL_SPAN = contextlib.nullcontext()
 
 
-def span(name: str):
-    """Host-side labeled region for xprof (nests freely)."""
-    return jax.profiler.TraceAnnotation(name)
+def span(name: str, **args):
+    """Host-side labeled region for xprof (nests freely); ``args``
+    become the event's arguments, encoded only while a trace is on."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def step_span(step: int, name: str = "train"):
     """Per-step region; xprof's step-oriented views key on these."""
     return jax.profiler.StepTraceAnnotation(name, step_num=step)
+
+
+class PhaseTotal:
+    """What the closed spans of one name add up to."""
+
+    __slots__ = ("seconds", "count", "longest")
+
+    def __init__(self):
+        self.seconds = 0.0             # self time, summed
+        self.count = 0
+        self.longest = 0.0             # longest single span (self time)
+        #                                since its reader last reset it
+
+
+class PhaseClock:
+    """Where one thread's spans add up their host seconds, by name.
+
+    ``totals[name]`` sums the spans of that name that have closed, in
+    SELF time: what a span's children (spans opened inside it under the
+    same clock) took goes to their own names, so the names partition
+    the thread's time under any span. Plain floats written by the one
+    thread that opens the spans: no lock, nothing reaches a registry
+    until its owner copies them out (``Engine._emit_record``)."""
+
+    __slots__ = ("totals", "open")
+
+    def __init__(self):
+        self.totals = {}               # {span name: PhaseTotal}
+        self.open = None               # the innermost span still open
+
+
+class Span:
+    """One host trace span: ``inner`` (a ``TraceAnnotation`` or
+    ``StepTraceAnnotation``: the profiler's clock, which the device's
+    operations share) that can also drop begin/end events into a
+    flight-recorder ring (``rec.record``: the crash tail's "which
+    phase were we in", the timeline's device phases) and add its
+    ``perf_counter`` seconds to a ``PhaseClock``. The trainer's spans
+    (``Observability.span``) and the serve engine's phases
+    (``Engine._phase``) are both this class. The end lands in the ring
+    even when the body raised, so a failing device call leaves no
+    open span for the timeline to stretch to the end of the
+    recording."""
+
+    __slots__ = ("_inner", "_name", "_rec", "_clock", "_parent", "_t0",
+                 "_child_s")
+
+    def __init__(self, inner, name: str, rec=None, clock=None):
+        self._inner = inner
+        self._name = name
+        self._rec = rec
+        self._clock = clock
+
+    def __enter__(self):
+        if self._rec is not None:
+            self._rec.record("span", self._name)
+        clock = self._clock
+        if clock is not None:
+            self._parent = clock.open
+            clock.open = self
+            self._child_s = 0.0
+            self._t0 = time.perf_counter()
+        self._inner.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        try:
+            return self._inner.__exit__(*exc)
+        finally:
+            clock = self._clock
+            if clock is not None:
+                elapsed = time.perf_counter() - self._t0
+                own = elapsed - self._child_s
+                total = clock.totals.get(self._name)
+                if total is None:
+                    total = clock.totals[self._name] = PhaseTotal()
+                total.seconds += own
+                total.count += 1
+                if own > total.longest:
+                    total.longest = own
+                parent = clock.open = self._parent
+                if parent is not None:
+                    parent._child_s += elapsed
+            if self._rec is not None:
+                self._rec.record("span_end", self._name)
 
 
 class WindowedProfiler:

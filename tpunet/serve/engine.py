@@ -63,21 +63,22 @@ reference to it.
 
 Obs wiring: SLO counters/gauges/histograms land in a ``tpunet.obs``
 ``Registry`` (serve_* names incl. the ``serve_kv_*`` page-pool
-gauges, docs/metrics_schema.md ``obs_serve``), prefill/decode phases
-run under trace spans, and a periodic ``obs_serve`` record is emitted
-to every attached sink/exporter.
+gauges, docs/metrics_schema.md ``obs_serve``), everything the engine
+thread does runs under a phase (``Engine._phase``: trace spans that
+tile the thread, their host seconds summed per engine), and a periodic
+``obs_serve`` record is emitted to every attached sink/exporter.
 """
 
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
 from typing import List, Optional
 
 import numpy as np
 
-from tpunet.obs import tracing
+from tpunet.obs import flightrec, tracing
+from tpunet.obs.spans import PhaseClock, Span, span
 from tpunet.serve.scheduler import (FINISH_CANCELLED, FINISH_DEADLINE,
                                     FINISH_DRAIN, FINISH_ERROR,
                                     FINISH_LENGTH, FINISH_STOP,
@@ -88,22 +89,15 @@ class PromptTooLongError(Exception):
     """Prompt exceeds the largest prefill bucket or the KV length."""
 
 
-@contextlib.contextmanager
-def _ring_span(name: str):
-    """The serve twin of the trainer's ``_RecordedSpan``: an xprof
-    trace span whose begin/end ALSO land in the flight-recorder ring
-    (the unified timeline's device phases; the crash tail's "which
-    phase was the replica in"). ``span_end`` sits in a finally so a
-    raising device call cannot leave a dangling open span for the
-    timeline to stretch to the end of the recording."""
-    from tpunet.obs import flightrec
-    from tpunet.obs.spans import span
-    flightrec.record("span", name)
-    try:
-        with span(name):
-            yield
-    finally:
-        flightrec.record("span_end", name)
+# The engine thread's phases (``Engine._phase``): trace spans named
+# ``tpunet/serve_<phase>`` that tile everything the thread does, and
+# the gauges ``serve_host_s_<phase>`` / ``serve_host_max_s_<phase>``
+# an ``obs_serve`` record carries (docs/serving.md "What the engine
+# thread was doing").
+_PHASE_PREFIX = "tpunet/serve_"
+HOST_PHASES = ("admit", "prefill_args", "prefill", "prefix_adopt",
+               "publish", "decode_args", "decode", "decode_wait", "idle",
+               "spec_prefill", "spec_draft", "spec_verify")
 
 
 def build_serve_record(reg, *, queue_depth: int, active_slots: int,
@@ -222,6 +216,16 @@ def build_serve_record(reg, *, queue_depth: int, active_slots: int,
     record["spec_accepted_tokens_per_verify"] = (
         round(record["spec_accepted_tokens_total"] / verifies, 4)
         if verifies else 0.0)
+    # The engine thread's seconds by phase (``Engine._phase``: self
+    # time, so the phases add up to the thread's time under any of
+    # them) and each phase's longest single span since the last
+    # record: which phase holds the host, and which one stalled.
+    for prefix, field in (("serve_host_s_", "host_s"),
+                          ("serve_host_max_s_", "host_max_s")):
+        values = ((phase, reg.gauge(prefix + phase).value)
+                  for phase in HOST_PHASES)
+        record[field] = {phase: round(float(v), 6)
+                         for phase, v in values if v is not None}
     if final:
         record["final"] = True
     return record
@@ -510,6 +514,7 @@ class Engine:
         self.error: Optional[str] = None
         self._last_emit = time.perf_counter()
         self._started = time.perf_counter()
+        self._host_clock = PhaseClock()  # seconds by phase (_phase)
 
         # -- device programs (compiled lazily, one per shape) ----------
         # One callable; jit specializes per token shape: [N, 1] decode
@@ -1295,7 +1300,6 @@ class Engine:
         req.preemptions += 1
         req._preempt_t = time.perf_counter()
         self.registry.counter("serve_kv_preemptions_total").inc()
-        from tpunet.obs import flightrec
         flightrec.record("req", f"preempt {req.id}")
         if req.trace_id:
             tracing.crumb("preempt", req.trace_id, req.trace_hop,
@@ -1311,7 +1315,6 @@ class Engine:
         # Host-thread registry (tpunet/obs/flightrec/): a decode
         # iteration wedged on the device past the budget pages
         # thread_stalled; idle waits (empty pool) do not.
-        from tpunet.obs import flightrec
         self._thread_handle = flightrec.register_thread(
             "serve-engine", stall_after_s=120.0)
         flightrec.record("serve", f"engine start slots={self.slots}")
@@ -1411,7 +1414,6 @@ class Engine:
         # queue/prefill/decode phases on the unified timeline
         # (tpunet/obs/history/timeline.py). ~1-2 us each, no-op
         # without an armed recorder.
-        from tpunet.obs import flightrec
         flightrec.record("req", f"submit {req.id} len={req.prompt.size}")
         if req.resume_offset:
             # Cross-replica resume (router failover): without this
@@ -1503,7 +1505,6 @@ class Engine:
     # -- engine loop -----------------------------------------------------
 
     def _run(self) -> None:
-        from tpunet.obs import flightrec
         handle = self._thread_handle
         try:
             while not self._stop.is_set():
@@ -1526,7 +1527,8 @@ class Engine:
                     break
                 if not did_work:
                     handle.beat("idle")
-                    self._wake.wait(timeout=0.02)
+                    with self._phase("tpunet/serve_idle"):
+                        self._wake.wait(timeout=0.02)
                     self._wake.clear()
             handle.beat("idle")
             self._emit_record(final=True)
@@ -1573,24 +1575,42 @@ class Engine:
             self._emit_record()
         return admitted or stepped
 
+    def _phase(self, name: str, ring: bool = False, **args) -> Span:
+        """One phase of the engine thread: the ``TraceAnnotation`` of
+        that name (the profiler's clock, which the device's operations
+        share; ``args`` are encoded only while a trace is on) whose
+        host seconds also go to ``self._host_clock``. The phases TILE
+        the thread: between them they cover everything an iteration
+        does, as siblings or as children of one another and never as a
+        parent around another's whole extent, so a reader can give each
+        instant of a device gap to the innermost phase open at it.
+        ``ring`` spans (the device calls) also land in the
+        flight-recorder ring — the unified timeline's device phases,
+        the crash tail's "which phase was the replica in"; the finer
+        phases do not (they would evict the request breadcrumbs the
+        timeline reads). Always on: no switch."""
+        return Span(span(name, **args), name,
+                    flightrec if ring else None, self._host_clock)
+
     def _reap(self) -> None:
         """Free slots whose request was cancelled or hit its deadline
         (cooperative cancellation point)."""
         now = time.perf_counter()
-        for i, slot in enumerate(self._active):
-            if slot is None:
-                continue
-            if slot.req.cancelled:
-                self._finish_slot(i, FINISH_CANCELLED)
-            elif slot.req.expired(now):
-                self._finish_slot(i, FINISH_DEADLINE)
+        due = [(i, FINISH_CANCELLED if slot.req.cancelled
+                else FINISH_DEADLINE)
+               for i, slot in enumerate(self._active)
+               if slot is not None
+               and (slot.req.cancelled or slot.req.expired(now))]
+        if due:
+            with self._phase("tpunet/serve_admit"):
+                for i, reason in due:
+                    self._finish_slot(i, reason)
 
     def _account_finish(self, req, reason: str) -> None:
         """Finish accounting shared by slot-finishes and requests the
         QUEUE finishes before they ever reach a slot: the counters must
         reconcile (requests_total == rejected + sum(finished_*))."""
         reg = self.registry
-        from tpunet.obs import flightrec
         flightrec.record("req", f"finish {req.id} {reason}")
         reg.counter(f"serve_finished_{reason}").inc()
         if reason in (FINISH_LENGTH, FINISH_STOP):
@@ -1633,7 +1653,6 @@ class Engine:
         request — when the
         pool cannot cover the next request's prompt, it (and everyone
         behind it) goes back to the queue head until pages free up."""
-        import collections
         free = [i for i, s in enumerate(self._active) if s is None]
         if not free:
             return False
@@ -1641,6 +1660,40 @@ class Engine:
         self.registry.gauge("serve_queue_depth").set(self.queue.depth())
         if not reqs:
             return False
+        with self._phase("tpunet/serve_admit"):
+            admitted, by_bucket = self._fit(reqs, free)
+        if not admitted:
+            return False
+        for bucket, group in sorted(by_bucket.items()):
+            self._prefill(bucket, group)
+        if self._drafter_model is not None:
+            # Drafter pool warm-up rides the same admission beat. The
+            # drafter re-embeds the FULL prompt (prefix hits included)
+            # so the grouping key is the full-length bucket, not the
+            # suffix bucket the main prefill used.
+            draft_groups: dict = {}
+            for slot_i, _, _, resume, _, _, _ in admitted:
+                if self._active[slot_i] is None:
+                    continue     # finished inside its own prefill
+                draft_groups.setdefault(
+                    self.bucket_for(int(resume.size)), []).append(
+                        (slot_i, resume))
+            for bucket, rows in sorted(draft_groups.items()):
+                self._draft_prefill(bucket, rows)
+        with self._phase("tpunet/serve_admit"):
+            self._update_kv_gauges()
+            now_active = self.active_slots()
+            self.peak_active_slots = max(self.peak_active_slots,
+                                         now_active)
+            self.registry.gauge("serve_active_slots").set(now_active)
+        return True
+
+    def _fit(self, reqs, free):
+        """Give each popped request, in order, a free slot, its pinned
+        prefix pages and the private pages its prompt needs; what the
+        pool cannot cover goes back to the queue's head. Returns the
+        admitted rows and the same grouped by prefill bucket."""
+        import collections
         if self._thread_handle is not None:
             # A request can land between the top-of-loop idle beat and
             # this pop; mark busy BEFORE the prefill device call, or a
@@ -1739,34 +1792,12 @@ class Engine:
             self.queue.requeue_front(pending)
             self.registry.gauge("serve_queue_depth").set(
                 self.queue.depth())
-        if not admitted:
-            return False
         by_bucket = {}
         for slot_i, bucket, req, resume, pages, start, pinned \
                 in admitted:
             by_bucket.setdefault(bucket, []).append(
                 (slot_i, req, resume, pages, start, pinned))
-        for bucket, group in sorted(by_bucket.items()):
-            self._prefill(bucket, group)
-        if self._drafter_model is not None:
-            # Drafter pool warm-up rides the same admission beat. The
-            # drafter re-embeds the FULL prompt (prefix hits included)
-            # so the grouping key is the full-length bucket, not the
-            # suffix bucket the main prefill used.
-            draft_groups: dict = {}
-            for slot_i, _, _, resume, _, _, _ in admitted:
-                if self._active[slot_i] is None:
-                    continue     # finished inside its own prefill
-                draft_groups.setdefault(
-                    self.bucket_for(int(resume.size)), []).append(
-                        (slot_i, resume))
-            for bucket, rows in sorted(draft_groups.items()):
-                self._draft_prefill(bucket, rows)
-        self._update_kv_gauges()
-        now_active = self.active_slots()
-        self.peak_active_slots = max(self.peak_active_slots, now_active)
-        self.registry.gauge("serve_active_slots").set(now_active)
-        return True
+        return admitted, by_bucket
 
     def _prefill(self, bucket: int, group) -> None:
         """Prefill every admitted request padded to this bucket: one
@@ -1810,6 +1841,43 @@ class Engine:
         page (writes go to positions >= start only) while the attend
         reads the pinned K/V through the page table."""
         t0 = time.perf_counter()
+        with self._phase("tpunet/serve_prefill_args"):
+            toks, positions, active, last_idx = self._prefill_args(
+                bucket, group, slot_i, t0)
+        # bucket and prompt beside the span: a reader can set a span's
+        # length against the prompt's (encoded only while a trace is on)
+        seen = dict(bucket=bucket, prompt_tokens=sum(
+            int(r.size) for _, _, r, _, _, _ in group))
+        with self._phase("tpunet/serve_prefill", ring=True, **seen):
+            self._cache, sampled = self._dispatch_step(
+                toks, positions, active, last_idx, slot_i)
+            for s_i, *_ in group:
+                self._active[s_i].generated += 1
+            # The call queues behind a decode step in flight (the pool
+            # orders them on the device). That step finishes first:
+            # read it first, so its tokens reach their clients when it
+            # ends and not a prefill later. This call's own first
+            # token is then read synchronously — one bubble a call,
+            # refilled by the next decode dispatch.
+            self._drain_decode()
+            sampled = np.asarray(sampled)
+        # Adopt freshly-written full prompt pages into the prefix
+        # cache (and spill them) BEFORE the finish checks below can
+        # release a short request's pages.
+        if self._prefix is not None:
+            with self._phase("tpunet/serve_prefix_adopt", **seen):
+                for s_i, req, resume, pages, start, pinned in group:
+                    slot = self._active[s_i]
+                    if slot is not None:
+                        self._adopt_prefix_pages(s_i, slot, resume)
+                self._update_kv_gauges()
+        with self._phase("tpunet/serve_publish"):
+            self._publish_first_tokens(bucket, group, slot_i, sampled, t0)
+
+    def _prefill_args(self, bucket: int, group, slot_i, t0: float):
+        """The host's arguments of one prefill call, with each
+        request's breadcrumbs and stamps (``t0``: when the call's host
+        work began)."""
         rows = self.slots if slot_i is None else 1
         toks = np.zeros((rows, bucket), np.int32)
         active = np.zeros((rows,), bool)
@@ -1822,7 +1890,6 @@ class Engine:
             active[row] = True
             last_idx[row] = n - start - 1
             positions[row] = start
-        from tpunet.obs import flightrec
         for _, req, resume, _, start, _ in group:
             # A resume-prefill (preempt-resume or cross-replica
             # failover resume) re-embeds prompt+generated; the
@@ -1846,29 +1913,14 @@ class Engine:
                               rid=req.id, b=bucket)
         if self.chaos is not None:
             self.chaos.on_prefill()     # kill@prefill injection point
-        with _ring_span("tpunet/serve_prefill"):
-            self._cache, sampled = self._dispatch_step(
-                toks, positions, active, last_idx, slot_i)
-            for s_i, *_ in group:
-                self._active[s_i].generated += 1
-            # The call queues behind a decode step in flight (the pool
-            # orders them on the device). That step finishes first:
-            # read it first, so its tokens reach their clients when it
-            # ends and not a prefill later. This call's own first
-            # token is then read synchronously — one bubble a call,
-            # refilled by the next decode dispatch.
-            self._drain_decode()
-            sampled = np.asarray(sampled)
+        return toks, positions, active, last_idx
+
+    def _publish_first_tokens(self, bucket: int, group, slot_i, sampled,
+                              t0: float) -> None:
+        """The tail of ``_prefill_call``: each row's first token to its
+        request, the stamps and histograms, the finish checks."""
         reg = self.registry
-        # Adopt freshly-written full prompt pages into the prefix
-        # cache (and spill them) BEFORE the finish checks below can
-        # release a short request's pages.
-        if self._prefix is not None:
-            for s_i, req, resume, pages, start, pinned in group:
-                slot = self._active[s_i]
-                if slot is not None:
-                    self._adopt_prefix_pages(s_i, slot, resume)
-            self._update_kv_gauges()
+        rows = self.slots if slot_i is None else 1
         prefill_done = time.perf_counter()
         for s_i, req, resume, _, start, _ in group:
             n = int(resume.size)
@@ -1932,7 +1984,7 @@ class Engine:
         # Prompt positions span 0..bucket-1, so the attention window
         # is static per bucket — the tag stays ``w{bucket}``.
         win = self._spec_window((bucket - 1) // self.page_tokens + 1)
-        with _ring_span("tpunet/serve_spec_prefill"):
+        with self._phase("tpunet/serve_spec_prefill", ring=True):
             self._draft_cache = self._dispatch_spec(
                 "spec_draft_prefill", f"w{bucket}",
                 self._draft_prefill_fn,
@@ -2008,11 +2060,17 @@ class Engine:
                 if s is not None and not self._ends_by_length(s)]
         ready = []
         blocked = []
-        for i, slot in live:
-            if self._ensure_page_capacity(i, slot):
-                ready.append((i, slot))
-            else:
-                blocked.append((i, slot))
+        args = None
+        if live:
+            with self._phase("tpunet/serve_decode_args"):
+                for i, slot in live:
+                    if self._ensure_page_capacity(i, slot):
+                        ready.append((i, slot))
+                    else:
+                        blocked.append((i, slot))
+                if ready:
+                    self._update_kv_gauges()
+                    args = self._decode_args(ready)
         if not ready:
             if flight is not None:
                 # Nothing to dispatch behind it: its rows end with it,
@@ -2023,17 +2081,13 @@ class Engine:
                 self._preempt_slot(self._choose_preempt_victim(blocked))
                 return True          # freed pages; retry next iteration
             return False
-        self._update_kv_gauges()
-        self._decode_width1(ready)
+        self._decode_width1(ready, args)
         return True
 
-    def _decode_width1(self, live) -> None:
-        """Dispatch one [slots, 1] masked decode call for ``live``
-        slots (page capacity already ensured by the caller), then read
-        the step that was in flight before it; the new one stays in
-        flight. One ``tpunet/serve_decode`` span per dispatched step
-        covers both. The spec path's tail fallback reads its step at
-        once (``_drain_decode``): that cycle stays synchronous."""
+    def _decode_args(self, live) -> tuple:
+        """The host's arguments of one [slots, 1] masked decode call
+        for ``live`` slots, behind the clock at which the step's host
+        work began (``_Step.t0``)."""
         t0 = time.perf_counter()
         flight = self._in_flight
         ahead = ({i: slot for i, slot, _ in flight.rows}
@@ -2049,8 +2103,20 @@ class Engine:
                 toks[i, 0] = slot.next_token
             positions[i] = slot.pos
             active[i] = True
+        return t0, toks, positions, active, from_prev
+
+    def _decode_width1(self, live, args) -> None:
+        """Dispatch one [slots, 1] masked decode call for ``live``
+        slots (page capacity already ensured by the caller, ``args``
+        from ``_decode_args``), then read the step that was in flight
+        before it; the new one stays in flight. One
+        ``tpunet/serve_decode`` span per dispatched step covers both.
+        The spec path's tail fallback reads its step at once
+        (``_drain_decode``): that cycle stays synchronous."""
+        t0, toks, positions, active, from_prev = args
+        flight = self._in_flight
         reg = self.registry
-        with _ring_span("tpunet/serve_decode"):
+        with self._phase("tpunet/serve_decode", ring=True):
             self._cache, self._sampled = self._dispatch_step(
                 toks, positions, active, self._zero_idx,
                 from_prev=from_prev)
@@ -2080,8 +2146,14 @@ class Engine:
         run the checks a token's value decides. A row whose slot was
         freed since the dispatch (stop token, cancel, deadline) is
         discarded."""
-        with _ring_span("tpunet/serve_decode_wait"):
+        with self._phase("tpunet/serve_decode_wait", ring=True):
             sampled = np.asarray(step.sampled)
+        with self._phase("tpunet/serve_publish"):
+            self._publish_step(step, sampled)
+
+    def _publish_step(self, step: _Step, sampled) -> None:
+        """A read step's tokens to their requests, the period's
+        histograms, the finish checks."""
         now = time.perf_counter()
         # The period between two consecutive reads; from its own
         # dispatch for a step that was not dispatched ahead.
@@ -2154,8 +2226,11 @@ class Engine:
             # token through the plain width-1 program. Their drafter
             # pool now lags the main cursor — benign per the
             # docstring's acceptance-vs-correctness argument.
-            self._decode_width1([(i, s) for i, s in seq_ready
-                                 if self._active[i] is s])
+            tail = [(i, s) for i, s in seq_ready
+                    if self._active[i] is s]
+            with self._phase("tpunet/serve_decode_args"):
+                args = self._decode_args(tail)
+            self._decode_width1(tail, args)
             self._drain_decode()
         return True
 
@@ -2189,7 +2264,7 @@ class Engine:
         # is the sequential sampler's next step counter, so draft and
         # verify keys stay in lockstep with the spec-off stream.
         samp = self._sampling_args(self._zero_idx)[1:]
-        with _ring_span("tpunet/serve_spec_draft"):
+        with self._phase("tpunet/serve_spec_draft", ring=True):
             self._draft_cache, drafts = self._dispatch_spec(
                 "spec_draft_burst", f"k{k}w{win}",
                 self._draft_burst_fn,
@@ -2199,7 +2274,7 @@ class Engine:
         verify_toks = np.zeros((self.slots, k + 1), np.int32)
         verify_toks[:, 0] = first
         verify_toks[:, 1:] = drafts
-        with _ring_span("tpunet/serve_spec_verify"):
+        with self._phase("tpunet/serve_spec_verify", ring=True):
             self._cache, choices = self._dispatch_spec(
                 "spec_verify", f"k{k}w{win}", self._verify_fn,
                 (self.variables["params"], self._cache, verify_toks,
@@ -2209,7 +2284,15 @@ class Engine:
         reg.counter("serve_decode_steps_total").inc()
         reg.histogram("serve_decode_iter_s").observe(lap)
         reg.histogram("serve_token_s").observe(lap)
+        with self._phase("tpunet/serve_publish"):
+            self._publish_burst(burst, drafts, choices)
+
+    def _publish_burst(self, burst, drafts, choices) -> None:
+        """A verified burst's accepted tokens to their requests, the
+        acceptance counters, the finish checks, the rewinds."""
         from tpunet.serve import spec as serve_spec
+        k = self.spec_k
+        reg = self.registry
         rows = np.asarray([i for i, _ in burst])
         accepted = serve_spec.accept_drafts(drafts[rows],
                                             choices[rows])
@@ -2282,6 +2365,15 @@ class Engine:
         now = time.perf_counter()
         window = now - self._last_emit
         self._last_emit = now
+        # The operator's copy of the phases: cumulative seconds, and
+        # the longest single span since the last record (then reset).
+        for name, total in self._host_clock.totals.items():
+            phase = name[len(_PHASE_PREFIX):]
+            reg.gauge("serve_host_s_" + phase).set(
+                round(total.seconds, 6))
+            reg.gauge("serve_host_max_s_" + phase).set(
+                round(total.longest, 6))
+            total.longest = 0.0
         record = build_serve_record(
             reg, queue_depth=self.queue.depth(),
             active_slots=self.active_slots(), slots=self.slots,

@@ -471,8 +471,11 @@ class Trainer:
         # branch per step, no spans, no timer objects, no wrapper
         # around the batch iterator.
         obs_hot = obs.hot
-        obs.begin_epoch(epoch)
-        batches = self._epoch_batches(epoch)
+        # The epoch's two edges run once a pass with the device drained
+        # or draining: named, so that a device gap there says which.
+        with obs.span("tpunet/train_epoch_start"):
+            obs.begin_epoch(epoch)
+            batches = self._epoch_batches(epoch)
         if obs_hot:
             batches = timed_batches(
                 batches, obs.observe_data_wait,
@@ -537,8 +540,9 @@ class Trainer:
                      f"loss {sm['loss']:.4f} acc {sm['accuracy']:.4f} "
                      f"lr {lr:.3e}")
         acc = acc if acc is not None else M.zeros_metrics()
-        summary = M.summarize(acc)        # the chunk's device fence
-        means = M.summarize_step_means(acc)
+        with obs.span("tpunet/train_summarize"):
+            summary = M.summarize(acc)    # the chunk's device fence
+            means = M.summarize_step_means(acc)
         if means and obs.enabled:
             # losses apart and the no-drop experts' routing load, as
             # gauges and as one record per pass over the data
