@@ -10,7 +10,9 @@ plain loop the engine ran before it looked ahead, written out over
 every token is on the host before the step that consumes it goes out.
 
 A script is ``[(iteration, prompt, request keywords)]``;
-``staggered_script`` is the one most tests serve.
+``staggered_script`` is the one most tests serve. ``counting_hashlib``
+counts what the prefix cache's key chain feeds its hash
+(tests/test_prefix_keys.py, tests/test_serve_paged.py).
 """
 
 import numpy as np
@@ -37,6 +39,36 @@ def staggered_script(sampling, vocab, seed=32):
              dict(max_new_tokens=new, **kw))
             for (at, n, new), kw in zip(
                 ((0, 5, 9), (0, 11, 4), (3, 7, 12), (7, 3, 6)), sampling)]
+
+
+def counting_hashlib(fed):
+    """A stand-in for the ``hashlib`` module a test puts in
+    ``prefixcache.keys``: its ``sha256`` appends to ``fed`` the length
+    of every buffer the hash is given (constructor and ``update``
+    alike), so a test counts bytes and calls instead of timing them."""
+    import hashlib
+
+    class Counting:
+        def __init__(self, data=b""):
+            self._h = hashlib.sha256(data)
+            fed.append(len(data))
+
+        def update(self, data):
+            fed.append(len(data))
+            self._h.update(data)
+
+        def copy(self):
+            twin = Counting.__new__(Counting)
+            twin._h = self._h.copy()
+            return twin
+
+        def hexdigest(self):
+            return self._h.hexdigest()
+
+    class Shim:
+        sha256 = Counting
+
+    return Shim
 
 
 def drive(eng, script, after=None, limit=400):

@@ -30,8 +30,8 @@ from tpunet.models import create_model, init_variables
 from tpunet.models.lm import filter_logits, generate
 from tpunet.serve import Engine, GenerateRequest, PromptTooLongError
 
-from _serve_script import (SAMPLING, drive, sequential_tokens,
-                           staggered_script)
+from _serve_script import (SAMPLING, counting_hashlib, drive,
+                           sequential_tokens, staggered_script)
 
 TINY = ModelConfig(name="lm", vit_hidden=32, vit_depth=2, vit_heads=2,
                    dropout_rate=0.0, dtype="float32", vocab_size=31,
@@ -675,6 +675,133 @@ def test_prefix_spill_and_warm_start_roundtrip(tmp_path, tiny_lm):
     assert snap2["serve_prefix_hit_tokens_total"] >= 8
     # the warmed replica never prefilled the shared prefix at all
     assert snap2["serve_prefill_tokens_total"] == p2.size - 8
+
+
+def _prefix_counts(eng):
+    snap = eng.registry.snapshot()
+    return tuple(int(snap.get(f"serve_prefix_{k}_total", 0)) for k in
+                 ("lookups", "hits", "hit_tokens", "inserts", "evictions",
+                  "cow"))
+
+
+def test_prefix_counters_per_admission_are_the_parents(tiny_lm):
+    """Nothing is adopted less, later or conditionally (PR 38): over a
+    fixed script — shared prefixes, repeats, a cache of 5 pages that
+    has to evict for every admission after the first two — lookups, hits, hit tokens,
+    inserts, evictions and COW copies read after every iteration what
+    the commit before the one-pass chain and the victim heap read
+    (the literals were printed by that commit running this test)."""
+    rng = np.random.default_rng(38)
+    a = rng.integers(0, TINY.vocab_size, size=12).astype(np.int32)
+    b = np.concatenate([a[:8], rng.integers(
+        0, TINY.vocab_size, size=5).astype(np.int32)])
+    c = rng.integers(0, TINY.vocab_size, size=16).astype(np.int32)
+    d = rng.integers(0, TINY.vocab_size, size=9).astype(np.int32)
+    script = [(0, a, dict(max_new_tokens=4)),
+              (0, b, dict(max_new_tokens=3)),
+              (9, c, dict(max_new_tokens=5)),
+              (12, a, dict(max_new_tokens=3)),
+              (20, d, dict(max_new_tokens=4)),
+              (21, b, dict(max_new_tokens=6)),
+              (30, c, dict(max_new_tokens=2)),
+              (31, a, dict(max_new_tokens=5)),
+              (40, c[:13], dict(max_new_tokens=3))]
+    eng = make_engine(tiny_lm, slots=2, kv_pages=14, kv_page_tokens=4,
+                      prefix_cache_pages=5)
+    seen = []
+
+    def after(k, reqs):
+        now = _prefix_counts(eng)
+        if not seen or seen[-1][1] != now:
+            seen.append((k, now))
+
+    reqs = drive(eng, script, after=after)
+    for (_, prompt, kw), req in zip(script, reqs):
+        assert req.tokens == solo_greedy(tiny_lm, prompt,
+                                         kw["max_new_tokens"])
+    assert seen == PARENT_PREFIX_COUNTS
+
+
+#: (iteration, (lookups, hits, hit tokens, inserts, evictions, COW)) at
+#: each iteration that moved a counter, as commit cfb319f read them.
+PARENT_PREFIX_COUNTS = [
+    (0, (2, 0, 0, 4, 0, 0)), (9, (3, 0, 0, 8, 3, 0)),
+    (12, (4, 1, 4, 8, 3, 0)), (20, (5, 1, 4, 10, 5, 0)),
+    (21, (6, 2, 8, 12, 7, 0)), (30, (7, 2, 8, 16, 11, 0)),
+    (31, (8, 3, 12, 18, 13, 0)), (40, (9, 4, 20, 19, 14, 0))]
+
+
+def test_an_admission_hashes_each_prompt_token_once_a_pass(tiny_lm,
+                                                           monkeypatch):
+    """Counted, not timed: admitting an n-token prompt feeds the hash at
+    most 4 n bytes in ``_fit`` (lookup and COW source off ONE chain) and
+    4 n in ``_adopt_prefix_pages``, in a number of ``update`` calls that
+    grows with the pages — for a prompt the cache has never seen and for
+    a page-aligned repeat that hits every page and copies the last."""
+    from tpunet.serve.prefixcache import keys as pk
+    fed = []
+    monkeypatch.setattr(pk, "hashlib", counting_hashlib(fed))
+    eng = make_engine(tiny_lm, slots=2, kv_pages=24, kv_page_tokens=4)
+    prompt = np.random.default_rng(5).integers(
+        0, TINY.vocab_size, size=16).astype(np.int32)
+    pages, passes = 4, {}
+    fit, adopt = eng._fit, eng._adopt_prefix_pages
+
+    def counted(name, fn):
+        def run(*args, **kw):
+            before = len(fed)
+            out = fn(*args, **kw)
+            passes.setdefault(name, []).append(fed[before:])
+            return out
+        return run
+
+    monkeypatch.setattr(eng, "_fit", counted("fit", fit))
+    monkeypatch.setattr(eng, "_adopt_prefix_pages",
+                        counted("adopt", adopt))
+    for turn in ("miss", "hit"):
+        fed.clear()
+        passes.clear()
+        (req,) = drive(eng, [(0, prompt, dict(max_new_tokens=3))])
+        assert req.tokens == solo_greedy(tiny_lm, prompt, 3)
+        for name in ("fit", "adopt"):
+            calls = [c for c in passes[name] if c]
+            assert len(calls) == 1, (turn, name, passes)
+            assert sum(calls[0]) <= 4 * prompt.size
+            assert len(calls[0]) <= pages + 1
+        assert sum(fed) <= 2 * 4 * prompt.size
+    snap = eng.registry.snapshot()
+    assert snap["serve_prefix_cow_total"] == 1
+    assert snap["serve_prefix_hit_tokens_total"] == 12
+    assert snap["serve_prefix_inserts_total"] == 4
+
+
+def test_spilled_file_names_are_the_flat_digests(tmp_path, tiny_lm):
+    """A store directory written by any version names a page by the flat
+    digest of the tokens through it: the per-token reference, kept here,
+    names the files this engine spills."""
+    import hashlib
+    from tpunet.serve.prefixcache import build_prefix_store
+
+    def flat(tokens, n):
+        h = hashlib.sha256()
+        for t in tokens[:n]:
+            h.update(int(t).to_bytes(4, "little", signed=True))
+        return h.hexdigest()[:16]
+
+    model, variables = tiny_lm
+    cfg = ServeConfig(slots=2, queue_max=8, prefill_buckets=(16,),
+                      default_max_new_tokens=6, emit_every_s=0.0,
+                      kv_pages=12, kv_page_tokens=4)
+    store = build_prefix_store(str(tmp_path), TINY, cfg)
+    prompt = np.random.default_rng(31).integers(
+        0, TINY.vocab_size, size=14).astype(np.int32)
+    eng = Engine(model, variables, cfg, prefix_store=store)
+    drive(eng, [(0, prompt, dict(max_new_tokens=2))])
+    assert sorted(p.name for p in tmp_path.glob("*.pfx")) == sorted(
+        f"{store.store_digest}-{flat(prompt, 4 * (d + 1))}.pfx"
+        for d in range(3))
+    assert [(e["depth"], e["parent"]) for e in store.load_all()] == [
+        (0, "root"), (1, flat(prompt, 4)), (2, flat(prompt, 8))]
 
 
 def test_prefix_store_of_the_old_row_shape_is_refused(tmp_path, tiny_lm):

@@ -1248,8 +1248,9 @@ class Engine:
         pt = self.page_tokens
         full = int(slot.req.prompt.size) // pt
         prev = slot.pinned[-1] if slot.pinned else None
-        for j in range(len(slot.pinned), full):
-            digest = pk.token_prefix_digest(resume, (j + 1) * pt)
+        first = len(slot.pinned)
+        for j, digest in enumerate(
+                pk.iter_chain_digests(resume, pt, full, first), first):
             node = self._prefix.get(digest)
             if node is not None:
                 # Duplicate: recycle our private page, share theirs.
@@ -1738,16 +1739,21 @@ class Engine:
                 # token is always re-prefilled — the logits at
                 # position n-1 come from compute, never from
                 # cached K/V (pages store only K/V rows).
+                # ONE pass over the prompt's key chain: the lookup
+                # draws from it as far as it hits, the COW source is
+                # its next key.
+                digests = pk.iter_chain_digests(
+                    resume, self.page_tokens, n // self.page_tokens)
                 pinned = self._prefix.lookup(
-                    resume, (n - 1) // self.page_tokens)
+                    resume, (n - 1) // self.page_tokens,
+                    digests=digests)
                 start = len(pinned) * self.page_tokens
                 if n % self.page_tokens == 0 and pinned \
                         and start == n - self.page_tokens:
                     # Full page-aligned match: the divergence page
                     # is cached too. COW it below instead of
                     # re-prefilling its whole page.
-                    cow_src = self._prefix.get(
-                        pk.token_prefix_digest(resume, n))
+                    cow_src = self._prefix.get(next(digests))
                 # Pin BEFORE allocating: allocation may evict
                 # unpinned cache pages, and the chain (and COW
                 # source) must survive until mapped/copied.

@@ -16,6 +16,7 @@ only hashes digests.
 
 from tpunet.serve.prefixcache.cache import PrefixCache, PrefixNode
 from tpunet.serve.prefixcache.keys import (ROOT, chain_digests,
+                                           iter_chain_digests,
                                            token_prefix_digest)
 from tpunet.serve.prefixcache.store import PrefixStore, build_prefix_store
 
@@ -26,5 +27,6 @@ __all__ = [
     "ROOT",
     "build_prefix_store",
     "chain_digests",
+    "iter_chain_digests",
     "token_prefix_digest",
 ]

@@ -7,14 +7,21 @@ stays the reserved garbage page). What the cache owns is the HOST
 bookkeeping that lets finished prefills outlive their slot:
 
 - a trie of :class:`PrefixNode`, one node per cached full page,
-  keyed by the digest of the token prefix THROUGH that page
-  (``keys.token_prefix_digest(tokens, (depth+1)*page_tokens)``) — so
-  two prompts sharing the first k pages share the first k nodes;
+  keyed by the digest of the token prefix THROUGH that page (flat in
+  value — ``keys.token_prefix_digest(tokens, (depth+1)*page_tokens)``
+  — and drawn page by page from ``keys.iter_chain_digests``, which
+  hashes each prompt token once) — so two prompts sharing the first k
+  pages share the first k nodes;
 - a refcount per node (slots currently mapping the page into their
   page table) — pinned pages are immutable and never freed;
 - an LRU over EVICTABLE nodes: ``refs == 0`` and no children.
   Leaf-first eviction keeps every cached chain prefix-closed, which
   is what makes lookup's "walk down while present" correct.
+
+What an admission costs here grows with ITS pages alone (PERF.md
+section 6, PR 38): lookup and adoption hash each prompt token once,
+and the eviction victim comes off a heap — no pass over the trie on
+the engine's step path.
 
 Threading: all mutation happens on the engine thread (the same
 discipline as the page allocator); no locks here.
@@ -29,7 +36,9 @@ stress test extends the zero-stale-bleed proof to this regime.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+import heapq
+from itertools import islice
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 from tpunet.serve.prefixcache import keys
 
@@ -42,11 +51,13 @@ class PrefixNode:
     of this page; ``parent`` is the depth d-1 node (None at depth 0).
     ``page`` is the pool page index holding the rows. ``refs`` counts
     slots whose page table currently maps this page. ``tick`` is the
-    cache's logical clock at last touch (LRU order).
+    cache's logical clock at last touch (LRU order); ``seq`` the
+    node's insertion number, which orders nodes of one tick (one
+    ``pin`` / ``unpin`` call stamps its whole chain alike).
     """
 
     __slots__ = ("digest", "parent", "children", "page", "refs",
-                 "tick", "depth")
+                 "tick", "depth", "seq")
 
     def __init__(self, digest: str, parent: Optional["PrefixNode"],
                  depth: int, page: int):
@@ -57,6 +68,7 @@ class PrefixNode:
         self.refs = 0
         self.tick = 0
         self.depth = depth
+        self.seq = 0
 
 
 class PrefixCache:
@@ -75,6 +87,11 @@ class PrefixCache:
         self.capacity = int(capacity)
         self._nodes: Dict[str, PrefixNode] = {}
         self._tick = 0
+        self._seq = 0
+        # (tick, seq, node) of every evictable node, and of nodes that
+        # were evictable at that tick and are not now: evict_one skips
+        # those (lazy invalidation), _offer bounds how many pile up.
+        self._lru: List[Tuple[int, int, PrefixNode]] = []
         self._reg = registry
         if registry is not None:
             self._c_lookups = registry.counter("serve_prefix_lookups_total")
@@ -110,17 +127,24 @@ class PrefixCache:
 
     # -- lookup / pin ----------------------------------------------------
 
-    def lookup(self, tokens: Sequence[int],
-               max_pages: int) -> List[PrefixNode]:
+    def lookup(self, tokens: Sequence[int], max_pages: int, *,
+               digests: Optional[Iterator[str]] = None
+               ) -> List[PrefixNode]:
         """The longest cached chain covering the first full pages of
         ``tokens``, capped at ``max_pages`` — counted as one lookup
         (and one hit when non-empty). Does NOT pin; the engine pins
-        only once the slot's remaining allocation succeeded."""
+        only once the slot's remaining allocation succeeded.
+
+        Lazy: the walk stops hashing at the first miss. ``digests`` is
+        a ``keys.iter_chain_digests`` over ``tokens`` from depth 0 that
+        the caller goes on drawing from — after a full chain its next
+        element keys depth ``max_pages`` (the engine's COW source)."""
         chain: List[PrefixNode] = []
         pt = self.page_tokens
-        for d in range(max_pages):
-            node = self._nodes.get(
-                keys.token_prefix_digest(tokens, (d + 1) * pt))
+        if digests is None:
+            digests = keys.iter_chain_digests(tokens, pt, max_pages)
+        for digest in islice(digests, max_pages):
+            node = self._nodes.get(digest)
             if node is None:
                 break
             chain.append(node)
@@ -135,6 +159,11 @@ class PrefixCache:
         """refcount++ each node (slot admission mapped its page)."""
         self._tick += 1
         for n in nodes:
+            if self._lru and self._lru[-1][2] is n:
+                # Insert-then-pin, the engine's adoption: the entry
+                # insert just queued is still the heap's last leaf and
+                # comes off at once instead of going stale.
+                self._lru.pop()
             n.refs += 1
             n.tick = self._tick
 
@@ -147,6 +176,7 @@ class PrefixCache:
             n.refs -= 1
             assert n.refs >= 0, "prefix page unpinned below zero"
             n.tick = self._tick
+            self._offer(n)
 
     # -- insert / evict --------------------------------------------------
 
@@ -164,27 +194,49 @@ class PrefixCache:
             parent.children.add(node)
         self._tick += 1
         node.tick = self._tick
+        self._seq += 1
+        node.seq = self._seq
         self._nodes[digest] = node
+        self._offer(node)
         if self._c_inserts is not None:
             self._c_inserts.inc()
             self._g_pages.set(len(self._nodes))
         return node
 
+    def _offer(self, node: PrefixNode) -> None:
+        """Queue ``node`` for eviction at its present tick if it is
+        evictable. Called wherever a node can BECOME evictable: its
+        insert, its last unpin, the eviction of its last child."""
+        if node.refs or node.children:
+            return
+        if len(self._lru) > 2 * len(self._nodes) + 64:
+            # Pins and re-pins of a cache that never fills leave stale
+            # entries nobody pops: rebuild from the live ones.
+            self._lru = [(n.tick, n.seq, n) for n in self._nodes.values()
+                         if n.refs == 0 and not n.children and n is not node]
+            heapq.heapify(self._lru)
+        heapq.heappush(self._lru, (node.tick, node.seq, node))
+
     def evict_one(self) -> Optional[int]:
         """Drop the least-recently-touched evictable node (refs == 0,
-        no children) and return its pool page for the free list; None
-        when nothing is evictable (every cached page is pinned by a
-        live slot or interior to a pinned chain)."""
+        no children; of one tick, the one inserted first) and return
+        its pool page for the free list; None when nothing is
+        evictable (every cached page is pinned by a live slot or
+        interior to a pinned chain)."""
         victim: Optional[PrefixNode] = None
-        for n in self._nodes.values():
-            if n.refs == 0 and not n.children:
-                if victim is None or n.tick < victim.tick:
-                    victim = n
+        while self._lru:
+            tick, _, n = heapq.heappop(self._lru)
+            if n.tick == tick and n.refs == 0 and not n.children \
+                    and self._nodes.get(n.digest) is n:
+                victim = n
+                break
         if victim is None:
             return None
         del self._nodes[victim.digest]
-        if victim.parent is not None:
-            victim.parent.children.discard(victim)
+        parent = victim.parent
+        if parent is not None:
+            parent.children.discard(victim)
+            self._offer(parent)
         if self._c_evictions is not None:
             self._c_evictions.inc()
             self._g_pages.set(len(self._nodes))
