@@ -650,3 +650,78 @@ class TestFlashAttention:
             assert np.isfinite(m["loss"])
         finally:
             trainer.close()
+
+
+# -- flash_prefill: grouped K/V and a sliding window (forward only) -----------
+
+def _prefill_reference(q, k, v, window):
+    """float64 on the host: query head h on KV head h // group, query t
+    on keys t - window < s <= t."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    b, t, h, d = q.shape
+    grp = h // k.shape[2]
+    out = np.zeros_like(q)
+    at = np.arange(t)
+    keep = at[None, :] <= at[:, None]
+    if window:
+        keep &= at[None, :] > at[:, None] - window
+    for i in range(h):
+        s = np.einsum("bqd,bkd->bqk", q[:, :, i], k[:, :, i // grp]) \
+            * d ** -0.5
+        s = np.where(keep, s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[:, :, i] = np.einsum("bqk,bkd->bqd", p / p.sum(-1, keepdims=True),
+                                 v[:, :, i // grp])
+    return out
+
+
+@pytest.mark.parametrize("t,h,hkv,d,window,block", [
+    (64, 8, 2, 128, 24, 8),       # a band of 4 blocks, group 4
+    (96, 6, 3, 128, 33, 32),      # a window that is no multiple of the block
+    (128, 16, 1, 128, 17, 16),    # group 16, a band of 2
+    (64, 8, 8, 64, 16, 16),       # ungrouped under a window
+    (64, 4, 2, 64, None, 16),     # grouped on the causal triangle
+    (64, 8, 2, 128, 200, 16),     # a window wider than the row: none
+])
+def test_flash_prefill_kernel_matches_the_grouped_windowed_reference(
+        t, h, hkv, d, window, block):
+    from tpunet.ops.flash import flash_prefill, grouped_window_attention
+    rng = np.random.default_rng(t + h)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, t, n, d)), jnp.float32)
+               for n in (h, hkv, hkv))
+    want = _prefill_reference(q, k, v, 0 if (window or 0) >= t else window)
+    got = flash_prefill(q, k, v, window=window, block=block, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
+    # off the TPU the entry takes the dense form: the same numbers
+    dense = flash_prefill(q, k, v, window=window, block=block)
+    np.testing.assert_allclose(np.asarray(dense), want, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(grouped_window_attention(
+        q, k, v, scale=d ** -0.5, window=window or 0)), want, atol=1e-5)
+
+
+def test_flash_prefill_grouped_is_the_repeated_kernel_bit_for_bit():
+    """K and V read by head group = K and V repeated to the query heads
+    through the same kernel: not one bit differs (what the hybrid
+    decoder's prefill did before)."""
+    from tpunet.ops.flash import flash_attention, flash_prefill
+    rng = np.random.default_rng(5)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 64, n, 128)),
+                           jnp.bfloat16) for n in (8, 2, 2))
+    got = flash_prefill(q, k, v, block=16, interpret=True)
+    want = flash_attention(q, jnp.repeat(k, 4, axis=2),
+                           jnp.repeat(v, 4, axis=2), causal=True,
+                           block_q=16, block_k=16, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_flash_prefill_band_walks_only_the_blocks_in_sight():
+    from tpunet.ops.flash import _band_blocks, flash_prefill
+    assert _band_blocks(4096, 512, 16) == 9      # 8 behind and its own
+    assert _band_blocks(4097, 512, 16) == 9
+    assert _band_blocks(4098, 512, 16) == 10
+    assert _band_blocks(1, 512, 16) == 1 and _band_blocks(24, 8, 8) == 4
+    assert _band_blocks(4096, 512, 4) == 4       # never more than the row
+    with pytest.raises(ValueError, match="whole groups"):
+        flash_prefill(jnp.zeros((1, 16, 6, 64)), jnp.zeros((1, 16, 4, 64)),
+                      jnp.zeros((1, 16, 4, 64)))

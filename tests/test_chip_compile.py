@@ -473,3 +473,70 @@ def test_hybrid_masked_step_fits_the_chip(one_chip, no_compile_cache,
                  r"f32\[32,3,8192\]"):
         assert re.search(pool, text)
         assert not re.search(pool + r"\S* copy\(", text)
+
+
+# -- the parallel decoder's two programs at published widths -------------------
+
+@pytest.fixture(scope="module")
+def parallel_engine():
+    """The serve engine of ``command-a-plus-05-2026.serve-closed16-ctx8k``
+    as the benchmark's runner builds it, never run: the 4.73 B parameters
+    are zero-stride views of one zero, the page pools real zeros."""
+    import numpy as np
+
+    from benchmark import harness, weights
+    from tpunet.config import ModelConfig, ServeConfig
+    from tpunet.models import create_model
+    from tpunet.serve.engine import Engine
+
+    cell = harness.load_cell("command-a-plus-05-2026.serve-closed16-ctx8k")
+    config = cell["config"]
+    spec = harness.load_reference(cell).param_spec(config, "serve")
+    zero = np.zeros((), jnp.bfloat16)
+    params = weights.nest({path: np.broadcast_to(zero, shape)
+                           for path, (shape, _, _) in spec.items()})
+    serve = dict(cell["cell"]["program"]["serve"])
+    serve["prefill_buckets"] = tuple(serve["prefill_buckets"])
+    return Engine(create_model(ModelConfig(**config["program"]["model"])),
+                  {"params": params}, ServeConfig(**serve))
+
+
+@pytest.mark.parametrize("width", [1, 8192])
+def test_parallel_masked_step_fits_the_chip(one_chip, no_compile_cache,
+                                            monkeypatch, parallel_engine,
+                                            width):
+    """The engine's own masked step, ``[16, 1]`` and ``[1, 8192]``, over
+    9.47 GB of weights and 16 x 10,240 tokens of K/V pages in four
+    layers: compiled for the chip with at least 1 GiB to spare; the
+    width-1 program attends through ``tpunet_paged_decode`` (grouped,
+    windowed in three layers), the bucket-wide one through the flash
+    kernel; neither copies a page pool."""
+    import re
+
+    monkeypatch.setattr(paged_decode, "_on_tpu", lambda: True)
+    monkeypatch.setattr(paged_decode, "_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # dispatch
+    eng = parallel_engine
+    assert eng.state_pool_bytes() == 0 and eng._prefix is not None
+    assert eng.kv_pool_bytes() == 4 * 2 * (16 * 640 + 1) * 16 * 1024 * 2
+    assert (eng._window_tokens, eng._window_bytes_share) == (4096, 0.75)
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._step_avals(width))
+    compiled = eng._step.lower(*avals).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print(f"parallel masked step width {width}: arguments "
+          f"{m.argument_size_in_bytes} outputs {m.output_size_in_bytes} "
+          f"aliased {m.alias_size_in_bytes} temporaries "
+          f"{m.temp_size_in_bytes} total {total}")
+    assert total + (1 << 30) <= V5E_BYTES_LIMIT, total
+    assert 4 * total >= V5E_BYTES_LIMIT             # the driver's 25 % floor
+    assert m.alias_size_in_bytes >= eng.kv_pool_bytes()   # pools donated
+    text = compiled.as_text()
+    assert ("tpunet_paged_decode" in text) == (width == 1)
+    assert ("tpunet_flash_fwd" in text) == (width > 1)
+    pool = r"bf16\[163856,1024\]"
+    assert re.search(pool, text)
+    assert not re.search(pool + r"\S* copy\(", text)

@@ -52,10 +52,11 @@ def make_pool(rng, heads, head_dim, dtype, slots, garbage=0.0):
             table)
 
 
-def dense_reference(q, k_pool, v_pool, table, lengths, kv_heads=None):
+def dense_reference(q, k_pool, v_pool, table, lengths, kv_heads=None,
+                    window=None):
     """What the dense path computes, in float64 on the host; grouped
     (``kv_heads`` < the query heads), query head h reads KV head
-    ``h // group``."""
+    ``h // group``; with ``window`` a row's last ``window`` keys."""
     b, q_heads, head_dim = q.shape
     heads = q_heads if kv_heads is None else kv_heads
     hd = heads * head_dim
@@ -65,11 +66,12 @@ def dense_reference(q, k_pool, v_pool, table, lengths, kv_heads=None):
         n = int(lengths[i])
         if n == 0:
             continue
-        rows = (table[i][:, None] * PT + np.arange(PT)).reshape(-1)[:n]
+        rows = (table[i][:, None] * PT + np.arange(PT)).reshape(-1)[
+            max(0, n - window) if window else 0:n]
         k = np.asarray(k_pool, np.float64)[rows, :hd].reshape(
-            n, heads, head_dim)
+            len(rows), heads, head_dim)
         v = np.asarray(v_pool, np.float64)[rows, :hd].reshape(
-            n, heads, head_dim)
+            len(rows), heads, head_dim)
         k, v = (np.repeat(x, q_heads // heads, axis=1) for x in (k, v))
         s = np.einsum("hd,khd->hk", q[i], k) * head_dim ** -0.5
         p = np.exp(s - s.max(-1, keepdims=True))
@@ -101,6 +103,43 @@ def test_grouped_heads_match_dense(q_heads, kv_heads, head_dim, dtype):
     got = run_kernel(q, k_pool, v_pool, table, lengths, kv_heads=kv_heads)
     want = dense_reference(q, k_pool, v_pool, table, lengths, kv_heads)
     assert got.shape == (len(lengths), q_heads, head_dim)
+    np.testing.assert_allclose(got, want, atol=TOL[dtype], rtol=0)
+    assert not got[[0, -1]].any()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("q_heads,kv_heads,head_dim,window",
+                         [(32, 2, 128, 40),      # group 16, 2.5 pages
+                          (32, 2, 128, CHUNK),   # exactly one chunk
+                          (5, 5, 64, PT + 3),    # ungrouped, odd heads
+                          (4, 1, 128, 1)])       # the token itself
+def test_window_starts_at_its_chunk_and_masks_before_it(
+        q_heads, kv_heads, head_dim, window, dtype):
+    """A sliding layer: lengths below, at and past the window, across a
+    page and a chunk edge. Every pool row that lies in a chunk BEFORE
+    the one holding key ``length - window`` is NaN: the work list must
+    start past it. (The rows of that first chunk before the window are
+    fetched and masked, so they hold numbers, as a live pool's do.)"""
+    rng = np.random.default_rng(window)
+    lengths = np.array((0,) + tuple(sorted(
+        {1, window - 1 or 1, window, window + 1, CHUNK - 1, CHUNK,
+         CHUNK + 1, min(CHUNK + window, PPS * PT - 1), PPS * PT}))
+        + (0,), np.int32)
+    k_pool, v_pool, table = make_pool(rng, kv_heads, head_dim, dtype,
+                                      len(lengths), garbage=np.nan)
+    k_pool, v_pool = np.array(k_pool, np.float32), np.array(v_pool,
+                                                             np.float32)
+    for i, n in enumerate(lengths):
+        dead = max(0, int(n) - window) // CHUNK * CHUNK   # whole chunks
+        rows = (table[i][:, None] * PT + np.arange(PT)).reshape(-1)[:dead]
+        k_pool[rows], v_pool[rows] = np.nan, np.nan
+    k_pool, v_pool = jnp.asarray(k_pool, dtype), jnp.asarray(v_pool, dtype)
+    q = jnp.asarray(rng.normal(size=(len(lengths), q_heads, head_dim)),
+                    dtype)
+    got = run_kernel(q, k_pool, v_pool, table, lengths, kv_heads=kv_heads,
+                     window=window)
+    want = dense_reference(q, k_pool, v_pool, table, lengths, kv_heads,
+                           window)
     np.testing.assert_allclose(got, want, atol=TOL[dtype], rtol=0)
     assert not got[[0, -1]].any()
 

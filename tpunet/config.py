@@ -165,7 +165,9 @@ class ModelConfig:
     # max_seq_len, dtype and param_dtype above apply as for "lm".
     # ``model_type`` "qwen3_next" among them selects the hybrid family
     # (tpunet/models/hybrid_mixers.py: linear attention with a per-slot
-    # state beside grouped-query attention).
+    # state beside grouped-query attention), "cohere2_moe" the parallel
+    # family (one LayerNorm a block feeds windowed or position-free
+    # grouped-query attention and the expert layer side by side).
     latent: Optional[Mapping[str, Any]] = None
     # Weight of the multi-token-prediction loss where the model has
     # such a module (the train step adds it to the next-token loss).

@@ -1,6 +1,12 @@
-"""The two mixers of a hybrid decoder (``LatentArch.model_type``
+"""The mixers beside latent attention under ``latent_lm``'s block and LM
+shell: the delta rule of a hybrid decoder (``LatentArch.model_type``
 ``qwen3_next``; the benchmark's ``qwen3-next-80b-a3b`` is the worked
-configuration), under ``latent_lm``'s block and LM shell.
+configuration) and ONE grouped-query attention whose properties are the
+family's — the hybrid decoder's is gated, with per-head norms and
+partial rotate-half rotary; the parallel family's (``cohere2_moe``; the
+benchmark's ``command-a-plus-05-2026``) has neither gate nor norm, and
+per layer kind interleaved rotary positions under a sliding window or no
+positions at all.
 
 - ``GatedDeltaNet`` (``linear_attention`` layers): linear attention by
   the gated delta rule. Per value head a state ``S`` [d_k, d_v] float32
@@ -16,14 +22,15 @@ configuration), under ``latent_lm``'s block and LM shell.
   by ``silu(z)``. What a sequence leaves behind is FIXED in size: ``S``
   and the last ``kernel - 1`` rows of the convolution's input — a row of
   the engine's state pool, one per slot, beside the page pools.
-- ``GatedAttention`` (``full_attention`` layers): grouped-query softmax
+- ``GroupedQueryAttention`` (``full_attention`` and, in the parallel
+  family, ``sliding_attention`` layers): grouped-query softmax
   attention (``num_attention_heads`` queries over
-  ``num_key_value_heads`` keys/values of ``head_dim``), zero-centred
-  RMSNorm per head on q and k, rotary positions on the first
-  ``partial_rotary_factor`` of each head, and a sigmoid gate per output
-  number from the query projection's second half. Its cache is paged:
-  ``k`` (after norm and rotary) and ``v``, ``num_key_value_heads *
-  head_dim`` numbers each a token.
+  ``num_key_value_heads`` keys/values of ``head_dim``); gate, per-head
+  norms, rotary share and layout, positions or none, window or none
+  are ``LatentArch.gqa(kind)``'s. Its cache is paged: ``k`` (after
+  norm and rotary) and ``v``, ``num_key_value_heads * head_dim``
+  numbers each a token; a sliding layer keeps every position in pages
+  too (one page table) and says how far back it reads.
 
 Each mixer states what it keeps (``cache_spec``): numbers a token keeps
 in page pools, and arrays a SLOT keeps in the state pool. The engine and
@@ -313,20 +320,30 @@ class GatedDeltaNet(nn.Module):
             return jnp.dot(y.reshape(b, t, hv * dv), w_out.astype(dt))
 
 
-# -- gated grouped-query attention ---------------------------------------------
+# -- grouped-query attention ----------------------------------------------------
 
-class GatedAttention(nn.Module):
-    """One gated ``full_attention`` layer of the hybrid decoder on the
-    block's normed input ``u`` [B, T, C]; see the module's text.
+class GroupedQueryAttention(nn.Module):
+    """One grouped-query softmax-attention layer on the block's normed
+    input ``u`` [B, T, C]: ``num_attention_heads`` queries over
+    ``num_key_value_heads`` keys/values of ``head_dim``, query head h on
+    KV head ``h // group``. What else it has is the family's, read from
+    ``LatentArch.gqa(kind)``: a sigmoid gate per output number from the
+    query projection's second half, zero-centred RMSNorm per head on q
+    and k, rotary positions on the leading ``rotary`` dims of a head in
+    the rotate-half or the interleaved layout — or no positions at all —
+    and a ``window`` of keys a query looks back over (itself counted).
+    The cache is paged: ``k`` (after norm and rotary) and ``v``.
 
     Three calls: one token per row against the paged pool (the kernel
     ``tpunet_paged_decode`` where ``paged_decode.kernel_applies``, else
-    the row's pages gathered and dense); a wider call whose rows all
-    start at position 0 (a prefill with no adopted prefix, a plain
-    forward) through the flash kernel over the call's own tokens, K and
-    V repeated to the query heads; a wider call that continues a row
-    (an adopted prefix) over the row's pooled keys in position order,
-    queries in blocks."""
+    the row's pages gathered and dense; a window starts the kernel's
+    walk at the chunk that holds the oldest key in sight); a wider call
+    whose rows all start at position 0 (a prefill with no adopted
+    prefix, a plain forward) through the flash kernel over the call's
+    own tokens (``flash_prefill``: K and V read by head group, a band
+    of blocks under a window); a wider call that continues a row (an
+    adopted prefix) over the row's pooled keys in position order,
+    queries in blocks, a windowed block over its own stretch of keys."""
 
     arch: LatentArch
     kind: str = "full_attention"
@@ -335,17 +352,18 @@ class GatedAttention(nn.Module):
 
     @classmethod
     def cache_spec(cls, a: LatentArch, kind: str, dtype) -> dict:
+        z = a.gqa(kind)
         width = lane_rounded(a.num_key_value_heads * a.head_dim)
-        return {"paged": {"kv": (2 * width, dtype)}, "state": {},
-                "decode_kernel": True}
+        return {"paged": {z["cache"]: (2 * width, dtype)}, "state": {},
+                "decode_kernel": True, "window": z["window"]}
 
     @nn.compact
     def __call__(self, u, decode: bool = False, positions=None,
                  active=None, paged_kv=None, page_table=None,
                  train: bool = False, state_rows=None, lengths=None):
-        a = self.arch
+        a, z = self.arch, self.arch.gqa(self.kind)
         h, hkv, d = a.num_attention_heads, a.num_key_value_heads, a.head_dim
-        grp, rot = h // hkv, int(d * a.partial_rotary_factor)
+        grp, rot, window = h // hkv, z["rotary"], z["window"]
         b, t, c = u.shape
         dt, eps, scale = self.dtype, a.rms_norm_eps, d ** -0.5
         u = u.astype(dt)
@@ -354,41 +372,47 @@ class GatedAttention(nn.Module):
         def w(name, *shape, init=init):
             return self.param(name, init, shape, self.param_dtype)
 
-        w_q, w_k, w_v = (w("q_proj", c, h * 2 * d), w("k_proj", c, hkv * d),
-                         w("v_proj", c, hkv * d))
+        w_q = w("q_proj", c, h * (2 * d if z["gated"] else d))
+        w_k, w_v = w("k_proj", c, hkv * d), w("v_proj", c, hkv * d)
         w_o = w("o_proj", h * d, c)
-        qn, kn = (w(n, d, init=nn.initializers.zeros)
-                  for n in ("q_norm", "k_norm"))
+        qn, kn = ((w(n, d, init=nn.initializers.zeros)
+                   for n in ("q_norm", "k_norm")) if z["qk_norm"]
+                  else (None, None))
         if positions is None:
             positions = jnp.zeros((b,), jnp.int32)
         pos_t = positions[:, None] + jnp.arange(t)[None, :]
 
-        def rotary(x):
-            return jnp.concatenate(
-                [rope(x[..., :rot], pos_t, float(a.rope_theta)),
-                 x[..., rot:]], -1)
+        def placed(x, norm):
+            """A head's numbers as they are scored (and cached)."""
+            if norm is not None:
+                x = rms_norm(x, norm, eps, offset=1.0)
+            if not rot:
+                return x
+            turned = rope(x[..., :rot], pos_t, float(a.rope_theta),
+                          interleaved=z["layout"] == "interleaved")
+            return turned if rot == d else jnp.concatenate(
+                [turned, x[..., rot:]], -1)
 
-        with jax.named_scope("tpunet_gqa_full"):
-            qg = jnp.dot(u, w_q.astype(dt)).reshape(b, t, h, 2 * d)
-            q, gate = qg[..., :d], qg[..., d:]
+        with jax.named_scope(z["scope"]):
+            q = jnp.dot(u, w_q.astype(dt)).reshape(b, t, h, -1)
+            q, gate = q[..., :d], (q[..., d:] if z["gated"] else None)
             k = jnp.dot(u, w_k.astype(dt)).reshape(b, t, hkv, d)
             v = jnp.dot(u, w_v.astype(dt)).reshape(b, t, hkv, d)
-            q = rotary(rms_norm(q, qn, eps, offset=1.0))
-            k = rotary(rms_norm(k, kn, eps, offset=1.0))
+            q, k = placed(q, qn), placed(k, kn)
 
         def project_out(o):
-            with jax.named_scope("tpunet_gqa_full"):
-                o = o.astype(jnp.float32) * jax.nn.sigmoid(
-                    gate.astype(jnp.float32))
+            with jax.named_scope(z["scope"]):
+                if gate is not None:
+                    o = o.astype(jnp.float32) * jax.nn.sigmoid(
+                        gate.astype(jnp.float32))
                 return jnp.dot(o.astype(dt).reshape(b, t, h * d),
                                w_o.astype(dt))
 
         def own_tokens():
-            from tpunet.ops.flash import flash_attention
-            with jax.named_scope("tpunet_gqa_full"):
-                return flash_attention(
-                    q, jnp.repeat(k, grp, axis=2), jnp.repeat(v, grp, axis=2),
-                    causal=True, scale=scale).astype(dt)
+            from tpunet.ops.flash import flash_prefill
+            with jax.named_scope(z["scope"]):
+                return flash_prefill(q, k, v, scale=scale,
+                                     window=window).astype(dt)
 
         if not decode:
             return project_out(own_tokens())
@@ -412,7 +436,7 @@ class GatedAttention(nn.Module):
             raise ValueError("paged decode requires engine-owned per-row "
                              "positions and a page table")
 
-        with jax.named_scope("tpunet_gqa_full"):
+        with jax.named_scope(z["scope"]):
             page = jnp.take_along_axis(
                 page_table, jnp.clip(pos_t // pt, 0, page_table.shape[1] - 1),
                 axis=1)
@@ -433,13 +457,17 @@ class GatedAttention(nn.Module):
                          .astype(dt).reshape(k_max, hkv, d)
                          for var in (ck, cv))
 
-        def attend(q_, qpos, kf, vf):
-            """``q_`` [Q, H, D] at positions ``qpos`` over one row's
-            pooled keys -> [Q, H, D] float32."""
+        def attend(q_, qpos, kf, vf, first=0):
+            """``q_`` [Q, H, D] at positions ``qpos`` over a row's
+            pooled keys from position ``first`` on -> [Q, H, D]
+            float32."""
             n = q_.shape[0]
             s = jnp.einsum("qngd,knd->ngqk", q_.reshape(n, hkv, grp, d), kf,
                            preferred_element_type=jnp.float32) * scale
-            keep = jnp.arange(k_max)[None, :] <= qpos[:, None]
+            kpos = first + jnp.arange(kf.shape[0])
+            keep = kpos[None, :] <= qpos[:, None]
+            if window:
+                keep = keep & (kpos[None, :] > qpos[:, None] - window)
             p = jax.nn.softmax(jnp.where(keep[None, None], s, _NEG_INF), -1)
             o = jnp.einsum("ngqk,knd->qngd", p.astype(dt), vf,
                            preferred_element_type=jnp.float32)
@@ -447,14 +475,15 @@ class GatedAttention(nn.Module):
 
         if t == 1:
             from tpunet.ops import paged_decode
-            with jax.named_scope("tpunet_gqa_full"):
+            with jax.named_scope(z["scope"]):
                 if paged_decode.kernel_applies(paged_kv, t, store):
                     live = positions + 1
                     if active is not None:
                         live = jnp.where(active, live, 0)
                     o = paged_decode.paged_decode_attention(
                         q[:, 0], ck.value, cv.value, page_table, live,
-                        page_tokens=pt, scale=scale, kv_heads=hkv)[:, None]
+                        page_tokens=pt, scale=scale, kv_heads=hkv,
+                        window=window)[:, None]
                 else:
                     o = jax.vmap(lambda q_, pos, table: attend(
                         q_, pos[None], *pooled(table)))(
@@ -462,15 +491,33 @@ class GatedAttention(nn.Module):
             return project_out(o)
 
         def continued():
+            # (blocks sized to the score tensor: 512 queries of 16 heads)
+            bq = _block(t, max(8, _Q_BLOCK * 16 // h))
+            kb = min(k_max, bq + window - 1) if window else k_max
+
             def row(q_, start, table):
-                bq = _block(t, _Q_BLOCK)
                 kf, vf = pooled(table)
-                o = lax.map(lambda xs: attend(*xs, kf, vf), (
+
+                def block(xs):
+                    q_b, qpos_b = xs
+                    if kb == k_max:
+                        return attend(q_b, qpos_b, kf, vf)
+                    first = jnp.clip(qpos_b[0] - (window - 1), 0,
+                                     k_max - kb)
+                    return attend(
+                        q_b, qpos_b,
+                        lax.dynamic_slice_in_dim(kf, first, kb),
+                        lax.dynamic_slice_in_dim(vf, first, kb), first)
+
+                o = lax.map(block, (
                     q_.reshape(t // bq, bq, h, d),
                     (start + jnp.arange(t)).reshape(t // bq, bq)))
                 return o.reshape(t, h, d).astype(dt)
-            with jax.named_scope("tpunet_gqa_full"):
+            with jax.named_scope(z["scope"]):
                 return by_row(row, active, q, positions, page_table)
 
         return project_out(lax.cond(jnp.all(positions == 0), own_tokens,
                                     continued))
+
+
+GatedAttention = GroupedQueryAttention   # the hybrid family's layers are gated

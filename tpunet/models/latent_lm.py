@@ -37,6 +37,19 @@ shell (``models/hybrid_mixers.py``; the benchmark's
 delta rule, whose per-slot state lives in the engine's state pool, beside
 gated grouped-query ``full_attention`` layers; zero-centred norm weights;
 a softmax router and a gated shared expert in ``RoutedShareMlp``.
+``model_type`` ``cohere2_moe`` is the third (the benchmark's
+``command-a-plus-05-2026``, served): a PARALLEL block — one norm a
+layer, ``x + Attn(n) + FFN(n)`` from the same ``n``, so the two halves
+have no data dependence — with a mean-subtracting LayerNorm that has a
+weight and no bias (block and final), the head TIED to the embedding
+(times ``logit_scale``), ``sliding_attention`` / ``full_attention``
+layers that name the grouped-query mixer of ``hybrid_mixers.py`` over a
+KEY/VALUE cache (sliding layers: interleaved rotary positions and a
+``sliding_window``; full layers: no positions at all), and a sigmoid
+router without a bias beside ``num_shared_experts`` averaged shared
+experts. A wide call of this family takes the FFN ``_FFN_ROWS`` tokens
+at a time: its experts are 4,096 wide, and a bucket's (token, expert)
+pair tables would not fit beside the weights.
 
 Three forms of one mathematics, chosen by the call:
 
@@ -105,6 +118,7 @@ from tpunet.ops.attention import _NEG_INF
 _LANES = 128
 _Q_BLOCK_FULL = 128      # queries per block, full layers (K = whole row)
 _Q_BLOCK_WINDOW = 512    # queries per block, sliding layers (K = block + window)
+_FFN_ROWS = 2048         # tokens of a wide row the parallel family's FFN takes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,15 +179,32 @@ class LatentArch:
     linear_key_head_dim: int = 128
     linear_value_head_dim: int = 128
     linear_conv_kernel_dim: int = 4
+    # -- the parallel family (``model_type`` "cohere2_moe"): one
+    # LayerNorm a block feeds attention and FFN side by side; grouped-
+    # query attention over K/V pages, ``sliding_attention`` layers with
+    # interleaved rotary positions over ``sliding_window`` keys (the
+    # token itself counts) and ``full_attention`` layers with no
+    # positions; ``num_shared_experts`` averaged; the head tied to the
+    # embedding and scaled. ``intermediate_size`` is its experts' width.
+    layer_norm_eps: float = 1e-5
+    sliding_window: Optional[int] = None
+    rotary_pct: float = 1.0
+    logit_scale: float = 1.0
+    num_shared_experts: int = 1
 
     @classmethod
     def from_mapping(cls, m) -> "LatentArch":
         known = {f.name for f in dataclasses.fields(cls)} | {"num_experts"}
-        unknown = set(m) - known
+        kw = dict(m)
+        if kw.get("model_type") == "cohere2_moe":
+            kw = _parallel_keys(kw)
+        elif set(kw) & _PARALLEL_ONLY:
+            raise ValueError("latent_lm: only cohere2_moe reads "
+                             f"{sorted(set(kw) & _PARALLEL_ONLY)}")
+        unknown = set(kw) - known
         if unknown:
             raise ValueError(f"latent_lm: unknown keys {sorted(unknown)}")
-        kw = dict(m)
-        if "num_experts" in kw:          # the hybrid family's name for it
+        if "num_experts" in kw:          # the later families' name for it
             kw["n_routed_experts"] = kw.pop("num_experts")
         for key in ("layer_types", "held_experts"):
             if kw.get(key) is not None:
@@ -188,7 +219,7 @@ class LatentArch:
         if arch.num_nextn_predict_layers not in (0, 1):
             raise ValueError("latent_lm builds one multi-token-prediction "
                              "module at most")
-        if arch.model_type not in (None, "qwen3_next"):
+        if arch.model_type not in (None, "qwen3_next", "cohere2_moe"):
             raise ValueError(f"latent_lm: unknown model_type "
                              f"{arch.model_type!r}")
         kinds = (("full_attention", "linear_attention") if arch.hybrid
@@ -196,14 +227,23 @@ class LatentArch:
         if set(arch.layer_types) - set(kinds):
             raise ValueError(f"latent_lm: layer_types of model_type "
                              f"{arch.model_type!r} are {kinds}")
-        if arch.hybrid and (
+        if (arch.hybrid or arch.parallel) and (
                 not arch.num_key_value_heads or not arch.head_dim
                 or arch.num_attention_heads % arch.num_key_value_heads
                 or arch.linear_num_value_heads % arch.linear_num_key_heads):
             raise ValueError(
-                "latent_lm: qwen3_next needs head_dim and a "
+                f"latent_lm: {arch.model_type} needs head_dim and a "
                 "num_key_value_heads that divides num_attention_heads, and "
                 "linear_num_key_heads dividing linear_num_value_heads")
+        if arch.parallel and (
+                arch.first_k_dense_replace or not arch.sliding_window
+                or arch.sliding_window < 1 or arch.num_shared_experts < 1
+                or int(arch.head_dim * arch.rotary_pct) % 2):
+            raise ValueError(
+                "latent_lm: cohere2_moe needs a sliding_window, shared "
+                "experts, an even rotary share of head_dim, and "
+                "first_k_dense_replace 0 (prefix dense layers are not "
+                "built)")
         return arch
 
     @property
@@ -211,10 +251,36 @@ class LatentArch:
         return self.model_type == "qwen3_next"
 
     @property
+    def parallel(self) -> bool:
+        return self.model_type == "cohere2_moe"
+
+    @property
     def norm_offset(self) -> float:
         """What a norm adds to its weight: the hybrid family's are
         zero-centred (``1 + w``, initial ``w`` 0)."""
         return 1.0 if self.hybrid else 0.0
+
+    def gqa(self, kind: str) -> dict:
+        """What the grouped-query mixer (``hybrid_mixers.py``) of a
+        layer of ``kind`` has: a sigmoid ``gated`` output, ``qk_norm``
+        per head, how many leading dims of a head are rotated
+        (``rotary``, 0 = no positions) in which ``layout``, over how
+        many keys it looks back (``window``, None = all), and the
+        ``scope`` and page-cache name its work and rows go under."""
+        d = self.head_dim
+        if self.hybrid:
+            return {"gated": True, "qk_norm": True, "window": None,
+                    "rotary": int(d * self.partial_rotary_factor),
+                    "layout": "rotate_half", "scope": "tpunet_gqa_full",
+                    "cache": "kv"}
+        sliding = kind == "sliding_attention"
+        return {"gated": False, "qk_norm": False,
+                "window": self.sliding_window if sliding else None,
+                "rotary": int(d * self.rotary_pct) if sliding else 0,
+                "layout": "interleaved",
+                "scope": "tpunet_gqa_window" if sliding
+                else "tpunet_gqa_full",
+                "cache": "kv_window" if sliding else "kv"}
 
     def layer(self, kind: str) -> dict:
         """Sizes of one attention kind: heads, latent ranks, head dims,
@@ -232,6 +298,46 @@ class LatentArch:
                 "topk": self.index_topk if full else None,
                 "s_q": math.sqrt(self.hidden_size / rq) if scale else 1.0,
                 "s_kv": math.sqrt(self.hidden_size / rkv) if scale else 1.0}
+
+
+# What the parallel family's block IS: a published switch is taken only
+# at the value this block builds, and then dropped.
+_PARALLEL_BUILT = {
+    "use_parallel_block": True, "use_qk_norm": False,
+    "position_embedding_type": "rope_gptj", "tie_word_embeddings": True,
+    "expert_selection_fn": "sigmoid", "norm_topk_prob": True,
+    "shared_expert_combination_strategy": "average",
+    "use_gated_activation": True, "hidden_act": "silu",
+    "attention_bias": False}
+# The numbers it reads. Every other key ``LatentArch`` knows belongs to
+# latent attention or the delta rule: a silent default there would be
+# a size the configuration never reads.
+_PARALLEL_KEYS = {
+    "model_type", "hidden_size", "num_hidden_layers", "layer_types",
+    "intermediate_size", "first_k_dense_replace", "num_attention_heads",
+    "num_key_value_heads", "head_dim", "rope_theta", "rotary_pct",
+    "sliding_window", "layer_norm_eps", "logit_scale", "num_experts",
+    "num_experts_per_tok", "num_shared_experts", "held_experts"}
+_PARALLEL_ONLY = {"layer_norm_eps", "sliding_window", "rotary_pct",
+                  "logit_scale", "num_shared_experts"}
+
+
+def _parallel_keys(kw: dict) -> dict:
+    """A ``cohere2_moe`` mapping as ``LatentArch`` holds it: the
+    switches checked and dropped, ``intermediate_size`` (the width of
+    one expert, routed or shared) under the expert width's name."""
+    for key, built in _PARALLEL_BUILT.items():
+        if key in kw and kw.pop(key) != built:
+            raise ValueError(f"latent_lm: cohere2_moe is built with "
+                             f"{key} = {built!r}")
+    foreign = set(kw) - _PARALLEL_KEYS
+    if foreign:
+        raise ValueError("latent_lm: cohere2_moe does not read "
+                         f"{sorted(foreign)} (latent attention's, the delta "
+                         "rule's or unknown keys)")
+    if "intermediate_size" in kw:
+        kw["moe_intermediate_size"] = kw["intermediate_size"]
+    return kw
 
 
 # -- arithmetic ---------------------------------------------------------------
@@ -262,25 +368,50 @@ def norm_param(module, name: str, n: int, arch: "LatentArch", dtype):
     return module.param(name, init, (n,), dtype)
 
 
-def layer_norm(x, scale, bias, eps):
+def layer_norm(x, scale, bias, eps, dtype=None):
+    """LayerNorm in float32 (``bias`` None: a weight alone); the result
+    in ``dtype`` (``x``'s own by default)."""
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, -1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mean), -1, keepdims=True)
-    y = (xf - mean) * lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32)
-            + bias.astype(jnp.float32)).astype(x.dtype)
+    y = (xf - mean) * lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(dtype or x.dtype)
 
 
-def rope(x, pos, theta: float):
-    """Rotate-half rotary embedding of ``x`` [..., T, d] or
-    [..., T, H, d] at integer positions ``pos`` [..., T]."""
+def block_norm(arch: "LatentArch", v, scale, dtype):
+    """A block's (or the final) norm of the residual stream, as the
+    family has it: the parallel family's LayerNorm without bias, else
+    RMSNorm (zero-centred where the family's weights are)."""
+    if arch.parallel:
+        return layer_norm(v, scale, None, arch.layer_norm_eps, dtype)
+    return rms_norm(v, scale, arch.rms_norm_eps, dtype=dtype,
+                    offset=arch.norm_offset)
+
+
+def rope(x, pos, theta: float, interleaved: bool = False):
+    """Rotary embedding of ``x`` [..., T, d] or [..., T, H, d] at
+    integer positions ``pos`` [..., T]: pair j is ``(x_j, x_{j + d/2})``
+    (rotate-half) or, ``interleaved``, ``(x_{2j}, x_{2j+1})``, rotated
+    by ``pos * theta ** (-2j / d)``. The interleaved form stays in the
+    lane layout: each number's partner is its neighbour, fetched by two
+    shifts and a select, no ``[..., d/2, 2]`` view."""
     d = x.shape[-1]
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos.astype(jnp.float32)[..., None] * freq           # [..., T, d/2]
     if x.ndim == ang.ndim + 1:
         ang = ang[..., None, :]                               # heads axis
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        cos, sin = jnp.repeat(cos, 2, axis=-1), jnp.repeat(sin, 2, axis=-1)
+        even = jnp.arange(d) % 2 == 0
+        partner = jnp.where(even, jnp.roll(xf, -1, axis=-1),
+                            jnp.roll(xf, 1, axis=-1))
+        return (xf * cos + partner * jnp.where(even, -sin, sin)).astype(
+            x.dtype)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            -1).astype(x.dtype)
 
@@ -372,12 +503,15 @@ def mixer_class(arch: LatentArch, kind: str):
     pools, lane-rounded rows as stored; their dtype)}``, ``state`` =
     ``{name: (shape, dtype)}`` of what a SLOT keeps whatever its
     length, ``decode_kernel`` = whether its one-token call attends
-    through ``tpunet_paged_decode`` where that kernel applies."""
-    if not arch.hybrid:
+    through ``tpunet_paged_decode`` where that kernel applies, and —
+    where the mixer reads only a row's newest positions — ``window`` =
+    how many (absent or None: it reads them all; the pages wholly
+    behind it are what an allocator by layer kind would free)."""
+    if not (arch.hybrid or arch.parallel):
         return LatentAttention
     from tpunet.models import hybrid_mixers
     return (hybrid_mixers.GatedDeltaNet if kind == "linear_attention"
-            else hybrid_mixers.GatedAttention)
+            else hybrid_mixers.GroupedQueryAttention)
 
 
 # -- the attention layer ------------------------------------------------------
@@ -696,11 +830,13 @@ class LatentAttention(nn.Module):
 # -- the model ----------------------------------------------------------------
 
 class LatentBlock(nn.Module):
-    """``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the
-    FFN dense (``dense`` True) or the expert layer. A wide call
-    (T > 1) takes the FFN one batch row at a time, skipping the rows
-    ``row_active`` marks idle; a training call (``train``) takes it
-    over every token of the batch at once."""
+    """``h = x + Attn(norm(x))``, ``y = h + FFN(norm(h))`` or, the
+    parallel family, ``y = x + Attn(n) + FFN(n)`` with the one
+    ``n = norm(x)``; the FFN dense (``dense`` True) or the expert layer.
+    A wide call (T > 1) takes the FFN one batch row at a time, skipping
+    the rows ``row_active`` marks idle (the parallel family:
+    ``_FFN_ROWS`` tokens of a row at a time); a training call
+    (``train``) takes it over every token of the batch at once."""
 
     arch: LatentArch
     kind: str
@@ -717,39 +853,51 @@ class LatentBlock(nn.Module):
         row_active = active if (decode and wide) else None
 
         def norm(name, v):
-            return rms_norm(v, norm_param(self, name, c, a, self.param_dtype),
-                            a.rms_norm_eps, dtype=jnp.float32,
-                            offset=a.norm_offset)
+            return block_norm(a, v, norm_param(self, name, c, a,
+                                               self.param_dtype), jnp.float32)
 
-        x = x + mixer_class(a, self.kind)(
-            a, self.kind, dtype=self.dtype, param_dtype=self.param_dtype,
-            name="linear_attn" if self.kind == "linear_attention"
-            else "attn")(
-            norm("ln1", x), decode, positions, active, paged_kv,
-            page_table, train, state_rows, lengths).astype(x.dtype)
-        u = norm("ln2", x)
-        if train:                # every token a row of its own, as in decode
-            u = u.reshape(b * t, 1, c)
-        if self.dense:
-            init = nn.initializers.normal(stddev=0.02)
-            f = a.intermediate_size
-            mlp = [self.param(f"mlp_{n}", init, shape, self.param_dtype)
-                   for n, shape in (("gate", (c, f)), ("up", (c, f)),
-                                    ("down", (f, c)))]
-            ffn = lambda u_: gated_silu(u_, *mlp, self.dtype)  # noqa: E731
-            with jax.named_scope("tpunet_dense_mlp"):
-                y = by_row(ffn, row_active, u) if wide else ffn(u)
-        else:
-            y = RoutedShareMlp(
-                a.n_routed_experts, a.moe_intermediate_size,
-                a.num_experts_per_tok, held=a.held_experts,
-                scaling=a.routed_scaling_factor,
-                scoring="softmax" if a.hybrid else "sigmoid",
-                shared_gate=a.hybrid, dtype=self.dtype,
-                param_dtype=self.param_dtype, name="moe")(
-                    u if wide else u[:, 0], row_active)
-            y = y if wide else y[:, None]
-        return x + y.reshape(b, t, c).astype(x.dtype)
+        def mix(u):
+            return mixer_class(a, self.kind)(
+                a, self.kind, dtype=self.dtype, param_dtype=self.param_dtype,
+                name="linear_attn" if self.kind == "linear_attention"
+                else "attn")(
+                u, decode, positions, active, paged_kv, page_table, train,
+                state_rows, lengths).astype(x.dtype)
+
+        def ffn(u):
+            rows, on = b, row_active
+            if train:            # every token a row of its own, as in decode
+                u = u.reshape(b * t, 1, c)
+            elif wide and a.parallel and t % _FFN_ROWS == 0:
+                rows = b * (t // _FFN_ROWS)
+                u = u.reshape(rows, _FFN_ROWS, c)
+                on = None if on is None else jnp.repeat(on, rows // b)
+            if self.dense:
+                init = nn.initializers.normal(stddev=0.02)
+                f = a.intermediate_size
+                mlp = [self.param(f"mlp_{n}", init, shape, self.param_dtype)
+                       for n, shape in (("gate", (c, f)), ("up", (c, f)),
+                                        ("down", (f, c)))]
+                one = lambda u_: gated_silu(u_, *mlp, self.dtype)  # noqa: E731
+                with jax.named_scope("tpunet_dense_mlp"):
+                    y = by_row(one, on, u) if wide else one(u)
+            else:
+                y = RoutedShareMlp(
+                    a.n_routed_experts, a.moe_intermediate_size,
+                    a.num_experts_per_tok, held=a.held_experts,
+                    scaling=a.routed_scaling_factor,
+                    scoring="softmax" if a.hybrid else "sigmoid",
+                    shared_gate=a.hybrid, n_shared=a.num_shared_experts,
+                    router_bias=not a.parallel, dtype=self.dtype,
+                    param_dtype=self.param_dtype, name="moe")(
+                        u if wide else u[:, 0], on)
+            return y.reshape(b, t, c).astype(x.dtype)
+
+        if a.parallel:
+            u = norm("ln1", x)
+            return x + mix(u) + ffn(u)
+        x = x + mix(norm("ln1", x))
+        return x + ffn(norm("ln2", x))
 
 
 def _block_class(remat: bool):
@@ -900,18 +1048,24 @@ class LatentLM(nn.Module):
                       name=f"block{i:02d}")(
                 x, decode, positions, decode_active, paged_kv, page_table,
                 train, state_rows, lengths)
-        h = rms_norm(x, norm_param(self, "ln", a.hidden_size, a,
-                                   self.param_dtype),
-                     a.rms_norm_eps, dtype=self.dtype, offset=a.norm_offset)
+        h = block_norm(a, x, norm_param(self, "ln", a.hidden_size, a,
+                                        self.param_dtype), self.dtype)
         if return_hidden:
             return h.astype(jnp.float32)
-        head = self.param("head", nn.initializers.normal(stddev=0.02),
-                          (a.hidden_size, self.vocab_size), self.param_dtype)
+        if a.parallel:           # the head is the embedding, scaled
+            head, vocab_axis = embed.embedding, 0
+        else:
+            head, vocab_axis = self.param(
+                "head", nn.initializers.normal(stddev=0.02),
+                (a.hidden_size, self.vocab_size), self.param_dtype), 1
 
         def logits_of(h_):
             with jax.named_scope("tpunet_head"):
-                return jnp.dot(h_, head.astype(self.dtype),
-                               preferred_element_type=jnp.float32)
+                out = lax.dot_general(
+                    h_, head.astype(self.dtype),
+                    (((h_.ndim - 1,), (1 - vocab_axis,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return out * a.logit_scale if a.logit_scale != 1.0 else out
 
         if not (a.num_nextn_predict_layers
                 and (train or self.is_initializing())):

@@ -8,14 +8,15 @@
    text describes it.
 2. ``RoutedShareMlp`` / ``routed_share`` — the no-drop layer that
    *serves* (model ``latent_lm``, the benchmark's ``dots3-note-prev``):
-   sigmoid gates with a selection bias, top-k renormalised over the
-   selected, gated-SiLU experts beside one shared expert, and no
+   sigmoid gates (with a selection bias or none) or softmax, top-k
+   renormalised over the selected, gated-SiLU experts beside one shared
+   expert or several averaged ones, and no
    capacity: the (token, expert) pairs are sorted by expert and go
    through one grouped product (``jax.lax.ragged_dot``). The layer is
    told which experts it ``held`` (one chip's share under expert
    parallelism): the router keeps all its outputs, the top-k and the
    normalisation run over all of them, and the layer returns the part
-   of the sum its own experts give plus the shared expert. Nothing
+   of the sum its own experts give plus the shared part. Nothing
    stands in for the absent chips.
 
 The reference is a dense CNN (SURVEY.md 2b lists EP/MoE as absent);
@@ -88,7 +89,7 @@ routing under pipe > 1 (tpunet/models/lm_pp.py).
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -439,12 +440,13 @@ def route_sigmoid(u, router, bias, top_k: int, scaling: float = 1.0):
     """Sigmoid routing without auxiliary loss (``noaux_tc``): ``u``
     [n, d] -> (expert ids [n, k] int32, weights [n, k] float32). The
     scores ``p = sigmoid(W_r u)`` and everything after them are
-    float32; ``bias`` only chooses (top-k of ``p + bias``), the weights
-    are ``p`` renormalised over the chosen k."""
+    float32; ``bias`` only chooses (top-k of ``p + bias``; None: of
+    ``p``), the weights are ``p`` renormalised over the chosen k."""
     p = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32),
                                router.astype(jnp.float32),
                                precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(p + bias.astype(jnp.float32), top_k)
+    _, idx = jax.lax.top_k(
+        p if bias is None else p + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(p, idx, axis=-1)
     return idx, scaling * chosen / jnp.sum(chosen, -1, keepdims=True)
 
@@ -631,12 +633,15 @@ _experts_of_held.defvjp(_experts_of_held_fwd, _experts_of_held_bwd)
 
 
 def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
-                 scaling: float = 1.0, dtype=jnp.bfloat16):
+                 scaling: float = 1.0, dtype=jnp.bfloat16,
+                 scoring: Optional[str] = None):
     """One chip's share of a routed expert layer, without capacity.
 
     ``u`` [n, d]; ``router`` [d, E] and ``bias`` [E] over ALL ``E``
-    experts (``bias`` None: softmax routing, ``route_softmax``, which
-    has neither bias nor scaling); ``gate``/``up`` [len(held), d, f] and ``down`` [len(held),
+    experts; ``scoring`` "softmax" (``route_softmax``, which has neither
+    bias nor scaling; what a ``bias`` of None means where ``scoring`` is
+    not given) or "sigmoid" (``route_sigmoid``, with or without a
+    bias); ``gate``/``up`` [len(held), d, f] and ``down`` [len(held),
     f, d] for the experts held here; ``held`` their ids (static).
     Returns ``(y [n, d] in ``dtype``, stats)`` with ``y = sum over the
     chosen experts that are held of weight * expert(u)``: the pairs are
@@ -656,8 +661,10 @@ def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
     n, d = u.shape
     h = len(held)
     m = n * top_k
+    if scoring is None:
+        scoring = "softmax" if bias is None else "sigmoid"
     with jax.named_scope("tpunet_moe_router"):
-        if bias is None:
+        if scoring == "softmax":
             idx, weight = route_softmax(u, router, top_k)
         else:
             idx, weight = route_sigmoid(u, router, bias, top_k, scaling)
@@ -696,14 +703,20 @@ def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
 
 
 class RoutedShareMlp(nn.Module):
-    """``routed_share`` plus the shared expert: the FFN of an expert
+    """``routed_share`` plus the shared experts: the FFN of an expert
     layer as one chip of an expert-parallel deployment computes it, on
     ``x`` [n, d] or, one batch row at a time (``by_row``, skipping rows
     whose ``row_active`` is False), on ``x`` [B, T, d]. ``held`` lists
     the routed experts whose weights live here (all of them by
     default). ``scoring`` "softmax" routes by ``route_softmax`` (no
-    ``router_bias`` parameter then, ``scaling`` unused); ``shared_gate``
-    multiplies the shared expert's output by ``sigmoid(x . w)``, one
+    ``router_bias`` parameter then, ``scaling`` unused); "sigmoid" has
+    that parameter unless ``router_bias`` is False (a configuration
+    that chooses by the scores alone). ``n_shared`` shared experts of
+    ``width`` are AVERAGED, as one gated product ``n_shared * width``
+    wide times ``1 / n_shared`` (the hidden columns of the experts side
+    by side: the down projection sums over all of them, which is the
+    sum of the experts' outputs); ``shared_gate``
+    multiplies the shared part by ``sigmoid(x . w)``, one
     number a token (``shared_expert_gate`` [d, 1]). The routing load is ``sow``n into the ``stats``
     collection (per batch row for a 3-D ``x``): free unless a caller
     makes it mutable (the serve engine's step does not; the LM train
@@ -716,6 +729,8 @@ class RoutedShareMlp(nn.Module):
     scaling: float = 1.0
     scoring: str = "sigmoid"           # sigmoid | softmax
     shared_gate: bool = False
+    n_shared: int = 1                  # shared experts, averaged
+    router_bias: bool = True           # sigmoid scoring: a bias that chooses
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -734,20 +749,23 @@ class RoutedShareMlp(nn.Module):
             raise ValueError(f"unknown scoring {self.scoring!r}")
         bias = (self.param("router_bias", nn.initializers.zeros,
                            (self.n_experts,), self.param_dtype)
-                if self.scoring == "sigmoid" else None)
+                if self.scoring == "sigmoid" and self.router_bias else None)
         experts = (w("experts_gate", len(held), d, f),
                    w("experts_up", len(held), d, f),
                    w("experts_down", len(held), f, d))
-        shared = (w("shared_gate", d, f), w("shared_up", d, f),
-                  w("shared_down", f, d))
+        fs = self.n_shared * f
+        shared = (w("shared_gate", d, fs), w("shared_up", d, fs),
+                  w("shared_down", fs, d))
         open_w = w("shared_expert_gate", d, 1) if self.shared_gate else None
 
         def ffn(u):
             y, stats = routed_share(u, router, bias, *experts, held,
                                     top_k=self.top_k, scaling=self.scaling,
-                                    dtype=self.dtype)
+                                    dtype=self.dtype, scoring=self.scoring)
             with jax.named_scope("tpunet_moe_shared"):
                 y_shared = gated_silu(u, *shared, self.dtype)
+                if self.n_shared > 1:
+                    y_shared = y_shared * (1.0 / self.n_shared)
                 if open_w is not None:
                     y_shared = (y_shared * jax.nn.sigmoid(jnp.dot(
                         u.astype(jnp.float32), open_w.astype(jnp.float32),

@@ -44,6 +44,13 @@ Design notes:
 - Rows of a chunk past the live length are never fetched; what the
   buffer holds there is stale VMEM, so K's scores are masked and V's
   rows are zeroed before the product (0 * NaN is NaN).
+- ``window`` (static; a sliding-attention layer): a row attends to its
+  last ``window`` keys only (the newest counts). Its work list then
+  STARTS at the chunk that holds key ``live - window``: nothing before
+  that chunk is fetched, whatever the row's length, and the keys of
+  that chunk that lie before the window are masked (they are pool rows
+  the row wrote earlier, so their V rows are finite and a zero
+  probability drops them). Without a window the program is what it was.
 - Off-TPU the caller keeps the dense path (the Pallas interpreter is
   far too slow for an engine step); tests drive this kernel body on the
   CPU with ``interpret=True``, the scheme of flash.py and fused_ir.py.
@@ -102,19 +109,26 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
             kbuf, vbuf, sems, work_row, work_chunk, qbd_ref, m_ref,
             l_ref, acc_ref, *, slots: int, pages_per_slot: int,
             page_tokens: int, pages_per_chunk: int, heads: int,
-            head_dim: int, scale: float, split_p: bool, group: int = 1):
+            head_dim: int, scale: float, split_p: bool, group: int = 1,
+            window: int = 0):
     pt, ppc = page_tokens, pages_per_chunk
     ct = pt * ppc                          # keys per chunk
     hp, w = acc_ref.shape
 
-    # -- the work list: every (row, chunk) that holds a live key -------
+    def first_chunk(length):
+        """The chunk that holds a row's oldest key in sight."""
+        if not window:
+            return 0
+        return jnp.maximum(length - window, 0) // ct
+
+    # -- the work list: every (row, chunk) that holds a key in sight ---
     def list_row(b, n):
         def list_chunk(c, n):
             work_row[n] = b
             work_chunk[n] = c
             return n + 1
-        return lax.fori_loop(0, pl.cdiv(lengths_ref[b], ct), list_chunk,
-                             n)
+        return lax.fori_loop(first_chunk(lengths_ref[b]),
+                             pl.cdiv(lengths_ref[b], ct), list_chunk, n)
     n_work = lax.fori_loop(0, slots, list_row, jnp.int32(0))
 
     def live_copies(i, slot, act: str) -> None:
@@ -163,7 +177,7 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
         def _():
             live_copies(i + 1, 1 - slot, "start")
 
-        @pl.when(c == 0)
+        @pl.when(c == first_chunk(length))
         def _():
             # Selects run on 32-bit lanes (Mosaic cannot carry a
             # mask between the 32-bit and the packed 16-bit tiling).
@@ -196,12 +210,14 @@ def _kernel(lengths_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
         v = vbuf[slot]
         s = lax.dot_general(qbd, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        live = (c * ct + lax.broadcasted_iota(jnp.int32, (hp, ct), 1)
-                < length)
+        kpos = c * ct + lax.broadcasted_iota(jnp.int32, (hp, ct), 1)
+        live = kpos < length
+        if window:
+            live = live & (kpos >= length - window)
         s = jnp.where(live, s, _NEG_INF)                     # [hp, ct]
         m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # every listed chunk holds a live key, so m_new is a real
+        # every listed chunk holds a key in sight, so m_new is a real
         # score and a masked one's exp is exactly 0
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
@@ -244,7 +260,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            lengths: jax.Array, *, page_tokens: int,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
-                           kv_heads: Optional[int] = None) -> jax.Array:
+                           kv_heads: Optional[int] = None,
+                           window: Optional[int] = None) -> jax.Array:
     """One query token per row against its own pages of the pool.
 
     ``q`` [B, H, D]; ``k_pool`` / ``v_pool`` [pool_rows, pool_width(H, D)]
@@ -256,7 +273,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     ``page_table`` [B, pages_per_slot] int32 page ids; ``lengths`` [B]
     int32 live keys per row (0 = inactive: nothing read, zeros out).
     Returns [B, H, D] in ``q``'s dtype — softmax(q k^T * scale) v over
-    keys ``0 .. lengths[b] - 1`` of row b, float32 inside.
+    keys ``0 .. lengths[b] - 1`` of row b (with ``window``: over the
+    last ``window`` of them), float32 inside.
     ``interpret`` defaults to "off the TPU"."""
     if interpret is None:
         interpret = _interpret()
@@ -268,10 +286,13 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         raise ValueError(f"{heads} query heads of {q.shape[-1]} over "
                          f"{kv_heads} KV heads: the group must be whole and "
                          f"a grouped head a multiple of {_LANES} lanes")
-    # (group 1 traces the ungrouped program, as it always was)
+    if window is not None and window < 1:
+        raise ValueError(f"window {window}: a row sees itself at least")
+    # (group 1 without a window traces the program as it always was)
     return _attend(q, k_pool, v_pool, page_table, lengths,
                    page_tokens=page_tokens, scale=float(scale),
-                   interpret=bool(interpret), group=heads // kv_heads)
+                   interpret=bool(interpret), group=heads // kv_heads,
+                   window=int(window or 0))
 
 
 # jit: a model calls this once per layer with one signature, and an
@@ -279,9 +300,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 # separate lowerings of the kernel body cost a GPT-2 XL engine 74 s of
 # set-up in every process, compile cache or not; my chip runs, PR 26).
 @functools.partial(jax.jit, static_argnames=("page_tokens", "scale",
-                                             "interpret", "group"))
+                                             "interpret", "group",
+                                             "window"))
 def _attend(q, k_pool, v_pool, page_table, lengths, *, page_tokens,
-            scale, interpret, group=1):
+            scale, interpret, group=1, window=0):
     b, heads, head_dim = q.shape
     w = pool_width(heads // group, head_dim)
     if k_pool.shape[1] != w or v_pool.shape != k_pool.shape:
@@ -306,7 +328,8 @@ def _attend(q, k_pool, v_pool, page_table, lengths, *, page_tokens,
     kern = functools.partial(
         _kernel, slots=b, pages_per_slot=pages_per_slot,
         page_tokens=page_tokens, pages_per_chunk=ppc, heads=heads,
-        head_dim=head_dim, scale=scale, split_p=split_p, group=group)
+        head_dim=head_dim, scale=scale, split_p=split_p, group=group,
+        window=window)
     max_work = b * (-(-pages_per_slot // ppc))
     whole = lambda i, *_: (0, 0, 0)      # noqa: E731 — one invocation
     # Scope: hlo_bytes.KERNEL_SCOPES' convention (<prefix>_fwd); the

@@ -73,7 +73,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -278,6 +278,21 @@ def _shapes_of(tree):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
 
+def _windowed_cache(specs) -> Tuple[int, float]:
+    """``(window, share)`` from the mixers' ``cache_spec``s: the longest
+    ``window`` any layer states (0: every layer reads its whole row)
+    and the share of a token's paged bytes that the windowed layers
+    keep."""
+    def paged_bytes(spec):
+        return sum(width * np.dtype(dtype).itemsize
+                   for width, dtype in spec["paged"].values())
+    windowed = [s for s in specs if s.get("window")]
+    if not windowed:
+        return 0, 0.0
+    return (max(s["window"] for s in windowed),
+            sum(map(paged_bytes, windowed)) / sum(map(paged_bytes, specs)))
+
+
 class _Slot:
     """Host-side bookkeeping for one KV-cache row."""
 
@@ -406,6 +421,16 @@ class Engine:
         # cache is built, and speculative decoding is refused.
         self._state_bytes_per_slot = int(
             getattr(model, "state_bytes_per_slot", 0))
+        # -- pages behind a window (a mixer's ``cache_spec`` "window") -
+        # Layers that read only a row's newest ``window`` positions
+        # still keep every position in pages (one page table): of the
+        # page-bytes the decoding slots hold, the share that lies
+        # wholly behind the window is what an allocator by layer kind
+        # would free. Counted per dispatched decode step (two integer
+        # sums over its rows), published per record.
+        self._window_tokens, self._window_bytes_share = _windowed_cache(
+            getattr(model, "cache_specs", list)())
+        self._window_pages = [0, 0]     # [dead, held], summed over steps
         if self._state_bytes_per_slot and getattr(cfg, "spec_decode",
                                                   False):
             raise ValueError(
@@ -2133,6 +2158,11 @@ class Engine:
                 rows.append((i, slot, self._ends_by_length(slot)))
             self._in_flight = _Step(self._sampled, rows, t0)
             reg.counter("serve_decode_steps_total").inc()
+            if self._window_tokens:
+                pt, seen = self.page_tokens, self._window_pages
+                for _, slot in live:
+                    seen[0] += max(0, (slot.pos - self._window_tokens) // pt)
+                    seen[1] += -(-slot.pos // pt)
             if flight is not None:
                 reg.counter("serve_decode_steps_overlapped_total").inc()
                 self._read_decode(flight)
@@ -2380,6 +2410,10 @@ class Engine:
             reg.gauge("serve_host_max_s_" + phase).set(
                 round(total.longest, 6))
             total.longest = 0.0
+        if self._window_pages[1]:
+            dead, held = self._window_pages
+            reg.gauge("serve_cache_window_dead_pct").set(round(
+                100.0 * self._window_bytes_share * dead / held, 4))
         record = build_serve_record(
             reg, queue_depth=self.queue.depth(),
             active_slots=self.active_slots(), slots=self.slots,
