@@ -439,15 +439,25 @@ def hybrid_engine():
                   {"params": params}, ServeConfig(**serve))
 
 
-@pytest.mark.parametrize("width", [1, 8192])
+# A bucket-wide call is handed the table columns its positions reach
+# (``Engine._reach``): 640 = the whole row, the worst program a continued
+# row can run; 512 = a fresh admission of the 8,192 bucket, what a cell runs.
+WIDTH_AND_COLUMNS = pytest.mark.parametrize(
+    "width, columns", [(1, 640), (8192, 640), (8192, 512)],
+    ids=["1", "8192-whole-row", "8192-fresh"])
+
+
+@WIDTH_AND_COLUMNS
 def test_hybrid_masked_step_fits_the_chip(one_chip, no_compile_cache,
-                                          monkeypatch, hybrid_engine, width):
-    """The engine's own masked step, ``[32, 1]`` and ``[1, 8192]``, over
-    7.33 GB of weights, 32 x 10,240 tokens of K/V pages and 32 state
-    rows: compiled for the chip with at least 1 GiB to spare; the
-    width-1 program attends through ``tpunet_paged_decode`` (grouped),
-    the bucket-wide one through the flash kernel; neither copies a page
-    pool or the state pool."""
+                                          monkeypatch, hybrid_engine, width,
+                                          columns):
+    """The engine's own masked step, ``[32, 1]`` and ``[1, 8192]`` (over
+    the whole row's keys and over a fresh admission's), over 7.33 GB of
+    weights, 32 x 10,240 tokens of K/V pages and 32 state rows: compiled
+    for the chip with at least 1 GiB to spare; the width-1 program
+    attends through ``tpunet_paged_decode`` (grouped), the bucket-wide
+    one through the flash kernel; neither copies a page pool or the
+    state pool."""
     import re
 
     monkeypatch.setattr(paged_decode, "_on_tpu", lambda: True)
@@ -456,13 +466,17 @@ def test_hybrid_masked_step_fits_the_chip(one_chip, no_compile_cache,
     eng = hybrid_engine
     assert eng.state_pool_bytes() == 32 * 6 * (32 * 128 * 128 + 3 * 8192) * 4
     assert eng.kv_pool_bytes() == 2 * 2 * (32 * 640 + 1) * 16 * 512 * 2
+    assert (eng.pages_per_slot, eng._reach(1), eng._reach(8192)) == \
+        (640, 640, 512)
     avals = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        eng._step_avals(width))
+        eng._step_avals(width, columns))
     compiled = eng._step.lower(*avals).compile()
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print(f"hybrid masked step width {width} columns {columns}: "
+          f"temporaries {m.temp_size_in_bytes} total {total}")
     assert total + (1 << 30) <= V5E_BYTES_LIMIT, total
     assert m.alias_size_in_bytes >= eng.kv_pool_bytes() \
         + eng.state_pool_bytes()                  # the pools are donated
@@ -501,16 +515,17 @@ def parallel_engine():
                   {"params": params}, ServeConfig(**serve))
 
 
-@pytest.mark.parametrize("width", [1, 8192])
+@WIDTH_AND_COLUMNS
 def test_parallel_masked_step_fits_the_chip(one_chip, no_compile_cache,
                                             monkeypatch, parallel_engine,
-                                            width):
-    """The engine's own masked step, ``[16, 1]`` and ``[1, 8192]``, over
-    9.47 GB of weights and 16 x 10,240 tokens of K/V pages in four
-    layers: compiled for the chip with at least 1 GiB to spare; the
-    width-1 program attends through ``tpunet_paged_decode`` (grouped,
-    windowed in three layers), the bucket-wide one through the flash
-    kernel; neither copies a page pool."""
+                                            width, columns):
+    """The engine's own masked step, ``[16, 1]`` and ``[1, 8192]`` (over
+    the whole row's keys and over a fresh admission's), over 9.47 GB of
+    weights and 16 x 10,240 tokens of K/V pages in four layers: compiled
+    for the chip with at least 1 GiB to spare; the width-1 program
+    attends through ``tpunet_paged_decode`` (grouped, windowed in three
+    layers), the bucket-wide one through the flash kernel; neither
+    copies a page pool."""
     import re
 
     monkeypatch.setattr(paged_decode, "_on_tpu", lambda: True)
@@ -520,14 +535,16 @@ def test_parallel_masked_step_fits_the_chip(one_chip, no_compile_cache,
     assert eng.state_pool_bytes() == 0 and eng._prefix is not None
     assert eng.kv_pool_bytes() == 4 * 2 * (16 * 640 + 1) * 16 * 1024 * 2
     assert (eng._window_tokens, eng._window_bytes_share) == (4096, 0.75)
+    assert (eng.pages_per_slot, eng._reach(1), eng._reach(8192)) == \
+        (640, 640, 512)
     avals = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        eng._step_avals(width))
+        eng._step_avals(width, columns))
     compiled = eng._step.lower(*avals).compile()
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    print(f"parallel masked step width {width}: arguments "
+    print(f"parallel masked step width {width} columns {columns}: arguments "
           f"{m.argument_size_in_bytes} outputs {m.output_size_in_bytes} "
           f"aliased {m.alias_size_in_bytes} temporaries "
           f"{m.temp_size_in_bytes} total {total}")

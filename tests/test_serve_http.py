@@ -475,7 +475,7 @@ def test_engine_aot_store_roundtrip(tmp_path):
         toks2 = _answer(eng2, prompt, 5)
     finally:
         eng2.stop()
-    assert eng2.aot_status == {"w1": "loaded", "w16": "loaded"}
+    assert eng2.aot_status == {"w1": "loaded", "k4w16": "loaded"}
     assert toks2 == toks1
 
     # jit fallback (no store) agrees too.

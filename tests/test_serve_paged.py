@@ -435,8 +435,9 @@ def test_program_texts_have_the_rows_the_engine_dispatches(tiny_lm,
 def test_step_avals_state_the_masked_steps_signature(tiny_lm, build):
     """``_step_avals`` is the one statement of ``_masked_step``'s
     signature: fifteen entries in its parameters' order, ``slots`` rows
-    at width 1 and ``_prefill_rows`` at a bucket — and the jit program
-    traces at exactly those shapes."""
+    at width 1 and ``_prefill_rows`` at a bucket, the table columns a
+    fresh admission reaches (tests/test_serve_reach.py) — and the jit
+    program traces at exactly those shapes."""
     import inspect
     eng = build(tiny_lm)
     names = list(inspect.signature(eng._step).parameters)
@@ -449,7 +450,7 @@ def test_step_avals_state_the_masked_steps_signature(tiny_lm, build):
         assert len(avals) == len(names)
         by_name = dict(zip(names, avals))
         assert by_name["tokens"].shape == (rows, width)
-        assert by_name["page_table"].shape == (rows, eng.pages_per_slot)
+        assert by_name["page_table"].shape == (rows, eng._reach(width))
         assert by_name["active"].dtype == by_name["from_prev"].dtype \
             == bool
         for name in names[3:5] + names[6:]:
@@ -1013,7 +1014,7 @@ def test_paged_aot_store_roundtrip(tmp_path, tiny_lm):
         toks2 = _answer(eng2, prompt, 5)
     finally:
         eng2.stop()
-    assert eng2.aot_status == {"w1": "loaded", "w16": "loaded"}
+    assert eng2.aot_status == {"w1": "loaded", "k4w16": "loaded"}
     assert toks2 == toks1 == solo_greedy(tiny_lm, prompt, 5)
     # the store's own executables: [slots, 1] decode, [1, 16] prefill
     texts = eng2.program_texts()
@@ -1083,13 +1084,13 @@ def test_aot_store_written_before_the_options_went_is_a_clean_miss(
     finally:
         eng.stop()
     assert eng.aot_status == {"w1": "compiled+saved",
-                              "w16": "compiled+saved"}
+                              "k4w16": "compiled+saved"}
     added = sorted(entries() - before)
     assert [f.split("-")[:2] for f in added] == \
-        [["masked_step", "w1"], ["masked_step", "w16"]]
+        [["masked_step", "k4w16"], ["masked_step", "w1"]]
     assert all(store.config_digest in f for f in added)
     assert Engine(model, variables, cfg, aot_store=store).aot_status == \
-        {"w1": "loaded", "w16": "loaded"}
+        {"w1": "loaded", "k4w16": "loaded"}
 
 
 def test_aot_save_is_load_verified(tmp_path, monkeypatch):

@@ -500,10 +500,10 @@ def test_aot_store_written_for_the_given_trees_types_is_a_clean_miss(
 
     first = Engine(model, variables, cfg, aot_store=store)
     assert first.aot_status == {"w1": "compiled+saved",
-                                "w16": "compiled+saved"}
+                                "k4w16": "compiled+saved"}
     added = {f for f in os.listdir(tmp_path)
              if f.endswith(".aotx")} - before
     assert len(added) == 2 and all(store.config_digest in f for f in added)
     second = Engine(model, variables, cfg, aot_store=store)
-    assert second.aot_status == {"w1": "loaded", "w16": "loaded"}
+    assert second.aot_status == {"w1": "loaded", "k4w16": "loaded"}
     assert answer(first) == answer(second)

@@ -257,9 +257,11 @@ def build_aot_store(directory: str, model_cfg, serve_cfg):
         "params": "resident",
         # The step takes a row's token from the previous step's output
         # or from the host (``prev``, ``from_prev``) and names each
-        # row's state row (``state_rows``): a store written for a
-        # twelve- or fourteen-argument step misses whole.
-        "step": "tokens forwarded, state rows named",
+        # row's state row (``state_rows``), and a wide call's table
+        # has the columns its positions reach: a store written for a
+        # twelve- or fourteen-argument step, or for whole tables,
+        # misses whole.
+        "step": "tokens forwarded, state rows named, wide table cut",
         # Spec-decode levers select a different program SET (drafter
         # width changes the drafter executables, K changes the verify
         # width): spec-on and spec-off engines must never share blobs.
@@ -382,6 +384,29 @@ class Engine:
             raise ValueError(
                 f"kv_page_tokens must be >= 1, got {cfg.kv_page_tokens}")
         self.pages_per_slot = -(-self.max_seq_len // self.page_tokens)
+        # -- how many table columns a wide call is handed --------------
+        # The paged attend of a call wider than one token derives its
+        # whole key range from ``page_table.shape[1]`` — the gather,
+        # the score matrix, the mask, a latent layer's up-projection
+        # and its indexer — so a call that embeds positions
+        # start..start+width-1 is handed only the columns those
+        # positions can reach (``_reach``), rounded up to one of these
+        # doubling buckets: O(reach) keys instead of O(max_seq_len)
+        # with NO model change. The columns dropped are the ones the
+        # causal mask sets to -inf (exp -> 0: they contribute exactly
+        # zero), so outputs do not depend on the bucket; doubling
+        # bounds the programs of one token width at
+        # log2(pages_per_slot). The target's prefill, the drafter's
+        # prefill and the verify / burst programs share the one set.
+        buckets, w = [], 4
+        while w < self.pages_per_slot:
+            buckets.append(w)
+            w *= 2
+        self._window_buckets = tuple(buckets) + (self.pages_per_slot,)
+        # Σ (start + width) and Σ columns × page_tokens over the wide
+        # calls of ``_dispatch_step``: ``serve_prefill_key_reach_pct``
+        self._prefill_keys = [0, 0]
+        self._wide_run: set = set()     # (width, columns) dispatched
         usable = int(cfg.kv_pages) or self.slots * self.pages_per_slot
         if usable < 1:
             raise ValueError(f"kv_pages must be >= 1, got "
@@ -544,7 +569,9 @@ class Engine:
         # -- device programs (compiled lazily, one per shape) ----------
         # One callable; jit specializes per token shape: [N, 1] decode
         # plus one program per prefill bucket Lb — [1, Lb] on one
-        # device, [N, Lb] over a mesh (``_prefill_rows``). The cache is
+        # device, [N, Lb] over a mesh (``_prefill_rows``) — over the
+        # table columns a fresh admission reaches (``_programs``: a
+        # continued row may add a wider table's). The cache is
         # donated — it is the engine's single biggest buffer and every
         # call replaces it. The page-table rows of the call's slots
         # ride along as one small int32 input, and the batched sampler
@@ -641,11 +668,12 @@ class Engine:
         self._init_kv_gauges()
         # AOT warm-start (tpunet/utils/cache.py AotProgramStore): the
         # engine's program set is closed — [N, 1] decode + one program
-        # per bucket — so fully-compiled executables deserialize at
-        # boot and the jit path above becomes the fallback for shapes
-        # the store has never seen. Single-device only: a sharded pool
-        # would bake device assignments into the executable.
-        self._aot: dict = {}
+        # per bucket (``_programs``) — so fully-compiled executables
+        # deserialize at boot and the jit path above becomes the
+        # fallback for shapes the store has never seen. Single-device
+        # only: a sharded pool would bake device assignments into the
+        # executable.
+        self._aot: dict = {}            # (width, table columns) -> program
         self.aot_status: dict = {}
         if aot_store is not None and mesh is None:
             self._warm_start_aot(aot_store)
@@ -681,6 +709,22 @@ class Engine:
             [(cache_s, *self._step_avals(width)[2:6])
              for width in (1, self.buckets[-1])])
 
+    def _window(self, need: int) -> int:
+        """Smallest window bucket covering ``need`` page-table columns
+        (the whole row where none does)."""
+        return next((w for w in self._window_buckets if w >= need),
+                    self.pages_per_slot)
+
+    def _reach(self, width: int, start: int = 0) -> int:
+        """Page-table columns handed to a call that embeds positions
+        ``start .. start + width - 1``: nothing past its last position
+        can be attended to or written. ``start`` 0 is a fresh
+        admission's. The width-1 step keeps the whole table
+        (``tpunet_paged_decode`` walks each row to its live length)."""
+        if width == 1:
+            return self.pages_per_slot
+        return self._window(-(-(start + width) // self.page_tokens))
+
     def _rows_at(self, width: int) -> int:
         """Batch rows of the masked step the engine dispatches at token
         width ``width``: every slot for the width-1 decode program (a
@@ -688,12 +732,15 @@ class Engine:
         bucket-wide one."""
         return self.slots if width == 1 else self._prefill_rows
 
-    def _step_avals(self, width: int) -> list:
+    def _step_avals(self, width: int, reach: Optional[int] = None) -> list:
         """``_masked_step``'s fifteen arguments at token width
-        ``width``, as shapes: the one statement of its signature."""
+        ``width``, as shapes: the one statement of its signature. The
+        table has ``reach`` columns, a fresh admission's
+        (``_reach(width)``) unless given."""
         import jax
 
         n = self._rows_at(width)
+        columns = reach or self._reach(width)
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
         f32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.float32)  # noqa: E731
         return [_shapes_of(self.variables["params"]),
@@ -701,7 +748,7 @@ class Engine:
                 i32(n, width),                          # tokens
                 i32(n),                                 # positions
                 jax.ShapeDtypeStruct((n,), bool),       # active
-                i32(n, self.pages_per_slot),            # page_table
+                i32(n, columns),                        # page_table
                 i32(n),                                 # last_idx
                 f32(n), i32(n), f32(n),                 # temp, top_k, top_p
                 i32(n), i32(n),                         # seeds, steps
@@ -709,33 +756,52 @@ class Engine:
                 jax.ShapeDtypeStruct((n,), bool),       # from_prev
                 i32(n)]                                 # state_rows
 
+    def _programs(self) -> list:
+        """``(width, table columns)`` of every masked-step program the
+        engine holds: the width-1 step over the whole table, a fresh
+        admission of each bucket (``_reach(bucket)``), and every other
+        reach a continued row — a prefix hit, a resume — has been
+        dispatched with (at most log2(``pages_per_slot``) a bucket,
+        each compiled at its first use)."""
+        fresh = {(width, self._reach(width))
+                 for width in (1,) + self.buckets}
+        return sorted(fresh | self._wide_run)
+
     def program_texts(self) -> dict:
-        """``{label: optimized HLO text}`` of the masked step at each
-        token width the engine holds (1 = decode, a bucket = prefill):
-        the AOT executable's own text where one was warm-started, else
-        the jit program lowered for the live parameters and pool (a
-        mesh engine's carry their shardings) and the host inputs'
-        shapes — the signature the running program was compiled for,
-        so a width that has run compiles nothing. Registered with
+        """``{label: optimized HLO text}`` of the masked step, one per
+        program of ``_programs()``: ``.../w<width>`` for the width-1
+        step and for a fresh admission of each bucket,
+        ``.../k<columns>/w<width>`` for a continued row's other
+        reaches, so a label's tail always names the token width. The
+        AOT executable's own text where one was warm-started, else the
+        jit program lowered for the live parameters and pool (a mesh
+        engine's carry their shardings) and the host inputs' shapes —
+        the signature the running program was compiled for, so a
+        program that has run compiles nothing. Registered with
         tpunet/obs/device_time.py at construction and called only by a
         reader of the device trace, never by the engine."""
         texts = {}
-        for width in (1,) + self.buckets:
-            program = self._aot.get(width)
+        for width, columns in self._programs():
+            program = self._aot.get((width, columns))
             if program is None:
                 program = self._step.lower(
                     self.variables["params"], self._cache,
-                    *self._step_avals(width)[2:]).compile()
-            texts[f"jit_{self._step.__name__}/w{width}"] = program.as_text()
+                    *self._step_avals(width, columns)[2:]).compile()
+            reach = ("" if columns == self._reach(width)
+                     else f"/k{columns}")
+            texts[f"jit_{self._step.__name__}{reach}/w{width}"] = \
+                program.as_text()
         return texts
 
     def _warm_start_aot(self, store) -> None:
-        """Load (or compile-and-save) every program the pool can run.
+        """Load (or compile-and-save) every program the pool can run
+        on fresh admissions (``_programs()`` before any call): the
+        tag carries the table's columns beside the token width.
         Deserialization skips tracing/lowering/XLA entirely — the
         compile-bound replica cold-start becomes an mmap + relink."""
         import jax
-        for width in (1,) + self.buckets:
-            tag = f"w{width}"
+        for width, columns in self._programs():
+            tag = f"w{width}" if width == 1 else f"k{columns}w{width}"
             program = store.load("masked_step", tag)
             if program is None:
                 # Compile fresh (persistent compile cache off): a
@@ -744,13 +810,13 @@ class Engine:
                 from tpunet.utils.cache import serializable_compile
                 with serializable_compile():
                     program = self._step.lower(
-                        *self._step_avals(width)).compile()
+                        *self._step_avals(width, columns)).compile()
                 saved = store.save("masked_step", tag, program)
                 self.aot_status[tag] = ("compiled+saved" if saved
                                         else "compiled")
             else:
                 self.aot_status[tag] = "loaded"
-            self._aot[width] = program
+            self._aot[(width, columns)] = program
         if self._drafter_model is None:
             return
         # Spec programs are part of the replica's closed program set
@@ -770,7 +836,7 @@ class Engine:
         # Burst/verify are compiled per attention-window bucket (the
         # engine slices the page table to the live window at call
         # time); the full closed set is log2(pages_per_slot) pairs.
-        for win in self._spec_window_buckets:
+        for win in self._window_buckets:
             win_s = i32(self.slots, win)
             programs.append(
                 ("spec_draft_burst", f"k{k}w{win}",
@@ -782,8 +848,7 @@ class Engine:
                  (params_s, cache_s, i32(self.slots, k + 1), pos_s,
                   act_s, win_s) + samp_s))
         for width in self.buckets:
-            win = self._spec_window(
-                (width - 1) // self.page_tokens + 1)
+            win = self._reach(width)
             programs.append(
                 ("spec_draft_prefill", f"w{width}",
                  self._draft_prefill_fn,
@@ -805,34 +870,45 @@ class Engine:
     def _dispatch_step(self, toks, positions, active, last_idx,
                        slot_i=None, from_prev=None):
         """Dispatch one masked-step program: the AOT executable for
-        this token width when warm-started, the jit fallback
-        otherwise. Batch row i is slot i, or — ``slot_i`` given — the
-        call's one row is that slot (a [1, bucket] prefill). Rows set
-        in ``from_prev`` (decode only) take their token from the
-        newest decode step's output on the device, not from ``toks``.
-        Returns (cache, sampled tokens) without waiting for either.
+        this token width and table width when warm-started, the jit
+        fallback otherwise. Batch row i is slot i, or — ``slot_i``
+        given — the call's one row is that slot (a [1, bucket]
+        prefill). A call wider than one token is handed the table
+        columns its positions can reach (``_reach``), not the row's
+        ``pages_per_slot``. Rows set in ``from_prev`` (decode only)
+        take their token from the newest decode step's output on the
+        device, not from ``toks``. Returns (cache, sampled tokens)
+        without waiting for either.
 
         The call may still be in flight when the host next touches its
         own state, so every numpy argument is a buffer of the call's
         own: the page table is copied (the allocator rewrites
         ``_page_table`` in place, and the CPU backend may alias host
         memory), the rest are built per call."""
-        program = self._aot.get(toks.shape[1])
-        if program is None:
-            program = self._step
-        rows = toks.shape[0]
+        rows, width = toks.shape
+        table = (self._page_table if slot_i is None
+                 else self._page_table[slot_i:slot_i + 1])
+        if width > 1:
+            # the columns the call's positions can reach (idle rows
+            # stand at position 0); the width-1 step keeps the table
+            start = int(np.max(positions))
+            columns = self._reach(width, start)
+            table = table[:, :columns]
+            self._wide_run.add((width, columns))
+            keys = columns * self.page_tokens
+            self._prefill_keys[0] += min(start + width, keys)
+            self._prefill_keys[1] += keys
+        program = self._aot.get((width, table.shape[1]), self._step)
         if from_prev is None:
             prev = np.zeros((rows,), np.int32)
             from_prev = np.zeros((rows,), bool)
         else:
             prev = self._sampled
-        table = (self._page_table if slot_i is None
-                 else self._page_table[slot_i:slot_i + 1]).copy()
         state_rows = (np.arange(rows, dtype=np.int32) if slot_i is None
                       else np.full((1,), slot_i, np.int32))
         return program(
             self.variables["params"], self._cache, toks, positions,
-            active, table, *self._sampling_args(last_idx, slot_i),
+            active, table.copy(), *self._sampling_args(last_idx, slot_i),
             prev, from_prev, state_rows)
 
     def _sampling_args(self, last_idx, slot_i=None):
@@ -942,30 +1018,6 @@ class Engine:
                                        donate_argnums=(1,))
         self._verify_fn = jax.jit(_verify, donate_argnums=(1,))
         self._spec_aot: dict = {}
-        # Attention-window buckets for the spec programs, in PAGE
-        # SLOTS (columns of the page table). The paged attend derives
-        # its whole key window from ``page_table.shape[1]`` — gather
-        # size, score matrix, mask — so slicing the table to the
-        # smallest bucket covering every burst slot's pos+K shrinks
-        # the verify/burst attention from O(max_seq_len) keys to
-        # O(live sequence) with NO model change, and the extra
-        # (masked, exp->0) columns it drops contribute exactly zero,
-        # so outputs stay bitwise identical across buckets. Doubling
-        # buckets bound the compile count at log2(pages_per_slot).
-        buckets, w = [], 4
-        while w < self.pages_per_slot:
-            buckets.append(w)
-            w *= 2
-        buckets.append(self.pages_per_slot)
-        self._spec_window_buckets = tuple(buckets)
-
-    def _spec_window(self, need_slots: int) -> int:
-        """Smallest window bucket covering ``need_slots`` page-table
-        columns (attention window for a spec program call)."""
-        for w in self._spec_window_buckets:
-            if w >= need_slots:
-                return w
-        return self._spec_window_buckets[-1]
 
     def _dispatch_spec(self, name: str, tag: str, fallback, args):
         """Run one spec program: the AOT executable when warm-started,
@@ -2014,7 +2066,7 @@ class Engine:
             active[slot_i] = True
         # Prompt positions span 0..bucket-1, so the attention window
         # is static per bucket — the tag stays ``w{bucket}``.
-        win = self._spec_window((bucket - 1) // self.page_tokens + 1)
+        win = self._reach(bucket)
         with self._phase("tpunet/serve_spec_prefill", ring=True):
             self._draft_cache = self._dispatch_spec(
                 "spec_draft_prefill", f"w{bucket}",
@@ -2292,9 +2344,7 @@ class Engine:
         # table — they attend over (and gather) only the live key
         # window instead of all max_seq_len rows, which is where the
         # verify's per-position cost lives on short sequences.
-        win = self._spec_window(
-            max(int(s.pos) + k for _, s in burst)
-            // self.page_tokens + 1)
+        win = self._reach(k + 1, max(int(s.pos) for _, s in burst))
         table = self._page_table[:, :win]
         # temp/top_k/top_p/seeds/steps0 — steps0[i] = len(req.tokens)
         # is the sequential sampler's next step counter, so draft and
@@ -2410,6 +2460,10 @@ class Engine:
             reg.gauge("serve_host_max_s_" + phase).set(
                 round(total.longest, 6))
             total.longest = 0.0
+        if self._prefill_keys[1]:
+            reached, handed = self._prefill_keys
+            reg.gauge("serve_prefill_key_reach_pct").set(round(
+                100.0 * reached / handed, 4))
         if self._window_pages[1]:
             dead, held = self._window_pages
             reg.gauge("serve_cache_window_dead_pct").set(round(
