@@ -725,3 +725,69 @@ def test_flash_prefill_band_walks_only_the_blocks_in_sight():
     with pytest.raises(ValueError, match="whole groups"):
         flash_prefill(jnp.zeros((1, 16, 6, 64)), jnp.zeros((1, 16, 4, 64)),
                       jnp.zeros((1, 16, 4, 64)))
+
+
+# -- flash_prefill differentiated: the log-sum-exp under a window, dQ on the
+# -- band, dK/dV by KV head over the group and the band transposed --------------
+
+@pytest.mark.parametrize("group", [7, 1])
+@pytest.mark.parametrize("t,window,block", [
+    (96, 0, 16),        # no window: the triangle forward, every later block back
+    (96, 5, 16),        # a window shorter than a block
+    (96, 40, 16),       # spanning blocks, T no multiple of the window
+    (80, 24, 16),       # a band of 3, T no multiple of the window
+    (96, 200, 16),      # a window at least the row: none
+])
+def test_flash_prefill_backward_matches_the_grouped_windowed_reference(
+        group, t, window, block):
+    from tpunet.ops.flash import flash_prefill, grouped_window_attention
+    hkv, d = 2, 16
+    rng = np.random.default_rng(t + window + group)
+    q, k, v, g = (jnp.asarray(rng.standard_normal((2, t, n, d)), jnp.float32)
+                  for n in (hkv * group, hkv, hkv, hkv * group))
+    seen = 0 if window >= t else window
+
+    def through(attend):
+        return jax.value_and_grad(
+            lambda q_, k_, v_: jnp.sum(attend(q_, k_, v_) * g),
+            argnums=(0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        got, (dq, dk, dv) = through(lambda *a: flash_prefill(
+            *a, window=window, block=block, interpret=True))
+        want, (rq, rk, rv) = through(lambda *a: grouped_window_attention(
+            *a, scale=d ** -0.5, window=seen))
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for name, a, b in (("dq", dq, rq), ("dk", dk, rk), ("dv", dv, rv)):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   err_msg=name)
+
+
+def test_flash_forward_keeps_its_log_sum_exp_under_a_window():
+    from tpunet.ops.flash import _forward_impl
+    rng = np.random.default_rng(11)
+    t, d, window = 64, 16, 24
+    q, k, v = (jnp.asarray(rng.standard_normal((1, t, n, d)), jnp.float32)
+               for n in (4, 2, 2))
+    with jax.default_matmul_precision("highest"):
+        out, lse = _forward_impl(q, k, v, True, d ** -0.5, 16, 16, True,
+                                 with_lse=True, window=window)
+        s = jnp.einsum("bqngd,bknd->bngqk", q.reshape(1, t, 2, 2, d), k,
+                       precision="highest") * d ** -0.5
+    at = np.arange(t)
+    keep = (at[None, :] <= at[:, None]) & (at[None, :] > at[:, None] - window)
+    want = jax.nn.logsumexp(jnp.where(keep, s, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(lse, want.reshape(1, 4, t), atol=1e-5)
+    assert out.shape == q.shape
+
+
+def test_causal_blocks_visited_is_the_bands_count():
+    from tpunet.ops.flash import causal_blocks_visited
+    # 8192 tokens in 512-blocks under a 4096-key window: a band of 9
+    assert causal_blocks_visited(8192, 4096) == (108, 136)
+    assert causal_blocks_visited(16384, 4096) == (
+        sum(min(i + 1, 9) for i in range(32)), 32 * 33 // 2)
+    assert causal_blocks_visited(8192, 0) == (136, 136)
+    assert causal_blocks_visited(4096, 4096) == (36, 36)
+    assert causal_blocks_visited(96, 40, 16) == (1 + 2 + 3 + 4 + 4 + 4, 21)

@@ -557,3 +557,108 @@ def test_parallel_masked_step_fits_the_chip(one_chip, no_compile_cache,
     pool = r"bf16\[163856,1024\]"
     assert re.search(pool, text)
     assert not re.search(pool + r"\S* copy\(", text)
+
+
+# -- the grouped-query decoder that trains: kernels and the whole step ---------
+
+@pytest.mark.parametrize("window", [4096, 0], ids=["window", "global"])
+def test_grouped_windowed_flash_compiles_forward_and_backward(
+        one_chip, no_compile_cache, window):
+    """``flash_prefill`` differentiated at smallthinker-21ba3b's train
+    shape — one row of 8192 tokens, 28 query heads over 4 KV heads of
+    128 — under the 4096-key window and without one: the forward that
+    keeps its log-sum-exp, dQ on the band, dK/dV by KV head over the
+    group and the band transposed; no [T, T] tensor."""
+    from tpunet.ops.flash import flash_prefill
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(flash_prefill(
+            *a, window=window, interpret=False).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(grads, ((1, 8192, 28, 128), BF16),
+                    ((1, 8192, 4, 128), BF16), ((1, 8192, 4, 128), BF16),
+                    sharding=one_chip)
+    assert text.count("tpu_custom_call") == 3
+    assert "8192,8192" not in text
+    # dK and dV leave their kernel by KV head: no sum over query heads after
+    assert re.search(r"= \(bf16\[1,4,8192,128\]\S*, bf16\[1,4,8192,128\]\S*\) "
+                     r"custom-call", text)
+
+
+@pytest.mark.parametrize("shape", [(8, 1024, 25, 64), (1, 8192, 20, 256)],
+                         ids=["gpt2-xl", "glm-4.7-flash"])
+def test_the_accepted_train_cells_flash_calls_lower_as_before(
+        one_chip, no_compile_cache, shape):
+    """One head count and no window — the attention of
+    ``gpt2-xl.train-b8-t1024`` and of ``glm-4.7-flash.train-b1-t8192`` —
+    still lowers to the three kernels on the triangular grids: the
+    group and the band are static Python branches the shared entry does
+    not take."""
+    from tpunet.ops import flash
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, causal=True, interpret=False).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _compile(grads, *[(shape, BF16)] * 3, sharding=one_chip)
+    assert text.count("tpu_custom_call") == 3
+    assert flash._use_tri(True, shape[1], shape[1], 512, 512)
+
+
+@pytest.fixture(scope="module")
+def smallthinker_step():
+    """The ``Trainer``'s LM train step of
+    ``smallthinker-21ba3b.train-b1-t8192`` as shapes: the state from
+    ``create_train_state`` under ``jax.eval_shape`` (656.5 M float32
+    parameters with Adam's two moments), one row of 8192 tokens."""
+    from benchmark import harness
+    from tpunet.config import ModelConfig, OptimConfig
+    from tpunet.train.state import create_train_state
+    from tpunet.train.steps import make_lm_train_step
+
+    config = harness.load_cell("smallthinker-21ba3b.train-b1-t8192")["config"]
+    model_cfg = ModelConfig(**config["program"]["model"])
+    optim_cfg = OptimConfig(**config["program"]["optim"])
+    state = jax.eval_shape(lambda: create_train_state(
+        model_cfg, optim_cfg, jax.random.PRNGKey(0), image_size=0,
+        steps_per_epoch=8, epochs=1, seq_len=8))
+    return make_lm_train_step(optim_cfg, model_cfg, None), state
+
+
+def test_smallthinker_train_step_fits_the_chip(one_chip, no_compile_cache,
+                                               monkeypatch,
+                                               smallthinker_step):
+    """The whole step compiled for the chip with at least 1 GiB to
+    spare, per-block recomputation on: 16 flash kernels (a layer:
+    forward, recomputed forward, dQ, dK/dV) and no [T, T] tensor."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # dispatch
+    step, state = smallthinker_step
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    n_params = sum(a.size for a in jax.tree_util.tree_leaves(state.params))
+    assert n_params == 656_529_920
+    compiled = jax.jit(step, donate_argnums=0).lower(
+        on_chip(state),
+        jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    text = compiled.as_text()
+    print(f"smallthinker train step: arguments {m.argument_size_in_bytes} "
+          f"outputs {m.output_size_in_bytes} aliased "
+          f"{m.alias_size_in_bytes} temporaries {m.temp_size_in_bytes} "
+          f"total {total} generated code "
+          f"{m.generated_code_size_in_bytes} custom calls "
+          f"{text.count('tpu_custom_call')}")
+    assert m.argument_size_in_bytes >= 12 * n_params
+    assert total + (1 << 30) <= V5E_BYTES_LIMIT, total
+    assert 4 * total >= V5E_BYTES_LIMIT             # the driver's 25 % floor
+    kernels = re.findall(r"%(tpunet_flash_\w+?)(?:\.\d+)? = .*custom-call", text)
+    assert (kernels.count("tpunet_flash_fwd"),
+            kernels.count("tpunet_flash_bwd")) == (8, 8)
+    assert "8192,8192" not in text

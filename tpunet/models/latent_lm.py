@@ -51,6 +51,20 @@ experts. A wide call of this family takes the FFN ``_FFN_ROWS`` tokens
 at a time: its experts are 4,096 wide, and a bucket's (token, expert)
 pair tables would not fit beside the weights.
 
+``model_type`` ``smallthinker`` is the fourth, and the second one
+trained (the benchmark's ``smallthinker-21ba3b``): a sequential
+pre-RMSNorm block whose ROUTER reads the block's input ``x`` itself,
+before the attention norm — ``LatentBlock`` takes ``x W_r`` first and
+hands the logits to the expert layer after attention — over the same
+grouped-query mixer (``sliding_attention`` layers: rotate-half rotary
+over the whole head and a ``sliding_window_size`` window;
+``full_attention`` layers: no positions), softmax top-k over ReGLU
+experts with no shared one, an untied head. It is read from its
+published keys (``rope_layout`` / ``sliding_window_layout``, ``moe_*``).
+Its training call takes the batch's rows through ``ops/flash.py
+flash_prefill`` and that kernel's backward by head group and under the
+window's band.
+
 Three forms of one mathematics, chosen by the call:
 
 - ``train=True`` (the ``Trainer``'s step): every row of the batch at
@@ -112,7 +126,8 @@ from flax import linen as nn
 from jax import lax
 
 from tpunet.config import ModelConfig
-from tpunet.models.moe import RoutedShareMlp, by_row, gated_silu
+from tpunet.models.moe import (RoutedShareMlp, by_row, gated_silu,
+                               router_logits)
 from tpunet.ops.attention import _NEG_INF
 
 _LANES = 128
@@ -191,12 +206,21 @@ class LatentArch:
     rotary_pct: float = 1.0
     logit_scale: float = 1.0
     num_shared_experts: int = 1
+    # -- what a family's key mapping sets, no configuration names: the
+    # rotary pairs' layout under a window, the experts' activation
+    rotary_layout: str = "interleaved"
+    expert_act: str = "silu"
 
     @classmethod
     def from_mapping(cls, m) -> "LatentArch":
         known = {f.name for f in dataclasses.fields(cls)} | {"num_experts"}
         kw = dict(m)
-        if kw.get("model_type") == "cohere2_moe":
+        if set(kw) & _MAPPED:
+            raise ValueError(f"latent_lm: {sorted(set(kw) & _MAPPED)} are "
+                             "set by a family's key mapping, not named")
+        if kw.get("model_type") == "smallthinker":
+            kw = _early_router_keys(kw)
+        elif kw.get("model_type") == "cohere2_moe":
             kw = _parallel_keys(kw)
         elif set(kw) & _PARALLEL_ONLY:
             raise ValueError("latent_lm: only cohere2_moe reads "
@@ -219,7 +243,8 @@ class LatentArch:
         if arch.num_nextn_predict_layers not in (0, 1):
             raise ValueError("latent_lm builds one multi-token-prediction "
                              "module at most")
-        if arch.model_type not in (None, "qwen3_next", "cohere2_moe"):
+        if arch.model_type not in (None, "qwen3_next", "cohere2_moe",
+                                   "smallthinker"):
             raise ValueError(f"latent_lm: unknown model_type "
                              f"{arch.model_type!r}")
         kinds = (("full_attention", "linear_attention") if arch.hybrid
@@ -227,7 +252,7 @@ class LatentArch:
         if set(arch.layer_types) - set(kinds):
             raise ValueError(f"latent_lm: layer_types of model_type "
                              f"{arch.model_type!r} are {kinds}")
-        if (arch.hybrid or arch.parallel) and (
+        if (arch.hybrid or arch.parallel or arch.early_router) and (
                 not arch.num_key_value_heads or not arch.head_dim
                 or arch.num_attention_heads % arch.num_key_value_heads
                 or arch.linear_num_value_heads % arch.linear_num_key_heads):
@@ -255,6 +280,11 @@ class LatentArch:
         return self.model_type == "cohere2_moe"
 
     @property
+    def early_router(self) -> bool:
+        """The router reads the block's input, before attention."""
+        return self.model_type == "smallthinker"
+
+    @property
     def norm_offset(self) -> float:
         """What a norm adds to its weight: the hybrid family's are
         zero-centred (``1 + w``, initial ``w`` 0)."""
@@ -277,7 +307,7 @@ class LatentArch:
         return {"gated": False, "qk_norm": False,
                 "window": self.sliding_window if sliding else None,
                 "rotary": int(d * self.rotary_pct) if sliding else 0,
-                "layout": "interleaved",
+                "layout": self.rotary_layout,
                 "scope": "tpunet_gqa_window" if sliding
                 else "tpunet_gqa_full",
                 "cache": "kv_window" if sliding else "kv"}
@@ -320,23 +350,71 @@ _PARALLEL_KEYS = {
     "num_experts_per_tok", "num_shared_experts", "held_experts"}
 _PARALLEL_ONLY = {"layer_norm_eps", "sliding_window", "rotary_pct",
                   "logit_scale", "num_shared_experts"}
+_MAPPED = {"rotary_layout", "expert_act"}
+
+
+def _as_built(kw: dict, family: str, built: dict, read: set) -> None:
+    """A family's published switches are taken only at the value its
+    block builds, and then dropped from ``kw``; what is left has to be
+    a key the family reads."""
+    for key, value in built.items():
+        if key in kw and kw.pop(key) != value:
+            raise ValueError(f"latent_lm: {family} is built with "
+                             f"{key} = {value!r}")
+    foreign = set(kw) - read
+    if foreign:
+        raise ValueError(f"latent_lm: {family} does not read "
+                         f"{sorted(foreign)} (another family's or unknown "
+                         "keys)")
 
 
 def _parallel_keys(kw: dict) -> dict:
     """A ``cohere2_moe`` mapping as ``LatentArch`` holds it: the
     switches checked and dropped, ``intermediate_size`` (the width of
     one expert, routed or shared) under the expert width's name."""
-    for key, built in _PARALLEL_BUILT.items():
-        if key in kw and kw.pop(key) != built:
-            raise ValueError(f"latent_lm: cohere2_moe is built with "
-                             f"{key} = {built!r}")
-    foreign = set(kw) - _PARALLEL_KEYS
-    if foreign:
-        raise ValueError("latent_lm: cohere2_moe does not read "
-                         f"{sorted(foreign)} (latent attention's, the delta "
-                         "rule's or unknown keys)")
+    _as_built(kw, "cohere2_moe", _PARALLEL_BUILT, _PARALLEL_KEYS)
     if "intermediate_size" in kw:
         kw["moe_intermediate_size"] = kw["intermediate_size"]
+    return kw
+
+
+# The fourth family, under its published names: what its block IS
+# (switches taken only at the value built) and the numbers it reads.
+_EARLY_ROUTER_BUILT = {
+    "moe_primary_router_apply_softmax": True, "norm_topk_prob": True,
+    "tie_word_embeddings": False, "rope_scaling": None}
+_EARLY_ROUTER_NAMES = {
+    "moe_ffn_hidden_size": "moe_intermediate_size",
+    "moe_num_active_primary_experts": "num_experts_per_tok",
+    "moe_num_primary_experts": "n_routed_experts"}
+_EARLY_ROUTER_KEYS = {
+    "model_type", "hidden_size", "num_hidden_layers", "rope_layout",
+    "sliding_window_layout", "sliding_window_size", "num_attention_heads",
+    "num_key_value_heads", "head_dim", "rope_theta", "rms_norm_eps",
+    "held_experts", *_EARLY_ROUTER_NAMES}
+
+
+def _early_router_keys(kw: dict) -> dict:
+    """A ``smallthinker`` mapping as ``LatentArch`` holds it: the
+    switches checked and dropped, the expert layer's sizes under the
+    arch's names, the two layouts (1 = rotary positions, 1 = a window;
+    they go together) as ``layer_types``, the window, the whole-head
+    rotate-half rotary and the ReGLU experts as the fields ``gqa`` and
+    ``LatentBlock`` read; no dense layer, no shared expert."""
+    _as_built(kw, "smallthinker", _EARLY_ROUTER_BUILT, _EARLY_ROUTER_KEYS)
+    for name, ours in _EARLY_ROUTER_NAMES.items():
+        if name in kw:
+            kw[ours] = kw.pop(name)
+    rope_on = list(kw.pop("rope_layout", ()))
+    if rope_on != list(kw.pop("sliding_window_layout", ())):
+        raise ValueError("latent_lm: smallthinker's layers with rotary "
+                         "positions are its windowed layers")
+    kw["layer_types"] = ["sliding_attention" if on else "full_attention"
+                         for on in rope_on]
+    kw.update(intermediate_size=kw.get("moe_intermediate_size"),
+              first_k_dense_replace=0, num_shared_experts=0,
+              sliding_window=kw.pop("sliding_window_size", None),
+              rotary_pct=1.0, rotary_layout="rotate_half", expert_act="relu")
     return kw
 
 
@@ -507,7 +585,7 @@ def mixer_class(arch: LatentArch, kind: str):
     where the mixer reads only a row's newest positions — ``window`` =
     how many (absent or None: it reads them all; the pages wholly
     behind it are what an allocator by layer kind would free)."""
-    if not (arch.hybrid or arch.parallel):
+    if not (arch.hybrid or arch.parallel or arch.early_router):
         return LatentAttention
     from tpunet.models import hybrid_mixers
     return (hybrid_mixers.GatedDeltaNet if kind == "linear_attention"
@@ -833,6 +911,9 @@ class LatentBlock(nn.Module):
     """``h = x + Attn(norm(x))``, ``y = h + FFN(norm(h))`` or, the
     parallel family, ``y = x + Attn(n) + FFN(n)`` with the one
     ``n = norm(x)``; the FFN dense (``dense`` True) or the expert layer.
+    Where the family's router reads the block's input
+    (``early_router``), ``x W_r`` is taken first, in float32, and the
+    expert layer after attention routes by those logits.
     A wide call (T > 1) takes the FFN one batch row at a time, skipping
     the rows ``row_active`` marks idle (the parallel family:
     ``_FFN_ROWS`` tokens of a row at a time); a training call
@@ -864,10 +945,12 @@ class LatentBlock(nn.Module):
                 u, decode, positions, active, paged_kv, page_table, train,
                 state_rows, lengths).astype(x.dtype)
 
-        def ffn(u):
+        def ffn(u, logits=None):
             rows, on = b, row_active
             if train:            # every token a row of its own, as in decode
                 u = u.reshape(b * t, 1, c)
+                if logits is not None:
+                    logits = logits.reshape(b * t, 1, -1)
             elif wide and a.parallel and t % _FFN_ROWS == 0:
                 rows = b * (t // _FFN_ROWS)
                 u = u.reshape(rows, _FFN_ROWS, c)
@@ -882,22 +965,32 @@ class LatentBlock(nn.Module):
                 with jax.named_scope("tpunet_dense_mlp"):
                     y = by_row(one, on, u) if wide else one(u)
             else:
+                pick = (lambda v: v) if wide else (lambda v: v[:, 0])  # noqa: E731
                 y = RoutedShareMlp(
                     a.n_routed_experts, a.moe_intermediate_size,
                     a.num_experts_per_tok, held=a.held_experts,
                     scaling=a.routed_scaling_factor,
-                    scoring="softmax" if a.hybrid else "sigmoid",
+                    scoring="softmax" if a.hybrid or a.early_router
+                    else "sigmoid",
                     shared_gate=a.hybrid, n_shared=a.num_shared_experts,
-                    router_bias=not a.parallel, dtype=self.dtype,
-                    param_dtype=self.param_dtype, name="moe")(
-                        u if wide else u[:, 0], on)
+                    router_bias=not a.parallel,
+                    act=a.expert_act,
+                    dtype=self.dtype, param_dtype=self.param_dtype,
+                    name="moe")(
+                        pick(u), on, None if logits is None else pick(logits))
             return y.reshape(b, t, c).astype(x.dtype)
 
         if a.parallel:
             u = norm("ln1", x)
             return x + mix(u) + ffn(u)
+        logits = None
+        if a.early_router and not self.dense:
+            with jax.named_scope("tpunet_moe_router"):
+                logits = router_logits(x, self.param(
+                    "router", nn.initializers.normal(stddev=0.02),
+                    (c, a.n_routed_experts), self.param_dtype))
         x = x + mix(norm("ln1", x))
-        return x + ffn(norm("ln2", x))
+        return x + ffn(norm("ln2", x), logits)
 
 
 def _block_class(remat: bool):
@@ -974,9 +1067,20 @@ class LatentLM(nn.Module):
         return {f"{prefix}_experts_total": a.n_routed_experts,
                 f"{prefix}_experts_held": held}
 
-    def train_gauges(self) -> dict:
-        """What the trainer sets once, at construction."""
-        return self.expert_gauges("train")
+    def train_gauges(self, seq_len: int) -> dict:
+        """What the trainer sets once, at construction: the experts
+        held and, where layers have a window and train through
+        ``flash_prefill``, the share of a row's causal (query block,
+        key block) pairs that kernel's grids visit in such a layer."""
+        a, out = self.arch, self.expert_gauges("train")
+        if (a.early_router or a.parallel) \
+                and "sliding_attention" in a.layer_types:
+            from tpunet.ops.flash import causal_blocks_visited
+            visited, causal = causal_blocks_visited(
+                seq_len, a.gqa("sliding_attention")["window"])
+            out["train_attn_window_blocks_visited_pct"] = \
+                100.0 * visited / causal
+        return out
 
     def cache_specs(self) -> list:
         """Each layer's ``cache_spec``, as its mixer states it."""

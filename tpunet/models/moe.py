@@ -88,6 +88,7 @@ routing under pipe > 1 (tpunet/models/lm_pp.py).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional
 
@@ -436,29 +437,34 @@ def gated_silu(x, gate, up, down, dtype):
     return jnp.dot(h, down.astype(dtype))
 
 
+def router_logits(u, router):
+    """``W_r u`` in float32 (``highest``): ``u`` [..., d] -> [..., E]."""
+    return jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
 def route_sigmoid(u, router, bias, top_k: int, scaling: float = 1.0):
     """Sigmoid routing without auxiliary loss (``noaux_tc``): ``u``
     [n, d] -> (expert ids [n, k] int32, weights [n, k] float32). The
     scores ``p = sigmoid(W_r u)`` and everything after them are
     float32; ``bias`` only chooses (top-k of ``p + bias``; None: of
     ``p``), the weights are ``p`` renormalised over the chosen k."""
-    p = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32),
-                               router.astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST))
+    p = jax.nn.sigmoid(router_logits(u, router))
     _, idx = jax.lax.top_k(
         p if bias is None else p + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(p, idx, axis=-1)
     return idx, scaling * chosen / jnp.sum(chosen, -1, keepdims=True)
 
 
-def route_softmax(u, router, top_k: int):
+def route_softmax(u, router, top_k: int, logits=None):
     """Softmax routing: ``u`` [n, d] -> (expert ids [n, k] int32,
     weights [n, k] float32). ``p = softmax(W_r u)`` over ALL experts in
     float32, the ``top_k`` largest chosen, their ``p`` renormalised
-    over the chosen k; no bias, no scaling."""
-    p = jax.nn.softmax(jnp.dot(u.astype(jnp.float32),
-                               router.astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST), -1)
+    over the chosen k; no bias, no scaling. ``logits`` [n, E]: ``W_r u``
+    where the caller has it already (a router that reads another input
+    than the experts do); ``u`` and ``router`` are then not read."""
+    p = jax.nn.softmax(router_logits(u, router) if logits is None
+                       else logits.astype(jnp.float32), -1)
     chosen, idx = jax.lax.top_k(p, top_k)
     return idx, chosen / jnp.sum(chosen, -1, keepdims=True)
 
@@ -512,8 +518,11 @@ _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 PAIR_CHUNK = 8192
 
 
-def _gated_experts(rows, valid, sizes, gate, up, down):
-    """``down_g(silu(gate_g x) * up_g x)`` on ``rows`` [m, d], the first
+ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+
+
+def _gated_experts(rows, valid, sizes, gate, up, down, act: str = "silu"):
+    """``down_g(act(gate_g x) * up_g x)`` on ``rows`` [m, d], the first
     ``sizes[0]`` of them through expert 0 of ``gate``/``up`` [h, d, f]
     and ``down`` [h, f, d], the next ``sizes[1]`` through expert 1, and
     so on: three grouped products. ``valid`` [m, 1] marks the rows the
@@ -521,7 +530,7 @@ def _gated_experts(rows, valid, sizes, gate, up, down):
     rows = _held_rows(rows, valid)
     a = _held_rows(jax.lax.ragged_dot(rows, gate, sizes), valid)
     b = _held_rows(jax.lax.ragged_dot(rows, up, sizes), valid)
-    y = jax.lax.ragged_dot(nn.silu(a) * b, down, sizes)
+    y = jax.lax.ragged_dot(ACTIVATIONS[act](a) * b, down, sizes)
     return jnp.where(valid, y, 0)
 
 
@@ -555,8 +564,8 @@ def _sum_pairs(y, back, weight):
                    * weight.T[:, :, None], axis=0).astype(y.dtype)
 
 
-@jax.custom_vjp
-def _experts_of_held(u, weight, gate, up, down, plan):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _experts_of_held(u, weight, gate, up, down, plan, act):
     """``_sum_pairs`` of ``_gated_experts`` on the sorted pairs' rows
     ``u[tok]``, for a layer with more pairs than ``PAIR_CHUNK``.
     ``plan`` holds the pairs' places: ``tok`` / ``order`` (token and
@@ -577,16 +586,16 @@ def _experts_of_held(u, weight, gate, up, down, plan):
     recomputes its own forward, and adds to the cotangents of the
     weights, summed in the parameters' own dtype; the cotangent of
     ``u`` is each token's sum over its pairs' rows, in float32."""
-    return _experts_of_held_fwd(u, weight, gate, up, down, plan)[0]
+    return _experts_of_held_fwd(u, weight, gate, up, down, plan, act)[0]
 
 
-def _experts_of_held_fwd(u, weight, gate, up, down, plan):
+def _experts_of_held_fwd(u, weight, gate, up, down, plan, act):
     experts = tuple(w.astype(u.dtype) for w in (gate, up, down))
 
     def body(j, y):
         lo, tok, valid, inside = _chunk(j, plan)
         out = _gated_experts(jnp.take(u, tok, axis=0), valid, inside,
-                             *experts)
+                             *experts, act)
         return jax.lax.dynamic_update_slice_in_dim(y, out, lo, 0)
 
     y = jax.lax.fori_loop(
@@ -596,7 +605,7 @@ def _experts_of_held_fwd(u, weight, gate, up, down, plan):
             (u, weight, gate, up, down, plan))
 
 
-def _experts_of_held_bwd(res, g):
+def _experts_of_held_bwd(act, res, g):
     u, weight, gate, up, down, plan = res
     experts = tuple(w.astype(u.dtype) for w in (gate, up, down))
     g = g.astype(jnp.float32)
@@ -605,7 +614,7 @@ def _experts_of_held_bwd(res, g):
         lo, tok, valid, inside = _chunk(j, plan)
         pair = jax.lax.dynamic_slice_in_dim(plan["order"], lo, PAIR_CHUNK)
         out, pull = jax.vjp(
-            lambda rows, *w: _gated_experts(rows, valid, inside, *w),
+            lambda rows, *w: _gated_experts(rows, valid, inside, *w, act),
             jnp.take(u, tok, axis=0), *experts)
         g_tok = jnp.take(g, tok, axis=0)
         d_rows, *d_w = pull(
@@ -634,15 +643,19 @@ _experts_of_held.defvjp(_experts_of_held_fwd, _experts_of_held_bwd)
 
 def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
                  scaling: float = 1.0, dtype=jnp.bfloat16,
-                 scoring: Optional[str] = None):
+                 scoring: Optional[str] = None, logits=None,
+                 act: str = "silu"):
     """One chip's share of a routed expert layer, without capacity.
 
     ``u`` [n, d]; ``router`` [d, E] and ``bias`` [E] over ALL ``E``
     experts; ``scoring`` "softmax" (``route_softmax``, which has neither
     bias nor scaling; what a ``bias`` of None means where ``scoring`` is
     not given) or "sigmoid" (``route_sigmoid``, with or without a
-    bias); ``gate``/``up`` [len(held), d, f] and ``down`` [len(held),
-    f, d] for the experts held here; ``held`` their ids (static).
+    bias); ``logits`` [n, E] takes the router's place where the caller
+    computed ``W_r`` times another input (softmax scoring; ``router`` is
+    then None); ``gate``/``up`` [len(held), d, f] and ``down``
+    [len(held), f, d] for the experts held here, gated by ``act``
+    ("silu" or "relu"); ``held`` their ids (static).
     Returns ``(y [n, d] in ``dtype``, stats)`` with ``y = sum over the
     chosen experts that are held of weight * expert(u)``: the pairs are
     sorted by held expert (pairs on absent experts last, computed by
@@ -665,7 +678,9 @@ def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
         scoring = "softmax" if bias is None else "sigmoid"
     with jax.named_scope("tpunet_moe_router"):
         if scoring == "softmax":
-            idx, weight = route_softmax(u, router, top_k)
+            idx, weight = route_softmax(u, router, top_k, logits)
+        elif logits is not None:
+            raise ValueError("logits from the caller route by softmax")
         else:
             idx, weight = route_sigmoid(u, router, bias, top_k, scaling)
     with jax.named_scope("tpunet_moe_experts"):
@@ -684,7 +699,7 @@ def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
             on_held = (jnp.take(slot, order) < h)[:, None]
             y = _gated_experts(jnp.take(u.astype(dtype), tok, axis=0),
                                on_held, sizes, gate.astype(dtype),
-                               up.astype(dtype), down.astype(dtype))
+                               up.astype(dtype), down.astype(dtype), act)
             y = _sum_pairs(y, back, weight)
             run = jnp.float32(1.0)
         else:
@@ -692,7 +707,7 @@ def routed_share(u, router, bias, gate, up, down, held, *, top_k: int,
             y = _experts_of_held(
                 u.astype(dtype), weight, gate, up, down,
                 {"tok": jnp.pad(tok, pad), "order": jnp.pad(order, pad),
-                 "back": back, "sizes": sizes})
+                 "back": back, "sizes": sizes}, act)
             run = _live_chunks(sizes).astype(jnp.float32) / chunks
         load = sizes.astype(jnp.float32)
         stats = {"held_pair_share": jnp.sum(load) / m,
@@ -715,12 +730,18 @@ class RoutedShareMlp(nn.Module):
     ``width`` are AVERAGED, as one gated product ``n_shared * width``
     wide times ``1 / n_shared`` (the hidden columns of the experts side
     by side: the down projection sums over all of them, which is the
-    sum of the experts' outputs); ``shared_gate``
+    sum of the experts' outputs), and with ``n_shared`` 0 there is no
+    shared part and none of its parameters; ``shared_gate``
     multiplies the shared part by ``sigmoid(x . w)``, one
-    number a token (``shared_expert_gate`` [d, 1]). The routing load is ``sow``n into the ``stats``
-    collection (per batch row for a 3-D ``x``): free unless a caller
-    makes it mutable (the serve engine's step does not; the LM train
-    step does, and sums it into the trainer's gauges)."""
+    number a token (``shared_expert_gate`` [d, 1]). ``act`` gates the
+    routed experts ("silu", or "relu": ReGLU). A call that is handed
+    ``logits`` (shaped as ``x`` but ``n_experts`` wide: a family whose
+    router reads the block's input, ``latent_lm.LatentBlock``) routes
+    by them, and the module then has no ``router`` of its own. The
+    routing load is ``sow``n into the ``stats`` collection (per batch
+    row for a 3-D ``x``): free unless a caller makes it mutable (the
+    serve engine's step does not; the LM train step does, and sums it
+    into the trainer's gauges)."""
 
     n_experts: int
     width: int
@@ -731,11 +752,12 @@ class RoutedShareMlp(nn.Module):
     shared_gate: bool = False
     n_shared: int = 1                  # shared experts, averaged
     router_bias: bool = True           # sigmoid scoring: a bias that chooses
+    act: str = "silu"                  # silu | relu, of the routed experts
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x, row_active=None):
+    def __call__(self, x, row_active=None, logits=None):
         d, f = x.shape[-1], self.width
         held = (tuple(range(self.n_experts)) if self.held is None
                 else tuple(self.held))
@@ -744,7 +766,7 @@ class RoutedShareMlp(nn.Module):
         def w(name, *shape):
             return self.param(name, init, shape, self.param_dtype)
 
-        router = w("router", d, self.n_experts)
+        router = w("router", d, self.n_experts) if logits is None else None
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown scoring {self.scoring!r}")
         bias = (self.param("router_bias", nn.initializers.zeros,
@@ -755,13 +777,16 @@ class RoutedShareMlp(nn.Module):
                    w("experts_down", len(held), f, d))
         fs = self.n_shared * f
         shared = (w("shared_gate", d, fs), w("shared_up", d, fs),
-                  w("shared_down", fs, d))
+                  w("shared_down", fs, d)) if fs else None
         open_w = w("shared_expert_gate", d, 1) if self.shared_gate else None
 
-        def ffn(u):
+        def ffn(u, logits=None):
             y, stats = routed_share(u, router, bias, *experts, held,
                                     top_k=self.top_k, scaling=self.scaling,
-                                    dtype=self.dtype, scoring=self.scoring)
+                                    dtype=self.dtype, scoring=self.scoring,
+                                    logits=logits, act=self.act)
+            if shared is None:
+                return y, stats
             with jax.named_scope("tpunet_moe_shared"):
                 y_shared = gated_silu(u, *shared, self.dtype)
                 if self.n_shared > 1:
@@ -774,6 +799,8 @@ class RoutedShareMlp(nn.Module):
                 y = y + y_shared
             return y, stats
 
-        y, stats = ffn(x) if x.ndim == 2 else by_row(ffn, row_active, x)
+        given = () if logits is None else (logits,)
+        y, stats = (ffn(x, *given) if x.ndim == 2
+                    else by_row(ffn, row_active, x, *given))
         self.sow("stats", "routing", stats)
         return y

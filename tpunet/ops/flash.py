@@ -29,13 +29,15 @@ Design notes:
   interpreter is far too slow for a hot path); tests exercise the real
   kernel body on CPU with interpret=True, the same scheme as
   tpunet/ops/depthwise.py.
-- ``flash_prefill`` (forward only; the serve engine's row prefill of a
-  grouped-query model): K and V keep their own, fewer heads — the K/V
-  index maps send query head h to KV head ``h // group``, so nothing is
-  repeated in HBM — and a sliding ``window`` walks a BAND of the grid:
-  per query block only the k blocks that hold a key in sight, the dead
-  leading steps of the first rows clamped to block 0 (no copy, no
-  product).
+- ``flash_prefill`` (the serve engine's row prefill of a grouped-query
+  model, and its training forward): K and V keep their own, fewer heads
+  — the K/V index maps send query head h to KV head ``h // group``, so
+  nothing is repeated in HBM — and a sliding ``window`` walks a BAND of
+  the grid: per query block only the k blocks that hold a key in sight,
+  the dead leading steps of the first rows clamped to block 0 (no copy,
+  no product). Its backward: dQ on the same band; dK/dV one grid row a
+  KV head and k block, accumulated over the group's query heads and
+  the q blocks ``j .. j + band - 1`` that see block ``j``.
 
 Measured on a real TPU v5e chip (B=4, T=4096, H=8, D=64, causal,
 bfloat16; synchronized by fetching a data-dependent output element;
@@ -295,11 +297,28 @@ def _band_blocks(window: int, block: int, nq: int) -> int:
     return min(nq, -(-(window - 1) // block) + 1)
 
 
+def _rows_grid(grid, qmap, kvmap, b, h, nq, band: int, group: int):
+    """The forward's and dQ's ``(grid, qmap, kvmap)`` under a band
+    (step j of query block i is k block ``i - (band - 1) + j``, the
+    dead leading steps clamped to block 0: no copy) and with K/V read
+    by head group (query head h on KV head ``h // group``)."""
+    if band:
+        grid = (b, h, nq, band)
+        qmap = lambda b, h, i, j: (b, h, i, 0)          # noqa: E731
+        kvmap = lambda b, h, i, j: (                    # noqa: E731
+            b, h, jnp.maximum(i - (band - 1) + j, 0), 0)
+    if group > 1:
+        per_head = kvmap
+        kvmap = lambda b, h, *at: per_head(b, h // group, *at)  # noqa: E731
+    return grid, qmap, kvmap
+
+
 def _forward_impl(q, k, v, causal, scale, block_q, block_k, interpret,
                   with_lse: bool, segment_ids=None, window: int = 0):
     """``k`` / ``v`` may hold fewer heads than ``q`` (a whole group of
     query heads a KV head); ``window`` > 0 needs causal self-attention
-    with square blocks. Both are forward-only (``flash_prefill``)."""
+    with square blocks (``flash_prefill``, whose backward is
+    ``_pallas_backward`` under the same two)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, tq, h, d = q.shape
@@ -312,9 +331,9 @@ def _forward_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     group = h // k.shape[2]
     band = 0
     if window:
-        if not (causal and tq == tk and bq == bk) or with_lse or with_seg:
-            raise ValueError("a window is built for the forward of causal "
-                             "self-attention with square blocks")
+        if not (causal and tq == tk and bq == bk) or with_seg:
+            raise ValueError("a window is built for causal self-attention "
+                             "with square blocks, without segments")
         band = _band_blocks(window, bq, nq)
 
     qt = q.swapaxes(1, 2)                          # [B, H, Tq, D]
@@ -327,14 +346,7 @@ def _forward_impl(q, k, v, causal, scale, block_q, block_k, interpret,
                              band=band)
     grid, qmap, kvmap, qsegmap, ksegmap = _grid_and_maps(
         causal, bq, bk, nq, nk, tq, tk, b, h)
-    if band:
-        grid = (b, h, nq, band)
-        qmap = lambda b, h, i, j: (b, h, i, 0)          # noqa: E731
-        kvmap = lambda b, h, i, j: (                    # noqa: E731
-            b, h, jnp.maximum(i - (band - 1) + j, 0), 0)
-    if group > 1:
-        per_head = kvmap
-        kvmap = lambda b, h, *at: per_head(b, h // group, *at)  # noqa: E731
+    grid, qmap, kvmap = _rows_grid(grid, qmap, kvmap, b, h, nq, band, group)
 
     in_specs = [
         pl.BlockSpec((1, 1, bq, d), qmap),
@@ -406,13 +418,14 @@ def _pallas_forward(q, k, v, causal, scale, block_q, block_k, interpret):
 
 
 def _recompute_p_ds(q, k, v, do, lse, delta, glse, scale, causal,
-                    qi, ki, bq, bk, tq, tk, seg=None):
+                    qi, ki, bq, bk, tq, tk, seg=None, window: int = 0):
     """Shared block math: p = exp(s - lse) (masked), dp = dO Vᵀ,
     ds = p * (dp - delta + glse) * scale. All f32; lse/delta/glse are
     [bq, 1]. ``glse`` is the cotangent of the lse OUTPUT (d lse/d s is
     exactly p, so it adds inside the parenthesis); zero for plain
     attention, nonzero when attention-state merging consumed the lse
-    (the ring). ``seg`` is the optional [bq, bk] same-segment mask."""
+    (the ring). ``seg`` is the optional [bq, bk] same-segment mask;
+    ``window`` the forward's (the query itself counts)."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     mask = None
@@ -420,6 +433,8 @@ def _recompute_p_ds(q, k, v, do, lse, delta, glse, scale, causal,
         qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         mask = qpos + (tk - tq) >= kpos
+        if window:
+            mask = mask & (kpos > qpos + (tk - tq) - window)
     if seg is not None:
         mask = seg if mask is None else mask & seg
     if mask is not None:
@@ -435,7 +450,7 @@ def _recompute_p_ds(q, k, v, do, lse, delta, glse, scale, causal,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                scale, causal, bq, bk, nk, tq, tk, with_glse, tri,
-               with_segments):
+               with_segments, window: int = 0, band: int = 0):
     # glse is an input only when the lse output's cotangent is nonzero
     # (the ring's state merging); plain attention skips its HBM reads.
     if with_glse:
@@ -446,16 +461,22 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
     if with_segments:
         qseg_ref, kseg_ref, *refs = refs
     dq_ref, dq_scr = refs
-    if tri:
+    if band:
+        # the forward's band: step j of query block qi is k block
+        # qi - (band - 1) + j, the blocks before the sequence are dead
+        qi = pl.program_id(2)
+        ki = qi - (band - 1) + pl.program_id(3)
+        first, last, needed = pl.program_id(3) == 0, ki == qi, ki >= 0
+    elif tri:
         qi, ki = _tri_qi_ki(pl.program_id(2))
-        last, needed = ki == qi, True
+        first, last, needed = ki == 0, ki == qi, True
     else:
         qi, ki = pl.program_id(2), pl.program_id(3)
-        last = ki == nk - 1
+        first, last = ki == 0, ki == nk - 1
         needed = ((qi + 1) * bq - 1 + (tk - tq) >= ki * bk) if causal \
             else True
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -467,7 +488,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                                 lse_ref[0, 0, :, :1], delta_ref[0, 0, :, :1],
                                 glse,
                                 scale, causal, qi, ki, bq, bk, tq, tk,
-                                seg=seg)
+                                seg=seg, window=window)
         dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -479,7 +500,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                 scale, causal, bq, bk, nq, tq, tk, with_glse,
-                with_segments, tri):
+                with_segments, tri, window: int = 0, group: int = 1,
+                steps: int = 0):
     if with_glse:
         glse_ref, *refs = refs
         glse = glse_ref[0, 0, :, :1]
@@ -488,14 +510,26 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
     if with_segments:
         qseg_ref, kseg_ref, *refs = refs
     dk_ref, dv_ref, dk_scr, dv_scr = refs
-    if tri:
+    if steps:
+        # By KV head and under a band: grid (B, Hkv, nk, group, steps).
+        # Row ki accumulates, for each of its group's query heads in
+        # turn, the q blocks ki .. ki + steps - 1 that see it (the
+        # forward's band transposed; every later block without a
+        # window); the blocks past the sequence are dead.
+        ki = pl.program_id(2)
+        qi = ki + pl.program_id(4)
+        first = (pl.program_id(3) == 0) & (pl.program_id(4) == 0)
+        last = (pl.program_id(3) == group - 1) & (pl.program_id(4)
+                                                  == steps - 1)
+        needed = qi <= nq - 1
+    elif tri:
         # Fused upper-triangular grid: row ki accumulates qi = ki..nq-1,
         # exactly the blocks a causal self-attention needs.
         ki, qi = _tri_ki_qi_upper(pl.program_id(2), nq)
-        first, needed = qi == ki, True
+        first, last, needed = qi == ki, qi == nq - 1, True
     else:
         ki, qi = pl.program_id(2), pl.program_id(3)  # k outer, q inner
-        first = qi == 0
+        first, last = qi == 0, qi == nq - 1
         needed = ((qi + 1) * bq - 1 + (tk - tq) >= ki * bk) if causal \
             else True
 
@@ -513,7 +547,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                                 lse_ref[0, 0, :, :1], delta_ref[0, 0, :, :1],
                                 glse,
                                 scale, causal, qi, ki, bq, bk, tq, tk,
-                                seg=seg)
+                                seg=seg, window=window)
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -521,7 +555,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -530,21 +564,37 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 def _pallas_backward(q, k, v, out, lse, do,
                      causal: bool, scale: float,
                      block_q: int, block_k: int, interpret: bool,
-                     glse=None, segment_ids=None):
+                     glse=None, segment_ids=None, window: int = 0):
     """-> (dq, dk, dv), all in their input layouts/dtypes. ``glse``
     [B,H,Tq] is the lse output's cotangent — None (plain attention)
     compiles kernels without the extra input. ``segment_ids``:
-    (q_seg [B,Tq], kv_seg [B,Tk]) for packed-sequence masking."""
+    (q_seg [B,Tq], kv_seg [B,Tk]) for packed-sequence masking.
+    ``k`` / ``v`` with fewer heads than ``q`` (whole groups of query
+    heads a KV head) and a ``window`` are the forward's
+    (``_forward_impl``): causal self-attention with square blocks. dQ
+    then walks the forward's band, K/V read by head group; dK/dV take
+    one grid row a KV head and k block and accumulate, in float32,
+    over the group's query heads and the q blocks that see the block
+    (the band transposed)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, hkv = k.shape[1], k.shape[2]
     bq = _divisor_block(tq, block_q)
     bk = _divisor_block(tk, block_k)
     nq, nk = tq // bq, tk // bk
     with_glse = glse is not None
     with_seg = segment_ids is not None
     tri = _use_tri(causal, tq, tk, bq, bk)
+    group = h // hkv
+    band = steps = 0
+    if window or group > 1:
+        if not (causal and tq == tk and bq == bk) or with_seg:
+            raise ValueError("head groups and a window are built for "
+                             "causal self-attention with square blocks, "
+                             "without segments")
+        band = _band_blocks(window, bq, nq) if window else 0
+        steps = band or nq
 
     qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))
     dot_ = do.swapaxes(1, 2)
@@ -557,10 +607,13 @@ def _pallas_backward(q, k, v, out, lse, do,
     rows = [lane(lse), lane(delta)] + ([lane(glse)] if with_glse else [])
     segs = list(_seg_operands(segment_ids, b, tq, tk)) if with_seg else []
 
-    # dQ: same grid/order as the forward — triangular when eligible,
-    # else rectangular with clamped k/v maps (dead copies elided).
+    # dQ: same grid/order as the forward — the band under a window,
+    # triangular when eligible, else rectangular with clamped k/v maps
+    # (dead copies elided).
     grid_dq, qmap, kvmap, qsegmap, ksegmap = _grid_and_maps(
         causal, bq, bk, nq, nk, tq, tk, b, h)
+    grid_dq, qmap, kvmap = _rows_grid(grid_dq, qmap, kvmap, b, h, nq, band,
+                                      group)
     q_spec = pl.BlockSpec((1, 1, bq, d), qmap)
     row_spec = pl.BlockSpec((1, 1, bq, 128), qmap)
     kv_spec = pl.BlockSpec((1, 1, bk, d), kvmap)
@@ -574,7 +627,8 @@ def _pallas_backward(q, k, v, out, lse, do,
             functools.partial(_dq_kernel, scale=scale, causal=causal,
                               bq=bq, bk=bk, nk=nk, tq=tq, tk=tk,
                               with_glse=with_glse, tri=tri,
-                              with_segments=with_seg),
+                              with_segments=with_seg, window=window,
+                              band=band),
             grid=grid_dq,
             in_specs=[q_spec, kv_spec, kv_spec, q_spec]
             + [row_spec] * len(rows) + seg_specs,
@@ -589,6 +643,12 @@ def _pallas_backward(q, k, v, out, lse, do,
     # eligible).
     grid_dkv, qmap_t, kvmap_t, qsegmap_t, ksegmap_t = _grid_and_maps(
         causal, bq, bk, nq, nk, tq, tk, b, h, transposed=True)
+    if steps:
+        # (a dead trailing step re-references the last q block: no copy)
+        grid_dkv = (b, hkv, nk, group, steps)
+        qmap_t = lambda b, n, j, g, s: (                # noqa: E731
+            b, n * group + g, jnp.minimum(j + s, nq - 1), 0)
+        kvmap_t = lambda b, n, j, g, s: (b, n, j, 0)    # noqa: E731
     qi_spec = pl.BlockSpec((1, 1, bq, d), qmap_t)
     rowi_spec = pl.BlockSpec((1, 1, bq, 128), qmap_t)
     kvj_spec = pl.BlockSpec((1, 1, bk, d), kvmap_t)
@@ -599,13 +659,14 @@ def _pallas_backward(q, k, v, out, lse, do,
             functools.partial(_dkv_kernel, scale=scale, causal=causal,
                               bq=bq, bk=bk, nq=nq, tq=tq, tk=tk,
                               with_glse=with_glse, with_segments=with_seg,
-                              tri=tri),
+                              tri=tri, window=window, group=group,
+                              steps=steps),
             grid=grid_dkv,
             in_specs=[qi_spec, kvj_spec, kvj_spec, qi_spec]
             + [rowi_spec] * len(rows) + segi_specs,
             out_specs=[kvj_spec, kvj_spec],
-            out_shape=[jax.ShapeDtypeStruct((b, h, tk, d), k.dtype),
-                       jax.ShapeDtypeStruct((b, h, tk, d), v.dtype)],
+            out_shape=[jax.ShapeDtypeStruct((b, hkv, tk, d), k.dtype),
+                       jax.ShapeDtypeStruct((b, hkv, tk, d), v.dtype)],
             scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                             pltpu.VMEM((bk, d), jnp.float32)],
             interpret=interpret,
@@ -916,18 +977,56 @@ def grouped_window_attention(q, k, v, *, scale: float, window: int = 0):
     return o.reshape(b, t, h, d).astype(q.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_rows(q, k, v, scale, window, block, interpret):
+    """``flash_prefill``'s kernel call; differentiated, the forward
+    keeps its log-sum-exp and the backward is ``_pallas_backward`` by
+    head group and under the band."""
+    return _forward_impl(q, k, v, True, scale, block, block, interpret,
+                         with_lse=False, window=window)
+
+
+def _flash_rows_fwd(q, k, v, scale, window, block, interpret):
+    with jax.named_scope("tpunet_flash_fwd"):
+        out, lse = _forward_impl(q, k, v, True, scale, block, block,
+                                 interpret, with_lse=True, window=window)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_rows_bwd(scale, window, block, interpret, res, g):
+    q, k, v, out, lse = res
+    with jax.named_scope("tpunet_flash_bwd"):
+        return _pallas_backward(q, k, v, out, lse, g, True, scale, block,
+                                block, interpret, window=window)
+
+
+_flash_rows.defvjp(_flash_rows_fwd, _flash_rows_bwd)
+
+
+def causal_blocks_visited(t: int, window: int, block: int = 512):
+    """``(visited, causal)``: the (query block, key block) pairs the
+    grids of ``flash_prefill`` and of its backward visit for rows of
+    ``t`` tokens under ``window`` (0: none), of the pairs on or under
+    the diagonal. What a trainer's gauge says of a windowed layer."""
+    bs = _divisor_block(t, block)
+    nq = t // bs
+    band = _band_blocks(window, bs, nq) if 0 < window < t else nq
+    return sum(min(i + 1, band) for i in range(nq)), nq * (nq + 1) // 2
+
+
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   scale: Optional[float] = None,
                   window: Optional[int] = None, block: int = 512,
                   interpret: Optional[bool] = None) -> jax.Array:
-    """Causal self-attention of rows that start at position 0, forward
-    only: ``q`` [B, T, H, D] over ``k`` / ``v`` [B, T, Hkv, D] with
-    ``Hkv`` dividing ``H`` (grouped-query attention: K and V are read
-    by head group, never repeated) and, with ``window``, each query on
-    its last ``window`` keys (itself counted). The flash forward kernel
-    on the TPU, ``grouped_window_attention`` off it (``interpret=True``
-    drives the kernel's body in tests) and for lengths whose only
-    divisors are tiny."""
+    """Causal self-attention of rows that start at position 0 (a
+    prefill, a training batch): ``q`` [B, T, H, D] over ``k`` / ``v``
+    [B, T, Hkv, D] with ``Hkv`` dividing ``H`` (grouped-query
+    attention: K and V are read by head group, never repeated) and,
+    with ``window``, each query on its last ``window`` keys (itself
+    counted). Differentiable in ``q``, ``k`` and ``v``. The flash
+    kernels on the TPU, ``grouped_window_attention`` off it
+    (``interpret=True`` drives the kernels' bodies in tests) and for
+    lengths whose only divisors are tiny."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     t, window = q.shape[1], int(window or 0)
     if q.shape[2] % k.shape[2] or k.shape != v.shape or k.shape[1] != t:
@@ -939,5 +1038,4 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if (interpret is None and jax.default_backend() != "tpu") \
             or (bs < 64 and bs < min(block, t)):
         return grouped_window_attention(q, k, v, scale=scale, window=window)
-    return _forward_impl(q, k, v, True, scale, bs, bs, bool(interpret),
-                         with_lse=False, window=window)
+    return _flash_rows(q, k, v, scale, window, bs, bool(interpret))

@@ -242,8 +242,9 @@ class Trainer:
             # what the model says of itself (latent_lm: experts held).
             gauges = {"train_params_resident_bytes": sum(
                 p.nbytes for p in jax.tree_util.tree_leaves(state.params))}
-            gauges.update(getattr(create_model(cfg.model, mesh=self.mesh),
-                                  "train_gauges", dict)())
+            gauges.update(getattr(
+                create_model(cfg.model, mesh=self.mesh), "train_gauges",
+                lambda seq_len: {})(cfg.data.seq_len))
             for name, value in gauges.items():
                 self.obs.registry.gauge(name).set(value)
         self.ckpt = Checkpointer(cfg.checkpoint, obs=self.obs)
