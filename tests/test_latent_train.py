@@ -399,12 +399,14 @@ def test_rows_no_group_holds_send_nothing_back(monkeypatch):
 
 # -- what the cells that share this code keep ---------------------------------
 
-# Recorded from the parent commit (6d7e8bd) on the CPU with the two
-# functions below; a change that means to alter either program records
-# them again and says so.
+# Recorded on the CPU with the two functions below; a change that means
+# to alter either program records them again and says so. The tokens
+# are the parent commit's of PR 36 (6d7e8bd); the LM step's texts are
+# PR 43's, which meant to alter it: `steps._ce_loss` picks its target
+# by a compare and brings its own backward (tests/test_ce_loss.py).
 LM_STEP_SHA256 = {
-    1: "3b419f40678d69f70f594eba76f0487ff02ff24dcecb8d02bf592ed3e1b87907",
-    2: "df92e8f897498d2ebdbe6d38b7d4130429ddb911b3c9cd61b9bb6b5a429c9082"}
+    1: "36ae9e8468d497e848df1b858c79dfb182f254417c270e44975dce2a7adef227",
+    2: "92011fe64c2e81a208636d8a4f5a59e78f84350fec5aef31e1e2ccc45b889cf0"}
 DOTS3_TOKENS = [[40, 19, 40, 25, 42, 28, 27, 11, 11],
                 [32, 32, 32, 32, 32, 32, 32, 32, 32, 32, 32, 32],
                 [16, 8, 9, 42, 28, 13, 19]]

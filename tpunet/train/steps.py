@@ -33,7 +33,39 @@ def _ce_loss(logits, targets, smoothing: float):
         return optax.softmax_cross_entropy(
             logits, optax.smooth_labels(
                 jax.nn.one_hot(targets, logits.shape[-1]), smoothing))
-    return optax.softmax_cross_entropy_with_integer_labels(logits, targets)
+    return _int_label_ce(logits, targets)
+
+
+@jax.custom_vjp
+def _int_label_ce(logits, targets):
+    """``logsumexp(logits) - logits[target]`` per position, in float32:
+    optax's integer-label cross-entropy to the bit, but the target is
+    picked by a compare against the vocabulary's iota, not a gather.
+    The gather comes back as a scatter into the flattened logits, and
+    the TPU compiler wraps that in two serial reshape loops and four
+    more passes over the ``[positions, vocabulary]`` cotangent (31 ms
+    of a 378 ms step at 8,191 positions: PERF.md section 6, PR 43).
+    This backward is one elementwise pass, and what it keeps is the
+    logits and one number a row."""
+    return _int_label_ce_fwd(logits, targets)[0]
+
+
+def _int_label_ce_fwd(logits, targets):
+    wide = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(wide, axis=-1)
+    hot = jax.nn.one_hot(targets, logits.shape[-1], dtype=jnp.bool_)
+    picked = jnp.sum(jnp.where(hot, wide, 0.0), axis=-1)
+    return lse - picked, (logits, lse, targets)
+
+
+def _int_label_ce_bwd(res, g):
+    logits, lse, targets = res
+    softmax = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+    hot = jax.nn.one_hot(targets, logits.shape[-1], dtype=jnp.float32)
+    return ((softmax - hot) * g[..., None]).astype(logits.dtype), None
+
+
+_int_label_ce.defvjp(_int_label_ce_fwd, _int_label_ce_bwd)
 
 
 def _aux_term(mutated, aux_weight: float):
