@@ -31,8 +31,10 @@ supports (depth is what the model's reference workload uses):
   width-1 decode step of a GPT-2 XL-wide LM (25 heads of 64, 16-token
   pages, bfloat16 pool) through the ``tpunet_paged_decode`` kernel and
   through the dense gather path, logits compared; then the default
-  Trainer's train_step is lowered and its compiled text must hold the
-  Pallas custom calls.
+  Trainers' train_steps are lowered: the LM step's compiled text must
+  hold the flash custom calls, the MobileNetV2 step's the fused-IR pair
+  exactly where ``fused_ir._kernel_pays`` engages it (nowhere, by the
+  v5e A/B of PR 44).
 - latent: the ``latent_lm`` decoder at the widths and the cut of
   ``benchmark/configs/dots3-note-prev.json`` over the serve engine's
   own pool and page table (``model.apply`` as ``Engine._masked_step``
@@ -1313,10 +1315,12 @@ def _kernels_child(rehearse: bool) -> int:
     from tpunet.train.loop import Trainer
     from tpunet.utils.prng import step_key
 
-    def trainer_step(label, argv, scope, run_one_step):
+    def trainer_step(label, argv, scope, run_one_step, engaged=None):
         """Lower the Trainer's own train_step on its own first batch,
         as bench.py does, and count the Pallas custom calls under
-        ``scope`` in the compiled text."""
+        ``scope`` in the compiled text: there have to be some, or,
+        where ``engaged`` is a list the kernel's own dispatch filled
+        while the step was traced, some exactly if it engaged."""
         cfg = config_from_args(argv + [
             "--checkpoint-dir",
             os.path.join(os.environ["TPUNET_SMOKE_OUT"], "kernels")])
@@ -1343,9 +1347,12 @@ def _kernels_child(rehearse: bool) -> int:
                            "output_size_in_bytes",
                            "alias_size_in_bytes")
                        if hasattr(mem, k)}}
-            if not rehearse and not rec[f"{scope}_custom_calls"]:
-                failures.append({label: f"no {scope} tpu_custom_call "
-                                 "in the Trainer's compiled step"})
+            want = True if engaged is None else any(engaged)
+            if not rehearse and bool(rec[f"{scope}_custom_calls"]) != want:
+                failures.append({label: f"{rec[f'{scope}_custom_calls']} "
+                                 f"{scope} tpu_custom_calls in the "
+                                 "Trainer's compiled step, the dispatch "
+                                 f"engaged: {want}"})
             if run_one_step:
                 # One real step, so the peak is a step's peak.
                 _, metrics = trainer.train_step(
@@ -1375,8 +1382,21 @@ def _kernels_child(rehearse: bool) -> int:
     else:
         lm += LM_TRAIN_WIDTH + ["--seq-len", "2048", "--batch-size",
                                 "8", "--synthetic-size", "32"]
-    trainer_step("mobilenet_v2_224_b128", vision, "tpunet_fused_ir",
-                 run_one_step=True)
+    # The MobileNetV2 step holds the fused-IR pair exactly where the
+    # chip's recorded verdict engages it (fused_ir._kernel_pays), so
+    # the dispatch's own answers are what the compiled text is held to.
+    engaged, dispatch = [], fused_ir.use_fused_ir_kernel
+
+    def recording(shape):
+        engaged.append(dispatch(shape))
+        return engaged[-1]
+
+    fused_ir.use_fused_ir_kernel = recording
+    try:
+        trainer_step("mobilenet_v2_224_b128", vision, "tpunet_fused_ir",
+                     run_one_step=True, engaged=engaged)
+    finally:
+        fused_ir.use_fused_ir_kernel = dispatch
     gc.collect()
     # Compile only: the train_lm phase runs it (and finds this compile
     # in the cache).

@@ -25,7 +25,8 @@ import pytest
 
 from tpunet.config import ModelConfig
 from tpunet.models import create_model
-from tpunet.models.mobilenetv2 import InvertedResidual
+from tpunet.models.mobilenetv2 import (INVERTED_RESIDUAL_SETTINGS,
+                                       InvertedResidual)
 from tpunet.ops import fused_ir
 
 
@@ -169,20 +170,68 @@ def test_dispatch_off_tpu_is_reference(monkeypatch):
     assert not fused_ir.use_fused_ir_kernel((8, 28, 28, 96))
 
 
-def test_dispatch_per_shape_on_tpu(monkeypatch):
+def _ir_1x1_input_shapes(batch=128, image=224):
+    """(unit, input shape) of the 33 train-mode 1x1 ConvBN units of the
+    inverted-residual stack at ``image`` px, walked from the settings
+    table as MobileNetV2.__call__ walks it (the stride-2 stem hands
+    block00 32 channels at image/2)."""
+    shapes, hw, ci, idx = [], image // 2, 32, 0
+    for t, c, n, s in INVERTED_RESIDUAL_SETTINGS:
+        for i in range(n):
+            if t != 1:
+                shapes.append((f"block{idx:02d}/expand",
+                               (batch, hw, hw, ci)))
+            hw //= s if i == 0 else 1       # the depthwise's stride
+            shapes.append((f"block{idx:02d}/project",
+                           (batch, hw, hw, ci * t)))
+            ci, idx = c, idx + 1
+    return shapes
+
+
+IR_1X1_SHAPES = _ir_1x1_input_shapes()
+
+# The recorded v5e verdict (the table in fused_ir._kernel_pays'
+# docstring; PERF.md section 6, PR 44): the units whose D-arm beat the
+# compiler's convolution in the step. None did.
+ENGAGED_ON_V5E = frozenset()
+
+
+def test_ir_1x1_shapes_are_the_models(monkeypatch):
+    """The walk above hands the dispatch test exactly the shapes the
+    224px model hands ``conv1x1_bn_act`` at batch 128, in order."""
+    seen = []
+    real = fused_ir.conv1x1_bn_act
+
+    def recording(x, *args, **kwargs):
+        seen.append(tuple(x.shape))
+        return real(x, *args, **kwargs)
+
+    monkeypatch.setattr(fused_ir, "conv1x1_bn_act", recording)
+    model = create_model(ModelConfig(fused_bn=True, fused_ir=True))
+    jax.eval_shape(
+        lambda x: model.init({"params": jax.random.PRNGKey(0),
+                              "dropout": jax.random.PRNGKey(1)},
+                             x, train=True),
+        jax.ShapeDtypeStruct((128, 224, 224, 3), jnp.float32))
+    assert len(IR_1X1_SHAPES) == 33
+    assert seen == [shape for _, shape in IR_1X1_SHAPES]
+
+
+@pytest.mark.parametrize("unit,shape", IR_1X1_SHAPES,
+                         ids=[u for u, _ in IR_1X1_SHAPES])
+def test_dispatch_per_shape_on_tpu(monkeypatch, unit, shape):
+    """On the TPU each unit takes the Pallas pair exactly where the
+    chip's A/B said it beats the compiler's convolution."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.delenv("TPUNET_FUSED_IR_REF", raising=False)
-    # 112px..14px expand/project shapes pay (Ci < H*W)...
-    assert fused_ir.use_fused_ir_kernel((512, 112, 112, 16))
-    assert fused_ir.use_fused_ir_kernel((512, 14, 14, 96))
-    # ...the 7px tail and the 320->1280 head keep the XLA emitter.
-    assert not fused_ir.use_fused_ir_kernel((512, 7, 7, 160))
-    assert not fused_ir.use_fused_ir_kernel((512, 7, 7, 320))
+    assert fused_ir.use_fused_ir_kernel(shape) == (unit in ENGAGED_ON_V5E)
 
 
 def test_escape_hatch_env_var(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setenv("TPUNET_FUSED_IR_REF", "1")
+    # Whatever the recorded verdict says of a shape, the hatch wins.
+    monkeypatch.setattr(fused_ir, "_kernel_pays", lambda shape: True)
     assert not fused_ir.use_fused_ir_kernel((512, 112, 112, 16))
     # And the public op still runs (reference path) with the hatch set
     # on a "TPU" backend — no Pallas lowering is attempted.
@@ -197,7 +246,7 @@ def test_escape_hatch_env_var(monkeypatch):
 # ----------------------------------------------- model-level contract
 
 def _model_and_vars(fused_flag, block_remat=False, dtype="float32"):
-    cfg = ModelConfig(width_mult=0.5, fused_ir=fused_flag,
+    cfg = ModelConfig(width_mult=0.5, fused_bn=True, fused_ir=fused_flag,
                       block_remat=block_remat, dtype=dtype)
     model = create_model(cfg)
     x = _rand(0, (2, 32, 32, 3), jnp.float32)

@@ -216,15 +216,17 @@ class _FusedIRBN(nn.Module):
 class ConvBN(nn.Module):
     """Conv + BatchNorm (+ optional ReLU6), the MobileNetV2 building unit.
 
-    ``fused_bn`` (default) expresses BN + clamp through ``FusedBNAct``
-    — one fusable epilogue region; off, the original ``nn.BatchNorm``
-    + separate ReLU6 path (bit-compatible variable trees either way).
-    ``fused_ir`` (default, train-mode 1x1 convs only) additionally
-    routes conv + batch stats through the one-pass fused-IR kernel
-    (tpunet/ops/fused_ir.py): the training-BN statistics read of the
-    conv output never hits HBM, and the backward recomputes the
-    epilogue in VMEM. Eval mode always takes the plain path, so eval
-    logits are bit-identical across the flag.
+    ``fused_bn`` expresses BN + clamp through ``FusedBNAct`` — one
+    jax.numpy epilogue region; off (``ModelConfig``'s default since the
+    v5e A/B of PERF.md section 6, PR 44: 29% faster in the step), the
+    original ``nn.BatchNorm`` + separate ReLU6 path, which the TPU
+    compiler fuses into its convolutions (bit-compatible variable
+    trees either way). ``fused_ir`` (needs ``fused_bn``; train-mode
+    1x1 convs only) routes conv + batch stats through
+    tpunet/ops/fused_ir.py: the one-pass Pallas pair where
+    ``fused_ir._kernel_pays`` engages it (nowhere on the v5e, by the
+    same A/B), FusedBNAct's math elsewhere. Eval mode always takes the
+    plain path, so eval logits are bit-identical across the flag.
     """
 
     features: int

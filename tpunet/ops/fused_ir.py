@@ -31,19 +31,21 @@ project 1x1 convolutions that bracket the depthwise kernel
   complete over the whole batch before any stripe's t is computable,
   so they cannot live inside the sequential grid.
 
-Per-shape dispatch (``_kernel_pays``): the per-image dw partial costs
-``Cin*Cout*4`` bytes against the ``~3*H*W*Cout*2`` bytes of saved
-epilogue traffic, so the pair pays (with margin) when ``Cin < H*W``.
-At 224px input that engages 20 of the 33 expand/project convs — every
-expand at 112..14px spatial and every project through 28px; the
-fat-input 14px projects (Cin 384..576 vs H*W = 196), the 7px tail,
-and the 320->1280 head keep the XLA path — the same honest per-shape
-verdict discipline as the round-4 depthwise-forward result
-(docs/performance.md). Off-TPU the reference runs (the interpreter is
-far too slow for a hot path); ``interpret=True`` exercises both
-kernels in tests; ``TPUNET_FUSED_IR_REF=1`` is the escape hatch back
-to the XLA reference on TPU (e.g. a Mosaic regression on a new
-toolchain) without touching checkpoints or configs.
+Per-shape dispatch (``_kernel_pays``): the chip's verdict, taken IN THE
+STEP on a TPU v5e (2026-10-03, PERF.md section 6, PR 44) — and no shape
+of the 224px model engages the pair. The byte model this module was
+written to (the per-image dw partial's ``Cin*Cout*4`` bytes against
+``~3*H*W*Cout*2`` of saved epilogue traffic, so ``Cin < H*W``: 20 of
+the 33 expand/project convs) counted the kernels' own traffic only.
+What it left out decides: the compiler lays a ``[128, H, W, C]``
+activation out batch-minor (128 images fill the 128 lanes whatever C
+is), a Mosaic call takes its operands channel-minor, so every engaged
+call is bracketed by whole-activation layout copies in HBM and, inside,
+C = 16..192 fills an eighth to three quarters of a lane tile. Off-TPU
+the reference runs (the interpreter is far too slow for a hot path);
+``interpret=True`` exercises both kernels in tests whatever the
+dispatch says; ``TPUNET_FUSED_IR_REF=1`` is the escape hatch back to
+the XLA reference on TPU without touching checkpoints or configs.
 
 The reference path (``conv1x1_bn_act_reference``) mirrors
 ``models.mobilenetv2.FusedBNAct`` op for op, so flipping
@@ -366,16 +368,34 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
 def _kernel_pays(shape) -> bool:
-    """Per-shape profitability: the backward's per-image dw partial
-    costs Ci*Co*4 bytes against ~3*H*W*Co*2 bytes of saved epilogue
-    traffic, so the pair pays (with margin) iff Ci < H*W. At 224px
-    that is 20/33 expand+project convs — every expand at 112..14px
-    and every project through 28px; the fat-input 14px projects
-    (Ci 384..576 vs H*W = 196), the 7px tail, and the 320->1280 head
-    keep the XLA emitter — a recorded per-shape verdict, like the
-    round-4 depthwise-forward result."""
-    _, h, w, ci = shape
-    return ci < h * w
+    """The chip's verdict for an input shape ``(N, H, W, Ci)``: does the
+    Pallas pair beat the compiler's convolution IN THE STEP, layout
+    copies around the call included? Not for any shape of the 224px
+    model. One TPU v5 lite, 2026-10-03, batch 128, the benchmark's
+    ``mnv2-224.train-b128`` cell traced (``fwd_bwd_ms.train``, 64-160
+    steps, seed 1618033988; PERF.md section 6, PR 44), the pair engaged
+    at ONE resolution and the reference everywhere else:
+
+    ====== ================================== ========= ============
+    H = W  engaged input shapes (Ci)          with pair without (B)
+    ====== ================================== ========= ============
+    112    32, 16                             50.21 ms  25.36 ms
+    56     96, 24, 144, 24                    35.99 ms  25.36 ms
+    28     144, 32 x3, 192 x2                 31.27 ms  25.36 ms
+    14     192, 64 x4, 96 x3                  30.60 ms  25.36 ms
+    14, 7  Ci >= H*W (the old rule's 13 off)  never ran the pair
+    ====== ================================== ========= ============
+
+    All 20 at once (the old rule ``Ci < H*W``): 71.67 ms and 1,764
+    img/s against 25.36 ms and 4,848 img/s untraced (three seeds a
+    side). A resolution had to win by 1% to engage; the closest loses
+    by 21%, so batch 512 (not measured) was not needed to decide. Of
+    the 72.2 ms of operations with the 20 engaged, the kernels are
+    16.4, the layout copies around them 15.7 and the neighbouring
+    elementwise fusions, run channel-minor on lane-padded C, 37.7 (the
+    module docstring says why)."""
+    del shape
+    return False
 
 
 def use_fused_ir_kernel(shape) -> bool:
