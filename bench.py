@@ -124,31 +124,7 @@ def _note(msg: str) -> None:
     print(f"# {msg}", file=sys.stderr, flush=True)
 
 
-def _model_overrides(argv) -> dict:
-    """ModelConfig overrides from the variant flags, so lever A/Bs are
-    one command each (docs/performance.md "A/B workflow"):
-    --block-remat / --no-block-remat, --fused-ir / --no-fused-ir,
-    --fused-bn / --no-fused-bn, --pallas-depthwise. Repeated flags are
-    last-wins in argv order, matching the train CLI's argparse
-    BooleanOptionalAction (so a sweep script may append an override to
-    a base command)."""
-    spec = {}
-    for flag, field in (("block-remat", "block_remat"),
-                        ("fused-ir", "fused_ir"),
-                        ("fused-bn", "fused_bn"),
-                        ("pallas-depthwise", "use_pallas_depthwise")):
-        spec[f"--{flag}"] = (field, True)
-        spec[f"--no-{flag}"] = (field, False)
-    out = {}
-    for arg in argv:
-        if arg in spec:
-            field, value = spec[arg]
-            out[field] = value
-    return out
-
-
-def _measure(per_chip_batch: int, timed: int = 24, image_size: int = 224,
-             model_overrides: dict | None = None):
+def _measure(per_chip_batch: int, timed: int = 24, image_size: int = 224):
     """Steady-state throughput of the full train step at the given
     per-chip batch. Returns (img/s/chip, flops-per-execution or 0)."""
     from tpunet.config import (CheckpointConfig, DataConfig, MeshConfig,
@@ -163,7 +139,7 @@ def _measure(per_chip_batch: int, timed: int = 24, image_size: int = 224,
     cfg = TrainConfig(
         data=DataConfig(dataset="synthetic", batch_size=batch,
                         image_size=image_size),
-        model=ModelConfig(**(model_overrides or {})),  # bf16 compute
+        model=ModelConfig(),  # bf16 compute
         optim=OptimConfig(),
         mesh=MeshConfig(),
         checkpoint=CheckpointConfig(save_best=False, save_last=False),
@@ -250,20 +226,6 @@ def _measure(per_chip_batch: int, timed: int = 24, image_size: int = 224,
 
 def main() -> None:
     n_chips = jax.device_count()
-    overrides = _model_overrides(sys.argv[1:])
-    if overrides and "--enforce-budget" in sys.argv[1:]:
-        # The budget is the accepted measurement of the DEFAULT tree;
-        # gating a deliberately non-default lever state against it
-        # manufactures a false REGRESSION (e.g. --no-fused-ir measures
-        # the legacy path, which is over the ratcheted budget by
-        # design). Refuse loudly rather than letting the combination
-        # masquerade as a regression — same posture as bench_serve's
-        # --http --enforce-budget refusal.
-        _note("--enforce-budget gates the default configuration; "
-              f"refusing with lever overrides {overrides} (run the "
-              "gate without override flags, or compare A/B records "
-              "by hand per docs/performance.md)")
-        sys.exit(2)
     smoke = "--smoke" in sys.argv[1:]
     if not smoke:
         # A measurement path that finds no chip fails; it does not
@@ -275,24 +237,24 @@ def main() -> None:
         # JSON/bytes plumbing is what's exercised. Its record (below)
         # carries counts only — no rate, time or utilization.
         (peak_ips, flops, dt_step, traffic, xla_bytes, pcb,
-         breakdown, identity) = _measure(8, timed=3, image_size=32,
-                                         model_overrides=overrides)
+         breakdown, identity) = _measure(8, timed=3, image_size=32)
         ref_ips = None
     elif "--peak-only" in sys.argv[1:]:
-        # Flag/variant sweeps: just the peak-shape number (the batch-128
-        # companion costs a second warmup and doesn't move with flags).
+        # XLA-flag sweeps (scripts/flag_sweep.py): just the peak-shape
+        # number (the batch-128 companion costs a second warmup and
+        # doesn't move with flags).
         # The batch128_* fields become null — aliasing them to the
         # batch-512 figure would fabricate a measurement under a name
         # that promises the reference shape.
         (peak_ips, flops, dt_step, traffic, xla_bytes, pcb,
-         breakdown, identity) = _measure(512, model_overrides=overrides)
+         breakdown, identity) = _measure(512)
         ref_ips = None
     else:
         # Peak-throughput shape (per-chip batch sweep optimum) and the
         # reference's exact shape (cifar10_128batch.py:59: batch 128).
         (peak_ips, flops, dt_step, traffic, xla_bytes, pcb,
-         breakdown, identity) = _measure(512, model_overrides=overrides)
-        ref_ips = _measure(128, model_overrides=overrides)[0]
+         breakdown, identity) = _measure(512)
+        ref_ips = _measure(128)[0]
 
     peak = _chip_spec(_PEAK_FLOPS)
     bw = _chip_spec(_HBM_BW)
@@ -355,11 +317,6 @@ def main() -> None:
                       "xla_bytes_accessed_per_image",
                       "bytes_per_image_breakdown", "platform",
                       "device_kind", "device_count", *identity)}}
-    if overrides:
-        # Variant runs are self-describing: a sweep artifact records
-        # which levers it measured (default runs omit the field, so
-        # the driver's BENCH_r* records keep their shape).
-        record["model_overrides"] = overrides
     print(json.dumps(record))
 
     if "--enforce-budget" in sys.argv[1:]:

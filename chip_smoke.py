@@ -11,9 +11,8 @@ The last stdout line is one JSON object,
 everything else (per-phase JSON, losses, tokens, seconds, cache hits)
 is printed on earlier lines. Exit code 0 only when every phase passed
 ON A TPU: with no chip, a non-TPU platform, a failed or timed-out
-phase, or ``TPUNET_FUSED_IR_REF`` / ``TPUNET_FLASH_INTERPRET`` in the
-environment, the last line says ``"ok": false`` and the exit code is
-non-zero.
+phase, or ``TPUNET_FLASH_INTERPRET`` in the environment, the last
+line says ``"ok": false`` and the exit code is non-zero.
 
 This parent process never initialises a jax backend, so it never owns
 the chip: every phase is a child process, run one after another, with
@@ -25,16 +24,15 @@ children print (train.py's ``JAX devices:`` line, the server's
 Phases of the default run, each at the full width of a model the repo
 supports (depth is what the model's reference workload uses):
 
-- kernels: on-chip parity (``interpret=False``) of conv1x1_bn_act,
-  flash_attention (plain + segmented) and depthwise_conv3x3 fwd/bwd
-  against the repo's references at the 224px / T=2048 shapes; one
-  width-1 decode step of a GPT-2 XL-wide LM (25 heads of 64, 16-token
-  pages, bfloat16 pool) through the ``tpunet_paged_decode`` kernel and
-  through the dense gather path, logits compared; then the default
-  Trainers' train_steps are lowered: the LM step's compiled text must
-  hold the flash custom calls, the MobileNetV2 step's the fused-IR pair
-  exactly where ``fused_ir._kernel_pays`` engages it (nowhere, by the
-  v5e A/B of PR 44).
+- kernels: on-chip parity (``interpret=False``) of flash_attention
+  (plain + segmented), forward and backward, against dense attention
+  at T=2048; one width-1 decode step of a GPT-2 XL-wide LM (25 heads
+  of 64, 16-token pages, bfloat16 pool) through the
+  ``tpunet_paged_decode`` kernel and through the dense gather path,
+  logits compared; then the default Trainers' train_steps are lowered:
+  the LM step's compiled text must hold the flash custom calls, the
+  MobileNetV2 step's no custom call at all (convolutions, BatchNorm
+  and ReLU6 are the compiler's).
 - latent: the ``latent_lm`` decoder at the widths and the cut of
   ``benchmark/configs/dots3-note-prev.json`` over the serve engine's
   own pool and page table (``model.apply`` as ``Engine._masked_step``
@@ -82,7 +80,7 @@ import urllib.error
 import urllib.request
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-FORBIDDEN_ENV = ("TPUNET_FUSED_IR_REF", "TPUNET_FLASH_INTERPRET")
+FORBIDDEN_ENV = ("TPUNET_FLASH_INTERPRET",)
 
 # LM width of serve and the router replicas: the 604M row of
 # runs/bench-lm-mfu/MFU_r03.json (hidden 2048, depth 12, 16 heads).
@@ -1116,8 +1114,6 @@ def _kernels_child(rehearse: bool) -> int:
     import jax
     import jax.numpy as jnp
 
-    from tpunet.ops import (depthwise_conv3x3,
-                            depthwise_conv3x3_reference, fused_ir)
     from tpunet.ops.attention import dense_attention
     from tpunet.ops.flash import flash_attention
     from tpunet.parallel.dist import initialize_distributed
@@ -1155,81 +1151,6 @@ def _kernels_child(rehearse: bool) -> int:
         say(row)
         if not (np.isfinite(got).all() and ratio <= 1.0):
             failures.append(row)
-
-    def rel_compare(kernel, name, got, want, tol, mask_grad=False):
-        """tests/test_fused_ir.py _rel_err: max|a-b| / max|a| < tol.
-
-        ``mask_grad``: dx/dw behind a ReLU6 in bfloat16. The kernel and
-        the reference round the conv output to bf16 separately, so a
-        ReLU6 mask bit flips on ~0.5% of the elements and moves that
-        pixel's dx by one whole term of its sum: the max error is then
-        a property of bf16, not of the kernel (the same comparison in
-        interpret mode on the CPU reads max 0.27 in bf16 and 2e-6
-        everywhere in float32; on the chip at batch 128: max 0.09-0.14,
-        relative L2 <= 6.3e-3, <= 6.5e-4 of the elements over the
-        bound — CHANGES.md PR 21). Gate those two on what a wrong
-        kernel cannot pass: the tests' 2e-2 as a relative L2, and
-        under 0.2% of the elements off by more than tol * max."""
-        got = np.asarray(got, np.float32)
-        want = np.asarray(want, np.float32)
-        err = np.abs(got - want)
-        scale = float(np.max(np.abs(want))) + 1e-6
-        row = {"kernel": kernel, "tensor": name,
-               "rel_err": float(err.max()) / scale, "tol": tol,
-               "l2_rel": float(np.linalg.norm(got - want)
-                               / (np.linalg.norm(want) + 1e-12)),
-               "share_over_tol": float(np.mean(err > tol * scale))}
-        if mask_grad:
-            row["gate"] = "l2_rel<2e-2 and share_over_tol<2e-3"
-            ok = row["l2_rel"] < tol and row["share_over_tol"] < 2e-3
-        else:
-            ok = row["rel_err"] < tol
-        rows.append(row)
-        say(row)
-        if not (np.isfinite(got).all() and ok):
-            failures.append(row)
-
-    # -- conv1x1_bn_act vs its XLA reference: value, stats, 4 grads --
-    # (tests/test_fused_ir.py test_kernel_parity_fwd_and_grad, bf16
-    # tolerance 2e-2, at the 224px shapes of MobileNetV2 batch 128.)
-    ir_shapes = [(2, 8, 8, 16, 24)] if rehearse else [
-        (128, 112, 112, 16, 96), (128, 56, 56, 24, 144),
-        (128, 28, 28, 32, 192), (128, 56, 56, 144, 24)]
-    # (The rehearsal runs float32: at 128 elements per channel one
-    # bf16 rounding that flips a ReLU6 mask bit moves dbias by more
-    # than the tolerance, whichever path computed it.)
-    ir_dtype, ir_tol = ((jnp.float32, 1e-4) if rehearse
-                        else (jnp.bfloat16, 2e-2))
-    for n, h, w, ci, co in ir_shapes:
-        x = rnd(0, (n, h, w, ci), ir_dtype)
-        wgt = rnd(1, (ci, co), ir_dtype, 0.1)
-        scale = 1.0 + 0.5 * rnd(2, (co,), jnp.float32)
-        bias = 0.1 * rnd(3, (co,), jnp.float32)
-        act = ci < co        # expand convs carry the ReLU6
-
-        def run(fn):
-            def loss(x, wgt, scale, bias):
-                out, mean, var = fn(x, wgt, scale, bias, act, 1e-5)
-                # Non-uniform cotangent with a non-zero mean: the
-                # per-channel sums (dbias, dscale) must not cancel to
-                # nothing, or their relative error is all rounding.
-                ct = 1.0 + 0.5 * jnp.cos(jnp.arange(
-                    out.size, dtype=jnp.float32)).reshape(out.shape)
-                return (jnp.sum(out.astype(jnp.float32) * ct),
-                        (out, mean, var))
-            (_, aux), grads = jax.jit(jax.value_and_grad(
-                loss, argnums=(0, 1, 2, 3), has_aux=True))(
-                    x, wgt, scale, bias)
-            return aux + grads
-        ref = run(fused_ir.conv1x1_bn_act_reference)
-        ker = run(lambda *a: fused_ir.conv1x1_bn_act(
-            *a, interpret=interpret))
-        tag = f"conv1x1_bn_act[{n}x{h}x{w} {ci}->{co} act={act}]"
-        for name, a, b in zip(("out", "mean", "var", "dx", "dw",
-                               "dscale", "dbias"), ref, ker):
-            rel_compare(tag, name, b, a, ir_tol,
-                        mask_grad=(act and not rehearse
-                                   and name in ("dx", "dw")))
 
     # -- flash attention (plain + segmented) vs dense_attention ------
     # bf16 vs the f32 dense reference: rtol/atol 2e-2 on the value
@@ -1271,38 +1192,6 @@ def _kernels_child(rehearse: bool) -> int:
             for name, a, r in zip(("dq", "dk", "dv"), g, gr):
                 compare(tag, name, a, r, 5e-2, 5e-2)
 
-    # -- depthwise 3x3: fwd kernel and the dx/dw backward kernels ----
-    # bf16 vs the f32 reference: fwd 2e-2 (tests/test_ops.py:57-66),
-    # bwd 5e-2 (:176-198).
-    dw_shapes = [(2, 8, 8, 32, 1)] if rehearse else [
-        (128, 112, 112, 96, 1), (128, 112, 112, 96, 2)]
-    for n, h, w, c, stride in dw_shapes:
-        x = rnd(20, (n, h, w, c), jnp.float32)
-        wgt = rnd(21, (3, 3, c), jnp.float32)
-        ho = (h - 1) // stride + 1
-        g = rnd(22, (n, ho, ho, c), jnp.float32)
-
-        def vjp_of(f, x, wgt, g):
-            out, vjp = jax.vjp(f, x, wgt)
-            return (out,) + vjp(g)
-        got = jax.jit(lambda x, w_, g: vjp_of(
-            lambda a, b: depthwise_conv3x3(a, b, stride, interpret),
-            x, w_, g))(x.astype(jnp.bfloat16), wgt.astype(jnp.bfloat16),
-                       g.astype(jnp.bfloat16))
-
-        def ref_fn(x, w_, g):
-            with jax.default_matmul_precision("highest"):
-                return vjp_of(lambda a, b: depthwise_conv3x3_reference(
-                    a, b, stride), x, w_, g)
-        want = jax.jit(ref_fn)(
-            x.astype(jnp.bfloat16).astype(jnp.float32),
-            wgt.astype(jnp.bfloat16).astype(jnp.float32),
-            g.astype(jnp.bfloat16).astype(jnp.float32))
-        tag = f"depthwise3x3[{n}x{h}x{w}x{c} s{stride} bf16]"
-        compare(tag, "out", got[0], want[0], 2e-2, 2e-2)
-        compare(tag, "dx", got[1], want[1], 5e-2, 5e-2)
-        compare(tag, "dw", got[2], want[2], 5e-2, 5e-2)
-
     _paged_decode_check(rehearse, rows, failures)
     kernels_s = time.monotonic() - t0
 
@@ -1315,12 +1204,11 @@ def _kernels_child(rehearse: bool) -> int:
     from tpunet.train.loop import Trainer
     from tpunet.utils.prng import step_key
 
-    def trainer_step(label, argv, scope, run_one_step, engaged=None):
+    def trainer_step(label, argv, scope, run_one_step):
         """Lower the Trainer's own train_step on its own first batch,
-        as bench.py does, and count the Pallas custom calls under
-        ``scope`` in the compiled text: there have to be some, or,
-        where ``engaged`` is a list the kernel's own dispatch filled
-        while the step was traced, some exactly if it engaged."""
+        as bench.py does, and count the Pallas custom calls in the
+        compiled text: some under ``scope``, or, where ``scope`` is
+        None, none at all."""
         cfg = config_from_args(argv + [
             "--checkpoint-dir",
             os.path.join(os.environ["TPUNET_SMOKE_OUT"], "kernels")])
@@ -1338,8 +1226,6 @@ def _kernels_child(rehearse: bool) -> int:
             rec = {"train_step": label,
                    "compile_seconds": round(time.monotonic() - t1, 1),
                    "tpu_custom_calls": len(lines),
-                   f"{scope}_custom_calls":
-                       sum(1 for ln in lines if scope in ln),
                    "memory_analysis": {
                        k: int(getattr(mem, k)) for k in (
                            "temp_size_in_bytes",
@@ -1347,12 +1233,14 @@ def _kernels_child(rehearse: bool) -> int:
                            "output_size_in_bytes",
                            "alias_size_in_bytes")
                        if hasattr(mem, k)}}
-            want = True if engaged is None else any(engaged)
-            if not rehearse and bool(rec[f"{scope}_custom_calls"]) != want:
-                failures.append({label: f"{rec[f'{scope}_custom_calls']} "
-                                 f"{scope} tpu_custom_calls in the "
-                                 "Trainer's compiled step, the dispatch "
-                                 f"engaged: {want}"})
+            held = lines if scope is None else [ln for ln in lines
+                                                if scope in ln]
+            if scope is not None:
+                rec[f"{scope}_custom_calls"] = len(held)
+            if not rehearse and bool(held) != (scope is not None):
+                failures.append({label: f"{len(held)} tpu_custom_calls "
+                                 f"({scope or 'any scope'}) in the "
+                                 "Trainer's compiled step"})
             if run_one_step:
                 # One real step, so the peak is a step's peak.
                 _, metrics = trainer.train_step(
@@ -1382,21 +1270,9 @@ def _kernels_child(rehearse: bool) -> int:
     else:
         lm += LM_TRAIN_WIDTH + ["--seq-len", "2048", "--batch-size",
                                 "8", "--synthetic-size", "32"]
-    # The MobileNetV2 step holds the fused-IR pair exactly where the
-    # chip's recorded verdict engages it (fused_ir._kernel_pays), so
-    # the dispatch's own answers are what the compiled text is held to.
-    engaged, dispatch = [], fused_ir.use_fused_ir_kernel
-
-    def recording(shape):
-        engaged.append(dispatch(shape))
-        return engaged[-1]
-
-    fused_ir.use_fused_ir_kernel = recording
-    try:
-        trainer_step("mobilenet_v2_224_b128", vision, "tpunet_fused_ir",
-                     run_one_step=True, engaged=engaged)
-    finally:
-        fused_ir.use_fused_ir_kernel = dispatch
+    # The MobileNetV2 step calls no kernel: its convolutions, BatchNorm
+    # and ReLU6 are the compiler's own fusions.
+    trainer_step("mobilenet_v2_224_b128", vision, None, run_one_step=True)
     gc.collect()
     # Compile only: the train_lm phase runs it (and finds this compile
     # in the cache).

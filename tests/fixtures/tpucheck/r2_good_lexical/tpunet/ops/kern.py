@@ -12,19 +12,19 @@ def _kernel(x_ref, o_ref):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=())
 def fused_op(x):
-    with jax.named_scope("tpunet_fused_ir_fwd"):
+    with jax.named_scope("tpunet_flash_fwd"):
         return pl.pallas_call(_kernel, out_shape=x)(x)
 
 
 def _fwd(x):
-    with jax.named_scope("tpunet_fused_ir_fwd"):
+    with jax.named_scope("tpunet_flash_fwd"):
         y = pl.pallas_call(_kernel, out_shape=x)(x)
     return y, (x,)
 
 
 def _bwd(res, g):
     (x,) = res
-    with jax.named_scope("tpunet_fused_ir_bwd"):
+    with jax.named_scope("tpunet_flash_bwd"):
         return (pl.pallas_call(_kernel, out_shape=g)(g),)
 
 

@@ -1,4 +1,4 @@
-# tpucheck R2 good fixture: the depthwise layout — the pallas_call
+# tpucheck R2 good fixture: the wrapper layout — the pallas_call
 # lives in a wrapper (here additionally hidden behind a
 # custom_partitioning alias) whose every live call site is scoped;
 # the bwd body carries its own scope.
@@ -31,7 +31,7 @@ def _partition(mesh, arg_shapes, result_shape):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=())
 def depthwise_op(x):
-    with jax.named_scope("tpunet_depthwise_fwd"):
+    with jax.named_scope("tpunet_flash_fwd"):
         return _partitioned(x)
 
 
@@ -41,7 +41,7 @@ def _fwd(x):
 
 def _bwd(res, g):
     (x,) = res
-    with jax.named_scope("tpunet_depthwise_bwd"):
+    with jax.named_scope("tpunet_flash_bwd"):
         return (_pallas_forward(g),)
 
 

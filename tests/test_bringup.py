@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -171,66 +171,6 @@ def _mesh(n):
     return make_mesh(MeshConfig(data=n), devices=jax.devices()[:n])
 
 
-def test_fused_ir_kernel_splits_over_the_data_axis():
-    """conv1x1_bn_act under a data=4 mesh: the Pallas pair (interpret
-    mode) runs per shard through shard_map and value, batch stats and
-    all four gradients match the unsharded XLA reference — the BN
-    statistics are still those of the WHOLE batch."""
-    import functools
-
-    from tpunet.ops import fused_ir
-    from tpunet.ops.partition import traced_under
-    mesh = _mesh(4)
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (8, 6, 6, 8), jnp.float32)
-    w = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (8, 16))
-    scale = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(2), (16,))
-    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (16,))
-
-    def run(fn):
-        def loss(x, w, scale, bias):
-            out, mean, var = fn(x, w, scale, bias, True, 1e-5)
-            return jnp.sum(out ** 2), (out, mean, var)
-        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
-                                  has_aux=True)
-
-    xs = jax.device_put(x, NamedSharding(mesh, P("data")))
-    kernel = functools.partial(fused_ir.conv1x1_bn_act, interpret=True)
-    (_, aux), grads = jax.jit(traced_under(mesh, run(kernel)))(
-        xs, w, scale, bias)
-    (_, raux), rgrads = run(fused_ir.conv1x1_bn_act_reference)(
-        x, w, scale, bias)
-    assert aux[0].sharding.spec[0] == "data"
-    for a, b in zip(aux + grads, raux + rgrads):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("stride", [1, 2])
-def test_depthwise_kernels_split_over_the_data_axis(stride):
-    from tpunet.ops import depthwise_conv3x3, depthwise_conv3x3_reference
-    from tpunet.ops.partition import traced_under
-    mesh = _mesh(4)
-    x = jax.random.normal(jax.random.PRNGKey(0), (4, 8, 8, 16))
-    w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 16))
-
-    def run(fn):
-        return jax.value_and_grad(
-            lambda x, w: jnp.sum(fn(x, w) ** 2), argnums=(0, 1))
-
-    xs = jax.device_put(x, NamedSharding(mesh, P("data")))
-    val, (gx, gw) = jax.jit(traced_under(mesh, run(
-        lambda a, b: depthwise_conv3x3(a, b, stride, True))))(xs, w)
-    rval, (rx, rw) = run(
-        lambda a, b: depthwise_conv3x3_reference(a, b, stride))(x, w)
-    assert gx.sharding.spec[0] == "data"
-    np.testing.assert_allclose(float(val), float(rval), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(gx), np.asarray(rx),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(gw), np.asarray(rw),
-                               rtol=2e-4, atol=2e-4)
-
-
 def test_sharded_kernel_is_plain_without_a_mesh_or_a_fit():
     """No kernel mesh, a one-device mesh, or a batch the data axis does
     not divide: the kernel is called as it is."""
@@ -303,7 +243,7 @@ def test_measurement_scripts_refuse_the_cpu(monkeypatch, capsys):
 
 def test_chip_smoke_refuses_off_the_chip(tmp_path):
     """chip_smoke.py never passes off the chip: with the kernel escape
-    hatches in the environment it refuses to start, and with no TPU its
+    hatch in the environment it refuses to start, and with no TPU its
     first child fails — both print '"ok": false' last and exit
     non-zero, and no phase ran on the CPU."""
     import json
@@ -319,7 +259,7 @@ def test_chip_smoke_refuses_off_the_chip(tmp_path):
     assert "refusing to start" in out.stdout
 
     env = {k: v for k, v in os.environ.items()
-           if k not in ("TPUNET_FLASH_INTERPRET", "TPUNET_FUSED_IR_REF")}
+           if k != "TPUNET_FLASH_INTERPRET"}
     out = subprocess.run([sys.executable, smoke, "--out", str(tmp_path)],
                          env=env, capture_output=True, text=True,
                          timeout=300)

@@ -4,14 +4,13 @@ The TPU compiler is installed wherever the tests run, and compiles for
 a chip that is described and not attached (a ``v5e:2x2`` topology), so
 what Mosaic refuses — a slice off the tiling, too much VMEM, a shape
 cast it cannot lay out — fails here at no chip time. Interpret mode,
-which every other kernel test uses, shows none of that: the stride-2
-depthwise backward passed all of them and was refused on the chip
-(CHANGES.md PR 21).
+which every other kernel test uses, shows none of that: a backward
+kernel passed all of them and was refused on the chip (CHANGES.md
+PR 21).
 
 Shapes are the ones the smoke (chip_smoke.py) checks for parity on the
-chip: MobileNetV2 224px at batch 128, attention at [4, 2048, 16, D],
-the serve cell's width-1 decode over a paged pool (16 slots, 25 heads of
-64, 16-token pages).
+chip: attention at [4, 2048, 16, D], the serve cell's width-1 decode
+over a paged pool (16 slots, 25 heads of 64, 16-token pages).
 Nothing runs, so nothing here says anything about results or times.
 
 The topology is described inside a fixture (never at import: only one
@@ -26,7 +25,7 @@ import re
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from tpunet.ops import depthwise_conv3x3, fused_ir, paged_decode
+from tpunet.ops import paged_decode
 from tpunet.ops.flash import flash_attention
 
 
@@ -153,43 +152,6 @@ def test_segmented_flash_attention_compiles(one_chip, no_compile_cache):
 
     _compile(fwd, *[((4, 2048, 16, 128), BF16)] * 3,
              ((4, 2048), jnp.int32), sharding=one_chip)
-
-
-# The 224px inverted-residual 1x1 convs at batch 128: three expands
-# (ReLU6) and a project (linear).
-@pytest.mark.parametrize("hw,ci,co", [(112, 16, 96), (56, 24, 144),
-                                      (28, 32, 192), (56, 144, 24)])
-def test_conv1x1_bn_act_compiles(one_chip, no_compile_cache, hw, ci, co):
-    def fwd_bwd(x, w, scale, bias):
-        def loss(x, w, scale, bias):
-            out, _, _ = fused_ir.conv1x1_bn_act(
-                x, w, scale, bias, ci < co, 1e-5, interpret=False)
-            return jnp.sum(out.astype(jnp.float32))
-        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(
-            x, w, scale, bias)
-
-    text = _compile(fwd_bwd, ((128, hw, hw, ci), BF16), ((ci, co), BF16),
-                    ((co,), jnp.float32), ((co,), jnp.float32),
-                    sharding=one_chip)
-    assert text.count("tpu_custom_call") >= 2      # forward + backward
-
-
-# Stride 2 in bf16 is the case Mosaic refused on the chip until the
-# backward dilated in float32.
-@pytest.mark.parametrize("stride", [1, 2])
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_depthwise_conv3x3_compiles(one_chip, no_compile_cache, stride,
-                                    direction):
-    def fwd(x, w):
-        return depthwise_conv3x3(x, w, stride, False)
-
-    def bwd(x, w):
-        return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
-                        argnums=(0, 1))(x, w)
-
-    _compile(fwd if direction == "fwd" else bwd,
-             ((128, 112, 112, 96), BF16), ((3, 3, 96), BF16),
-             sharding=one_chip)
 
 
 # The serve cell's decode geometry (benchmark/configs/gpt2-xl.json,

@@ -7,7 +7,7 @@ import sys
 
 import pytest
 
-from tpunet.config import config_from_args
+from tpunet.config import ModelConfig, config_from_args
 
 REPO = os.path.dirname(os.path.dirname(__file__))
 
@@ -145,3 +145,26 @@ def test_eval_only_evaluates_best_checkpoint(tmp_path):
             empty.evaluate_checkpoint()
     finally:
         empty.close()
+
+
+# MobileNetV2 has one train path since PR 45: a launch script or a
+# configuration that still names one of its four removed levers fails
+# loudly, it does not run another program.
+REMOVED_LEVERS = {
+    "--pallas-depthwise": "use_pallas_depthwise",
+    "--no-pallas-depthwise": "use_pallas_depthwise",
+    "--fused-bn": "fused_bn", "--no-fused-bn": "fused_bn",
+    "--fused-ir": "fused_ir", "--no-fused-ir": "fused_ir",
+    "--block-remat": "block_remat", "--no-block-remat": "block_remat",
+}
+
+
+@pytest.mark.parametrize("spelling", list(REMOVED_LEVERS))
+def test_removed_lever_is_refused(spelling, capsys):
+    with pytest.raises(SystemExit) as ei:
+        config_from_args(["--preset", "serial", spelling])
+    assert ei.value.code == 2
+    assert f"unrecognized arguments: {spelling}" in capsys.readouterr().err
+    field = REMOVED_LEVERS[spelling]
+    with pytest.raises(TypeError, match=field):
+        ModelConfig(**{field: not spelling.startswith("--no-")})

@@ -245,36 +245,6 @@ def test_budget_vs_latest_bench_artifact():
                 (kind, entry["xla_bytes_accessed_per_image"], latest_total)
 
 
-def test_bench_model_overrides_last_flag_wins():
-    """Repeated lever flags resolve last-wins in argv order, matching
-    the train CLI's argparse BooleanOptionalAction — a sweep script
-    appending an override to a base command gets the appended state."""
-    import bench
-    assert bench._model_overrides(["--no-fused-ir", "--fused-ir"]) == \
-        {"fused_ir": True}
-    assert bench._model_overrides(["--fused-ir", "--no-fused-ir"]) == \
-        {"fused_ir": False}
-    assert bench._model_overrides(["--peak-only"]) == {}
-    assert bench._model_overrides(["--block-remat", "--no-fused-bn"]) == \
-        {"block_remat": True, "fused_bn": False}
-
-
-def test_bench_enforce_budget_refuses_lever_overrides(monkeypatch,
-                                                      capsys):
-    """--enforce-budget gates the default tree; combined with a lever
-    override it would gate a deliberately non-default configuration
-    against the default budget (false REGRESSION) — bench refuses
-    loudly with exit 2 instead."""
-    import bench
-    monkeypatch.setattr(sys, "argv",
-                        ["bench.py", "--peak-only", "--no-fused-ir",
-                         "--enforce-budget"])
-    with pytest.raises(SystemExit) as ei:
-        bench.main()
-    assert ei.value.code == 2
-    assert "refusing with lever overrides" in capsys.readouterr().err
-
-
 # ------------------------------------------------------------- end-to-end
 
 @pytest.mark.slow

@@ -41,7 +41,6 @@ TARGET_CLASSES: Tuple[str, ...] = ("ObsConfig", "ModelConfig",
 _FLAG_ALIASES: Dict[str, str] = {
     "ModelConfig.name": "--model",
     "ModelConfig.pretrained_path": "--pretrained",
-    "ModelConfig.use_pallas_depthwise": "--pallas-depthwise",
     "ObsConfig.enabled": "--no-obs",
     "ObsConfig.step_records_every": "--obs-step-every",
     "ObsConfig.hbm_attrib": "--obs-hbm-attrib",

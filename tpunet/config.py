@@ -186,52 +186,6 @@ class ModelConfig:
     # weights to convert (transfer learning is load-bearing for the ~96%
     # accuracy target — reference README.md:24-26).
     pretrained_path: Optional[str] = None
-    # Route 3x3 depthwise convs through the Pallas kernel (tpunet/ops/).
-    # Off by default: the one chip reading (round 4, a v5e, a stack
-    # before PR 1) had the kernel ~2.8x SLOWER end-to-end than XLA's
-    # conv emitter; PR 44's session did not run it again (it is
-    # bit-exact and SPMD-partitioned — kept as the worked TPU-kernel
-    # example and for experimentation). Only takes effect on a TPU
-    # backend; parameter trees are identical either way, so the flag
-    # can be flipped on existing checkpoints.
-    use_pallas_depthwise: bool = False
-    # MobileNetV2 HBM-traffic levers (tpunet/models/mobilenetv2.py;
-    # the step is bandwidth-bound at ~5% MFU). Both defaults are what
-    # one TPU v5 lite read IN THE STEP on 2026-10-03 (the benchmark's
-    # mnv2-224.train-b128 cell, batch 128; PERF.md section 6 "PR 44"):
-    #   both ON, pair engaged at Ci < H*W   1,764 img/s  fwd_bwd 71.67 ms
-    #   both ON, pair engaged nowhere       4,848 img/s  fwd_bwd 25.36 ms
-    #   both OFF (nn.BatchNorm)             6,270 img/s  fwd_bwd 19.11 ms
-    # fused_bn (default OFF since that A/B): conv -> BN -> ReLU6
-    # epilogue as FusedBNAct's jax.numpy (single-pass batch stats,
-    # per-channel FMA + clamp, bf16 residency) instead of nn.BatchNorm
-    # + separate clamp. The compiler fuses nn.BatchNorm's statistics
-    # and epilogue INTO its convolutions (11.3 of the 19.9 ms step are
-    # convolution fusions); FusedBNAct's float32 detour keeps them out
-    # as passes of their own (19.0 of 25.8 ms are elementwise fusions).
-    # Same variable tree, so flippable on checkpoints (--fused-bn).
-    fused_bn: bool = False
-    # fused_ir (default OFF: it requires fused_bn): route the
-    # inverted-residual expand / project 1x1 convs through
-    # tpunet/ops/fused_ir.py — on the TPU the Pallas kernel pair
-    # wherever fused_ir._kernel_pays engages it, which since that A/B
-    # is NOWHERE (every resolution's pair lost to the compiler's
-    # convolution by 21-98% of the step: each call is bracketed by
-    # layout copies of whole activations), so the flag selects the XLA
-    # reference path, numerically the fused_bn path. Eval mode is
-    # always the plain path (bit-identical logits across the flag) and
-    # the variable tree is unchanged, so it flips freely on checkpoints
-    # (--fused-ir; TPUNET_FUSED_IR_REF=1 is the kernel's hatch). Flag,
-    # hatch and kernels wait for a simplicity issue (ROADMAP Queue 3
-    # item 1).
-    fused_ir: bool = False
-    # block_remat (default OFF): saved-residual policy for the
-    # inverted-residual blocks — keep only conv outputs + (C,)-sized
-    # BN stats as residuals and recompute the elementwise epilogues in
-    # the backward replay (jax.checkpoint save_only_these_names).
-    # No chip has read it either way (PERF.md section 6, PR 44: not
-    # run); ROADMAP Queue 1 item 1 keeps the A/B.
-    block_remat: bool = False
 
 
 @dataclass(frozen=True)
@@ -1006,32 +960,6 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("--cutmix", type=float, default=None, metavar="ALPHA",
                    help="CutMix Beta(a,a) strength; with --mixup, each "
                         "step picks one at random")
-    p.add_argument("--pallas-depthwise", default=None,
-                   action=argparse.BooleanOptionalAction,
-                   help="route 3x3 depthwise convs through the Pallas "
-                        "kernel (default off: slower than XLA's conv "
-                        "emitter on v5e, kept for experimentation)")
-    p.add_argument("--fused-bn", default=None,
-                   action=argparse.BooleanOptionalAction,
-                   help="MobileNetV2: conv->BN->ReLU6 epilogue as one "
-                        "fusable region (default off since the v5e "
-                        "A/B: nn.BatchNorm + separate clamp is 29%% "
-                        "faster in the step; same parameters)")
-    p.add_argument("--fused-ir", default=None,
-                   action=argparse.BooleanOptionalAction,
-                   help="MobileNetV2: fused 1x1-conv + BN-stats Pallas "
-                        "kernel pair for the inverted-residual expand/"
-                        "project convs (default off: needs --fused-bn, "
-                        "and no shape engages on the v5e, so it is "
-                        "numerically --fused-bn; same parameters)")
-    p.add_argument("--block-remat", default=None,
-                   action=argparse.BooleanOptionalAction,
-                   help="MobileNetV2: recompute inverted-residual "
-                        "elementwise epilogues in backward, saving "
-                        "only conv outputs + BN stats as residuals "
-                        "(default off: measured as MORE bytes accessed "
-                        "on the CPU backend; compare per backend via "
-                        "bench.py's bytes_per_image_breakdown)")
     return p
 
 
@@ -1152,15 +1080,6 @@ def config_from_args(argv=None) -> TrainConfig:
             model = dataclasses.replace(model, **{name: val})
     if args.width_mult is not None:
         model = dataclasses.replace(model, width_mult=args.width_mult)
-    if args.pallas_depthwise is not None:
-        model = dataclasses.replace(model,
-                                    use_pallas_depthwise=args.pallas_depthwise)
-    if args.fused_bn is not None:
-        model = dataclasses.replace(model, fused_bn=args.fused_bn)
-    if args.fused_ir is not None:
-        model = dataclasses.replace(model, fused_ir=args.fused_ir)
-    if args.block_remat is not None:
-        model = dataclasses.replace(model, block_remat=args.block_remat)
     if args.dtype is not None:
         model = dataclasses.replace(model, dtype=args.dtype)
     if args.lr is not None:

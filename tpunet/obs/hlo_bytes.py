@@ -104,8 +104,6 @@ CATEGORIES = ("conv_fwd", "conv_bwd", "matmul", "bn", "augment",
 # Kernel scope prefix -> (forward category, backward category). The
 # scope in the code must be exactly ``<prefix>_fwd`` / ``<prefix>_bwd``.
 KERNEL_SCOPES: Dict[str, Tuple[str, str]] = {
-    "tpunet_fused_ir": ("conv_fwd", "conv_bwd"),
-    "tpunet_depthwise": ("conv_fwd", "conv_bwd"),
     # Flash attention is MXU matmul work; without the marker its
     # custom calls land in ``elementwise`` and its custom_vjp backward
     # (no ``transpose(`` scope) would misattribute to the fwd phase.
@@ -364,7 +362,7 @@ def _common_scope(paths: List[str]) -> str:
 
 def op_scopes(hlo_text: str) -> Dict[str, str]:
     """{instruction name: op_name path} for the instructions a device
-    trace shows (``fusion.59``, ``tpunet_fused_ir_bwd.38``,
+    trace shows (``fusion.59``, ``tpunet_flash_bwd.38``,
     ``copy.467``): ENTRY and the bodies it calls (while / conditional
     / call), never the inside of a fusion. A fusion takes its own
     ``metadata={op_name=...}``; where that is empty, the longest common

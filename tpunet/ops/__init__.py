@@ -1,39 +1,28 @@
 """TPU kernels and attention ops for tpunet's hot paths.
 
-Two families live here:
-
-- ``depthwise``: Pallas TPU kernel for the 3x3 depthwise convolution —
-  the VPU-bound hot op of MobileNetV2 (9 multiply-adds per output
-  element with no contraction to feed the MXU). Honest measurement:
-  XLA's fused conv pipeline beats it end-to-end, so it is off by
-  default and kept as the worked VPU-kernel example.
 - ``attention``: dense / blockwise / ring / Ulysses attention. Ring
   (K/V shards rotate over a mesh axis via ppermute with online-softmax
   accumulation) and Ulysses (all-to-all head resharding around a
   blockwise core) are the sequence-parallel primitives backing
   long-context support in the attention-based model families.
-- ``flash``: Pallas TPU flash-attention kernel — the fused MXU form of
-  the same online-softmax math (scores never leave VMEM).
-- ``fused_ir``: Pallas kernel pair for the inverted-residual 1x1 convs
-  (expand/project): one-pass conv + BN-stats forward and an IO-aware
-  backward that recomputes the elementwise epilogue in VMEM — the
-  HBM-traffic lever behind ``ModelConfig.fused_ir``.
+- ``flash``: Pallas TPU flash-attention kernels — the fused MXU form of
+  the same online-softmax math (scores never leave VMEM), forward and
+  backward, and the grouped / windowed prefill the serve engine calls.
+- ``paged_decode``: Pallas TPU kernel for the engine's width-1 decode
+  step over a paged K/V pool.
+- ``partition``: splits a kernel call over a mesh with ``shard_map``.
+- ``vocab_ce``: cross-entropy over a vocabulary sharded on the model
+  axis.
 """
 
 from tpunet.ops.attention import (blockwise_attention, dense_attention,
                                   ring_attention, ring_self_attention,
                                   ulysses_attention, ulysses_self_attention)
-from tpunet.ops.depthwise import depthwise_conv3x3, depthwise_conv3x3_reference
 from tpunet.ops.flash import flash_attention
-from tpunet.ops.fused_ir import conv1x1_bn_act, conv1x1_bn_act_reference
 
 __all__ = [
     "blockwise_attention",
-    "conv1x1_bn_act",
-    "conv1x1_bn_act_reference",
     "dense_attention",
-    "depthwise_conv3x3",
-    "depthwise_conv3x3_reference",
     "flash_attention",
     "ring_attention",
     "ring_self_attention",

@@ -53,7 +53,7 @@ Design notes:
   probability drops them). Without a window the program is what it was.
 - Off-TPU the caller keeps the dense path (the Pallas interpreter is
   far too slow for an engine step); tests drive this kernel body on the
-  CPU with ``interpret=True``, the scheme of flash.py and fused_ir.py.
+  CPU with ``interpret=True``, the scheme of flash.py.
 """
 
 from __future__ import annotations
